@@ -14,14 +14,14 @@ from __future__ import annotations
 import os as _os
 
 # Multi-process rendezvous must happen before ANY backend-initializing jax
-# call (jax.distributed.initialize's own requirement), and importing this
-# package touches the backend — so when the launch CLI has wired the env
-# (reference launch/controllers/collective.py), connect right here.
+# call (jax.distributed.initialize's own requirement), and code that
+# imports this package goes on to touch the backend — so when the launch
+# CLI has wired the env (reference launch/controllers/collective.py),
+# connect right here.
 if int(_os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1 and (
         _os.environ.get("PADDLE_MASTER")):
     import jax as _jax
-    from jax._src import distributed as _jd
-    if _jd.global_state.client is None:  # raw-jax workers may have connected
+    if not _jax.distributed.is_initialized():  # raw-jax workers may have
         _jax.distributed.initialize(
             coordinator_address=_os.environ["PADDLE_MASTER"],
             num_processes=int(_os.environ["PADDLE_TRAINERS_NUM"]),

@@ -147,10 +147,8 @@ class RowSequenceParallelLinear(nn.Layer):
 
             from ...core.dispatch import apply_op
 
-            from ...utils.compat import shard_map
-
             def f(xr, wr):
-                out = shard_map(
+                out = jax.shard_map(
                     local, mesh=mesh,
                     in_specs=(P(None, None, "mp"), P("mp", None)),
                     out_specs=P(None, "mp", None),

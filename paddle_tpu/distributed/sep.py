@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["ring_attention", "ulysses_attention", "sep_attention",
-           "ring_attention_local"]
+           "ring_attention_local", "mesh_flash_attention"]
 
 _NEG_INF = -1e30
 
@@ -107,9 +107,8 @@ def ring_attention(q, k, v, causal: bool = True, axis_name: str = "sep",
     spec = _seq_spec(axis_name)
     fn = functools.partial(ring_attention_local, axis_name=axis_name,
                            n_shards=n, causal=causal)
-    from ..utils.compat import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, axis_names={axis_name})(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names={axis_name})(q, k, v)
 
 
 def ulysses_attention(q, k, v, causal: bool = True, axis_name: str = "sep",
@@ -141,10 +140,29 @@ def ulysses_attention(q, k, v, causal: bool = True, axis_name: str = "sep",
                               tiled=True)
 
     # check_vma off: pallas_call inside shard_map can't express output vma
-    from ..utils.compat import shard_map
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, axis_names={axis_name},
-                     check_vma=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names={axis_name},
+                         check_vma=False)(q, k, v)
+
+
+def mesh_flash_attention(q, k, v, causal: bool = True, mesh=None):
+    """The flash kernel under a GSPMD mesh (no sep axis in play). The
+    compiler refuses to split a Pallas kernel by itself ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), so run it per shard of the layout the model's hints on
+    q/k/v already ask for: batch over dp, heads — query and kv alike —
+    over mp. A mesh with neither axis > 1 runs the kernel as it is."""
+    from ..kernels.flash_attention import flash_attention_fwd
+    from .fleet.mp_layers import _filter_spec
+    fn = functools.partial(flash_attention_fwd, causal=causal)
+    spec = _filter_spec(("dp", None, "mp", None), mesh)
+    axes = {a for a in spec if a is not None}
+    if not axes:
+        return fn(q, k, v)
+    # check_vma off: pallas_call inside shard_map can't express output vma
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=axes,
+                         check_vma=False)(q, k, v)
 
 
 def sep_attention(q, k, v, causal: bool = True, axis_name: str = "sep",

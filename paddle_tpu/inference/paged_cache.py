@@ -2,7 +2,7 @@
 continuous-batching DecodeEngine; reference shape: vLLM's BlockAllocator
 behind "Ragged Paged Attention", arxiv 2604.15464).
 
-The device side is a ``[L, n_blocks, block_size, kvh, hd]`` pool plus a
+The device side is a ``[L, n_blocks, kvh, block_size, hd]`` pool plus a
 per-row int32 block table; this module owns the HOST side: a free-list
 of page ids. Page 0 is the reserved NULL page (kernels/paged_attention
 NULL_PAGE): padded table entries and inactive rows read/write it, so

@@ -73,7 +73,7 @@ class DecodeEngine:
 
     Default mode is PAGED (``paged=True``; reference shape: "Ragged
     Paged Attention", arxiv 2604.15464 / vLLM's PagedAttention): the KV
-    cache is a ``[L, n_blocks, block_size, kvh, hd]`` block pool with a
+    cache is a ``[L, n_blocks, kvh, block_size, hd]`` block pool with a
     per-row block table and a host-side free-list
     (:class:`~paddle_tpu.inference.paged_cache.BlockAllocator`). Rows
     own ragged per-row lengths starting at their own position 0 —
@@ -622,7 +622,6 @@ class DecodeEngine:
             from jax.sharding import NamedSharding as _NS
             from jax.sharding import PartitionSpec as _P
 
-            from ..utils.compat import shard_map as _shard_map
             from .sharding import (pool_specs, quant_scale_specs,
                                    stacked_weight_specs)
             _R = _P()
@@ -636,13 +635,13 @@ class DecodeEngine:
                 sharded program with replicated outputs. A ``P()``
                 prefix covers the tied-embedding case (lm=None has no
                 leaves to place)."""
-                return _shard_map(
+                return jax.shard_map(
                     fn, mesh=self.mesh,
                     in_specs=(wsp, _R, _R, _R, ssp,
                               *([_R] * n_data), *psp),
                     out_specs=(_R, *psp))
 
-            cow_wrapped = _shard_map(
+            cow_wrapped = jax.shard_map(
                 cow_copy_seq if sq is not None else cow_copy,
                 mesh=self.mesh, in_specs=(_R, _R, *psp),
                 out_specs=psp)
@@ -767,9 +766,9 @@ class DecodeEngine:
             from .paged_cache import BlockAllocator
             from .prefix_cache import PrefixCache
             pool_dtype = jnp.int8 if self._kv_q else self._cache_dtype
-            self._kp = jnp.zeros((self._L, self.n_blocks,
-                                  self.block_size, self._kvh,
-                                  self._hd), pool_dtype)
+            self._kp = jnp.zeros((self._L, self.n_blocks, self._kvh,
+                                  self.block_size, self._hd),
+                                 pool_dtype)
             self._vp = jnp.zeros_like(self._kp)
             if self._kv_q:
                 from ..kernels.paged_attention import KV_SCALE_EPS

@@ -7,8 +7,8 @@ manual-coded in ``models/llama.py``).
 Design (SURVEY §7.17 for tp, §7.22 for the 2-D mesh):
 
 - What SHARDS over ``tp``: the paged KV block pools
-  ``[L, N, bs, kvh, hd]`` carry a ``PartitionSpec`` over the kv-head
-  axis (axis 3), the int8 page scales ``[L, N, kvh]`` shard alongside
+  ``[L, N, kvh, bs, hd]`` carry a ``PartitionSpec`` over the kv-head
+  axis (axis 2), the int8 page scales ``[L, N, kvh]`` shard alongside
   on their kvh axis, and the attention/MLP weights shard column/row
   Megatron-style (head and ff columns split, ``wo``/``w_down`` rows
   split and psum-finished inside the program). Embedding, norms,
@@ -30,10 +30,9 @@ Design (SURVEY §7.17 for tp, §7.22 for the 2-D mesh):
   keeping the per-shard strided gather dense; it still holds no tensor
   data and needs no coherence protocol.
 
-The programs themselves lower through ``jit`` + ``shard_map`` (via
-``utils.compat.shard_map``, which maps to the experimental shard_map on
-older jax); this module only builds meshes and the PartitionSpec
-pytrees the engine feeds those calls.
+The programs themselves lower through ``jit`` + ``jax.shard_map``; this
+module only builds meshes and the PartitionSpec pytrees the engine
+feeds those calls.
 """
 
 from __future__ import annotations
@@ -198,13 +197,13 @@ def same_pool_placement(mesh_a, mesh_b) -> bool:
 
 def pool_specs(n_pool, axis=TP_AXIS, seq_axis=None):
     """Specs for the paged-program pool tail: kp/vp
-    ``[L, N, bs, kvh, hd]`` shard their kv-head axis over ``axis`` and
+    ``[L, N, kvh, bs, hd]`` shard their kv-head axis over ``axis`` and
     — when ``seq_axis`` is given — their page axis over ``seq_axis``;
     the int8 page scales ``[L, N, kvh]`` shard alongside (a page's
     scale lives with its codes — no cross-device scale lookup on the
     write path). ``seq_axis=None`` yields the exact r15 specs
     (``P(None, None, ...)`` — an axis entry of None IS unsharded)."""
-    kv = P(None, seq_axis, None, axis, None)
+    kv = P(None, seq_axis, axis, None, None)
     if n_pool == 4:
         sc = P(None, seq_axis, axis)
         return (kv, kv, sc, sc)

@@ -29,12 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is unavailable on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # noqa: BLE001
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_fwd", "flash_attention"]
 
@@ -396,7 +391,7 @@ def _flash_fwd_impl(q, k, v, causal, interpret=False, with_lse=False):
     vf = jnp.swapaxes(v, 1, 2).reshape(B * Hkv, S, D)
     bq, bk, stream = _choose_blocks(S, D, q.dtype)
 
-    if stream and _HAS_PLTPU:
+    if stream:
         bqg = _grouped_bq_stream(G, D, bq, bk, q.dtype) if G > 1 else None
         if bqg is not None:
             # grouped streaming launch (r5): the grouped fwd no longer
@@ -568,6 +563,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
+def _group_col(x):
+    """[G, BQ] row statistics (lse / delta) -> the [G*BQ, 1] column the
+    grouped tiles broadcast against. Built head by head: the chip's
+    compiler refuses the flat ``[G, BQ] -> [G*BQ]`` shape cast
+    (``infer-vector-layout: unsupported shape cast``), while the per-head
+    ``[BQ] -> [BQ, 1]`` relayout is the one the ungrouped kernels
+    already use."""
+    return jnp.concatenate([x[i][:, None] for i in range(x.shape[0])],
+                           axis=0)
+
+
 def _dq_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dq_ref, *, bq, bk, seq_len, causal, scale):
     """GQA-grouped dQ (r5, VERDICT r4 #3): one program owns the whole
@@ -580,8 +586,8 @@ def _dq_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     rows = g * bq
     q2 = q.reshape(rows, d)
     do2 = do_ref[0].reshape(rows, d)
-    lse = lse_ref[0].reshape(rows)                   # [G*BQ] f32
-    delta = delta_ref[0].reshape(rows)
+    lse = _group_col(lse_ref[0])                     # [G*BQ, 1] f32
+    delta = _group_col(delta_ref[0])
 
     n_kblocks = seq_len // bk
     if causal:
@@ -604,11 +610,11 @@ def _dq_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_ids = j * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bk), 1)
             s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do2, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32,
                                  precision=jax.lax.Precision.DEFAULT)
-        ds = (p * (dp - delta[:, None])).astype(k.dtype)
+        ds = (p * (dp - delta)).astype(k.dtype)
         return dq + scale * jnp.dot(ds, k,
                                     preferred_element_type=jnp.float32,
                                     precision=jax.lax.Precision.DEFAULT)
@@ -643,8 +649,8 @@ def _dkv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = carry
         q = q_ref[0, :, pl.ds(j * bq, bq), :].reshape(rows, d)
         do = do_ref[0, :, pl.ds(j * bq, bq), :].reshape(rows, d)
-        lse = lse_ref[0, :, pl.ds(j * bq, bq)].reshape(rows)
-        delta = delta_ref[0, :, pl.ds(j * bq, bq)].reshape(rows)
+        lse = _group_col(lse_ref[0, :, pl.ds(j * bq, bq)])
+        delta = _group_col(delta_ref[0, :, pl.ds(j * bq, bq)])
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -653,7 +659,7 @@ def _dkv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_ids = j * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bk), 0) % bq
             s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None]).astype(do.dtype)
+        p = jnp.exp(s - lse).astype(do.dtype)
         dv = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -661,7 +667,7 @@ def _dkv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32,
                                  precision=jax.lax.Precision.DEFAULT)
-        ds = (p.astype(jnp.float32) * (dp - delta[:, None])
+        ds = (p.astype(jnp.float32) * (dp - delta)
               ).astype(q.dtype)
         dk = dk + scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
@@ -793,8 +799,8 @@ def _dq_kernel_stream_grouped(q_ref, k_hbm, v_hbm, do_ref, lse_ref,
     rows = g * bq
     q2 = q.reshape(rows, d)
     do2 = do_ref[0].reshape(rows, d)
-    lse = lse_ref[0].reshape(rows)
-    delta = delta_ref[0].reshape(rows)
+    lse = _group_col(lse_ref[0])
+    delta = _group_col(delta_ref[0])
 
     def kdma(slot, j):
         return pltpu.make_async_copy(
@@ -840,11 +846,11 @@ def _dq_kernel_stream_grouped(q_ref, k_hbm, v_hbm, do_ref, lse_ref,
             k_ids = j * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bk), 1)
             s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do2, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32,
                                  precision=jax.lax.Precision.DEFAULT)
-        ds = (p * (dp - delta[:, None])).astype(k.dtype)
+        ds = (p * (dp - delta)).astype(k.dtype)
         return dq + scale * jnp.dot(ds, k,
                                     preferred_element_type=jnp.float32,
                                     precision=jax.lax.Precision.DEFAULT)
@@ -903,8 +909,8 @@ def _dkv_kernel_stream_grouped(q_hbm, k_ref, v_ref, do_hbm, lse_ref,
         dodma(slot, j).wait()
         q = q_s[slot].reshape(rows, d)
         do = do_s[slot].reshape(rows, d)
-        lse = lse_ref[0, :, pl.ds(j * bq, bq)].reshape(rows)
-        delta = delta_ref[0, :, pl.ds(j * bq, bq)].reshape(rows)
+        lse = _group_col(lse_ref[0, :, pl.ds(j * bq, bq)])
+        delta = _group_col(delta_ref[0, :, pl.ds(j * bq, bq)])
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -913,7 +919,7 @@ def _dkv_kernel_stream_grouped(q_hbm, k_ref, v_ref, do_hbm, lse_ref,
             q_ids = j * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bk), 0) % bq
             s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None]).astype(do.dtype)
+        p = jnp.exp(s - lse).astype(do.dtype)
         dv = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -921,7 +927,7 @@ def _dkv_kernel_stream_grouped(q_hbm, k_ref, v_ref, do_hbm, lse_ref,
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32,
                                  precision=jax.lax.Precision.DEFAULT)
-        ds = (p.astype(jnp.float32) * (dp - delta[:, None])
+        ds = (p.astype(jnp.float32) * (dp - delta)
               ).astype(q.dtype)
         dk = dk + scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
@@ -1092,7 +1098,6 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, interpret=False,
         # because d lse_i / d s_ij = p_ij (see flash_attention_with_lse)
         delta = delta - g_lse
     bq, bk, stream = _choose_blocks(S, D, q.dtype)
-    stream = stream and _HAS_PLTPU
 
     if stream:
         dq_kernel = functools.partial(

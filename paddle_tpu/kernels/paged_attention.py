@@ -1,7 +1,7 @@
 """Pallas ragged paged-attention decode kernel (TPU).
 
 The serving decode path's KV cache becomes a BLOCK POOL
-``[n_blocks, block_size, kvh, hd]`` with a per-row block table instead
+``[n_blocks, kvh, block_size, hd]`` with a per-row block table instead
 of one contiguous right-aligned region (reference shape: "Ragged Paged
 Attention", arxiv 2604.15464 — the TPU-native kernel form of
 vLLM/PagedAttention). Rows own ragged per-row lengths; the kernel
@@ -10,9 +10,12 @@ needs a global fill position and the DecodeEngine never resets.
 
 Design (single-query decode, one token per row):
 - q: [B, kvh, G, hd] (grouped query heads for the token being decoded)
-- k_pages/v_pages: [N, bs, kvh, hd] block pool; page 0 is the reserved
-  NULL page (allocators never hand it out; padded table entries and
-  inactive rows write there, so fixed-shape programs need no masks)
+- k_pages/v_pages: [N, kvh, bs, hd] block pool — the kv head sits
+  AHEAD of the ``[bs, hd]`` tile, so one (page, head) is one contiguous,
+  tile-aligned DMA (the chip's tiler refuses a copy that takes one head
+  out of the second-minor axis). Page 0 is the reserved NULL page
+  (allocators never hand it out; padded table entries and inactive rows
+  write there, so fixed-shape programs need no masks)
 - block_table: [B, max_blocks] int32 page ids (data argument — shapes
   stay fixed, so the two-compiled-programs serving discipline holds)
 - seq_lens: [B] int32 valid tokens per row (ragged lengths)
@@ -23,9 +26,12 @@ Design (single-query decode, one token per row):
   applied through one level of indirection
 - online softmax (f32 m/l/acc) over the row's ceil(len/bs) blocks; the
   ragged tail masks positions >= seq_len
-- interpret-mode CPU fallback exactly like flash_attention.py: the DMA
-  and scalar prefetch execute faithfully under ``interpret=True``, so
-  CI proves the math without a TPU
+- every dot pins ``precision=DEFAULT`` like flash_attention.py: the
+  process-wide ``jax_default_matmul_precision="high"`` (flags.py) is a
+  precision the kernel lowering refuses
+- interpret mode on CPU exactly like flash_attention.py: the DMA and
+  scalar prefetch execute faithfully under ``interpret=True``, so CI
+  proves the math without a TPU
 
 The XLA fallback (`_paged_attn_reference`) gathers the row's pages into
 a contiguous view and runs the same masked softmax math as
@@ -63,12 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is unavailable on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # noqa: BLE001
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_decode_attention", "paged_attention_pallas",
            "mixed_paged_attention", "mixed_attention_pallas",
@@ -106,11 +107,11 @@ def _paged_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref, k_s, v_s,
 
     def kdma(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[tables[b, j], :, h, :], k_s.at[slot], ksem.at[slot])
+            k_hbm.at[tables[b, j], h], k_s.at[slot], ksem.at[slot])
 
     def vdma(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[tables[b, j], :, h, :], v_s.at[slot], vsem.at[slot])
+            v_hbm.at[tables[b, j], h], v_s.at[slot], vsem.at[slot])
 
     m0 = jnp.full((g,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((g,), jnp.float32)
@@ -137,7 +138,8 @@ def _paged_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref, k_s, v_s,
         v = v_s[slot]
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [G, bs]
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT) * scale   # [G, bs]
         # ragged tail: positions at or past the row's length are invalid
         k_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
         s = jnp.where(k_ids < n, s, _NEG_INF)
@@ -148,7 +150,8 @@ def _paged_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref, k_s, v_s,
         l = l * alpha + p.sum(axis=-1)
         acc = acc * alpha[:, None] + jnp.dot(
             p, v.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
@@ -171,7 +174,8 @@ def _int8_block_update(q, kc, vc, ks, vs, m, l, acc, k_ids, n,
     v = vc.astype(jnp.float32) * vs
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale      # [G, bs]
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT) * sm_scale     # [G, bs]
     s = jnp.where(k_ids < n, s, _NEG_INF)
     m_new = jnp.maximum(m, s.max(axis=-1))
     p = jnp.exp(s - m_new[:, None])
@@ -179,17 +183,27 @@ def _int8_block_update(q, kc, vc, ks, vs, m, l, acc, k_ids, n,
     alpha = jnp.exp(m - m_new)
     l = l * alpha + p.sum(axis=-1)
     acc = acc * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT)
     return m_new, l, acc
 
 
-def _paged_kernel_int8(tables, lens, kscale, vscale, q_ref, k_hbm,
-                       v_hbm, o_ref, k_s, v_s, ksem, vsem, *, bs,
-                       scale):
+def _out_struct(shape, *operands):
+    """f32 output of a launch. Inside a ``shard_map`` region (the tp
+    engine) the output varies over every mesh axis an operand varies
+    over, and ``pallas_call`` has to be told so."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
+
+
+def _paged_kernel_int8(tables, lens, q_ref, ks_ref, vs_ref, k_hbm,
+                       v_hbm, o_ref, k_s, v_s, ksem, vsem, *, bs, scale):
     """int8 twin of :func:`_paged_kernel`: identical DMA structure, but
-    the streamed pages are int8 codes dequantized inside the program —
-    the scale arrays ride the scalar-prefetch lane beside the block
-    table, one f32 per (page, kv head)."""
+    the streamed pages are int8 codes dequantized inside the program.
+    ``ks_ref``/``vs_ref`` are this (row, kv head)'s page scales in table
+    order ([1, 1, 1, max_blocks] f32, an SMEM block) — gathered through
+    the block table by the launch, so scalar memory holds one row's
+    scales and never the pool's."""
     b = pl.program_id(0)
     h = pl.program_id(1)
     q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
@@ -200,11 +214,11 @@ def _paged_kernel_int8(tables, lens, kscale, vscale, q_ref, k_hbm,
 
     def kdma(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[tables[b, j], :, h, :], k_s.at[slot], ksem.at[slot])
+            k_hbm.at[tables[b, j], h], k_s.at[slot], ksem.at[slot])
 
     def vdma(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[tables[b, j], :, h, :], v_s.at[slot], vsem.at[slot])
+            v_hbm.at[tables[b, j], h], v_s.at[slot], vsem.at[slot])
 
     m0 = jnp.full((g,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((g,), jnp.float32)
@@ -229,8 +243,8 @@ def _paged_kernel_int8(tables, lens, kscale, vscale, q_ref, k_hbm,
         vdma(slot, j).wait()
         k_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
         return _int8_block_update(
-            q, k_s[slot], v_s[slot], kscale[tables[b, j], h],
-            vscale[tables[b, j], h], m, l, acc, k_ids, n, scale)
+            q, k_s[slot], v_s[slot], ks_ref[0, 0, 0, j],
+            vs_ref[0, 0, 0, j], m, l, acc, k_ids, n, scale)
 
     m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(
@@ -239,7 +253,7 @@ def _paged_kernel_int8(tables, lens, kscale, vscale, q_ref, k_hbm,
 
 def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
                            interpret=False, kv_scales=None):
-    """Raw Pallas launch. q [B, kvh, G, hd]; k/v_pages [N, bs, kvh, hd];
+    """Raw Pallas launch. q [B, kvh, G, hd]; k/v_pages [N, kvh, bs, hd];
     block_table [B, max_blocks] int32; seq_lens [B] int32. Returns
     [B, kvh, G, hd] f32. ``kv_scales=(kscale, vscale)`` ([N, kvh] f32
     each) switches to the int8 kernel: the pools hold int8 codes,
@@ -249,7 +263,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
             q, k_pages, v_pages, block_table, seq_lens, kv_scales,
             interpret=interpret)
     B, kvh, G, hd = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     scale = 1.0 / (hd ** 0.5)
     kernel = functools.partial(_paged_kernel, bs=bs, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -257,8 +271,8 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
         grid=(B, kvh),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, *_: (b, h, 0, 0)),
@@ -272,29 +286,47 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kvh, G, hd), jnp.float32),
+        out_shape=_out_struct((B, kvh, G, hd), q, k_pages, v_pages),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32), q, k_pages, v_pages)
 
 
+def _row_page_scales(scales, block_table):
+    """[N, kvh] page scales -> [B, kvh, 1, max_blocks], row b's scales
+    in block-table order: what one (row, kv head) program of the int8
+    kernel reads."""
+    rows = jnp.take(jnp.asarray(scales, jnp.float32), block_table,
+                    axis=0)                               # [B, mb, kvh]
+    return jnp.swapaxes(rows, 1, 2)[:, :, None, :]
+
+
 def _paged_attention_pallas_int8(q, k_pages, v_pages, block_table,
                                  seq_lens, kv_scales, interpret=False):
     """int8 launch: pools are int8 codes, ``kv_scales=(kscale, vscale)``
-    ([N, kvh] f32 each) ride the scalar-prefetch lane beside the block
-    table so every program can read its pages' scales from SMEM."""
+    ([N, kvh] f32 each). The scales a row needs are gathered through its
+    block table here and handed to each program as an SMEM block. (The
+    whole ``[N, kvh]`` arrays used to ride the scalar-prefetch lane; the
+    chip pads each row to 128 lanes there, so 4096 pages already asked
+    for 2 MiB of its 1 MiB of scalar memory.)"""
     kscale, vscale = kv_scales
     B, kvh, G, hd = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
+    block_table = jnp.asarray(block_table, jnp.int32)
+    mb = block_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
     kernel = functools.partial(_paged_kernel_int8, bs=bs, scale=scale)
+    sc_spec = pl.BlockSpec((1, 1, 1, mb), lambda b, h, *_: (b, h, 0, 0),
+                           memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=2,
         grid=(B, kvh),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            sc_spec,
+            sc_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, *_: (b, h, 0, 0)),
@@ -308,42 +340,46 @@ def _paged_attention_pallas_int8(q, k_pages, v_pages, block_table,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kvh, G, hd), jnp.float32),
+        out_shape=_out_struct((B, kvh, G, hd), q, k_pages, v_pages),
         interpret=interpret,
-    )(jnp.asarray(block_table, jnp.int32),
-      jnp.asarray(seq_lens, jnp.int32),
-      jnp.asarray(kscale, jnp.float32),
-      jnp.asarray(vscale, jnp.float32), q, k_pages, v_pages)
+    )(block_table, jnp.asarray(seq_lens, jnp.int32), q,
+      _row_page_scales(kscale, block_table),
+      _row_page_scales(vscale, block_table), k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
 # XLA reference / fallback
 # ---------------------------------------------------------------------------
 
+def _rows_view(g, B, mb):
+    """Gathered pages [B*mb, kvh, bs, hd] -> the contiguous per-row
+    token view [B, mb*bs, kvh, hd] the reference math reads."""
+    _, kvh, bs, hd = g.shape
+    g = jnp.swapaxes(g.reshape(B, mb, kvh, bs, hd), 2, 3)
+    return g.reshape(B, mb * bs, kvh, hd)
+
+
 def gather_pages(pages, block_table):
-    """[N, bs, kvh, hd] pool + [B, max_blocks] table -> contiguous
+    """[N, kvh, bs, hd] pool + [B, max_blocks] table -> contiguous
     per-row view [B, max_blocks*bs, kvh, hd] (padded tail reads the
     NULL page — masked out by seq_lens downstream)."""
     B, mb = block_table.shape
-    bs = pages.shape[1]
     g = jnp.take(pages, block_table.reshape(-1), axis=0)
-    return g.reshape(B, mb * bs, *pages.shape[2:])
+    return _rows_view(g, B, mb)
 
 
 def gather_pages_dequant(pages, block_table, scales):
     """int8 counterpart of :func:`gather_pages`: gather code pages AND
     their per-(page, kv head) scales, dequantize to f32. pages
-    [N, bs, kvh, hd] int8; scales [N, kvh] f32. Returns
+    [N, kvh, bs, hd] int8; scales [N, kvh] f32. Returns
     [B, max_blocks*bs, kvh, hd] f32 (NULL-page tail dequantizes with
     whatever scale page 0 carries — masked out by seq_lens downstream
     exactly like the fp gather)."""
     B, mb = block_table.shape
-    bs = pages.shape[1]
     flat = block_table.reshape(-1)
     g = jnp.take(pages, flat, axis=0).astype(jnp.float32)
     sc = jnp.take(scales, flat, axis=0)            # [B*mb, kvh]
-    g = g * sc[:, None, :, None]
-    return g.reshape(B, mb * bs, *pages.shape[2:])
+    return _rows_view(g * sc[:, :, None, None], B, mb)
 
 
 def _paged_attn_reference(q, k_pages, v_pages, block_table, seq_lens):
@@ -380,7 +416,7 @@ def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
     k_pages = jnp.asarray(k_pages)
     v_pages = jnp.asarray(v_pages)
     B, kvh, G, hd = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     scale = 1.0 / (hd ** 0.5)
     tables = jnp.asarray(block_table, jnp.int32)
     lens = jnp.asarray(seq_lens, jnp.int32)
@@ -396,9 +432,9 @@ def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
                 m, l, acc = carry
                 page = tables[b, j]
                 kc = jax.lax.dynamic_index_in_dim(
-                    k_pages, page, 0, keepdims=False)[:, h, :]
+                    k_pages, page, 0, keepdims=False)[h]
                 vc = jax.lax.dynamic_index_in_dim(
-                    v_pages, page, 0, keepdims=False)[:, h, :]
+                    v_pages, page, 0, keepdims=False)[h]
                 k_ids = j * bs + jax.lax.broadcasted_iota(
                     jnp.int32, (G, bs), 1)
                 return _int8_block_update(
@@ -415,35 +451,38 @@ def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
     return jnp.stack(rows)
 
 
+def _kernel_serves(pages):
+    """The ONE place the paged entries choose between the Pallas kernels
+    and the XLA references: the kernels on the TPU backend when a
+    (page, kv head) slab ``[bs, hd]`` is tile-aligned for the pool's
+    dtype, the references everywhere else (the CPU tests' oracle)."""
+    bs, hd = pages.shape[2], pages.shape[3]
+    min_bs = 32 if pages.dtype == jnp.int8 else 8
+    return (jax.default_backend() == "tpu" and hd % 128 == 0
+            and bs % min_bs == 0)
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
                            kv_scales=None, seq_axis=None, n_seq=1):
     """Entry used by the llama paged decode step: the Pallas kernel on
-    TPU when the block pool is tileable, else the XLA gather reference
-    (CPU tests pin the reference's bit-parity with the contiguous
-    path; the kernel's own parity is pinned in interpret mode).
-    ``kv_scales`` switches to the int8 path — the TPU gate tightens to
-    the int8 minimum tile (bs % 32, hd % 128). ``seq_axis`` (inside a
-    shard_map whose pools are page-sharded over that mesh axis into
-    ``n_seq`` stripes) switches to the partial-softmax form — each
-    shard attends over its local pages and the partials merge with one
-    collective (SURVEY §7.22)."""
+    TPU when the block pool is tileable (:func:`_kernel_serves`), else
+    the XLA gather reference (CPU tests pin the reference's bit-parity
+    with the contiguous path; the kernel's own parity is pinned in
+    interpret mode). ``kv_scales`` switches to the int8 path.
+    ``seq_axis`` (inside a shard_map whose pools are page-sharded over
+    that mesh axis into ``n_seq`` stripes) switches to the
+    partial-softmax form — each shard attends over its local pages and
+    the partials merge with one collective (SURVEY §7.22)."""
     if seq_axis is not None and n_seq > 1:
         return _paged_decode_attention_seq(
             q, k_pages, v_pages, block_table, seq_lens, seq_axis,
             n_seq, kv_scales=kv_scales)
-    bs, hd = k_pages.shape[1], k_pages.shape[3]
+    if _kernel_serves(k_pages):
+        return paged_attention_pallas(q, k_pages, v_pages, block_table,
+                                      seq_lens, kv_scales=kv_scales)
     if kv_scales is not None:
-        if (_HAS_PLTPU and jax.default_backend() == "tpu"
-                and hd % 128 == 0 and bs % 32 == 0):
-            return paged_attention_pallas(
-                q, k_pages, v_pages, block_table, seq_lens,
-                kv_scales=kv_scales)
         return _paged_attn_reference_int8(
             q, k_pages, v_pages, block_table, seq_lens, kv_scales)
-    if (_HAS_PLTPU and jax.default_backend() == "tpu"
-            and hd % 128 == 0 and bs % 8 == 0):
-        return paged_attention_pallas(q, k_pages, v_pages, block_table,
-                                      seq_lens)
     return _paged_attn_reference(q, k_pages, v_pages, block_table,
                                  seq_lens)
 
@@ -488,11 +527,11 @@ def _mixed_kernel(tables, kv_lens, q_lens, q_ref, k_hbm, v_hbm, o_ref,
 
     def kdma(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[tables[b, j], :, h, :], k_s.at[slot], ksem.at[slot])
+            k_hbm.at[tables[b, j], h], k_s.at[slot], ksem.at[slot])
 
     def vdma(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[tables[b, j], :, h, :], v_s.at[slot], vsem.at[slot])
+            v_hbm.at[tables[b, j], h], v_s.at[slot], vsem.at[slot])
 
     m0 = jnp.full((t * g,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((t * g,), jnp.float32)
@@ -519,7 +558,8 @@ def _mixed_kernel(tables, kv_lens, q_lens, q_ref, k_hbm, v_hbm, o_ref,
         v = v_s[slot]
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [t*g, bs]
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT) * scale   # [t*g, bs]
         k_ids = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (t * g, bs), 1)
         ok = (k_ids <= limit) & (k_ids < n)            # ragged + causal
@@ -531,7 +571,8 @@ def _mixed_kernel(tables, kv_lens, q_lens, q_ref, k_hbm, v_hbm, o_ref,
         l = l * alpha + p.sum(axis=-1)
         acc = acc * alpha[:, None] + jnp.dot(
             p, v.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
@@ -543,11 +584,11 @@ def mixed_attention_pallas(q, k_pages, v_pages, block_table, kv_lens,
                            q_lens, interpret=False):
     """Raw Pallas launch for a MIXED batch. q [B, T, kvh, G, hd] (T =
     padded query tokens per row; decode rows use q_lens=1); k/v_pages
-    [N, bs, kvh, hd]; block_table [B, max_blocks] int32; kv_lens [B]
+    [N, kvh, bs, hd]; block_table [B, max_blocks] int32; kv_lens [B]
     int32 resident tokens INCLUDING this launch's queries; q_lens [B]
     int32 valid query tokens. Returns [B, T, kvh, G, hd] f32."""
     B, T, kvh, G, hd = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     scale = 1.0 / (hd ** 0.5)
     kernel = functools.partial(_mixed_kernel, bs=bs, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -556,8 +597,8 @@ def mixed_attention_pallas(q, k_pages, v_pages, block_table, kv_lens,
         in_specs=[
             pl.BlockSpec((1, T, 1, G, hd),
                          lambda b, h, *_: (b, 0, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, T, 1, G, hd),
                                lambda b, h, *_: (b, 0, h, 0, 0)),
@@ -571,7 +612,7 @@ def mixed_attention_pallas(q, k_pages, v_pages, block_table, kv_lens,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, kvh, G, hd), jnp.float32),
+        out_shape=_out_struct((B, T, kvh, G, hd), q, k_pages, v_pages),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32),
       jnp.asarray(kv_lens, jnp.int32),
@@ -625,16 +666,11 @@ def mixed_paged_attention(q, k_pages, v_pages, block_table, kv_lens,
         return _mixed_paged_attention_seq(
             q, k_pages, v_pages, block_table, kv_lens, q_lens,
             seq_axis, n_seq, kv_scales=kv_scales)
-    bs, hd = k_pages.shape[1], k_pages.shape[3]
-    if kv_scales is not None:
-        return _mixed_attn_reference(q, k_pages, v_pages, block_table,
-                                     kv_lens, q_lens, kv_scales)
-    if (_HAS_PLTPU and jax.default_backend() == "tpu"
-            and hd % 128 == 0 and bs % 8 == 0):
+    if kv_scales is None and _kernel_serves(k_pages):
         return mixed_attention_pallas(q, k_pages, v_pages, block_table,
                                       kv_lens, q_lens)
     return _mixed_attn_reference(q, k_pages, v_pages, block_table,
-                                 kv_lens, q_lens)
+                                 kv_lens, q_lens, kv_scales)
 
 
 # ---------------------------------------------------------------------------
@@ -728,8 +764,8 @@ def _paged_decode_attention_seq(q, k_pages, v_pages, block_table,
     """Page-sharded decode attention: the `_paged_attn_reference` math
     over this shard's strided columns, finished by
     :func:`merge_softmax_partials`. q [B, kvh_loc, G, hd]; pools
-    [n_local, bs, kvh_loc, hd]."""
-    n_local, bs = k_pages.shape[0], k_pages.shape[1]
+    [n_local, kvh_loc, bs, hd]."""
+    n_local, bs = k_pages.shape[0], k_pages.shape[2]
     local, k_ids = _seq_gather_ids(block_table, n_seq, n_local, bs,
                                    seq_axis)
     if kv_scales is not None:
@@ -760,7 +796,7 @@ def _mixed_paged_attention_seq(q, k_pages, v_pages, block_table,
     q [B, T, kvh_loc, G, hd]; rows with no attendable position on ANY
     shard (kv_len 0 / q_len 0 padding) come out exact zeros — every
     shard's l is 0 so the merged L floors at eps over a zero ACC."""
-    n_local, bs = k_pages.shape[0], k_pages.shape[1]
+    n_local, bs = k_pages.shape[0], k_pages.shape[2]
     local, k_ids = _seq_gather_ids(block_table, n_seq, n_local, bs,
                                    seq_axis)
     if kv_scales is not None:

@@ -17,14 +17,8 @@ import os
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices",
-                      int(os.environ.get("GSPMD_LOCAL_DEVICES", "4")))
-except AttributeError:  # jax < 0.5: pre-init XLA flag spelling
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count="
-        + os.environ.get("GSPMD_LOCAL_DEVICES", "4")).strip()
+jax.config.update("jax_num_cpu_devices",
+                  int(os.environ.get("GSPMD_LOCAL_DEVICES", "4")))
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 import json  # noqa: E402

@@ -1016,12 +1016,11 @@ class TestPipelineScheduleV2:
             return out
 
         def build(remat):
-            from paddle_tpu.utils.compat import shard_map
             apply = spmd_pipeline(stage_fn, pp, n_mb, axis_name="pp",
                                   remat=remat)
-            sm = shard_map(apply, mesh=mesh,
-                           in_specs=(P("pp"), P()), out_specs=P(),
-                           axis_names={"pp"})
+            sm = jax.shard_map(apply, mesh=mesh,
+                               in_specs=(P("pp"), P()), out_specs=P(),
+                               axis_names={"pp"})
 
             def loss(p, xx):
                 return sm(p, xx).sum()
@@ -1051,12 +1050,11 @@ class TestPipelineScheduleV2:
 
         grads = []
         for remat in (True, False):
-            from paddle_tpu.utils.compat import shard_map
             apply = spmd_pipeline(stage_fn, pp, n_mb, axis_name="pp",
                                   remat=remat)
-            sm = shard_map(apply, mesh=mesh,
-                           in_specs=(P("pp"), P()), out_specs=P(),
-                           axis_names={"pp"})
+            sm = jax.shard_map(apply, mesh=mesh,
+                               in_specs=(P("pp"), P()), out_specs=P(),
+                               axis_names={"pp"})
             grads.append(jax.jit(jax.grad(lambda p: sm(p, x).sum()))(params))
         np.testing.assert_allclose(np.asarray(grads[0]),
                                    np.asarray(grads[1]), atol=1e-5)

@@ -17,8 +17,8 @@ def _random_paged(seed=0, B=3, kvh=2, G=4, hd=128, n_blocks=9, bs=16,
     mid-block, one tiny, one exactly on a block boundary)."""
     rng = np.random.RandomState(seed)
     q = rng.randn(B, kvh, G, hd).astype(np.float32) * 0.5
-    kp = rng.randn(n_blocks, bs, kvh, hd).astype(np.float32) * 0.5
-    vp = rng.randn(n_blocks, bs, kvh, hd).astype(np.float32) * 0.5
+    kp = rng.randn(n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
+    vp = rng.randn(n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
     lens = np.asarray(lens, np.int32)
     table = np.zeros((B, max_blocks), np.int32)
     free = list(range(1, n_blocks))          # page 0 = NULL
@@ -92,8 +92,8 @@ def _random_mixed(seed=0, B=4, T=8, kvh=2, G=4, hd=128, n_blocks=13,
     beside prefill-chunk rows (q_len up to T) at ragged positions."""
     rng = np.random.RandomState(seed)
     q = rng.randn(B, T, kvh, G, hd).astype(np.float32) * 0.5
-    kp = rng.randn(n_blocks, bs, kvh, hd).astype(np.float32) * 0.5
-    vp = rng.randn(n_blocks, bs, kvh, hd).astype(np.float32) * 0.5
+    kp = rng.randn(n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
+    vp = rng.randn(n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
     kv_lens = np.asarray(kv_lens, np.int32)
     q_lens = np.asarray(q_lens, np.int32)
     table = np.zeros((B, max_blocks), np.int32)
@@ -111,6 +111,8 @@ def _mixed_oracle(q, kp, vp, table, kv_lens, q_lens):
     kv_len - q_len + i and attends positions <= its own. Padding query
     slots are left at zero (callers ignore them)."""
     q, kp, vp = (np.asarray(a, np.float64) for a in (q, kp, vp))
+    # pools are [N, kvh, bs, hd]; the oracle reads pages token-major
+    kp, vp = np.swapaxes(kp, 1, 2), np.swapaxes(vp, 1, 2)
     table = np.asarray(table)
     B, T, kvh, G, hd = q.shape
     out = np.zeros((B, T, kvh, G, hd), np.float64)

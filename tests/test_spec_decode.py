@@ -231,7 +231,7 @@ class TestInt8PagedKV:
         from paddle_tpu.models.llama import _quantized_token_insert
         rng = np.random.RandomState(20)
         tok = rng.randn(2, 3, 8).astype(np.float32)
-        pool = jnp.zeros((4, 16, 3, 8), jnp.int8)
+        pool = jnp.zeros((4, 3, 16, 8), jnp.int8)
         scales = jnp.full((4, 3), KV_SCALE_EPS, jnp.float32)
         page = jnp.asarray([1, 2], jnp.int32)
         off = jnp.asarray([0, 5], jnp.int32)
@@ -239,7 +239,7 @@ class TestInt8PagedKV:
             pool, scales, page, off, jnp.asarray(tok))
         pool, scales = np.asarray(pool), np.asarray(scales)
         for b, (pg, o) in enumerate([(1, 0), (2, 5)]):
-            deq = pool[pg, o].astype(np.float32) * scales[pg][:, None]
+            deq = pool[pg, :, o].astype(np.float32) * scales[pg][:, None]
             step = scales[pg][:, None]
             assert np.all(np.abs(deq - tok[b]) <= 0.5 * step + 1e-7)
             # scale is exactly amax/127 for a fresh page
@@ -255,18 +255,18 @@ class TestInt8PagedKV:
         rng = np.random.RandomState(21)
         big = (rng.randn(1, 2, 8) * 4).astype(np.float32)
         small = (rng.randn(1, 2, 8) * 0.01).astype(np.float32)
-        pool = jnp.zeros((3, 16, 2, 8), jnp.int8)
+        pool = jnp.zeros((3, 2, 16, 8), jnp.int8)
         scales = jnp.full((3, 2), KV_SCALE_EPS, jnp.float32)
         page = jnp.asarray([1], jnp.int32)
         pool, scales = _quantized_token_insert(
             pool, scales, page, jnp.asarray([0], jnp.int32),
             jnp.asarray(big))
-        before = np.asarray(pool)[1, 0].copy()
+        before = np.asarray(pool)[1, :, 0].copy()
         s_before = np.asarray(scales)[1].copy()
         pool, scales = _quantized_token_insert(
             pool, scales, page, jnp.asarray([1], jnp.int32),
             jnp.asarray(small))
-        np.testing.assert_array_equal(np.asarray(pool)[1, 0], before)
+        np.testing.assert_array_equal(np.asarray(pool)[1, :, 0], before)
         np.testing.assert_array_equal(np.asarray(scales)[1], s_before)
 
     def test_gather_dequant_pool_edge_scale_indexing(self):
@@ -277,7 +277,7 @@ class TestInt8PagedKV:
         from paddle_tpu.kernels.paged_attention import (
             KV_SCALE_EPS, gather_pages_dequant)
         N, bs, kvh, hd = 6, 8, 2, 4
-        pages = jnp.ones((N, bs, kvh, hd), jnp.int8)
+        pages = jnp.ones((N, kvh, bs, hd), jnp.int8)
         scales = np.full((N, kvh), KV_SCALE_EPS, np.float32)
         scales[1] = [2.0, 3.0]
         scales[N - 1] = [5.0, 7.0]
@@ -301,9 +301,9 @@ class TestInt8PagedKV:
         B, kvh, G, hd, N, bs = 3, 2, 2, 16, 8, 16
         q = jnp.asarray(rng.randn(B, kvh, G, hd).astype(np.float32))
         kp = jnp.asarray(
-            rng.randint(-127, 128, (N, bs, kvh, hd)).astype(np.int8))
+            rng.randint(-127, 128, (N, kvh, bs, hd)).astype(np.int8))
         vp = jnp.asarray(
-            rng.randint(-127, 128, (N, bs, kvh, hd)).astype(np.int8))
+            rng.randint(-127, 128, (N, kvh, bs, hd)).astype(np.int8))
         ks = jnp.asarray(rng.rand(N, kvh).astype(np.float32) * 0.1)
         vs = jnp.asarray(rng.rand(N, kvh).astype(np.float32) * 0.1)
         tables = jnp.asarray(rng.permutation(np.arange(1, 7))[:6]

@@ -328,7 +328,7 @@ class TestShardedPoolInvariants:
                            mesh=make_tp_mesh(2), kv_dtype="int8")
         for arr in (eng._kp, eng._vp):
             shard = arr.addressable_shards[0]
-            assert shard.data.shape[3] == arr.shape[3] // 2
+            assert shard.data.shape[2] == arr.shape[2] // 2
         for arr in (eng._kscale, eng._vscale):
             shard = arr.addressable_shards[0]
             assert shard.data.shape[2] == arr.shape[2] // 2
