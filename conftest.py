@@ -10,10 +10,8 @@ are reserved by pytest, which is why the ini uses the long spelling.)
 """
 
 
-def pytest_addoption(parser):
-    try:
-        parser.addoption("--numprocesses", dest="_no_xdist_n",
-                         default=None)
-        parser.addoption("--dist", dest="_no_xdist_dist", default=None)
-    except ValueError:
-        pass  # real xdist is loaded and owns these flags
+def pytest_addoption(parser, pluginmanager):
+    if pluginmanager.hasplugin("xdist"):
+        return  # real xdist is loaded and owns these flags
+    parser.addoption("--numprocesses", dest="_no_xdist_n", default=None)
+    parser.addoption("--dist", dest="_no_xdist_dist", default=None)

@@ -1,0 +1,112 @@
+"""What the engine tests share, built in one place: the seeded read-only
+model of a preset, the solo-``generate`` oracle, the loop that drives an
+engine until it has served everything, and the clock every test runs
+under (``conftest.py`` arms it)."""
+
+import contextlib
+import signal
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+
+ENGINE_KW = dict(capacity=2, s_max=64, chunk=4, block_size=8)
+
+_SHARED = {}   # preset -> (model, generator state right after its build)
+_ORACLE = {}   # (preset, prompt, max_new) -> greedy tokens of a shared model
+
+
+def fresh_model(preset="debug"):
+    """``LlamaForCausalLM(preset)`` under ``paddle.seed(0)`` and
+    ``eval()``: for a test that writes to its model (quantizing it,
+    placing it on a mesh)."""
+    from paddle_tpu.models.llama import LlamaForCausalLM
+    paddle.seed(0)
+    m = LlamaForCausalLM(preset)
+    m.eval()
+    return m
+
+
+def shared_model(preset="debug"):
+    """One :func:`fresh_model` per preset and process, for the tests that
+    only read it: engines and ``generate`` do. Every call leaves the
+    global generator where a fresh build would, so what a test draws
+    afterwards does not depend on who built the model."""
+    if preset not in _SHARED:
+        _SHARED[preset] = (fresh_model(preset), paddle.get_rng_state())
+    m, state = _SHARED[preset]
+    paddle.set_rng_state(state)
+    return m
+
+
+def solo_generate(m, p, mn):
+    """The oracle: ``m.generate`` alone on prompt ``p``, greedy, ``mn``
+    new tokens. For a shared model the answer is kept, so the engine
+    variants of one prompt set ask once."""
+    def generate():
+        return np.asarray(m.generate(
+            paddle.to_tensor(p[None, :]), max_new_tokens=mn,
+            temperature=0.0)._value)[0]
+
+    preset = next((k for k, (shared, _) in _SHARED.items() if shared is m),
+                  None)
+    if preset is None:
+        return generate()
+    key = (preset, p.dtype.str, p.shape, p.tobytes(), mn)
+    if key not in _ORACLE:
+        _ORACLE[key] = generate()
+    return _ORACLE[key].copy()
+
+
+def drive(eng, pending=None, iters=2000):
+    """Admit ``pending`` (a list, consumed in place) and step the engine
+    until every request is served."""
+    if pending is None:
+        pending = []
+    for _ in range(iters):
+        eng.admit(pending)
+        eng.decode_once()
+        if eng.idle() and not pending:
+            return
+    raise AssertionError("engine did not drain the workload")
+
+
+def drain(eng, reqs):
+    """Serve what was submitted; the outputs of ``reqs`` in order."""
+    drive(eng)
+    return [np.asarray(r.wait(timeout=120)) for r in reqs]
+
+
+def make_prompts(rng, vocab, sizes):
+    return [rng.randint(1, vocab, (n,)).astype(np.int32) for n in sizes]
+
+
+def run_engine(m, prompt_list, max_new=8, mesh=None, **kw):
+    """A fresh four-slot paged engine serves ``prompt_list``; returns
+    (outputs, engine)."""
+    from paddle_tpu.inference.serving import DecodeEngine
+    eng = DecodeEngine(m, capacity=4, s_max=64, chunk=4, block_size=8,
+                       mesh=mesh, **kw)
+    reqs = [eng.submit(p, max_new_tokens=max_new) for p in prompt_list]
+    return drain(eng, reqs), eng
+
+
+@contextlib.contextmanager
+def per_test_clock(nodeid, limit_s):
+    """Fail the test named ``nodeid`` when ``limit_s`` seconds pass inside
+    the block (a hang becomes one failure, not a cut run). Main thread
+    only; on exit the timer and handler found on entry are put back, so
+    blocks nest."""
+    def fire(signum, frame):
+        pytest.fail(f"{nodeid} ran past its {limit_s} s limit",
+                    pytrace=False)
+
+    was_handler = signal.signal(signal.SIGALRM, fire)
+    was_timer = signal.setitimer(signal.ITIMER_REAL, limit_s)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *was_timer)
+        signal.signal(signal.SIGALRM, was_handler)
+

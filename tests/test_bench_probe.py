@@ -29,7 +29,7 @@ def test_prefix_preset_cpu_smoke(tmp_path):
                               BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -58,7 +58,7 @@ def test_fleet_preset_cpu_smoke(tmp_path):
                               BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -94,7 +94,7 @@ def test_slo_preset_cpu_smoke(tmp_path):
                               BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -134,7 +134,7 @@ def test_overload_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -185,7 +185,7 @@ def test_mixed_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -231,7 +231,7 @@ def test_spec_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -270,7 +270,7 @@ def test_tp_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -309,7 +309,7 @@ def test_cp_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -347,7 +347,7 @@ def test_chaos_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -395,7 +395,7 @@ def test_disagg_preset_cpu_smoke(tmp_path):
                BENCH_METRICS_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, bench.__file__], env=env,
-                       capture_output=True, text=True, timeout=540)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [ln for ln in r.stdout.strip().splitlines()
              if ln.startswith("{")]
@@ -542,7 +542,7 @@ def _run_script(args, cwd=_ROOT):
     env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
         [_ROOT] + [p for p in [env.get("PYTHONPATH")] if p]))
     return subprocess.run([sys.executable, *args], cwd=cwd, env=env,
-                          capture_output=True, text=True, timeout=300)
+                          capture_output=True, text=True, timeout=170)
 
 
 def test_chip_smoke_fails_without_accelerator():
@@ -561,7 +561,7 @@ def test_chip_smoke_fails_outside_the_repo(tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
                        env=dict(env, JAX_PLATFORMS="cpu"),
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=170)
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
 

@@ -15,7 +15,6 @@ clocks — no wall time anywhere (see test_no_adhoc_timers)."""
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.chaos import (FAULT_KINDS, ChaosPoisonError,
                                         FaultEvent, FaultInjector,
                                         FaultPlan)
@@ -23,21 +22,7 @@ from paddle_tpu.inference.fleet import (NoHealthyWorkersError,
                                         RequestPoisonedError,
                                         RestartPolicy, ServingFleet)
 
-ENGINE_KW = dict(capacity=2, s_max=64, chunk=4, block_size=8)
-
-
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
+from harness import ENGINE_KW, shared_model, solo_generate
 
 
 def _out(req, timeout=60):
@@ -84,7 +69,7 @@ class TestChaosDisabledBitIdentical:
         one with an installed injector whose plan is EMPTY must produce
         byte-for-byte the same tokens — and both must match the
         single-engine oracle."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(11)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (8, 11)]
@@ -109,7 +94,8 @@ class TestChaosDisabledBitIdentical:
         assert fired == []
         for a, b, p in zip(base, empty, prompts):
             np.testing.assert_array_equal(a, b)
-            np.testing.assert_array_equal(a, _solo(m, p, 6).reshape(-1))
+            np.testing.assert_array_equal(
+                a, solo_generate(m, p, 6).reshape(-1))
 
 
 class TestInjectedFaults:
@@ -118,7 +104,7 @@ class TestInjectedFaults:
         the backoff bound, the prefix directory re-registers the
         rejoined worker, and every request still completes
         bit-identical to the solo oracle."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(4)
         vt = [0.0]
         fleet = ServingFleet(
@@ -131,7 +117,7 @@ class TestInjectedFaults:
         for _ in range(4):
             p = rng.randint(1, 128, (10,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=12))
-            expect.append(_solo(m, p, 12))
+            expect.append(solo_generate(m, p, 12))
         fleet.step()                    # step 0: both workers admit
         vt[0] += 0.25
         fleet.step()                    # step 1: w1 crashes mid-step
@@ -167,7 +153,7 @@ class TestInjectedFaults:
         device-steps heartbeat just stops. The stall watchdog is the
         component that must notice — same detection path as a real
         wedged device loop."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(5)
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              stall_s=5.0, engine_kwargs=ENGINE_KW)
@@ -178,7 +164,7 @@ class TestInjectedFaults:
         for _ in range(2):
             p = rng.randint(1, 128, (8,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=10))
-            expect.append(_solo(m, p, 10))
+            expect.append(solo_generate(m, p, 10))
         fleet.step()                            # step 0: both decode
         assert fleet.check_watchdogs(now=50.0) == []    # baseline
         fleet.step()                            # step 1: w0 hung
@@ -199,7 +185,7 @@ class TestInjectedFaults:
         """An injected allocator OOM raises out of ``admit`` inside the
         worker step — the fleet must treat it exactly like any other
         raising step (fail the WORKER, re-route, finish elsewhere)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(6)
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
@@ -207,7 +193,7 @@ class TestInjectedFaults:
             [FaultEvent(0, "alloc_oom", "w0")])).install(fleet)
         p = rng.randint(1, 128, (10,)).astype(np.int32)
         req = fleet.submit(p, max_new_tokens=8)     # round-robin -> w0
-        expect = _solo(m, p, 8)
+        expect = solo_generate(m, p, 8)
         fleet.run_until_drained()
         np.testing.assert_array_equal(_out(req), expect.reshape(-1))
         assert not fleet.workers[0].healthy
@@ -227,7 +213,7 @@ class TestInjectedFaults:
             def emit(self, payload):
                 self.payloads.append(payload)
 
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         rec = _ListSink()
         fleet.enable_shipper([rec], interval_s=1e9)
@@ -252,7 +238,7 @@ class TestInjectedFaults:
 
 class TestRestartAndRejoin:
     def test_restart_worker_rebuilds_and_directory_repopulates(self):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         fleet = ServingFleet(m, n_workers=2, policy="affinity",
                              engine_kwargs=ENGINE_KW)
@@ -284,7 +270,7 @@ class TestRestartAndRejoin:
         fleet.close()
 
     def test_restart_rejects_healthy_and_unknown_workers(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         with pytest.raises(RuntimeError, match="healthy"):
             fleet.restart_worker("w0")
@@ -293,7 +279,7 @@ class TestRestartAndRejoin:
         fleet.close()
 
     def test_probation_excludes_rejoined_worker_from_routing(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
         fleet.kill_worker("w1")
@@ -320,7 +306,7 @@ class TestRestartAndRejoin:
         every worker restarted during the run and the final snapshot
         claimed zero retires). The dead incarnation's counters fold
         into the merge; its gauges die with it."""
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
         req = fleet.submit(np.arange(1, 9, dtype=np.int32),
@@ -343,7 +329,7 @@ class TestRestartAndRejoin:
         fleet.close()
 
     def test_max_restarts_caps_flapping(self):
-        m = _model()
+        m = shared_model()
         vt = [0.0]
         fleet = ServingFleet(
             m, n_workers=2, engine_kwargs=ENGINE_KW,
@@ -376,7 +362,7 @@ class TestPoisonQuarantine:
         max_retries re-routes — with ALL workers healthy again (auto
         restart) and every innocent request's output bit-identical to
         the fault-free oracle."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(9)
         fleet = ServingFleet(
             m, n_workers=3, policy="round_robin", engine_kwargs=ENGINE_KW,
@@ -388,7 +374,7 @@ class TestPoisonQuarantine:
         for _ in range(4):
             p = rng.randint(1, 100, (10,)).astype(np.int32)    # no 120
             innocents.append(fleet.submit(p, max_new_tokens=10))
-            expect.append(_solo(m, p, 10))
+            expect.append(solo_generate(m, p, 10))
         # long enough that the poison can never RETIRE within one step
         # of a re-admission (the crash fires at the NEXT step's chaos
         # check, so a request finishing in its admission step would
@@ -426,7 +412,7 @@ class TestPoisonQuarantine:
         """Zero healthy workers mid-failover: requests PARK (step never
         raises), submit raises the typed error, and the auto-restarted
         worker unparks everything with a ``restarted`` hop."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(10)
         vt = [0.0]
         fleet = ServingFleet(
@@ -439,7 +425,7 @@ class TestPoisonQuarantine:
         for _ in range(2):
             p = rng.randint(1, 128, (8,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=8))
-            expect.append(_solo(m, p, 8))
+            expect.append(solo_generate(m, p, 8))
         fleet.step()                    # step 0: admit
         fleet.step()                    # step 1: crash -> nowhere to go
         assert fleet.stats()["healthy_workers"] == 0
@@ -465,7 +451,7 @@ class TestPoisonQuarantine:
 
 class TestDegradationLadder:
     def test_knob_transitions_and_full_restore(self):
-        m = _model()
+        m = shared_model()
         kw = dict(ENGINE_KW, spec_decode=True, step_budget=16)
         fleet = ServingFleet(m, n_workers=2, engine_kwargs=kw)
         fleet.enable_slo()              # default boost 4.0
@@ -490,7 +476,7 @@ class TestDegradationLadder:
         fleet.close()
 
     def test_budget_never_halves_below_chunk(self):
-        m = _model()
+        m = shared_model()
         kw = dict(ENGINE_KW, spec_decode=True, step_budget=6)
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=kw)
         fleet.enable_slo()
@@ -501,7 +487,7 @@ class TestDegradationLadder:
         fleet.close()
 
     def test_restarted_worker_joins_at_current_brownout_level(self):
-        m = _model()
+        m = shared_model()
         kw = dict(ENGINE_KW, spec_decode=True, step_budget=16)
         fleet = ServingFleet(m, n_workers=2, engine_kwargs=kw)
         fleet.enable_slo()
@@ -519,7 +505,7 @@ class TestDegradationLadder:
         level per evaluation; the first clean evaluation restores every
         knob."""
         from paddle_tpu.observability import SLORule
-        m = _model()
+        m = shared_model()
         kw = dict(ENGINE_KW, spec_decode=True, step_budget=16)
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=kw)
         fleet.enable_slo(rules=[SLORule(
@@ -555,7 +541,7 @@ class TestSatellites:
     def test_no_healthy_workers_error_is_typed(self):
         assert issubclass(NoHealthyWorkersError, RuntimeError)
         assert issubclass(RequestPoisonedError, RuntimeError)
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         fleet.workers[0].healthy = False
         with pytest.raises(NoHealthyWorkersError, match="no healthy"):
@@ -601,7 +587,7 @@ class TestSatellites:
             def emit(self, payload):
                 self.payloads.append(payload)
 
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         rec = _ListSink()
         fleet.enable_shipper([rec], interval_s=1e9)
@@ -614,7 +600,7 @@ class TestSatellites:
         assert any(p.get("final") for p in rec.payloads)
 
     def test_run_until_drained_reports_stuck_work(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         fleet.submit(np.arange(1, 9, dtype=np.int32),
                      max_new_tokens=4, tenant="acme")
@@ -652,7 +638,7 @@ class TestSatellites:
         assert tr.summary()["poison_reason"] is None
 
     def test_new_counters_and_gauge_registered(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         for name in ("fleet_restarts_total", "fleet_poisoned_total",
                      "fleet_degradation_level"):
@@ -674,7 +660,7 @@ class TestPostmortemBundles:
     never perturbs the token stream."""
 
     def test_bundle_per_crash_with_bit_identical_outputs(self, tmp_path):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(23)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (8, 11, 6, 9)]
@@ -726,7 +712,7 @@ class TestPostmortemBundles:
     def test_stall_dumps_bundle(self, tmp_path):
         """A tripped stall watchdog triggers a bundle BEFORE the fleet
         harvests the worker (reason ``stall:<wid>``)."""
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              stall_s=1.0, engine_kwargs=ENGINE_KW,
                              postmortem_dir=str(tmp_path))
@@ -806,7 +792,7 @@ class TestProfiledFleetBitIdentical:
     byte-identical to the unprofiled default."""
 
     def test_profile_on_off_same_tokens(self):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(17)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (5, 12, 9)]
@@ -842,7 +828,7 @@ class TestMigrationFault:
     the routed worker — one slower request, never a wrong one."""
 
     def test_dead_transplant_cold_prefills(self):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(21)
         A = rng.randint(1, 128, (24,)).astype(np.int32)
         fleet = ServingFleet(m, n_workers=2,
@@ -867,7 +853,7 @@ class TestMigrationFault:
         assert fails and fails[0]["error"] == "ChaosMigrationError"
         fleet.run_until_drained()
         np.testing.assert_array_equal(out1, _out(r2))  # cold, correct
-        np.testing.assert_array_equal(out1, _solo(m, A, 8).reshape(-1))
+        np.testing.assert_array_equal(out1, solo_generate(m, A, 8).reshape(-1))
         for w in fleet.workers:
             assert w.engine._alloc.conservation_ok
         fleet.close()
@@ -876,7 +862,7 @@ class TestMigrationFault:
         """Role-split under a permanent migration_fail window: every
         handoff dies, rows decode to completion on the prefill worker,
         outputs still match the oracle."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(22)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (24, 14)]
@@ -891,7 +877,7 @@ class TestMigrationFault:
         assert fleet.stats()["migrations"] == 0
         for p, r in zip(prompts, reqs):
             np.testing.assert_array_equal(
-                _out(r), _solo(m, p, 8).reshape(-1))
+                _out(r), solo_generate(m, p, 8).reshape(-1))
         for w in fleet.workers:
             assert w.engine._alloc.conservation_ok
         fleet.close()

@@ -14,31 +14,9 @@ import math
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.scheduler import RequestScheduler, StepBudget
 
-
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
-
-
-def _drive(eng, pending, iters=400):
-    for _ in range(iters):
-        eng.admit(pending)
-        eng.decode_once()
-        if eng.idle() and not pending:
-            return
-    raise AssertionError("engine did not drain the workload")
+from harness import drive, shared_model, solo_generate
 
 
 class _Req:
@@ -103,7 +81,7 @@ class TestChunkedEngine:
     def test_requires_paged(self):
         from paddle_tpu.inference.serving import DecodeEngine
         with pytest.raises(ValueError, match="paged"):
-            DecodeEngine(_model(), capacity=2, s_max=64, chunk=4,
+            DecodeEngine(shared_model(), capacity=2, s_max=64, chunk=4,
                          paged=False, chunked_prefill=True)
 
     def test_bit_identical_vs_monolithic_and_solo(self):
@@ -111,19 +89,19 @@ class TestChunkedEngine:
         prefill vs chunked prefill, greedy outputs bit-identical (and
         both match solo generate)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(21)
         # mixed short/long: single-chunk, multi-chunk, and a prompt
         # whose final chunk is partial
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (8, 37, 7, 29)]
-        solo = [_solo(m, p, 8) for p in prompts]
+        solo = [solo_generate(m, p, 8) for p in prompts]
 
         def run(**kw):
             eng = DecodeEngine(m, capacity=4, s_max=96, chunk=4,
                                block_size=16, **kw)
             reqs = [_Request(p, 8) for p in prompts]
-            _drive(eng, list(reqs))
+            drive(eng, list(reqs))
             return eng, [r.wait(timeout=1) for r in reqs]
 
         mono_eng, mono = run()
@@ -140,13 +118,13 @@ class TestChunkedEngine:
 
     def test_trace_marks_and_first_token_at_last_chunk(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(22)
         p = rng.randint(1, 128, (37,)).astype(np.int32)
         eng = DecodeEngine(m, capacity=2, s_max=96, chunk=4,
                            block_size=16, chunked_prefill=True)
         r = _Request(p, 6)
-        _drive(eng, [r])
+        drive(eng, [r])
         tr = r.trace
         assert tr.count("prefill_chunk") == math.ceil(p.size / 16)
         # TTFT spans admission -> LAST chunk's first token
@@ -158,7 +136,7 @@ class TestChunkedEngine:
         takes ceil(n/chunk) decode steps to become resident, and the
         budget histogram records every step's spend."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(23)
         p = rng.randint(1, 128, (40,)).astype(np.int32)
         eng = DecodeEngine(m, capacity=2, s_max=96, chunk=4,
@@ -172,14 +150,15 @@ class TestChunkedEngine:
             assert row["pf_pos"] == 8 * step      # exactly one chunk
         h = eng.metrics.get("engine_step_budget_used")
         assert h.count >= 4
-        _drive(eng, [])
-        np.testing.assert_array_equal(r.wait(timeout=1), _solo(m, p, 4))
+        drive(eng, [])
+        np.testing.assert_array_equal(r.wait(timeout=1),
+                                      solo_generate(m, p, 4))
 
     def test_prefill_backlog_gauge(self):
         """stats()/gauge report queued prompt tokens not yet prefilled:
         scheduler backlog + in-flight rows' unprefilled remainders."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(24)
         p1 = rng.randint(1, 128, (24,)).astype(np.int32)
         p2 = rng.randint(1, 128, (16,)).astype(np.int32)
@@ -193,23 +172,23 @@ class TestChunkedEngine:
             "engine_prefill_backlog_tokens").value == 40
         eng.decode_once()                  # one 8-token chunk of r1
         assert eng.stats()["prefill_backlog"] == 32
-        _drive(eng, [])
+        drive(eng, [])
         assert eng.stats()["prefill_backlog"] == 0
         np.testing.assert_array_equal(r1.wait(timeout=1),
-                                      _solo(m, p1, 4))
+                                      solo_generate(m, p1, 4))
         np.testing.assert_array_equal(r2.wait(timeout=1),
-                                      _solo(m, p2, 4))
+                                      solo_generate(m, p2, 4))
 
     def test_preempt_mid_prefill_resumes_losslessly(self):
         """A high-priority arrival evicts a row that is still MID
         chunked prefill; the victim resumes through re-admission (its
         completed pages may prefix-hit) and still bit-matches solo."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(25)
         p_lo = rng.randint(1, 128, (20,)).astype(np.int32)
         p_hi = rng.randint(1, 128, (17,)).astype(np.int32)
-        solo_lo, solo_hi = _solo(m, p_lo, 4), _solo(m, p_hi, 4)
+        solo_lo, solo_hi = solo_generate(m, p_lo, 4), solo_generate(m, p_hi, 4)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8, n_blocks=4,
                            chunked_prefill=True, step_budget=8)
@@ -220,7 +199,7 @@ class TestChunkedEngine:
         assert "pf_seq" in row and row["pf_pos"] == 8
         hi = _Request(p_hi, 4, priority=5)
         pending = [hi]                     # needs all 3 usable pages
-        _drive(eng, pending)
+        drive(eng, pending)
         assert eng.stats()["preempted"] >= 1
         np.testing.assert_array_equal(hi.wait(timeout=1), solo_hi)
         np.testing.assert_array_equal(lo.wait(timeout=1), solo_lo)
@@ -230,16 +209,16 @@ class TestChunkedEngine:
         its emitted tokens (the r7 recompute path), and first_token is
         marked exactly once across the stints."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(26)
         prompts = [rng.randint(1, 128, (7,)).astype(np.int32)
                    for _ in range(2)]
-        solo = [_solo(m, p, 12) for p in prompts]
+        solo = [solo_generate(m, p, 12) for p in prompts]
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8, n_blocks=4,
                            chunked_prefill=True)
         reqs = [_Request(p, 12) for p in prompts]
-        _drive(eng, list(reqs))
+        drive(eng, list(reqs))
         assert eng.stats()["preempted"] >= 1
         for r, s in zip(reqs, solo):
             np.testing.assert_array_equal(r.wait(timeout=1), s)
@@ -252,18 +231,18 @@ class TestChunkedEngine:
         not self-preempt into an admit→prefill→grow-fail cycle that
         starves the neighbor forever."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(30)
         # 6-tok retires early; 45-tok needs 6 prompt pages + 1 grow
         # page; 13-tok sits mid-prefill holding the last 2 pages
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (6, 45, 13, 31)]
-        solo = [_solo(m, p, 10) for p in prompts]
+        solo = [solo_generate(m, p, 10) for p in prompts]
         eng = DecodeEngine(m, capacity=2, s_max=96, chunk=4,
                            block_size=8, n_blocks=9,
                            chunked_prefill=True, step_budget=8)
         reqs = [_Request(p, 10) for p in prompts]
-        _drive(eng, list(reqs), iters=500)
+        drive(eng, list(reqs), iters=500)
         assert eng.stats()["preempted"] >= 1
         for r, s in zip(reqs, solo):
             np.testing.assert_array_equal(r.wait(timeout=1), s)
@@ -272,7 +251,7 @@ class TestChunkedEngine:
         """A resubmitted shared prefix skips its cached pages: fewer
         chunks for the second request, outputs still bit-match solo."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(27)
         head = rng.randint(1, 128, (24,)).astype(np.int32)  # 3 pages
         p2 = np.concatenate([head, rng.randint(1, 128, (10,))
@@ -280,11 +259,11 @@ class TestChunkedEngine:
         eng = DecodeEngine(m, capacity=2, s_max=96, chunk=4,
                            block_size=8, chunked_prefill=True)
         r1 = _Request(head, 4)
-        _drive(eng, [r1])
+        drive(eng, [r1])
         cold_chunks = eng.stats()["prefill_chunks"]
         assert cold_chunks == 3
         r2 = _Request(p2, 4)
-        _drive(eng, [r2])
+        drive(eng, [r2])
         warm_chunks = eng.stats()["prefill_chunks"] - cold_chunks
         # 34-token prompt cold would be 5 chunks; the 24-token prefix
         # is resident, so only the uncached tail is chunked
@@ -292,9 +271,9 @@ class TestChunkedEngine:
         assert eng.metrics.get("engine_prefix_hit_tokens_total").value \
             >= 24
         np.testing.assert_array_equal(r1.wait(timeout=1),
-                                      _solo(m, head, 4))
+                                      solo_generate(m, head, 4))
         np.testing.assert_array_equal(r2.wait(timeout=1),
-                                      _solo(m, p2, 4))
+                                      solo_generate(m, p2, 4))
 
     def test_qos_fair_share_bit_parity(self):
         """Chunked prefill under the fair-share scheduler: per-chunk
@@ -308,7 +287,7 @@ class TestChunkedEngine:
             def __call__(self):
                 return self.t
 
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(28)
         qos = QoSPolicy([TenantPolicy("h", weight=1.0),
                          TenantPolicy("l", weight=10.0)],
@@ -327,7 +306,7 @@ class TestChunkedEngine:
                 break
         for p, r in work:
             np.testing.assert_array_equal(r.wait(timeout=1),
-                                          _solo(m, p, 5))
+                                          solo_generate(m, p, 5))
         assert eng.stats()["prefill_chunks"] >= 4
 
     def test_no_new_compiled_program_shapes(self):
@@ -336,14 +315,14 @@ class TestChunkedEngine:
         16-slot prefix-prefill bucket — no third program shape beyond
         the r7 bucket set, regardless of prompt length mix."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(29)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (5, 18, 33, 60)]
         eng = DecodeEngine(m, capacity=4, s_max=96, chunk=4,
                            block_size=16, chunked_prefill=True)
         reqs = [_Request(p, 4) for p in prompts]
-        _drive(eng, list(reqs))
+        drive(eng, list(reqs))
         for r in reqs:
             r.wait(timeout=1)
         # every chunk window bucketed to the one 16-slot program; the
@@ -358,5 +337,5 @@ class TestChunkedEngine:
                              block_size=16, chunked_prefill=True,
                              prefill_chunk=32)
         reqs = [_Request(p, 4) for p in prompts]
-        _drive(eng32, list(reqs))
+        drive(eng32, list(reqs))
         assert set(eng32._prefix_progs) <= {16, 32}

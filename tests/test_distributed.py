@@ -559,7 +559,7 @@ class TestBaselineConfig4SFT:
         ids = paddle.to_tensor(
             np.random.randint(0, 128, (4, 32), dtype=np.int32))
         first = None
-        for _ in range(6):
+        for _ in range(3):
             loss = llama_loss_fn(m, ids, ids)
             if first is None:
                 first = float(loss)
@@ -659,7 +659,7 @@ class TestBaselineConfig5MoE:
         ids = paddle.to_tensor(
             np.random.randint(0, 128, (4, 32), dtype=np.int32))
         first = None
-        for _ in range(5):
+        for _ in range(3):
             loss = llama_loss_fn(m, ids, ids)
             if first is None:
                 first = float(loss)
@@ -685,7 +685,7 @@ class TestBaselineConfig5MoE:
         ids = paddle.to_tensor(
             np.random.randint(0, 128, (4, 32), dtype=np.int32))
         first = None
-        for _ in range(6):
+        for _ in range(3):
             loss = llama_loss_fn(m, ids, ids)
             if first is None:
                 first = float(loss)
@@ -1384,7 +1384,7 @@ class TestLaunchCLI:  # -m 'not slow'; run explicitly with -m slow)
         r = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--nproc_per_node", "2", "--log_dir", str(tmp_path), worker],
-            cwd=root, capture_output=True, text=True, timeout=240)
+            cwd=root, capture_output=True, text=True, timeout=170)
         assert r.returncode == 0, r.stdout + r.stderr
         log1 = (tmp_path / "workerlog.1").read_text()
         assert "COMM_OK" in log1, log1
@@ -1400,7 +1400,7 @@ class TestLaunchCLI:  # -m 'not slow'; run explicitly with -m slow)
         r = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--nproc_per_node", "3", "--log_dir", str(tmp_path), worker],
-            cwd=root, capture_output=True, text=True, timeout=240)
+            cwd=root, capture_output=True, text=True, timeout=170)
         assert r.returncode == 0, r.stdout + r.stderr
         for i in range(3):
             log = (tmp_path / f"workerlog.{i}").read_text()
@@ -1429,14 +1429,14 @@ class TestLaunchCLI:  # -m 'not slow'; run explicitly with -m slow)
                    PYTHONPATH=root)
         single = subprocess.run([sys.executable, worker], cwd=root,
                                 env=env, capture_output=True, text=True,
-                                timeout=300)
+                                timeout=170)
         assert single.returncode == 0, single.stdout + single.stderr
         ref = losses_from(single.stdout)
 
         r = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--nproc_per_node", "2", "--log_dir", str(tmp_path), worker],
-            cwd=root, capture_output=True, text=True, timeout=420)
+            cwd=root, capture_output=True, text=True, timeout=170)
         assert r.returncode == 0, r.stdout + r.stderr
         ref_local = losses_from(single.stdout, "GSPMD_LOSSES_LOCAL ")
         np.testing.assert_allclose(ref_local, ref, rtol=1e-6)
@@ -1498,13 +1498,13 @@ class TestAutoCheckpoint:
         worker = os.path.join(root, "tests", "autockpt_worker.py")
         # first run crashes hard at step 6 (after the step-6 snapshot)
         r1 = subprocess.run([sys.executable, worker, str(tmp_path), "6"],
-                            capture_output=True, text=True, timeout=180,
+                            capture_output=True, text=True, timeout=170,
                             cwd=root)
         assert r1.returncode == 101, r1.stdout + r1.stderr
         assert "RESUMED_AT 0" in r1.stdout
         # relaunch: must resume from the recorded step (6) and finish
         r2 = subprocess.run([sys.executable, worker, str(tmp_path), "-1"],
-                            capture_output=True, text=True, timeout=180,
+                            capture_output=True, text=True, timeout=170,
                             cwd=root)
         assert r2.returncode == 0, r2.stdout + r2.stderr
         assert "RESUMED_AT 6" in r2.stdout, r2.stdout
@@ -1746,7 +1746,7 @@ class TestMultiControllerCheckpoint:
     the one topology the v5p north star actually uses."""
 
     def _run(self, worker, env=None, argv=(), nproc=2, log_dir=None,
-             timeout=420):
+             timeout=170):
         import os, subprocess, sys
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         cmd = [sys.executable, "-m", "paddle_tpu.distributed.launch",
@@ -1794,7 +1794,7 @@ class TestMultiControllerCheckpoint:
             [sys.executable, worker], cwd=root,
             env=dict(os.environ, GSPMD_LOCAL_DEVICES="8",
                      GSPMD_LOAD_DIR=str(ck), PYTHONPATH=root),
-            capture_output=True, text=True, timeout=300)
+            capture_output=True, text=True, timeout=170)
         assert r2.returncode == 0, r2.stdout + r2.stderr
         cross = self._tagged(r2.stdout, "GSPMD_CROSSTOPO_POST")
         np.testing.assert_allclose(cross, posts[0], rtol=1e-4)

@@ -11,27 +11,12 @@ import urllib.request
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.fleet import GlobalPrefixDirectory, ServingFleet
 from paddle_tpu.inference.fleet_metrics import (MetricsAggregator,
                                                 MetricsHTTPServer)
 from paddle_tpu.observability import MetricsRegistry
 
-ENGINE_KW = dict(capacity=2, s_max=64, chunk=4, block_size=8)
-
-
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
+from harness import ENGINE_KW, shared_model, solo_generate
 
 
 class TestGlobalPrefixDirectory:
@@ -116,7 +101,7 @@ class TestRouting:
         and publishes its pages, every follow-up with the same system
         prompt routes to THAT worker (directory hit beats the load
         tie), and the affinity counter records it."""
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="affinity",
                              engine_kwargs=ENGINE_KW)
         rng = np.random.RandomState(3)
@@ -142,7 +127,7 @@ class TestRouting:
         fleet.close()
 
     def test_round_robin_alternates(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
         p = np.arange(1, 9, dtype=np.int32)
@@ -154,7 +139,7 @@ class TestRouting:
         fleet.close()
 
     def test_submit_with_no_healthy_workers_raises(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=1, engine_kwargs=ENGINE_KW)
         fleet.workers[0].healthy = False
         with pytest.raises(RuntimeError, match="no healthy"):
@@ -168,7 +153,7 @@ class TestFailover:
         mid-decode; every request still completes on the survivor,
         token-for-token identical to an undisturbed solo run (the r7
         recompute-resume path, applied cross-worker)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(5)
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
@@ -176,7 +161,7 @@ class TestFailover:
         for _ in range(4):
             p = rng.randint(1, 128, (10,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=16))
-            expect.append(_solo(m, p, 16))
+            expect.append(solo_generate(m, p, 16))
         fleet.step()            # admit + first chunk on both workers
         victim = fleet.workers[1]
         assert victim.occupancy > 0     # rows genuinely in flight
@@ -195,7 +180,7 @@ class TestFailover:
         fleet.close()
 
     def test_raising_step_fails_worker_not_fleet(self):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(6)
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
@@ -203,7 +188,7 @@ class TestFailover:
         for _ in range(2):
             p = rng.randint(1, 128, (9,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=12))
-            expect.append(_solo(m, p, 12))
+            expect.append(solo_generate(m, p, 12))
         fleet.step()
         # wedge w1's next decode: the fleet must drain it, not crash
         def boom():
@@ -224,7 +209,7 @@ class TestFailover:
         a heartbeat that sits still while the worker is busy fires
         once, the on_stall hook marks the worker unhealthy, and the
         next step() re-routes its work."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(8)
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              stall_s=10.0, engine_kwargs=ENGINE_KW)
@@ -232,7 +217,7 @@ class TestFailover:
         for _ in range(2):
             p = rng.randint(1, 128, (8,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=16))
-            expect.append(_solo(m, p, 16))
+            expect.append(solo_generate(m, p, 16))
         fleet.step()                        # both workers now busy
         assert fleet.check_watchdogs(now=100.0) == []   # arms baseline
         fired = fleet.check_watchdogs(now=111.0)        # > stall_s idle
@@ -253,7 +238,7 @@ class TestFailover:
 
 class TestWorkerIds:
     def test_engine_stats_worker_id(self):
-        m = _model()
+        m = shared_model()
         from paddle_tpu.inference.serving import DecodeEngine
         eng = DecodeEngine(m, worker_id="w7", **ENGINE_KW)
         assert eng.stats()["worker_id"] == "w7"
@@ -261,7 +246,7 @@ class TestWorkerIds:
         assert eng2.stats()["worker_id"] is None
 
     def test_batching_server_threads_worker_id(self):
-        m = _model()
+        m = shared_model()
         from paddle_tpu.inference.serving import (BatchingServer,
                                                   GenerationPredictor)
         srv = BatchingServer(GenerationPredictor(m), max_batch=2,
@@ -276,7 +261,7 @@ class TestWorkerIds:
             srv.close()
 
     def test_fleet_assigns_distinct_ids(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, engine_kwargs=ENGINE_KW)
         ws = fleet.stats()["workers"]
         assert set(ws) == {"w0", "w1"}
@@ -342,7 +327,7 @@ class TestAggregatorAndEndpoint:
             srv.close()
 
     def test_fleet_serve_metrics_includes_router(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, engine_kwargs=ENGINE_KW)
         req = fleet.submit(np.arange(1, 9, dtype=np.int32),
                            max_new_tokens=2)

@@ -9,6 +9,8 @@ import paddle_tpu.nn as nn
 from paddle_tpu import inference
 from paddle_tpu.jit import InputSpec
 
+from harness import drive, fresh_model, shared_model, solo_generate
+
 
 def _net():
     paddle.seed(5)
@@ -313,23 +315,6 @@ class TestContinuousBatching:
     """VERDICT r4 #5: continuous batching — carried-KV DecodeEngine with
     chunk-boundary admit/retire — and the masked path under pp>1."""
 
-    def _model(self):
-        paddle.seed(0)
-        from paddle_tpu.models.llama import LlamaForCausalLM
-        m = LlamaForCausalLM("debug")
-        m.eval()
-        return m
-
-    @staticmethod
-    def _drive(eng, pending, iters=200):
-        """Run the engine loop until every pending request is served."""
-        for _ in range(iters):
-            eng.admit(pending)
-            eng.decode_once()
-            if eng.idle() and not pending:
-                return
-        raise AssertionError("engine did not drain the workload")
-
     def _workload(self, rng):
         # 2 long generations + 6 shorts: batch-at-a-time rides every
         # tick to its max(max_new); the engine retires shorts early and
@@ -341,17 +326,15 @@ class TestContinuousBatching:
 
     def test_engine_parity_with_solo_generation(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(1)
         prompts, max_news = self._workload(rng)
-        refs = [np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-            temperature=0.0)._value)[0]
+        refs = [solo_generate(m, p, mn)
             for p, mn in zip(prompts, max_news)]
         eng = DecodeEngine(m, capacity=4, s_max=96, chunk=4)
         reqs = [_Request(p, mn) for p, mn in zip(prompts, max_news)]
         pending = list(reqs)
-        self._drive(eng, pending)
+        drive(eng, pending)
         for req, ref in zip(reqs, refs):
             np.testing.assert_array_equal(req.wait(timeout=1), ref)
 
@@ -365,7 +348,7 @@ class TestContinuousBatching:
                                                   DecodeEngine,
                                                   GenerationPredictor,
                                                   _Request)
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(1)
         prompts, max_news = self._workload(rng)
 
@@ -389,7 +372,7 @@ class TestContinuousBatching:
         eng = DecodeEngine(m, capacity=4, s_max=96, chunk=4)
         pend = [_Request(p, mn) for p, mn in zip(prompts, max_news)]
         pending = list(pend)
-        self._drive(eng, pending)
+        drive(eng, pending)
         for r in pend:
             r.wait(timeout=1)
         assert eng.device_steps < baseline_steps, (
@@ -405,12 +388,10 @@ class TestContinuousBatching:
         import time as _time
         from paddle_tpu.inference.serving import (BatchingServer,
                                                   GenerationPredictor)
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(2)
         prompts, max_news = self._workload(rng)
-        refs = [np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-            temperature=0.0)._value)[0]
+        refs = [solo_generate(m, p, mn)
             for p, mn in zip(prompts, max_news)]
         pred = GenerationPredictor(m)
         srv = BatchingServer(pred, max_batch=4, continuous=True,
@@ -435,13 +416,11 @@ class TestContinuousBatching:
 
         import paddle_tpu.distributed as dist
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = fresh_model()
         rng = np.random.RandomState(5)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (8, 5)]
-        refs = [np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=5,
-            temperature=0.0)._value)[0] for p in prompts]
+        refs = [solo_generate(m, p, 5) for p in prompts]
         mesh = dist.ProcessMesh(shape=[1, 1, 1, 1, 8],
                                 dim_names=["dp", "pp", "sep", "ep", "mp"])
         with warnings.catch_warnings():
@@ -450,7 +429,7 @@ class TestContinuousBatching:
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4)
         reqs = [_Request(p, 5) for p in prompts]
         pending = list(reqs)
-        self._drive(eng, pending)
+        drive(eng, pending)
         for req, ref in zip(reqs, refs):
             np.testing.assert_array_equal(req.wait(timeout=1), ref)
 
@@ -460,17 +439,15 @@ class TestContinuousBatching:
         tokens match the cached generate path exactly."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
         from paddle_tpu.models.llama import quantize_weights_int8
-        m = self._model()
+        m = fresh_model()
         quantize_weights_int8(m)
         rng = np.random.RandomState(4)
         p = rng.randint(1, 128, (7,)).astype(np.int32)
-        ref = np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=5,
-            temperature=0.0)._value)[0]
+        ref = solo_generate(m, p, 5)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4)
         req = _Request(p, 5)
         pending = [req]
-        self._drive(eng, pending)
+        drive(eng, pending)
         np.testing.assert_array_equal(req.wait(timeout=1), ref)
 
     def test_continuous_falls_back_on_pp_mesh(self):
@@ -483,12 +460,10 @@ class TestContinuousBatching:
         from paddle_tpu.distributed.fleet.mp_layers import sharding_ctx
         from paddle_tpu.inference.serving import (BatchingServer,
                                                   GenerationPredictor)
-        m = self._model()
+        m = shared_model()
         p = np.random.RandomState(6).randint(1, 128, (7,)).astype(
             np.int32)
-        ref = np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=3,
-            temperature=0.0)._value)[0]
+        ref = solo_generate(m, p, 3)
         mesh = Mesh(np.array(_jax.devices()[:2]).reshape(2, 1),
                     ("pp", "mp"))
         with sharding_ctx(mesh):
@@ -515,13 +490,11 @@ class TestContinuousBatching:
         from paddle_tpu.distributed.fleet.mp_layers import sharding_ctx
         from paddle_tpu.inference.serving import (BatchingServer,
                                                   GenerationPredictor)
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(3)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (9, 5, 12)]
-        refs = [np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=4,
-            temperature=0.0)._value)[0] for p in prompts]
+        refs = [solo_generate(m, p, 4) for p in prompts]
         mesh = Mesh(np.array(_jax.devices()[:4]).reshape(2, 2),
                     ("pp", "mp"))
         with sharding_ctx(mesh):

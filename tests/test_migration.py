@@ -10,40 +10,17 @@ leave the r14 fleet byte-identical."""
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.fleet import ServingFleet
 from paddle_tpu.inference.migration import (MigrationResult,
                                             transplant_prefix)
 from paddle_tpu.inference.serving import DecodeEngine
 
-ENGINE_KW = dict(capacity=2, s_max=64, chunk=4, block_size=8)
-
-
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
-
-
-def _drain(eng):
-    for _ in range(10000):
-        eng.admit([])
-        if eng.idle():
-            break
-        eng.decode_once()
+from harness import ENGINE_KW, drive, shared_model, solo_generate
 
 
 def _run_one(eng, p, mn=8):
     r = eng.submit(p, max_new_tokens=mn)
-    _drain(eng)
+    drive(eng)
     return np.asarray(r.wait(timeout=120)).reshape(-1)
 
 
@@ -58,7 +35,7 @@ class TestTransplantPrimitive:
         """A transplanted chain serves the destination engine's own
         admission: the replayed prompt matches the migrated pages and
         decodes bit-identically to the source run (and the oracle)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(3)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
@@ -73,14 +50,14 @@ class TestTransplantPrimitive:
         # destination admission must HIT the transplanted chain
         out2 = _run_one(dst, p)
         np.testing.assert_array_equal(out, out2)
-        np.testing.assert_array_equal(out, _solo(m, p, 8).reshape(-1))
+        np.testing.assert_array_equal(out, solo_generate(m, p, 8).reshape(-1))
         assert dst._cache.hit_tokens > 0
         _conserved(src, dst)
 
     def test_source_chain_stays_published(self):
         """Migration COPIES — the source keeps serving its own chain
         warm afterwards (this is replication, not theft)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(4)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
@@ -97,7 +74,7 @@ class TestTransplantPrimitive:
         pages carry the source's running-max scales bit-exactly, not
         the eps floor a fresh allocation would have (the drain-before-
         copy ordering under test)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(5)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         src = DecodeEngine(m, kv_dtype="int8", worker_id="src",
@@ -127,7 +104,7 @@ class TestTransplantPrimitive:
         from paddle_tpu.inference.sharding import make_tp_mesh
         if len(jax.devices()) < 2:
             pytest.skip("needs 2 devices")
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(6)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         mesh = make_tp_mesh(2, devices=jax.devices()[:2])
@@ -138,7 +115,7 @@ class TestTransplantPrimitive:
         assert res.reason == "ok" and res.fused
         out2 = _run_one(dst, p)
         np.testing.assert_array_equal(out, out2)
-        np.testing.assert_array_equal(out, _solo(m, p, 8).reshape(-1))
+        np.testing.assert_array_equal(out, solo_generate(m, p, 8).reshape(-1))
         _conserved(src, dst)
 
     def test_tp2_disjoint_submeshes_host_bounce(self):
@@ -150,7 +127,7 @@ class TestTransplantPrimitive:
         from paddle_tpu.inference.sharding import make_tp_mesh
         if len(jax.devices()) < 4:
             pytest.skip("needs 4 devices")
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         src = DecodeEngine(
@@ -171,7 +148,7 @@ class TestTransplantPrimitive:
         the caller's hint and the transplant. The owner's match
         refutes the hint — reason ``stale``, ZERO allocator movement
         on either end (one cold prefill, never a wrong answer)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(8)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
@@ -188,7 +165,7 @@ class TestTransplantPrimitive:
         """Mid-migration safety: pages pinned by the transplant's own
         match are refcount>=2, so a concurrent evict sweep cannot free
         them (evict only drops refcount-1 childless nodes)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(9)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
@@ -206,7 +183,7 @@ class TestTransplantPrimitive:
         """All-or-nothing: a destination pool that cannot fund the
         chain (even after its own LRU eviction) aborts with nothing
         changed on either allocator."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(10)
         p = rng.randint(1, 128, (30,)).astype(np.int32)
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
@@ -220,7 +197,7 @@ class TestTransplantPrimitive:
         _conserved(src, dst)
 
     def test_budget_caps_pages(self):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(11)
         p = rng.randint(1, 128, (30,)).astype(np.int32)
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
@@ -231,7 +208,7 @@ class TestTransplantPrimitive:
         _conserved(src, dst)
 
     def test_no_chain_and_same_engine(self):
-        m = _model()
+        m = shared_model()
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
         dst = DecodeEngine(m, worker_id="dst", **ENGINE_KW)
         assert transplant_prefix(src, dst, [1, 2, 3]).reason \
@@ -242,7 +219,7 @@ class TestTransplantPrimitive:
             src, dst, list(range(20)), max_pages=0).reason == "no_chain"
 
     def test_layout_mismatch_raises(self):
-        m = _model()
+        m = shared_model()
         src = DecodeEngine(m, worker_id="src", **ENGINE_KW)
         kw = dict(ENGINE_KW, block_size=16)
         dst = DecodeEngine(m, worker_id="dst", **kw)
@@ -268,7 +245,7 @@ class TestFleetRouteMigration:
         """A directory hit that loses the route to its own load
         penalty moves the chain to the winner; the re-submitted prompt
         decodes bit-identically warm."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(12)
         A = rng.randint(1, 128, (24,)).astype(np.int32)
         fleet = ServingFleet(m, n_workers=2,
@@ -287,7 +264,7 @@ class TestFleetRouteMigration:
         fleet.run_until_drained()
         out2 = np.asarray(r2.wait(timeout=120)).reshape(-1)
         np.testing.assert_array_equal(out1, out2)
-        np.testing.assert_array_equal(out1, _solo(m, A, 8).reshape(-1))
+        np.testing.assert_array_equal(out1, solo_generate(m, A, 8).reshape(-1))
         ev = [e for e in fleet.flight.snapshot()["events"]
               if e.get("kind") == "kv_migrated"]
         assert ev and ev[0]["pages"] >= 1
@@ -299,7 +276,7 @@ class TestFleetRouteMigration:
         """A stale directory hint (owner evicted since on_insert) is
         refuted by the owner's match: the stale-hint counter moves and
         the request cold-prefills correctly on its routed worker."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(13)
         A = rng.randint(1, 128, (24,)).astype(np.int32)
         fleet = ServingFleet(m, n_workers=2,
@@ -318,14 +295,14 @@ class TestFleetRouteMigration:
         assert st["migrations"] == 0
         fleet.run_until_drained()
         out = np.asarray(r.wait(timeout=120)).reshape(-1)
-        np.testing.assert_array_equal(out, _solo(m, A, 8).reshape(-1))
+        np.testing.assert_array_equal(out, solo_generate(m, A, 8).reshape(-1))
         fleet.close()
 
     def test_migration_off_is_baseline(self):
         """Default knobs (roles=None, migration_budget_pages unset)
         keep the r14 fleet: zero migrations, zero migration debt, and
         bit-identical outputs vs the oracle."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(14)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (24, 18, 30, 12)]
@@ -341,11 +318,11 @@ class TestFleetRouteMigration:
         for p, r in zip(prompts, reqs):
             np.testing.assert_array_equal(
                 np.asarray(r.wait(timeout=120)).reshape(-1),
-                _solo(m, p, 8).reshape(-1))
+                solo_generate(m, p, 8).reshape(-1))
         fleet.close()
 
     def test_roles_validation(self):
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError):
             ServingFleet(m, n_workers=2, roles=("prefill",))
         with pytest.raises(ValueError):
@@ -361,7 +338,7 @@ class TestRoleSplitFleet:
         transplant, decode workers resume — and every output matches
         the solo oracle bit-for-bit, with the ``migrated`` hop on the
         traces and conservation on every pool."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(15)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (24, 18, 30, 12)]
@@ -380,7 +357,7 @@ class TestRoleSplitFleet:
         for p, r in zip(prompts, reqs):
             np.testing.assert_array_equal(
                 np.asarray(r.wait(timeout=120)).reshape(-1),
-                _solo(m, p, 8).reshape(-1))
+                solo_generate(m, p, 8).reshape(-1))
             hops = [h for h in getattr(r.trace, "hops", [])
                     if h.get("reason") == "migrated"]
             hopped += bool(hops)
@@ -392,7 +369,7 @@ class TestRoleSplitFleet:
     def test_role_split_repeat_bit_for_bit(self):
         """Same seed, run twice: the disaggregated fleet is
         deterministic end to end."""
-        m = _model()
+        m = shared_model()
 
         def run():
             rng = np.random.RandomState(16)
@@ -418,7 +395,7 @@ class TestRoleSplitFleet:
     def test_prefill_worker_down_degrades(self):
         """With the only prefill worker dead, the router falls back to
         any healthy worker — a degraded fleet beats a dead one."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(17)
         p = rng.randint(1, 128, (20,)).astype(np.int32)
         fleet = ServingFleet(m, n_workers=2,
@@ -429,5 +406,5 @@ class TestRoleSplitFleet:
         fleet.run_until_drained()
         np.testing.assert_array_equal(
             np.asarray(r.wait(timeout=120)).reshape(-1),
-            _solo(m, p, 8).reshape(-1))
+            solo_generate(m, p, 8).reshape(-1))
         fleet.close()

@@ -57,7 +57,7 @@ class TestLlama:
         data = paddle.to_tensor(
             np.random.randint(0, 128, (4, 32)))
         first = None
-        for _ in range(10):
+        for _ in range(5):
             loss = llama_loss_fn(m, data, data)
             if first is None:
                 first = float(loss)
@@ -117,11 +117,11 @@ class TestLlama:
         m = LlamaForCausalLM("debug")
         ids = paddle.to_tensor(
             np.random.randint(0, 128, (2, 12), dtype=np.int32))
-        cached = _np(m.generate(ids, max_new_tokens=10, temperature=0.0))
-        legacy = _np(m.generate(ids, max_new_tokens=10, temperature=0.0,
+        cached = _np(m.generate(ids, max_new_tokens=5, temperature=0.0))
+        legacy = _np(m.generate(ids, max_new_tokens=5, temperature=0.0,
                                 use_cache=False))
         assert (cached == legacy).all()
-        assert cached.shape == (2, 22)
+        assert cached.shape == (2, 17)
 
     def test_kv_cache_generate_qwen_biases_and_tied(self):
         from paddle_tpu.models.llama import LlamaForCausalLM
@@ -197,7 +197,7 @@ class TestLlama:
             return c.max() / c.sum()
 
         assert max_share() > 0.9  # collapsed
-        for _ in range(30):
+        for _ in range(18):
             moe(x)
             aux = moe.l_aux
             aux.backward()
@@ -314,7 +314,7 @@ class TestBertAndQwen:
         mask = paddle.to_tensor(np.ones((2, 16), dtype=np.int32))
         opt = paddle.optimizer.AdamW(1e-3, parameters=m.parameters())
         l0 = None
-        for _ in range(4):
+        for _ in range(2):
             logits = m(ids, attention_mask=mask)
             loss = F.cross_entropy(logits.reshape([-1, 128]),
                                    ids.reshape([-1]))

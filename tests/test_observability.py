@@ -17,6 +17,8 @@ from paddle_tpu.observability import (Counter, Gauge, Histogram,
                                       MetricsRegistry, RequestTrace,
                                       DEFAULT_LATENCY_BUCKETS)
 
+from harness import drive, shared_model
+
 
 class TestCounter:
     def test_inc_and_value(self):
@@ -222,27 +224,10 @@ class TestRequestTrace:
         json.dumps(a.summary())
 
 
-def _debug_model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _drive(eng, pending, iters=500):
-    for _ in range(iters):
-        eng.admit(pending)
-        eng.decode_once()
-        if eng.idle() and not pending:
-            return
-    raise AssertionError("engine did not drain the workload")
-
-
 class TestEngineLifecycleTelemetry:
     def test_every_retired_request_has_complete_trace(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _debug_model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8)
@@ -250,7 +235,7 @@ class TestEngineLifecycleTelemetry:
                                      (int(rng.randint(3, 12)),))
                          .astype(np.int32), int(rng.choice([3, 6])))
                 for _ in range(5)]
-        _drive(eng, list(reqs))
+        drive(eng, list(reqs))
         for r in reqs:
             r.wait(timeout=5)
             tr = r.trace
@@ -263,13 +248,13 @@ class TestEngineLifecycleTelemetry:
 
     def test_registry_histograms_match_lifecycle_counts(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _debug_model()
+        m = shared_model()
         rng = np.random.RandomState(9)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8)
         reqs = [_Request(rng.randint(1, 128, (6,)).astype(np.int32), 6)
                 for _ in range(4)]
-        _drive(eng, list(reqs))
+        drive(eng, list(reqs))
         snap = eng.metrics.snapshot()
         assert snap["counters"]["engine_admitted_total"] == 4
         assert snap["counters"]["engine_retired_total"] == 4
@@ -301,14 +286,14 @@ class TestEngineLifecycleTelemetry:
 
     def test_private_registries_do_not_cross_pollute(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _debug_model()
+        m = shared_model()
         rng = np.random.RandomState(3)
         p = rng.randint(1, 128, (6,)).astype(np.int32)
         e1 = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                           block_size=8)
         e2 = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                           block_size=8)
-        _drive(e1, [_Request(p, 4)])
+        drive(e1, [_Request(p, 4)])
         assert e1.stats()["retired"] == 1
         assert e2.stats()["retired"] == 0
 
@@ -316,7 +301,7 @@ class TestEngineLifecycleTelemetry:
         """A preempted-and-resumed request keeps ONE first_token mark:
         the TTFT histogram must not double-count the resume."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _debug_model()
+        m = shared_model()
         rng = np.random.RandomState(18)
         eng = DecodeEngine(m, capacity=3, s_max=64, chunk=4,
                            block_size=8, n_blocks=6)
@@ -354,7 +339,7 @@ class TestAllocatorConservation:
         of a pool-starved preempting workload, and the pool drains to
         zero — the counter-drift class the satellite closes."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _debug_model()
+        m = shared_model()
         rng = np.random.RandomState(18)
         eng = DecodeEngine(m, capacity=3, s_max=64, chunk=4,
                            block_size=8, n_blocks=6)
@@ -403,7 +388,7 @@ class TestChromeTraceUnifiedTimeline:
         and op-dispatch instants land in ONE chrome trace."""
         from paddle_tpu import profiler
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _debug_model()
+        m = shared_model()
         rng = np.random.RandomState(5)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8)
@@ -543,7 +528,7 @@ class TestStructuredLogging:
         continuous mode)."""
         from paddle_tpu.inference.serving import (BatchingServer,
                                                   GenerationPredictor)
-        m = _debug_model()
+        m = shared_model()
         srv = BatchingServer(GenerationPredictor(m), max_batch=2,
                              max_new_tokens=4, continuous=True,
                              engine_kwargs={"s_max": 64, "chunk": 4,

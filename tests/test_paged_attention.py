@@ -8,7 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import paddle_tpu as paddle
+from harness import drive, shared_model, solo_generate
 
 
 def _random_paged(seed=0, B=3, kvh=2, G=4, hd=128, n_blocks=9, bs=16,
@@ -293,22 +293,6 @@ class TestPagedEngine:
     bit-match the contiguous engine AND solo generation, and sustained
     mixed arrivals never hit a reset."""
 
-    def _model(self):
-        paddle.seed(0)
-        from paddle_tpu.models.llama import LlamaForCausalLM
-        m = LlamaForCausalLM("debug")
-        m.eval()
-        return m
-
-    @staticmethod
-    def _drive(eng, pending, iters=200):
-        for _ in range(iters):
-            eng.admit(pending)
-            eng.decode_once()
-            if eng.idle() and not pending:
-                return
-        raise AssertionError("engine did not drain the workload")
-
     def _workload(self, rng):
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (8, 10, 5, 6, 7, 5, 6, 4)]
@@ -317,12 +301,10 @@ class TestPagedEngine:
 
     def test_paged_matches_contiguous_and_solo(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(1)
         prompts, max_news = self._workload(rng)
-        solo = [np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-            temperature=0.0)._value)[0]
+        solo = [solo_generate(m, p, mn)
             for p, mn in zip(prompts, max_news)]
 
         def run(**kw):
@@ -330,7 +312,7 @@ class TestPagedEngine:
             reqs = [_Request(p, mn)
                     for p, mn in zip(prompts, max_news)]
             pending = list(reqs)
-            self._drive(eng, pending)
+            drive(eng, pending)
             return eng, [r.wait(timeout=1) for r in reqs]
 
         paged_eng, paged_out = run(paged=True, block_size=16)
@@ -346,7 +328,7 @@ class TestPagedEngine:
         pages and NEVER resets (the contiguous engine's failure mode
         this PR removes)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(2)
         eng = DecodeEngine(m, capacity=3, s_max=64, chunk=4,
                            block_size=8)
@@ -357,9 +339,7 @@ class TestPagedEngine:
             mn = int(rng.choice([3, 5, 9]))
             p = rng.randint(1, 128, (n,)).astype(np.int32)
             r = _Request(p, mn)
-            solo[id(r)] = np.asarray(m.generate(
-                paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-                temperature=0.0)._value)[0]
+            solo[id(r)] = solo_generate(m, p, mn)
             reqs.append(r)
         # feed 2 per iteration: admission happens while earlier rows
         # are mid-generation, the continuous-batching shape
@@ -385,13 +365,11 @@ class TestPagedEngine:
         error) until retiring rows free pages; every request still
         serves with solo-parity tokens."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(3)
         prompts = [rng.randint(1, 128, (12,)).astype(np.int32)
                    for _ in range(4)]
-        solo = [np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=4,
-            temperature=0.0)._value)[0] for p in prompts]
+        solo = [solo_generate(m, p, 4) for p in prompts]
         # 5 usable pages of 8 tokens: each row (prompt 12 + new 4 = 16)
         # needs exactly 2 pages at admission and never grows; 4 rows at
         # once would need 8 — admission must take turns on the pool
@@ -399,7 +377,7 @@ class TestPagedEngine:
                            block_size=8, n_blocks=6)
         reqs = [_Request(p, 4) for p in prompts]
         pending = list(reqs)
-        self._drive(eng, pending)
+        drive(eng, pending)
         for r, s in zip(reqs, solo):
             np.testing.assert_array_equal(r.wait(timeout=1), s)
         assert eng.resets == 1
@@ -409,13 +387,11 @@ class TestPagedEngine:
         needed new pages fails; its freed pages let the others finish
         (ADVICE r5 #3 in paged form)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(4)
         p1 = rng.randint(1, 128, (7,)).astype(np.int32)
         p2 = rng.randint(1, 128, (5,)).astype(np.int32)
-        solo2 = np.asarray(m.generate(
-            paddle.to_tensor(p2[None, :]), max_new_tokens=3,
-            temperature=0.0)._value)[0]
+        solo2 = solo_generate(m, p2, 3)
         # 3 usable pages of 8: row 2 (5 + 3 = 8 tokens) lives entirely
         # in its one admission page; the 40-token row grows chunk by
         # chunk, absorbs the page row 2 frees at retire, and still
@@ -424,7 +400,7 @@ class TestPagedEngine:
                            block_size=8, n_blocks=4)
         r1, r2 = _Request(p1, 40), _Request(p2, 3)
         pending = [r1, r2]
-        self._drive(eng, pending)
+        drive(eng, pending)
         with pytest.raises(RuntimeError, match="exhausted|s_max"):
             r1.wait(timeout=1)
         np.testing.assert_array_equal(r2.wait(timeout=1), solo2)
@@ -435,18 +411,16 @@ class TestPagedEngine:
         boundary; its neighbor is untouched (no engine-wide error, no
         reset)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(5)
         p1 = rng.randint(1, 128, (6,)).astype(np.int32)
         p2 = rng.randint(1, 128, (6,)).astype(np.int32)
-        solo2 = np.asarray(m.generate(
-            paddle.to_tensor(p2[None, :]), max_new_tokens=5,
-            temperature=0.0)._value)[0]
+        solo2 = solo_generate(m, p2, 5)
         eng = DecodeEngine(m, capacity=2, s_max=24, chunk=4,
                            block_size=8)
         r1, r2 = _Request(p1, 64), _Request(p2, 5)
         pending = [r1, r2]
-        self._drive(eng, pending)
+        drive(eng, pending)
         with pytest.raises(RuntimeError, match="s_max"):
             r1.wait(timeout=1)
         np.testing.assert_array_equal(r2.wait(timeout=1), solo2)
@@ -458,22 +432,13 @@ class TestContiguousClampedFinalChunk:
     remaining max_new fits the leftover fill ride ONE clamped chunk out;
     only rows that genuinely cannot fit get the exhaustion error."""
 
-    def _model(self):
-        paddle.seed(0)
-        from paddle_tpu.models.llama import LlamaForCausalLM
-        m = LlamaForCausalLM("debug")
-        m.eval()
-        return m
-
     def test_near_finished_row_completes(self):
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(6)
         pa = rng.randint(1, 128, (8,)).astype(np.int32)
         pb = rng.randint(1, 128, (8,)).astype(np.int32)
-        solo_b = np.asarray(m.generate(
-            paddle.to_tensor(pb[None, :]), max_new_tokens=28,
-            temperature=0.0)._value)[0]
+        solo_b = solo_generate(m, pb, 28)
         # fill walks 8 -> 32 in chunks of 8; the next chunk would cross
         # s_max=36, leaving space for 4: row B needs 3 more (fits the
         # clamp), row A needs 15 (cannot)
@@ -495,7 +460,7 @@ class TestContiguousClampedFinalChunk:
         """Every row too hungry for the leftover fill: all fail (the
         old behavior) and the engine resets for the next burst."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         pa = rng.randint(1, 128, (8,)).astype(np.int32)
         eng = DecodeEngine(m, capacity=2, s_max=36, chunk=8,

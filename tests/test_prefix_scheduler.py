@@ -8,10 +8,11 @@ preemption round-trips (tiny pool bit-matches ample pool)."""
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.paged_cache import BlockAllocator
 from paddle_tpu.inference.prefix_cache import PrefixCache
 from paddle_tpu.inference.scheduler import RequestScheduler
+
+from harness import drive, shared_model, solo_generate
 
 
 class TestAllocatorRefcounts:
@@ -251,33 +252,12 @@ class TestRequestScheduler:
 
 
 class TestPrefixEngine:
-    def _model(self):
-        paddle.seed(0)
-        from paddle_tpu.models.llama import LlamaForCausalLM
-        m = LlamaForCausalLM("debug")
-        m.eval()
-        return m
-
-    @staticmethod
-    def _drive(eng, pending, iters=300):
-        for _ in range(iters):
-            eng.admit(pending)
-            eng.decode_once()
-            if eng.idle() and not pending:
-                return
-        raise AssertionError("engine did not drain the workload")
-
-    def _solo(self, m, p, mn):
-        return np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-            temperature=0.0)._value)[0]
-
     def test_resubmission_allocates_zero_prefix_pages(self):
         """The acceptance delta: an identical re-submission funds ZERO
         pages for the shared prefix — only the one tail page (the
         allocator's cumulative counter makes the charge observable)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(11)
         # 17 tokens / bs 8: two FULL shared pages + a 1-token tail;
         # 17 + 4 new stays inside 3 pages, so admission is the only
@@ -286,18 +266,18 @@ class TestPrefixEngine:
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8)
         r1 = _Request(p, 4)
-        self._drive(eng, [r1])
+        drive(eng, [r1])
         cold_delta = eng._alloc.total_allocated
         assert cold_delta == 3             # ceil(17/8), charged in full
         r2 = _Request(p, 4)
-        self._drive(eng, [r2])
+        drive(eng, [r2])
         warm_delta = eng._alloc.total_allocated - cold_delta
         assert warm_delta == 1             # tail page only: both shared
         #                                    prefix pages cost nothing
         np.testing.assert_array_equal(r1.wait(timeout=1),
                                       r2.wait(timeout=1))
         np.testing.assert_array_equal(r1.wait(timeout=1),
-                                      self._solo(m, p, 4))
+                                      solo_generate(m, p, 4))
         s = eng.stats()
         assert s["prefix_hit_tokens"] == 16
         assert s["admitted"] == 2 and s["retired"] == 2
@@ -309,18 +289,18 @@ class TestPrefixEngine:
         Every warm admission runs the COW + position-offset tail
         prefill; greedy outputs must still bit-match solo generate."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(12)
         sys_p = rng.randint(1, 128, (12,)).astype(np.int32)
         prompts = [np.concatenate([sys_p, rng.randint(
             1, 128, (5,)).astype(np.int32)]) for _ in range(4)]
-        solo = [self._solo(m, p, 6) for p in prompts]
+        solo = [solo_generate(m, p, 6) for p in prompts]
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8)
         reqs = []
         for p in prompts:                  # serial: each retire
             r = _Request(p, 6)             # publishes before the next
-            self._drive(eng, [r])          # admission matches
+            drive(eng, [r])          # admission matches
             reqs.append(r)
         for r, s in zip(reqs, solo):
             np.testing.assert_array_equal(r.wait(timeout=1), s)
@@ -333,17 +313,17 @@ class TestPrefixEngine:
         growing rows forces self-preemption + recompute-resume; greedy
         outputs must be bit-identical to an ample pool (and solo)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(13)
         prompts = [rng.randint(1, 128, (7,)).astype(np.int32)
                    for _ in range(2)]
-        solo = [self._solo(m, p, 12) for p in prompts]
+        solo = [solo_generate(m, p, 12) for p in prompts]
 
         def run(**kw):
             eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                                block_size=8, **kw)
             reqs = [_Request(p, 12) for p in prompts]
-            self._drive(eng, list(reqs))
+            drive(eng, list(reqs))
             return eng, [r.wait(timeout=1) for r in reqs]
 
         # 3 usable pages; each row needs 3 to finish (7 + 12 - 1 = 18
@@ -363,12 +343,12 @@ class TestPrefixEngine:
         arrival evicts a strictly-lower running row when the pool can't
         fund it otherwise — the evicted row still finishes losslessly."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(14)
         p_lo = rng.randint(1, 128, (7,)).astype(np.int32)
         p_hi = rng.randint(1, 128, (17,)).astype(np.int32)
-        solo_lo = self._solo(m, p_lo, 12)
-        solo_hi = self._solo(m, p_hi, 4)
+        solo_lo = solo_generate(m, p_lo, 12)
+        solo_hi = solo_generate(m, p_hi, 4)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8, n_blocks=4)
         lo = _Request(p_lo, 12)
@@ -376,7 +356,7 @@ class TestPrefixEngine:
         eng.decode_once()                  # lo is mid-generation...
         hi = _Request(p_hi, 4, priority=5)
         pending = [hi]                     # ...when hi needs all 3 pages
-        self._drive(eng, pending)
+        drive(eng, pending)
         np.testing.assert_array_equal(hi.wait(timeout=1), solo_hi)
         np.testing.assert_array_equal(lo.wait(timeout=1), solo_lo)
         assert eng.stats()["preempted"] >= 1
@@ -385,7 +365,7 @@ class TestPrefixEngine:
         """Strictly-lower only: an equal-priority claimant WAITS for the
         running row instead of evicting it (no preemption cycles)."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(15)
         p1 = rng.randint(1, 128, (12,)).astype(np.int32)
         p2 = rng.randint(1, 128, (17,)).astype(np.int32)
@@ -397,17 +377,17 @@ class TestPrefixEngine:
         eng.admit([r2])
         assert eng.stats()["preempted"] == 0
         assert eng.backlog == 1 and not eng.idle()
-        self._drive(eng, [])
+        drive(eng, [])
         np.testing.assert_array_equal(r1.wait(timeout=1),
-                                      self._solo(m, p1, 4))
+                                      solo_generate(m, p1, 4))
         np.testing.assert_array_equal(r2.wait(timeout=1),
-                                      self._solo(m, p2, 4))
+                                      solo_generate(m, p2, 4))
 
     def test_infeasible_prompt_fails_loudly(self):
         """A prompt no amount of eviction/preemption can fund fails with
         the pool arithmetic in the message, not a silent hang."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(16)
         p = rng.randint(1, 128, (30,)).astype(np.int32)   # 4 pages
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
@@ -423,13 +403,13 @@ class TestPrefixEngine:
         """prefix_cache=False: no radix cache, no self-preemption — the
         r6 exhaustion behavior — but plain workloads are unchanged."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = self._model()
+        m = shared_model()
         rng = np.random.RandomState(17)
         p = rng.randint(1, 128, (9,)).astype(np.int32)
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
                            block_size=8, prefix_cache=False)
         r1, r2 = _Request(p, 4), _Request(p, 4)
-        self._drive(eng, [r1, r2])
+        drive(eng, [r1, r2])
         np.testing.assert_array_equal(r1.wait(timeout=1),
                                       r2.wait(timeout=1))
         s = eng.stats()
@@ -445,10 +425,7 @@ class TestPreemptionStress:
         completes must bit-match solo, nothing may hang, and the only
         allowed failures are explicit pool-infeasibility errors."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        paddle.seed(0)
-        from paddle_tpu.models.llama import LlamaForCausalLM
-        m = LlamaForCausalLM("debug")
-        m.eval()
+        m = shared_model()
         rng = np.random.RandomState(18)
         eng = DecodeEngine(m, capacity=3, s_max=64, chunk=4,
                            block_size=8, n_blocks=6)
@@ -458,9 +435,7 @@ class TestPreemptionStress:
             mn = int(rng.choice([3, 6, 10]))
             p = rng.randint(1, 128, (n,)).astype(np.int32)
             reqs.append(_Request(p, mn, priority=int(rng.randint(0, 3))))
-            solo.append(np.asarray(m.generate(
-                paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-                temperature=0.0)._value)[0])
+            solo.append(solo_generate(m, p, mn))
         queue = list(reqs)
         pending = []
         for _ in range(2000):

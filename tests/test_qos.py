@@ -9,7 +9,6 @@ sleeps or reads wall time to make a decision."""
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.qos import (AdmissionGate, FairShareScheduler,
                                       QoSPolicy, RequestShedError,
                                       TenantPolicy, TokenBucket,
@@ -18,6 +17,8 @@ from paddle_tpu.inference.scheduler import RequestScheduler
 from paddle_tpu.inference.traffic import (TenantProfile,
                                           TrafficGenerator, jain_index)
 from paddle_tpu.observability import RequestTrace
+
+from harness import drive, shared_model, solo_generate
 
 
 class _FakeReq:
@@ -356,28 +357,6 @@ class TestShedPlan:
 # ---------------------------------------------------------------------------
 # submit-path validation (satellite a)
 # ---------------------------------------------------------------------------
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
-
-
-def _drive(eng, iters=300):
-    pending = []
-    for _ in range(iters):
-        eng.admit(pending)
-        eng.decode_once()
-        if eng.idle() and not eng.backlog:
-            return
-    raise AssertionError("engine did not drain")
 
 
 class TestSubmitValidation:
@@ -392,7 +371,7 @@ class TestSubmitValidation:
 
     def test_engine_submit_validates(self):
         from paddle_tpu.inference.serving import DecodeEngine
-        eng = DecodeEngine(_model(), capacity=2, s_max=64, chunk=4)
+        eng = DecodeEngine(shared_model(), capacity=2, s_max=64, chunk=4)
         with pytest.raises(ValueError, match="empty"):
             eng.submit(np.array([], np.int32))
         with pytest.raises(ValueError, match="positive"):
@@ -401,7 +380,7 @@ class TestSubmitValidation:
     def test_batching_server_submit_validates(self):
         from paddle_tpu.inference.serving import (BatchingServer,
                                                   GenerationPredictor)
-        srv = BatchingServer(GenerationPredictor(_model()))
+        srv = BatchingServer(GenerationPredictor(shared_model()))
         try:
             with pytest.raises(ValueError, match="empty"):
                 srv.submit(np.array([], np.int32))
@@ -414,7 +393,7 @@ class TestSubmitValidation:
 
     def test_fleet_submit_validates(self):
         from paddle_tpu.inference.fleet import ServingFleet
-        fleet = ServingFleet(_model(), n_workers=2,
+        fleet = ServingFleet(shared_model(), n_workers=2,
                              engine_kwargs=dict(capacity=2, s_max=64,
                                                 chunk=4, block_size=8))
         try:
@@ -434,12 +413,12 @@ class TestEngineQoS:
     def test_qos_requires_paged(self):
         from paddle_tpu.inference.serving import DecodeEngine
         with pytest.raises(ValueError, match="paged"):
-            DecodeEngine(_model(), paged=False,
+            DecodeEngine(shared_model(), paged=False,
                          qos=QoSPolicy(clock=_VClock()))
 
     def test_submit_requires_paged(self):
         from paddle_tpu.inference.serving import DecodeEngine
-        eng = DecodeEngine(_model(), paged=False)
+        eng = DecodeEngine(shared_model(), paged=False)
         with pytest.raises(RuntimeError, match="paged"):
             eng.submit(np.array([1, 2], np.int32))
 
@@ -448,7 +427,7 @@ class TestEngineQoS:
         config must not perturb the decode — outputs stay bit-identical
         to the qos-less engine over the same workload."""
         from paddle_tpu.inference.serving import DecodeEngine, _Request
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(3)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (7, 5, 9, 4)]
@@ -464,7 +443,7 @@ class TestEngineQoS:
         qos = QoSPolicy(clock=_VClock())
         eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4, qos=qos)
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
-        _drive(eng)
+        drive(eng)
         for rq, rp in zip(reqs, plain_reqs):
             np.testing.assert_array_equal(rq.wait(timeout=1),
                                           rp.wait(timeout=1))
@@ -473,7 +452,7 @@ class TestEngineQoS:
         from paddle_tpu.inference.serving import DecodeEngine
         qos = QoSPolicy([TenantPolicy("free", weight=0.0)],
                         clock=_VClock())
-        eng = DecodeEngine(_model(), capacity=2, s_max=64, chunk=4,
+        eng = DecodeEngine(shared_model(), capacity=2, s_max=64, chunk=4,
                            qos=qos)
         req = eng.submit(np.arange(1, 6, dtype=np.int32),
                          max_new_tokens=4, tenant="free")
@@ -487,7 +466,7 @@ class TestEngineQoS:
         the bucket until the virtual clock refills it, then retires
         with solo-parity tokens."""
         from paddle_tpu.inference.serving import DecodeEngine
-        m = _model()
+        m = shared_model()
         clk = _VClock()
         p = np.arange(1, 7, dtype=np.int32)          # cost 6 + 4 = 10
         qos = QoSPolicy([TenantPolicy("a", rate=10.0, burst=10.0)],
@@ -496,12 +475,12 @@ class TestEngineQoS:
         r1 = eng.submit(p, max_new_tokens=4, tenant="a")
         r2 = eng.submit(p, max_new_tokens=4, tenant="a")
         assert eng._qos_gate.depth() == 1            # r2 held
-        _drive(eng)
+        drive(eng)
         assert r1.wait(timeout=1) is not None
         assert not r2.event.is_set()                 # still gated
         clk.t = 1.0                                  # refill 10 tokens
-        _drive(eng)
-        ref = _solo(m, p, 4)
+        drive(eng)
+        ref = solo_generate(m, p, 4)
         np.testing.assert_array_equal(r2.wait(timeout=1), ref)
         assert qos.stats()["a"]["throttled"] == 1
         assert qos.stats()["a"]["admitted"] == 2
@@ -513,7 +492,7 @@ class TestEngineQoS:
         """Fair sharing reorders service between tenants but never
         corrupts it — every request still bit-matches solo decode."""
         from paddle_tpu.inference.serving import DecodeEngine
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         qos = QoSPolicy([TenantPolicy("h", weight=1.0),
                          TenantPolicy("l", weight=10.0)],
@@ -524,10 +503,10 @@ class TestEngineQoS:
             p = rng.randint(1, 128, (4 + i,)).astype(np.int32)
             work.append((p, eng.submit(p, max_new_tokens=5,
                                        tenant="h" if i % 3 else "l")))
-        _drive(eng)
+        drive(eng)
         for p, r in work:
             np.testing.assert_array_equal(r.wait(timeout=1),
-                                          _solo(m, p, 5))
+                                          solo_generate(m, p, 5))
         st = qos.stats()
         assert st["h"]["served_tokens"] == 4 * 5
         assert st["l"]["served_tokens"] == 2 * 5
@@ -539,7 +518,7 @@ class TestEngineQoS:
 class TestFleetShedding:
     def test_shed_requires_qos(self):
         from paddle_tpu.inference.fleet import ServingFleet
-        fleet = ServingFleet(_model(), n_workers=1,
+        fleet = ServingFleet(shared_model(), n_workers=1,
                              engine_kwargs=dict(capacity=2, s_max=64,
                                                 chunk=4, block_size=8))
         try:
@@ -556,7 +535,7 @@ class TestFleetShedding:
         bit-matches solo decode."""
         from paddle_tpu.inference.fleet import ServingFleet
         from paddle_tpu.observability import SLORule
-        m = _model()
+        m = shared_model()
         clk = _VClock()
         qos = QoSPolicy([
             TenantPolicy("bulk", tier=0, shed_floor=1),
@@ -607,7 +586,7 @@ class TestFleetShedding:
         # loud, not lossy: survivors still bit-match solo decode
         for p, r in retired:
             np.testing.assert_array_equal(r.wait(timeout=1),
-                                          _solo(m, p, 4))
+                                          solo_generate(m, p, 4))
         fleet.close()
 
     def test_fleet_reject_tenant(self):
@@ -615,7 +594,7 @@ class TestFleetShedding:
         qos = QoSPolicy([TenantPolicy("m", rate=1.0, burst=1.0,
                                       on_limit="reject")],
                         clock=_VClock())
-        fleet = ServingFleet(_model(), n_workers=1,
+        fleet = ServingFleet(shared_model(), n_workers=1,
                              engine_kwargs=dict(capacity=2, s_max=64,
                                                 chunk=4, block_size=8),
                              qos=qos)
@@ -707,7 +686,7 @@ class TestTenantTelemetry:
     def test_fleet_aggregator_includes_tenant_registries(self):
         from paddle_tpu.inference.fleet import ServingFleet
         qos = QoSPolicy(clock=_VClock())
-        fleet = ServingFleet(_model(), n_workers=1,
+        fleet = ServingFleet(shared_model(), n_workers=1,
                              engine_kwargs=dict(capacity=2, s_max=64,
                                                 chunk=4, block_size=8),
                              qos=qos)

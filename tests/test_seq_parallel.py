@@ -22,42 +22,12 @@ at once, naming ``seq`` as the escape hatch past the kv-head cap."""
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.paged_cache import BlockAllocator
 from paddle_tpu.inference.serving import DecodeEngine
 from paddle_tpu.inference.sharding import (make_mesh, make_tp_mesh,
                                            validate_mesh_config)
 
-
-def _model(preset="debug"):
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM(preset)
-    m.eval()
-    return m
-
-
-def _drain(eng, reqs):
-    eng.admit([])
-    for _ in range(10000):
-        eng.decode_once()
-        eng.admit([])
-        if eng.idle():
-            break
-    return [np.asarray(r.wait(timeout=120)) for r in reqs]
-
-
-def _run(m, prompts, max_new=8, mesh=None, **kw):
-    eng = DecodeEngine(m, capacity=4, s_max=64, chunk=4, block_size=8,
-                       mesh=mesh, **kw)
-    reqs = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
-    outs = _drain(eng, reqs)
-    return outs, eng
-
-
-def _prompts(rng, vocab, sizes):
-    return [rng.randint(1, vocab, (n,)).astype(np.int32)
-            for n in sizes]
+from harness import drain, make_prompts, run_engine, shared_model
 
 
 class TestSeqParallelParity:
@@ -67,7 +37,7 @@ class TestSeqParallelParity:
         prefix cache + chunked prefill + spec decode ON, bit-identical
         to the unsharded engine across a cache-seeding wave and a
         hit + COW wave."""
-        m = _model()                       # debug: 4 heads / 2 kv heads
+        m = shared_model()                       # debug: 4 heads / 2 kv heads
         rng = np.random.RandomState(0)
         shared = rng.randint(1, 128, (10,)).astype(np.int32)
         wave1 = [np.tile(rng.randint(1, 128, (5,)).astype(np.int32), 4),
@@ -84,7 +54,7 @@ class TestSeqParallelParity:
             outs = []
             for wave in (wave1, wave2):
                 reqs = [eng.submit(p, max_new_tokens=10) for p in wave]
-                outs += _drain(eng, reqs)
+                outs += drain(eng, reqs)
             return outs, eng
 
         base, _ = run(None)
@@ -104,12 +74,12 @@ class TestSeqParallelParity:
         """int8 paged KV under page sharding: quantized insert/scatter
         route writes through the owned-page drop path and reads clamp,
         bit-matching the unsharded int8 engine."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(1)
-        prompts = _prompts(rng, 128, (5, 19, 11))
-        base, _ = _run(m, prompts, kv_dtype="int8", prefix_cache=True)
-        outs, eng = _run(m, prompts, mesh=make_mesh(2, 2),
-                         kv_dtype="int8", prefix_cache=True)
+        prompts = make_prompts(rng, 128, (5, 19, 11))
+        base, _ = run_engine(m, prompts, kv_dtype="int8", prefix_cache=True)
+        outs, eng = run_engine(m, prompts, mesh=make_mesh(2, 2),
+                               kv_dtype="int8", prefix_cache=True)
         for a, b in zip(base, outs):
             np.testing.assert_array_equal(a, b)
         assert eng.stats()["seq_degree"] == 2
@@ -117,13 +87,13 @@ class TestSeqParallelParity:
     def test_seq_only_mesh_parity(self):
         """tp=1, seq=4: page parallelism alone (no kv-head split at
         all) still bit-matches — the two axes are independent."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(2)
-        prompts = _prompts(rng, 128, (7, 33, 12))
-        base, _ = _run(m, prompts, chunked_prefill=True,
-                       spec_decode=True)
-        outs, eng = _run(m, prompts, mesh=make_mesh(1, 4),
-                         chunked_prefill=True, spec_decode=True)
+        prompts = make_prompts(rng, 128, (7, 33, 12))
+        base, _ = run_engine(m, prompts, chunked_prefill=True,
+                             spec_decode=True)
+        outs, eng = run_engine(m, prompts, mesh=make_mesh(1, 4),
+                               chunked_prefill=True, spec_decode=True)
         for a, b in zip(base, outs):
             np.testing.assert_array_equal(a, b)
         assert eng.stats()["mesh_shape"] == {"seq": 4, "tp": 1}
@@ -132,12 +102,12 @@ class TestSeqParallelParity:
         """seq_degree=1 is the regression satellite: a (1, tp) 2-D mesh
         must produce exactly the 1-D tp engine's outputs (and the
         unsharded engine's), with the unstriped allocator snapshot."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(3)
-        prompts = _prompts(rng, 128, (9, 17))
-        base, _ = _run(m, prompts)
-        out1d, e1 = _run(m, prompts, mesh=make_tp_mesh(2))
-        out2d, e2 = _run(m, prompts, mesh=make_mesh(2, 1))
+        prompts = make_prompts(rng, 128, (9, 17))
+        base, _ = run_engine(m, prompts)
+        out1d, e1 = run_engine(m, prompts, mesh=make_tp_mesh(2))
+        out2d, e2 = run_engine(m, prompts, mesh=make_mesh(2, 1))
         for a, b, c in zip(base, out1d, out2d):
             np.testing.assert_array_equal(a, b)
             np.testing.assert_array_equal(a, c)
@@ -150,7 +120,7 @@ class TestSeqParallelParity:
         """The tentpole's point: per-device KV footprint is
         1/(tp*seq) of the pool — page axis split over seq, kv-head
         axis split over tp."""
-        m = _model()
+        m = shared_model()
         eng = DecodeEngine(m, capacity=2, s_max=64, block_size=8,
                            mesh=make_mesh(2, 2), kv_dtype="int8")
         for arr in (eng._kp, eng._vp):
@@ -329,7 +299,7 @@ class TestValidationAggregate:
     def test_reports_all_violations_in_one_message(self):
         """Satellite: a bad degree lists EVERY violated divisibility
         constraint, not just the first."""
-        m = _model()                        # 4 heads / 2 kv heads
+        m = shared_model()                        # 4 heads / 2 kv heads
         with pytest.raises(ValueError) as e:
             validate_mesh_config(m.config, 3)
         msg = str(e.value)
@@ -340,7 +310,7 @@ class TestValidationAggregate:
     def test_kv_head_cap_names_seq_escape_hatch(self):
         """tp past the kv-head count points at the 2-D mesh instead of
         dead-ending."""
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError, match="seq_degree>1"):
             validate_mesh_config(m.config, 4)
         with pytest.raises(ValueError, match="seq_degree>1"):
@@ -348,7 +318,7 @@ class TestValidationAggregate:
                          mesh=make_tp_mesh(4))
 
     def test_n_blocks_must_divide_over_seq(self):
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError, match="n_blocks"):
             validate_mesh_config(m.config, 2, seq=2, n_blocks=7)
         with pytest.raises(ValueError, match="n_blocks"):
@@ -366,10 +336,10 @@ class TestObservability:
     def test_engine_seq_degree_gauge_and_stats(self):
         """Satellite: stats()/statusz report the full mesh shape per
         engine and the engine_seq_degree gauge reads it live."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(5)
-        outs, eng = _run(m, _prompts(rng, 128, (9,)),
-                         mesh=make_mesh(2, 2))
+        outs, eng = run_engine(m, make_prompts(rng, 128, (9,)),
+                               mesh=make_mesh(2, 2))
         snap = eng.metrics.snapshot()
         assert snap["gauges"]["engine_tp_degree"] == 2
         assert snap["gauges"]["engine_seq_degree"] == 2
@@ -377,7 +347,7 @@ class TestObservability:
         assert s["seq_degree"] == 2
         assert s["mesh_shape"] == {"seq": 2, "tp": 2}
         # unsharded engines still report degree 1 (gauge always there)
-        _, e0 = _run(m, _prompts(rng, 128, (5,)))
+        _, e0 = run_engine(m, make_prompts(rng, 128, (5,)))
         assert e0.metrics.snapshot()["gauges"]["engine_seq_degree"] == 1
 
 
@@ -388,10 +358,10 @@ class TestSeqParallelFleet:
         bit-matches the solo unsharded engine; fleet stats carry
         seq_degree beside tp_degree."""
         from paddle_tpu.inference.fleet import ServingFleet
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(6)
-        prompts = _prompts(rng, 128, (9, 21))
-        base, _ = _run(m, prompts)
+        prompts = make_prompts(rng, 128, (9, 21))
+        base, _ = run_engine(m, prompts)
         fl = ServingFleet(m, n_workers=1, tp_degree=2, seq_degree=4,
                           engine_kwargs=dict(capacity=4, s_max=64,
                                              chunk=4, block_size=8))
@@ -414,7 +384,7 @@ class TestSeqParallelFleet:
 
     def test_fleet_rejects_oversubscribed_2d_submeshes(self):
         from paddle_tpu.inference.fleet import ServingFleet
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError, match="seq_degree"):
             ServingFleet(m, n_workers=2, tp_degree=2, seq_degree=4,
                          engine_kwargs=dict(capacity=2, s_max=64))

@@ -10,28 +10,13 @@ import json
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.fleet import ServingFleet
 from paddle_tpu.inference.fleet_metrics import MetricsAggregator
 from paddle_tpu.observability import (MetricsRegistry, RequestTrace,
                                       SLOEngine, SLORule,
                                       TelemetryShipper, merge_snapshots)
 
-ENGINE_KW = dict(capacity=2, s_max=64, chunk=4, block_size=8)
-
-
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
+from harness import ENGINE_KW, shared_model, solo_generate
 
 
 # ---------------------------------------------------------------------------
@@ -368,7 +353,7 @@ class TestFleetTraceFailover:
         dead worker's segment to the survivor's, the Chrome export puts
         the segments in per-worker lanes, and output still bit-matches
         solo."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(5)
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
@@ -376,7 +361,7 @@ class TestFleetTraceFailover:
         for _ in range(4):
             p = rng.randint(1, 128, (10,)).astype(np.int32)
             reqs.append(fleet.submit(p, max_new_tokens=16))
-            expect.append(_solo(m, p, 16))
+            expect.append(solo_generate(m, p, 16))
         ids_before = [r.trace.trace_id for r in reqs]
         fleet.step()
         assert fleet.workers[1].occupancy > 0
@@ -436,7 +421,7 @@ class TestFleetSLOControlLoop:
         resolved through ``check_slo(now=)`` deterministically, and the
         FIRING alert measurably changes the affinity router's load
         penalty (restored on resolve)."""
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="affinity",
                              engine_kwargs=ENGINE_KW)
         seen = []
@@ -478,11 +463,11 @@ class TestFleetShipper:
         """An always-raising sink: the shipper drops with backoff, its
         self-observation counters land in the fleet scrape body, and
         generation output is bit-identical to a shipper-disabled run."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(11)
         prompts = [rng.randint(1, 128, (8,)).astype(np.int32)
                    for _ in range(3)]
-        expect = [_solo(m, p, 8) for p in prompts]
+        expect = [solo_generate(m, p, 8) for p in prompts]
 
         def run(sinks):
             fleet = ServingFleet(m, n_workers=2, policy="round_robin",
@@ -516,7 +501,7 @@ class TestFleetShipper:
         f_on.close()
 
     def test_collect_telemetry_payload_shape(self):
-        m = _model()
+        m = shared_model()
         fleet = ServingFleet(m, n_workers=2, policy="round_robin",
                              engine_kwargs=ENGINE_KW)
         fleet.enable_slo()

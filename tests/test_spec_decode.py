@@ -18,38 +18,16 @@ n_tokens; served_tokens counts emissions, not steps).
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.spec_decode import NgramDrafter
 
-
-def _model():
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM("debug")
-    m.eval()
-    return m
-
-
-def _solo(m, p, mn):
-    return np.asarray(m.generate(
-        paddle.to_tensor(p[None, :]), max_new_tokens=mn,
-        temperature=0.0)._value)[0]
-
-
-def _drive(eng, pending, iters=600):
-    for _ in range(iters):
-        eng.admit(pending)
-        eng.decode_once()
-        if eng.idle() and not pending:
-            return
-    raise AssertionError("engine did not drain the workload")
+from harness import drive, shared_model, solo_generate
 
 
 def _run(m, prompts, max_new, iters=600, **kw):
     from paddle_tpu.inference.serving import DecodeEngine, _Request
     eng = DecodeEngine(m, **kw)
     reqs = [_Request(p, max_new) for p in prompts]
-    _drive(eng, list(reqs), iters=iters)
+    drive(eng, list(reqs), iters=iters)
     return eng, reqs, [r.wait(timeout=1) for r in reqs]
 
 
@@ -96,25 +74,25 @@ class TestSpecEngine:
     def test_knob_validation(self):
         from paddle_tpu.inference.serving import DecodeEngine
         with pytest.raises(ValueError, match="paged"):
-            DecodeEngine(_model(), paged=False, spec_decode=True)
+            DecodeEngine(shared_model(), paged=False, spec_decode=True)
         with pytest.raises(ValueError, match="paged"):
-            DecodeEngine(_model(), paged=False, kv_dtype="int8")
+            DecodeEngine(shared_model(), paged=False, kv_dtype="int8")
         with pytest.raises(ValueError, match="kv_dtype"):
-            DecodeEngine(_model(), kv_dtype="fp16")
+            DecodeEngine(shared_model(), kv_dtype="fp16")
         with pytest.raises(ValueError, match="spec_max_draft"):
-            DecodeEngine(_model(), spec_decode=True, spec_max_draft=0)
+            DecodeEngine(shared_model(), spec_decode=True, spec_max_draft=0)
 
     def test_spec_bit_matches_greedy(self):
         """The tentpole oracle: spec ON emits EXACTLY the plain greedy
         tokens (every accepted token is the verify program's argmax),
         on a mix of draft-friendly periodic prompts and draft-hostile
         random ones — and actually accepts drafts on the former."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         prompts = [np.tile(rng.randint(1, 128, (8,)).astype(np.int32), 4),
                    rng.randint(1, 128, (17,)).astype(np.int32),
                    np.tile(rng.randint(1, 128, (6,)).astype(np.int32), 5)]
-        solo = [_solo(m, p, 16) for p in prompts]
+        solo = [solo_generate(m, p, 16) for p in prompts]
         kw = dict(capacity=4, s_max=128, chunk=4, block_size=16)
         _, _, plain = _run(m, prompts, 16, **kw)
         eng, reqs, spec = _run(m, prompts, 16, spec_decode=True, **kw)
@@ -130,7 +108,7 @@ class TestSpecEngine:
             == st["verify_steps"]
 
     def test_spec_with_chunked_prefill_bit_matches(self):
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(8)
         prompts = [np.tile(rng.randint(1, 128, (7,)).astype(np.int32), 5),
                    rng.randint(1, 128, (29,)).astype(np.int32)]
@@ -145,7 +123,7 @@ class TestSpecEngine:
         """A pool small enough that decode growth must preempt rows:
         preempted-mid-flight spec rows re-queue with their full emitted
         history and the final outputs still bit-match solo greedy."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(9)
         prompts = [rng.randint(1, 128, (24,)).astype(np.int32)
                    for _ in range(3)]
@@ -153,14 +131,14 @@ class TestSpecEngine:
             m, prompts, 16, capacity=3, s_max=64, chunk=4,
             block_size=8, n_blocks=13, spec_decode=True, iters=2000)
         for p, o in zip(prompts, out):
-            np.testing.assert_array_equal(o, _solo(m, p, 16))
+            np.testing.assert_array_equal(o, solo_generate(m, p, 16))
         assert eng.stats()["preempted"] > 0   # the scenario happened
 
     def test_rollback_conserves_allocator_accounting(self):
         """Rejected drafts roll back by lens rewind — no page churn.
         Under a reject-heavy random workload the allocator conservation
         invariant holds and the pool drains to empty at idle."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(10)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (9, 17, 23, 31)]
@@ -179,7 +157,7 @@ class TestSpecEngine:
         function of the weights and prompts)."""
         from paddle_tpu.inference.qos import QoSPolicy, TenantPolicy
         from paddle_tpu.inference.serving import DecodeEngine
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(30)
         prompts = [np.tile(rng.randint(1, 128, (6,)).astype(np.int32),
                            4) for _ in range(4)]
@@ -193,7 +171,7 @@ class TestSpecEngine:
             reqs = [eng.submit(p, max_new_tokens=12,
                                tenant="ab"[i % 2])
                     for i, p in enumerate(prompts)]
-            _drive(eng, [])
+            drive(eng, [])
             outs = [np.asarray(r.wait(timeout=5)) for r in reqs]
             return eng.stats()["spec"], qos.stats(), outs
 
@@ -209,7 +187,7 @@ class TestSpecEngine:
         a request's served_tokens equals its emitted decode tokens
         (max_new minus the prefill-produced first token) in BOTH the
         plain chunked path and the spec path."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(13)
         p = np.tile(rng.randint(1, 128, (8,)).astype(np.int32), 4)
         kw = dict(capacity=2, s_max=128, chunk=4, block_size=16)
@@ -322,7 +300,7 @@ class TestInt8PagedKV:
         changes logits by less than the greedy argmax margin — emitted
         tokens are identical to the fp pool (prefix cache and chunked
         prefill on, to exercise COW scale copies and the scatter path)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(23)
         prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                    for n in (8, 21, 33)]
@@ -344,7 +322,7 @@ class TestInt8PagedKV:
         import numpy as _np
         from paddle_tpu.kernels.paged_attention import KV_SCALE_EPS
         from paddle_tpu.inference.serving import DecodeEngine
-        eng = DecodeEngine(_model(), capacity=2, s_max=64, chunk=4,
+        eng = DecodeEngine(shared_model(), capacity=2, s_max=64, chunk=4,
                            block_size=8, prefix_cache=False,
                            kv_dtype="int8")
         assert eng._alloc.track_allocations
@@ -362,7 +340,7 @@ class TestInt8PagedKV:
         _np.testing.assert_array_equal(
             _np.asarray(eng._vscale[:, pg]), _np.float32(KV_SCALE_EPS))
         # fp engines never track, so the hand-out log stays empty
-        eng_fp = DecodeEngine(_model(), capacity=2, s_max=64, chunk=4,
+        eng_fp = DecodeEngine(shared_model(), capacity=2, s_max=64, chunk=4,
                               block_size=8, prefix_cache=False)
         eng_fp._alloc.allocate(2)
         assert eng_fp._alloc.drain_allocated() == []

@@ -20,41 +20,12 @@ sharded pool under COW."""
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference.serving import DecodeEngine
 from paddle_tpu.inference.sharding import (make_tp_mesh,
                                            validate_tp_config)
 
-
-def _model(preset="debug"):
-    paddle.seed(0)
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    m = LlamaForCausalLM(preset)
-    m.eval()
-    return m
-
-
-def _drain(eng, reqs):
-    eng.admit([])
-    for _ in range(10000):
-        eng.decode_once()
-        eng.admit([])
-        if eng.idle():
-            break
-    return [np.asarray(r.wait(timeout=120)) for r in reqs]
-
-
-def _run(m, prompts, max_new=8, mesh=None, **kw):
-    eng = DecodeEngine(m, capacity=4, s_max=64, chunk=4, block_size=8,
-                       mesh=mesh, **kw)
-    reqs = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
-    outs = _drain(eng, reqs)
-    return outs, eng
-
-
-def _prompts(rng, vocab, sizes):
-    return [rng.randint(1, vocab, (n,)).astype(np.int32)
-            for n in sizes]
+from harness import (drain, fresh_model, make_prompts, run_engine,
+                     shared_model, solo_generate)
 
 
 class TestShardedEngineParity:
@@ -63,7 +34,7 @@ class TestShardedEngineParity:
         decode + int8 KV all ON, tp=2 vs unsharded — greedy tokens
         bit-identical, and the sharded engine provably spends FEWER
         device launches (batched verify + single mixed step)."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(0)
         shared = rng.randint(1, 128, (10,)).astype(np.int32)
         wave1 = [np.tile(rng.randint(1, 128, (5,)).astype(np.int32), 4),
@@ -80,7 +51,7 @@ class TestShardedEngineParity:
             outs = []
             for wave in (wave1, wave2):   # second wave sees the cache
                 reqs = [eng.submit(p, max_new_tokens=10) for p in wave]
-                outs += _drain(eng, reqs)
+                outs += drain(eng, reqs)
             return outs, eng
 
         base, eng0 = run(None)
@@ -97,13 +68,13 @@ class TestShardedEngineParity:
     def test_tp4_parity(self):
         """tp=4 over the tiny preset (4 kv heads -> 1 head per shard,
         the deepest split the model admits)."""
-        m = _model("tiny")
+        m = shared_model("tiny")
         rng = np.random.RandomState(1)
-        prompts = _prompts(rng, 900, (9, 17))
-        base, _ = _run(m, prompts, chunked_prefill=True,
-                       spec_decode=True)
-        outs, eng = _run(m, prompts, mesh=make_tp_mesh(4),
-                         chunked_prefill=True, spec_decode=True)
+        prompts = make_prompts(rng, 900, (9, 17))
+        base, _ = run_engine(m, prompts, chunked_prefill=True,
+                             spec_decode=True)
+        outs, eng = run_engine(m, prompts, mesh=make_tp_mesh(4),
+                               chunked_prefill=True, spec_decode=True)
         for a, b in zip(base, outs):
             np.testing.assert_array_equal(a, b)
         assert eng.stats()["tp_degree"] == 4
@@ -116,13 +87,11 @@ class TestShardedEngineParity:
         import warnings
 
         import paddle_tpu.distributed as dist
-        m = _model()
+        m = fresh_model()
         rng = np.random.RandomState(2)
         p = rng.randint(1, 128, (10,)).astype(np.int32)
-        ref = np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=6,
-            temperature=0.0)._value)[0]
-        outs, _ = _run(m, [p], max_new=6, mesh=make_tp_mesh(2))
+        ref = solo_generate(m, p, 6)
+        outs, _ = run_engine(m, [p], max_new=6, mesh=make_tp_mesh(2))
         np.testing.assert_array_equal(outs[0], ref)
         mesh = dist.ProcessMesh(shape=[1, 1, 1, 1, 2],
                                 dim_names=["dp", "pp", "sep", "ep",
@@ -130,9 +99,7 @@ class TestShardedEngineParity:
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", RuntimeWarning)  # tiny dims
             dist.shard_model_state(m, mesh)
-        mp_out = np.asarray(m.generate(
-            paddle.to_tensor(p[None, :]), max_new_tokens=6,
-            temperature=0.0)._value)[0]
+        mp_out = solo_generate(m, p, 6)
         np.testing.assert_array_equal(outs[0], mp_out)
 
     def test_mesh_none_keeps_r14_outputs(self):
@@ -140,38 +107,36 @@ class TestShardedEngineParity:
         (mesh=None) must keep producing exactly the solo greedy
         outputs — the sharding hooks compile to the identical
         programs."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(3)
-        prompts = _prompts(rng, 128, (7, 12, 20))
+        prompts = make_prompts(rng, 128, (7, 12, 20))
         for kw in (dict(),
                    dict(chunked_prefill=True, spec_decode=True,
                         kv_dtype="int8", prefix_cache=True)):
-            outs, eng = _run(m, prompts, **kw)
+            outs, eng = run_engine(m, prompts, **kw)
             assert eng.mesh is None
             assert eng.stats()["tp_degree"] == 1
             assert "mesh_shape" not in eng.stats()
             for p, o in zip(prompts, outs):
-                ref = np.asarray(m.generate(
-                    paddle.to_tensor(p[None, :]), max_new_tokens=8,
-                    temperature=0.0)._value)[0]
+                ref = solo_generate(m, p, 8)
                 np.testing.assert_array_equal(o, ref)
 
 
 class TestValidation:
     def test_mesh_requires_paged(self):
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError, match="paged"):
             DecodeEngine(m, capacity=2, s_max=64, paged=False,
                          mesh=make_tp_mesh(2))
 
     def test_axis_name_checked(self):
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError, match="tp_axis"):
             DecodeEngine(m, capacity=2, s_max=64,
                          mesh=make_tp_mesh(2, axis="model"))
 
     def test_divisibility_checked(self):
-        m = _model()     # debug: 4 heads / 2 kv heads
+        m = shared_model()     # debug: 4 heads / 2 kv heads
         with pytest.raises(ValueError, match="kv"):
             DecodeEngine(m, capacity=2, s_max=64, mesh=make_tp_mesh(4))
         cfg = m.config
@@ -185,7 +150,7 @@ class TestValidation:
 
     def test_fleet_rejects_oversubscribed_submeshes(self):
         from paddle_tpu.inference.fleet import ServingFleet
-        m = _model()
+        m = shared_model()
         with pytest.raises(ValueError, match="devices"):
             ServingFleet(m, n_workers=5, tp_degree=2,
                          engine_kwargs=dict(capacity=2, s_max=64))
@@ -197,9 +162,9 @@ class TestShardedFleet:
         tp=2 over its own device pair, and routed traffic bit-matches
         the solo unsharded engine."""
         from paddle_tpu.inference.fleet import ServingFleet
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(5)
-        prompts = _prompts(rng, 128, (5, 11, 19, 8))
+        prompts = make_prompts(rng, 128, (5, 11, 19, 8))
         fleet = ServingFleet(m, n_workers=2, tp_degree=2,
                              engine_kwargs=dict(capacity=2, s_max=64,
                                                 chunk=4, block_size=8))
@@ -215,7 +180,7 @@ class TestShardedFleet:
             fleet.close()
         solo = []
         for p in prompts:
-            o, _ = _run(m, [p])
+            o, _ = run_engine(m, [p])
             solo.append(o[0])
         for a, b in zip(outs, solo):
             np.testing.assert_array_equal(a, b)
@@ -230,9 +195,9 @@ class TestShardedFleet:
                                                 FaultPlan)
         from paddle_tpu.inference.fleet import (RestartPolicy,
                                                 ServingFleet)
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(6)
-        prompts = _prompts(rng, 128, (10, 10, 10, 10))
+        prompts = make_prompts(rng, 128, (10, 10, 10, 10))
         vt = [0.0]
         fleet = ServingFleet(
             m, n_workers=2, policy="round_robin", tp_degree=2,
@@ -265,9 +230,7 @@ class TestShardedFleet:
         finally:
             fleet.close()
         for p, o in zip(prompts, outs):
-            ref = np.asarray(m.generate(
-                paddle.to_tensor(p[None, :]), max_new_tokens=10,
-                temperature=0.0)._value)[0]
+            ref = solo_generate(m, p, 10)
             np.testing.assert_array_equal(o, ref)
 
 
@@ -279,7 +242,7 @@ class TestShardedPoolInvariants:
         (total_allocated - total_freed == used) must hold at every
         step, and the final occupancy must match the unsharded engine
         page-for-page."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(7)
         shared = rng.randint(1, 128, (10,)).astype(np.int32)  # 8+2:
         #                 the 2-token tail page is the COW trigger
@@ -323,7 +286,7 @@ class TestShardedPoolInvariants:
     def test_pool_arrays_actually_sharded(self):
         """The tentpole's point: the per-device KV footprint is
         1/tp of the pool (the kv-head axis is split, not copied)."""
-        m = _model()
+        m = shared_model()
         eng = DecodeEngine(m, capacity=2, s_max=64, block_size=8,
                            mesh=make_tp_mesh(2), kv_dtype="int8")
         for arr in (eng._kp, eng._vp):
@@ -337,10 +300,10 @@ class TestShardedPoolInvariants:
         """Telemetry satellite: engine_device_calls_total counts every
         launch and engine_tp_degree reads the mesh, with the
         worker-labeled snapshot intact."""
-        m = _model()
+        m = shared_model()
         rng = np.random.RandomState(8)
-        outs, eng = _run(m, _prompts(rng, 128, (9,)),
-                         mesh=make_tp_mesh(2), spec_decode=True)
+        outs, eng = run_engine(m, make_prompts(rng, 128, (9,)),
+                               mesh=make_tp_mesh(2), spec_decode=True)
         snap = eng.metrics.snapshot()
         assert snap["gauges"]["engine_tp_degree"] == 2
         assert snap["counters"]["engine_device_calls_total"] \
