@@ -137,6 +137,16 @@ def paged_case(rng, block, dtype, heads=8, group=4, head_dim=128,
     return q, pool(), pool(), jnp.asarray(table), jnp.asarray(lens)
 
 
+def decode_case(case, *scales):
+    """``paged_case`` as the decode entry takes it: the pools (and int8
+    scales) stacked over two layers, the case's own as layer 1 behind a
+    layer of other values, and the layer index after the lengths."""
+    import jax.numpy as jnp
+    q, k, v, table, lens = case
+    k, v, *scales = (jnp.stack([a[::-1], a]) for a in (k, v, *scales))
+    return (q, k, v, table, lens, jnp.int32(1), *scales)
+
+
 def kernels_phase(seed, watch):
     """Each paged kernel against the repo's own XLA reference (the CPU
     tests' oracle) on one small ragged input at the real head widths.
@@ -168,16 +178,16 @@ def kernels_phase(seed, watch):
 
     fp = paged_case(rng, 16, jnp.bfloat16)
     check("paged_decode_bf16_block16", pa.paged_decode_attention,
-          pa._paged_attn_reference, *fp)
+          pa._paged_attn_reference, *decode_case(fp))
     n_pages, heads = fp[1].shape[:2]
     scales = [jnp.asarray(rng.uniform(0.005, 0.02, (n_pages, heads)),
                           jnp.float32) for _ in range(2)]
     check("paged_decode_int8_block32",
-          lambda q, k, v, t, n, ks, vs: pa.paged_decode_attention(
-              q, k, v, t, n, kv_scales=(ks, vs)),
-          lambda q, k, v, t, n, ks, vs: pa._paged_attn_reference_int8(
-              q, k, v, t, n, (ks, vs)),
-          *paged_case(rng, 32, jnp.int8), *scales)
+          lambda q, k, v, t, n, l, ks, vs: pa.paged_decode_attention(
+              q, k, v, t, n, l, kv_scales=(ks, vs)),
+          lambda q, k, v, t, n, l, ks, vs: pa._paged_attn_reference_int8(
+              q, k, v, t, n, l, (ks, vs)),
+          *decode_case(paged_case(rng, 32, jnp.int8), *scales))
     T = 16
     q_lens = np.asarray([1, 16, 5, 16], np.int32)
     q = jnp.asarray(rng.standard_normal(
@@ -488,12 +498,14 @@ def four_chip_phases(seed, watch, devices):
     from paddle_tpu.kernels import paged_attention as pa
     mark, t0 = watch.mark(), time.perf_counter()
     mesh = make_tp_mesh(4, devices=devices)
-    case = paged_case(np.random.default_rng(seed), 16, jnp.bfloat16)
-    heads = P(None, "tp", None, None)
+    case = decode_case(
+        paged_case(np.random.default_rng(seed), 16, jnp.bfloat16))
+    heads, pools = P(None, "tp", None, None), P(None, None, "tp", None, None)
     on_one = jax.jit(pa.paged_decode_attention)(*case)
     on_four = jax.jit(jax.shard_map(
         pa.paged_decode_attention, mesh=mesh,
-        in_specs=(heads, heads, heads, P(), P()), out_specs=heads))(*case)
+        in_specs=(heads, pools, pools, P(), P(), P()),
+        out_specs=heads))(*case)
     if device_span(on_four) != 4 or not np.array_equal(
             np.asarray(on_one), np.asarray(on_four)):
         raise AssertionError(

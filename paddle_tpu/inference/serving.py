@@ -475,7 +475,13 @@ class DecodeEngine:
         # Paged closures take the pool arrays LAST as ``*pool`` (ISSUE
         # 8): fp engines pass (kp, vp), int8 engines (kp, vp, kscale,
         # vscale) — one closure body serves both layouts, and the int8
-        # scale updates stay inside the compiled programs.
+        # scale updates stay inside the compiled programs. The arrays
+        # are stacked over layers ([L, N, kvh, bs, hd]; scales
+        # [L, N, kvh]) and the decode program never takes them apart:
+        # it carries the tuple through its chunk scan and its layer
+        # scan, each layer writes its rows' pages and reads its pages
+        # in place, so the donated buffers are the only pool-sized
+        # values the program has.
 
         def _kv_scales_of(pool):
             return (pool[2], pool[3]) if len(pool) == 4 else None
@@ -508,16 +514,16 @@ class DecodeEngine:
                 lm = embed.T
 
             def body(carry, i):
-                tok = carry[0]
-                out = _llama._paged_decode_step(
-                    cfg, stacked, embed, fnorm, lm, tok, carry[1],
-                    carry[2], tables, lens + i, *carry[3:],
-                    mp_axis=mp, seq_axis=sq, n_seq=n_sq)
-                nxt = jnp.argmax(out[0], axis=-1)
-                return (nxt, *out[1:]), nxt
+                tok, pool = carry
+                logits, pool = _llama._paged_decode_step(
+                    cfg, stacked, embed, fnorm, lm, tok, tables,
+                    lens + i, pool, mp_axis=mp, seq_axis=sq,
+                    n_seq=n_sq)
+                nxt = jnp.argmax(logits, axis=-1)
+                return (nxt, pool), nxt
 
-            (tok, *pool), toks = jax.lax.scan(
-                body, (tok, *pool), jnp.arange(self.chunk))
+            (tok, pool), toks = jax.lax.scan(
+                body, (tok, pool), jnp.arange(self.chunk))
             return (toks, *pool)
 
         def make_prefix_prefill(sc):

@@ -1,8 +1,9 @@
 """Pallas ragged paged-attention decode kernel (TPU).
 
 The serving decode path's KV cache becomes a BLOCK POOL
-``[n_blocks, kvh, block_size, hd]`` with a per-row block table instead
-of one contiguous right-aligned region (reference shape: "Ragged Paged
+``[n_blocks, kvh, block_size, hd]`` a layer, the layers STACKED in one
+array ``[L, n_blocks, kvh, block_size, hd]``, with a per-row block table
+instead of one contiguous right-aligned region (reference shape: "Ragged Paged
 Attention", arxiv 2604.15464 — the TPU-native kernel form of
 vLLM/PagedAttention). Rows own ragged per-row lengths; the kernel
 gathers each row's K/V blocks through the table, so admission never
@@ -10,10 +11,15 @@ needs a global fill position and the DecodeEngine never resets.
 
 Design (single-query decode, one token per row):
 - q: [B, kvh, G, hd] (grouped query heads for the token being decoded)
-- k_pages/v_pages: [N, kvh, bs, hd] block pool — the kv head sits
-  AHEAD of the ``[bs, hd]`` tile, so one (page, head) is one contiguous,
-  tile-aligned DMA (the chip's tiler refuses a copy that takes one head
-  out of the second-minor axis). Page 0 is the reserved NULL page
+- k_pages/v_pages: [L, N, kvh, bs, hd], the engine's stacked pools as
+  they lie in HBM, and ``layer``, the index of the layer to read — the
+  kv head sits AHEAD of the ``[bs, hd]`` tile, so one (layer, page,
+  head) is one contiguous, tile-aligned DMA (the chip's tiler refuses a
+  copy that takes one head out of the second-minor axis). The decode
+  step carries the pools through its layer scan and hands every layer
+  the whole stack: a launch never sees a slice of a pool, so nothing of
+  a pool's size is cut out, copied or re-laid for it, and a step costs
+  the same whatever the pool holds. Page 0 is the reserved NULL page
   (allocators never hand it out; padded table entries and inactive rows
   write there, so fixed-shape programs need no masks)
 - block_table: [B, max_blocks] int32 page ids (data argument — shapes
@@ -21,9 +27,9 @@ Design (single-query decode, one token per row):
 - seq_lens: [B] int32 valid tokens per row (ragged lengths)
 - grid (B, kvh): each program owns one (row, kv head); the row's pages
   stream HBM→VMEM through double-buffered ``make_async_copy`` DMA with
-  the page id scalar-prefetched from the table
-  (``PrefetchScalarGridSpec``) — the flash_attention.py streaming idiom
-  applied through one level of indirection
+  the page id scalar-prefetched from the table and the layer index
+  beside it (``PrefetchScalarGridSpec``) — the flash_attention.py
+  streaming idiom applied through one level of indirection
 - online softmax (f32 m/l/acc) over the row's ceil(len/bs) blocks; the
   ragged tail masks positions >= seq_len
 - every dot pins ``precision=DEFAULT`` like flash_attention.py: the
@@ -33,7 +39,8 @@ Design (single-query decode, one token per row):
   scalar prefetch execute faithfully under ``interpret=True``, so CI
   proves the math without a TPU
 
-The XLA fallback (`_paged_attn_reference`) gathers the row's pages into
+The XLA fallback (`_paged_attn_reference`) gathers the row's pages
+(indexed ``[layer, page]`` in the stacked pool, one gather) into
 a contiguous view and runs the same masked softmax math as
 ``models.llama._decode_attention`` — bit-matching the contiguous-cache
 decode on CPU, which is what the engine parity tests pin.
@@ -47,7 +54,8 @@ prefill schedules into every decode step.
 
 ISSUE 8 adds int8 PAGE READS: the block pool may store K/V as int8
 with one f32 scale per (page, kv head) living beside the pool
-(``kv_scales=(kscale, vscale)``, each [N, kvh]), dequantized INSIDE
+(``kv_scales=(kscale, vscale)``, each [N, kvh] a layer, stacked
+[L, N, kvh] for the decode entries), dequantized INSIDE
 the attention program — the r6 weight-dequant-inside-the-kernel recipe
 applied to the KV stream, halving the bytes a decode step moves.
 The int8 XLA reference (:func:`_paged_attn_reference_int8`) is a
@@ -93,12 +101,14 @@ _NEG_INF = -1e30
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref, k_s, v_s,
-                  ksem, vsem, *, bs, scale):
+def _paged_kernel(tables, lens, layer, q_ref, k_hbm, v_hbm, o_ref, k_s,
+                  v_s, ksem, vsem, *, bs, scale):
     """One program = one (row, kv_head): G query rows against the row's
-    ragged page list, pages double-buffered HBM→VMEM."""
+    ragged page list in layer ``layer[0]`` of the stacked pools, pages
+    double-buffered HBM→VMEM."""
     b = pl.program_id(0)
     h = pl.program_id(1)
+    lyr = layer[0]
     q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
     g, hd = q.shape
 
@@ -107,11 +117,11 @@ def _paged_kernel(tables, lens, q_ref, k_hbm, v_hbm, o_ref, k_s, v_s,
 
     def kdma(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[tables[b, j], h], k_s.at[slot], ksem.at[slot])
+            k_hbm.at[lyr, tables[b, j], h], k_s.at[slot], ksem.at[slot])
 
     def vdma(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[tables[b, j], h], v_s.at[slot], vsem.at[slot])
+            v_hbm.at[lyr, tables[b, j], h], v_s.at[slot], vsem.at[slot])
 
     m0 = jnp.full((g,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((g,), jnp.float32)
@@ -196,7 +206,7 @@ def _out_struct(shape, *operands):
     return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
 
-def _paged_kernel_int8(tables, lens, q_ref, ks_ref, vs_ref, k_hbm,
+def _paged_kernel_int8(tables, lens, layer, q_ref, ks_ref, vs_ref, k_hbm,
                        v_hbm, o_ref, k_s, v_s, ksem, vsem, *, bs, scale):
     """int8 twin of :func:`_paged_kernel`: identical DMA structure, but
     the streamed pages are int8 codes dequantized inside the program.
@@ -206,6 +216,7 @@ def _paged_kernel_int8(tables, lens, q_ref, ks_ref, vs_ref, k_hbm,
     scales and never the pool's."""
     b = pl.program_id(0)
     h = pl.program_id(1)
+    lyr = layer[0]
     q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
     g, hd = q.shape
 
@@ -214,11 +225,11 @@ def _paged_kernel_int8(tables, lens, q_ref, ks_ref, vs_ref, k_hbm,
 
     def kdma(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[tables[b, j], h], k_s.at[slot], ksem.at[slot])
+            k_hbm.at[lyr, tables[b, j], h], k_s.at[slot], ksem.at[slot])
 
     def vdma(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[tables[b, j], h], v_s.at[slot], vsem.at[slot])
+            v_hbm.at[lyr, tables[b, j], h], v_s.at[slot], vsem.at[slot])
 
     m0 = jnp.full((g,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((g,), jnp.float32)
@@ -252,84 +263,44 @@ def _paged_kernel_int8(tables, lens, q_ref, ks_ref, vs_ref, k_hbm,
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
-                           interpret=False, kv_scales=None):
-    """Raw Pallas launch. q [B, kvh, G, hd]; k/v_pages [N, kvh, bs, hd];
-    block_table [B, max_blocks] int32; seq_lens [B] int32. Returns
-    [B, kvh, G, hd] f32. ``kv_scales=(kscale, vscale)`` ([N, kvh] f32
-    each) switches to the int8 kernel: the pools hold int8 codes,
-    dequantized inside the program."""
-    if kv_scales is not None:
-        return _paged_attention_pallas_int8(
-            q, k_pages, v_pages, block_table, seq_lens, kv_scales,
-            interpret=interpret)
+                           layer, interpret=False, kv_scales=None):
+    """Raw Pallas launch against layer ``layer`` of the STACKED pools.
+    q [B, kvh, G, hd]; k/v_pages [L, N, kvh, bs, hd]; block_table
+    [B, max_blocks] int32; seq_lens [B] int32; layer an int32 scalar
+    (data: the decode step's layer scan hands it its counter). The pools
+    stay in HBM where they lie (``pl.ANY``) and the layer rides the
+    scalar-prefetch lane beside the table, so a page is read at
+    ``[layer, page, head]`` and nothing of a pool's size is sliced, moved
+    or re-laid for the launch. Returns [B, kvh, G, hd] f32.
+    ``kv_scales=(kscale, vscale)`` ([L, N, kvh] f32 each) switches to the
+    int8 kernel: the pools hold int8 codes, dequantized inside the
+    program."""
     B, kvh, G, hd = q.shape
-    bs = k_pages.shape[2]
-    scale = 1.0 / (hd ** 0.5)
-    kernel = functools.partial(_paged_kernel, bs=bs, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, kvh),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, *_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, bs, hd), k_pages.dtype),
-            pltpu.VMEM((2, bs, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_out_struct((B, kvh, G, hd), q, k_pages, v_pages),
-        interpret=interpret,
-    )(jnp.asarray(block_table, jnp.int32),
-      jnp.asarray(seq_lens, jnp.int32), q, k_pages, v_pages)
-
-
-def _row_page_scales(scales, block_table):
-    """[N, kvh] page scales -> [B, kvh, 1, max_blocks], row b's scales
-    in block-table order: what one (row, kv head) program of the int8
-    kernel reads."""
-    rows = jnp.take(jnp.asarray(scales, jnp.float32), block_table,
-                    axis=0)                               # [B, mb, kvh]
-    return jnp.swapaxes(rows, 1, 2)[:, :, None, :]
-
-
-def _paged_attention_pallas_int8(q, k_pages, v_pages, block_table,
-                                 seq_lens, kv_scales, interpret=False):
-    """int8 launch: pools are int8 codes, ``kv_scales=(kscale, vscale)``
-    ([N, kvh] f32 each). The scales a row needs are gathered through its
-    block table here and handed to each program as an SMEM block. (The
-    whole ``[N, kvh]`` arrays used to ride the scalar-prefetch lane; the
-    chip pads each row to 128 lanes there, so 4096 pages already asked
-    for 2 MiB of its 1 MiB of scalar memory.)"""
-    kscale, vscale = kv_scales
-    B, kvh, G, hd = q.shape
-    bs = k_pages.shape[2]
+    bs = k_pages.shape[3]
     block_table = jnp.asarray(block_table, jnp.int32)
-    mb = block_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
-    kernel = functools.partial(_paged_kernel_int8, bs=bs, scale=scale)
-    sc_spec = pl.BlockSpec((1, 1, 1, mb), lambda b, h, *_: (b, h, 0, 0),
-                           memory_space=pltpu.SMEM)
+    q_spec = pl.BlockSpec((1, 1, G, hd), lambda b, h, *_: (b, h, 0, 0))
+    if kv_scales is None:
+        kernel, scales, sc_specs = _paged_kernel, (), []
+    else:
+        # The scales a row needs are gathered through its block table
+        # here and handed to each program as an SMEM block. (The whole
+        # ``[N, kvh]`` arrays used to ride the scalar-prefetch lane; the
+        # chip pads each row to 128 lanes there, so 4096 pages already
+        # asked for 2 MiB of its 1 MiB of scalar memory.)
+        kernel = _paged_kernel_int8
+        scales = tuple(_row_page_scales(sc, block_table, layer)
+                       for sc in kv_scales)
+        sc_specs = [pl.BlockSpec(
+            (1, 1, 1, block_table.shape[1]),
+            lambda b, h, *_: (b, h, 0, 0), memory_space=pltpu.SMEM)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, kvh),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, *_: (b, h, 0, 0)),
-            sc_spec,
-            sc_spec,
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, *_: (b, h, 0, 0)),
+        in_specs=[q_spec, *sc_specs,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((2, bs, hd), k_pages.dtype),
             pltpu.VMEM((2, bs, hd), v_pages.dtype),
@@ -338,13 +309,21 @@ def _paged_attention_pallas_int8(q, k_pages, v_pages, block_table,
         ],
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(kernel, bs=bs, scale=scale),
         grid_spec=grid_spec,
         out_shape=_out_struct((B, kvh, G, hd), q, k_pages, v_pages),
         interpret=interpret,
-    )(block_table, jnp.asarray(seq_lens, jnp.int32), q,
-      _row_page_scales(kscale, block_table),
-      _row_page_scales(vscale, block_table), k_pages, v_pages)
+    )(block_table, jnp.asarray(seq_lens, jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, *scales, k_pages,
+      v_pages)
+
+
+def _row_page_scales(scales, block_table, layer):
+    """[L, N, kvh] page scales -> [B, kvh, 1, max_blocks], row b's
+    scales of layer ``layer`` in block-table order: what one (row, kv
+    head) program of the int8 kernel reads."""
+    rows = jnp.asarray(scales, jnp.float32)[layer, block_table]
+    return jnp.swapaxes(rows, 1, 2)[:, :, None, :]    # from [B, mb, kvh]
 
 
 # ---------------------------------------------------------------------------
@@ -359,36 +338,48 @@ def _rows_view(g, B, mb):
     return g.reshape(B, mb * bs, kvh, hd)
 
 
-def gather_pages(pages, block_table):
-    """[N, kvh, bs, hd] pool + [B, max_blocks] table -> contiguous
-    per-row view [B, max_blocks*bs, kvh, hd] (padded tail reads the
-    NULL page — masked out by seq_lens downstream)."""
+def _take_pages(pages, ids, layer):
+    """Pages ``ids`` of a pool ``[N, ...]``, or with ``layer`` given of
+    that layer of a stacked pool ``[L, N, ...]``: ONE gather either way,
+    indexed ``[layer, page]`` where the pool lies, never a slice of it."""
+    return jnp.take(pages, ids, axis=0) if layer is None \
+        else pages[layer, ids]
+
+
+def gather_pages(pages, block_table, layer=None):
+    """[N, kvh, bs, hd] pool (or, with ``layer``, that layer of the
+    stacked [L, N, kvh, bs, hd] pool) + [B, max_blocks] table ->
+    contiguous per-row view [B, max_blocks*bs, kvh, hd] (padded tail
+    reads the NULL page — masked out by seq_lens downstream)."""
     B, mb = block_table.shape
-    g = jnp.take(pages, block_table.reshape(-1), axis=0)
+    g = _take_pages(pages, block_table.reshape(-1), layer)
     return _rows_view(g, B, mb)
 
 
-def gather_pages_dequant(pages, block_table, scales):
+def gather_pages_dequant(pages, block_table, scales, layer=None):
     """int8 counterpart of :func:`gather_pages`: gather code pages AND
     their per-(page, kv head) scales, dequantize to f32. pages
-    [N, kvh, bs, hd] int8; scales [N, kvh] f32. Returns
+    [N, kvh, bs, hd] int8; scales [N, kvh] f32 (both with a leading L
+    when ``layer`` is given). Returns
     [B, max_blocks*bs, kvh, hd] f32 (NULL-page tail dequantizes with
     whatever scale page 0 carries — masked out by seq_lens downstream
     exactly like the fp gather)."""
     B, mb = block_table.shape
     flat = block_table.reshape(-1)
-    g = jnp.take(pages, flat, axis=0).astype(jnp.float32)
-    sc = jnp.take(scales, flat, axis=0)            # [B*mb, kvh]
+    g = _take_pages(pages, flat, layer).astype(jnp.float32)
+    sc = _take_pages(scales, flat, layer)          # [B*mb, kvh]
     return _rows_view(g * sc[:, :, None, None], B, mb)
 
 
-def _paged_attn_reference(q, k_pages, v_pages, block_table, seq_lens):
-    """Gather-then-masked-softmax, the exact math of
+def _paged_attn_reference(q, k_pages, v_pages, block_table, seq_lens,
+                          layer):
+    """Gather-then-masked-softmax over layer ``layer`` of the stacked
+    pools, the exact math of
     models.llama._decode_attention's single-softmax branch — masked
     entries contribute exact zeros, so contiguous-cache decode and
     paged decode bit-match on the same tokens."""
-    ck = gather_pages(k_pages, block_table)     # [B, S, kvh, hd]
-    cv = gather_pages(v_pages, block_table)
+    ck = gather_pages(k_pages, block_table, layer)  # [B, S, kvh, hd]
+    cv = gather_pages(v_pages, block_table, layer)
     s_tot = ck.shape[1]
     mask = jnp.arange(s_tot)[None, :] < seq_lens[:, None]
     qf = q.astype(jnp.float32)                  # [B, kvh, G, hd]
@@ -401,7 +392,7 @@ def _paged_attn_reference(q, k_pages, v_pages, block_table, seq_lens):
 
 
 def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
-                               seq_lens, kv_scales):
+                               seq_lens, layer, kv_scales):
     """int8 XLA reference: a BLOCK-LOOPED online softmax, deliberately
     NOT the single-softmax gather shape of
     :func:`_paged_attn_reference`. Each (row, kv head) cell walks its
@@ -416,7 +407,7 @@ def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
     k_pages = jnp.asarray(k_pages)
     v_pages = jnp.asarray(v_pages)
     B, kvh, G, hd = q.shape
-    bs = k_pages.shape[2]
+    bs = k_pages.shape[3]
     scale = 1.0 / (hd ** 0.5)
     tables = jnp.asarray(block_table, jnp.int32)
     lens = jnp.asarray(seq_lens, jnp.int32)
@@ -431,14 +422,13 @@ def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
             def body(j, carry, b=b, h=h, qc=qc, n=n):
                 m, l, acc = carry
                 page = tables[b, j]
-                kc = jax.lax.dynamic_index_in_dim(
-                    k_pages, page, 0, keepdims=False)[h]
-                vc = jax.lax.dynamic_index_in_dim(
-                    v_pages, page, 0, keepdims=False)[h]
+                kc = k_pages[layer, page, h]
+                vc = v_pages[layer, page, h]
                 k_ids = j * bs + jax.lax.broadcasted_iota(
                     jnp.int32, (G, bs), 1)
                 return _int8_block_update(
-                    qc, kc, vc, kscale[page, h], vscale[page, h],
+                    qc, kc, vc, kscale[layer, page, h],
+                    vscale[layer, page, h],
                     m, l, acc, k_ids, n, scale)
 
             m0 = jnp.full((G,), _NEG_INF, jnp.float32)
@@ -456,35 +446,41 @@ def _kernel_serves(pages):
     and the XLA references: the kernels on the TPU backend when a
     (page, kv head) slab ``[bs, hd]`` is tile-aligned for the pool's
     dtype, the references everywhere else (the CPU tests' oracle)."""
-    bs, hd = pages.shape[2], pages.shape[3]
+    bs, hd = pages.shape[-2:]
     min_bs = 32 if pages.dtype == jnp.int8 else 8
     return (jax.default_backend() == "tpu" and hd % 128 == 0
             and bs % min_bs == 0)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
-                           kv_scales=None, seq_axis=None, n_seq=1):
-    """Entry used by the llama paged decode step: the Pallas kernel on
+                           layer, kv_scales=None, seq_axis=None, n_seq=1):
+    """Entry used by the llama paged decode step, against layer
+    ``layer`` (an int32 scalar, data) of the STACKED pools
+    [L, N, kvh, bs, hd] (scales [L, N, kvh]): the Pallas kernel on
     TPU when the block pool is tileable (:func:`_kernel_serves`), else
     the XLA gather reference (CPU tests pin the reference's bit-parity
     with the contiguous path; the kernel's own parity is pinned in
-    interpret mode). ``kv_scales`` switches to the int8 path.
+    interpret mode). Every form reads the layer's pages where they lie:
+    none takes a slice of a pool. ``kv_scales`` switches to the int8
+    path.
     ``seq_axis`` (inside a shard_map whose pools are page-sharded over
     that mesh axis into ``n_seq`` stripes) switches to the
     partial-softmax form — each shard attends over its local pages and
     the partials merge with one collective (SURVEY §7.22)."""
     if seq_axis is not None and n_seq > 1:
         return _paged_decode_attention_seq(
-            q, k_pages, v_pages, block_table, seq_lens, seq_axis,
-            n_seq, kv_scales=kv_scales)
+            q, k_pages, v_pages, block_table, seq_lens, layer,
+            seq_axis, n_seq, kv_scales=kv_scales)
     if _kernel_serves(k_pages):
         return paged_attention_pallas(q, k_pages, v_pages, block_table,
-                                      seq_lens, kv_scales=kv_scales)
+                                      seq_lens, layer,
+                                      kv_scales=kv_scales)
     if kv_scales is not None:
         return _paged_attn_reference_int8(
-            q, k_pages, v_pages, block_table, seq_lens, kv_scales)
+            q, k_pages, v_pages, block_table, seq_lens, layer,
+            kv_scales)
     return _paged_attn_reference(q, k_pages, v_pages, block_table,
-                                 seq_lens)
+                                 seq_lens, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -759,21 +755,21 @@ def merge_softmax_partials(m, l, acc, axis):
 
 
 def _paged_decode_attention_seq(q, k_pages, v_pages, block_table,
-                                seq_lens, seq_axis, n_seq,
+                                seq_lens, layer, seq_axis, n_seq,
                                 kv_scales=None):
     """Page-sharded decode attention: the `_paged_attn_reference` math
     over this shard's strided columns, finished by
     :func:`merge_softmax_partials`. q [B, kvh_loc, G, hd]; pools
-    [n_local, kvh_loc, bs, hd]."""
-    n_local, bs = k_pages.shape[0], k_pages.shape[2]
+    [L, n_local, kvh_loc, bs, hd], read at layer ``layer``."""
+    n_local, bs = k_pages.shape[1], k_pages.shape[3]
     local, k_ids = _seq_gather_ids(block_table, n_seq, n_local, bs,
                                    seq_axis)
     if kv_scales is not None:
-        ck = gather_pages_dequant(k_pages, local, kv_scales[0])
-        cv = gather_pages_dequant(v_pages, local, kv_scales[1])
+        ck = gather_pages_dequant(k_pages, local, kv_scales[0], layer)
+        cv = gather_pages_dequant(v_pages, local, kv_scales[1], layer)
     else:
-        ck = gather_pages(k_pages, local)       # [B, W*bs, kvh, hd]
-        cv = gather_pages(v_pages, local)
+        ck = gather_pages(k_pages, local, layer)  # [B, W*bs, kvh, hd]
+        cv = gather_pages(v_pages, local, layer)
     mask = k_ids[None, :] < seq_lens[:, None]   # [B, W*bs]
     qf = q.astype(jnp.float32)
     scale = q.shape[-1] ** 0.5

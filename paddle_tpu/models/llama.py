@@ -968,60 +968,95 @@ def _decode_step(cfg, stacked, embed, final_norm, lm_head, token, cache_k,
     return logits, cks, cvs
 
 
-def _quantized_token_insert(pool, scales, page, off, tok,
-                            seq_axis=None):
-    """Append ONE token per row into an int8 pool page with a
-    RUNNING-MAX per-(page, kv head) scale (ISSUE 8 int8 paged KV).
+def _write_page_ids(page, n_pages, seq_axis):
+    """Where a decode step reads and writes its rows' write pages
+    ``page`` [b]: (read ids, write ids, scatter mode). On page-sharded
+    pools (``seq_axis``, 2-D mesh) ``page`` is a GLOBAL id: reads clamp
+    into the local stripe of ``n_pages`` (garbage on non-owners, whose
+    writes are dropped) and writes rebase + drop non-owned rows, so the
+    update lands exactly once, on the owning shard."""
+    if seq_axis is None:
+        return page, page, None
+    wp, owned = seq_local_pages(page, n_pages, seq_axis)
+    return jnp.where(owned, wp, 0), wp, "drop"
 
-    pool [N, kvh, bs, hd] int8 codes; scales [N, kvh] f32; page/off [b]
-    int32 write cursors; tok [b, kvh, hd] f32. The page's scale only
+
+def _set_page_row(pages, off, tok):
+    """pages [b, kvh, bs, hd] with row ``off[b]`` of row b's page
+    replaced by tok [b, kvh, hd]. A select, which fuses into the ops
+    around it in the pages' own layout (a scatter along the in-page
+    axis asks for another)."""
+    slot = jnp.arange(pages.shape[2])[:, None] == off[:, None, None, None]
+    return jnp.where(slot, tok[:, :, None, :].astype(pages.dtype), pages)
+
+
+def _token_insert(pool, layer, page, off, tok, seq_axis=None):
+    """Append ONE token per row into layer ``layer`` of a stacked pool
+    [L, N, kvh, bs, hd]: page/off [b] int32 write cursors, tok
+    [b, kvh, hd]. Written as a read-modify-write of the rows' PAGES
+    (b x [kvh, bs, hd], gathered and scattered whole at
+    ``[layer, page]``), not as a scatter of b rows of ``hd``: a page is
+    what the paged kernel's DMA reads, so the chip's compiler keeps the
+    pool in the kernel's layout for both, where a scatter along the
+    in-page axis wants a layout of its own and pays for it with a copy
+    of the whole pool in every layer. A row's write page is private
+    (shared prefix pages are full; copy-on-write clones a partial one
+    at admission), so no two live rows collide; inactive rows all land
+    on the NULL page, whose content nobody reads. ``seq_axis``:
+    :func:`_write_page_ids`."""
+    rp, wp, mode = _write_page_ids(page, pool.shape[1], seq_axis)
+    pages = _set_page_row(pool[layer, rp], off, tok)  # [b, kvh, bs, hd]
+    return pool.at[layer, wp].set(pages, mode=mode)
+
+
+def _quantized_token_insert(pool, scales, layer, page, off, tok,
+                            seq_axis=None):
+    """Append ONE token per row into layer ``layer`` of a stacked int8
+    pool with a RUNNING-MAX per-(page, kv head) scale (ISSUE 8 int8
+    paged KV).
+
+    pool [L, N, kvh, bs, hd] int8 codes; scales [L, N, kvh] f32; layer
+    an int32 scalar; page/off [b] int32 write cursors; tok [b, kvh, hd]
+    f32. Only the rows' pages are read and written, at ``[layer, page]``
+    where the pool lies. The page's scale only
     ever grows (``new = max(old, amax(tok)/127)``), and the resident
     codes are re-expressed in the new scale by ``round(q * old/new)`` —
     when the token doesn't raise the max the ratio is exactly 1.0 and
     ``round(q * 1.0) == q``, so untouched tokens keep their codes
     bit-identical (the no-op case every step but the occasional
     outlier). Inactive rows write the NULL page, same as the fp path.
-
-    ``seq_axis``: page-sharded pools (2-D mesh) — ``page`` is a GLOBAL
-    id; reads clamp into the local stripe (garbage on non-owners, whose
-    writes are dropped) and writes rebase + drop non-owned rows, so the
-    update lands exactly once, on the owning shard."""
-    b = tok.shape[0]
-    if seq_axis is not None:
-        wp, owned = seq_local_pages(page, pool.shape[0], seq_axis)
-        rp = jnp.where(owned, wp, 0)
-    else:
-        wp = rp = page
+    ``seq_axis``: :func:`_write_page_ids`."""
+    rp, wp, mode = _write_page_ids(page, pool.shape[1], seq_axis)
     amax = jnp.abs(tok).max(axis=-1)                     # [b, kvh]
-    old = jnp.take(scales, rp, axis=0)                   # [b, kvh]
+    old = scales[layer, rp]                              # [b, kvh]
     new = jnp.maximum(old, amax / 127.0)
-    codes = jnp.take(pool, rp, axis=0)                   # [b, kvh, bs, hd]
+    codes = pool[layer, rp]                              # [b, kvh, bs, hd]
     ratio = (old / new)[:, :, None, None]
     req = jnp.clip(jnp.round(codes.astype(jnp.float32) * ratio),
                    -127, 127)
     qt = jnp.clip(jnp.round(tok / new[:, :, None]), -127, 127)
-    req = req.at[jnp.arange(b), :, off].set(qt)
-    if seq_axis is not None:
-        pool = pool.at[wp].set(req.astype(pool.dtype), mode="drop")
-        scales = scales.at[wp].set(new, mode="drop")
-    else:
-        pool = pool.at[page].set(req.astype(pool.dtype))
-        scales = scales.at[page].set(new)
+    req = _set_page_row(req, off, qt)
+    pool = pool.at[layer, wp].set(req.astype(pool.dtype), mode=mode)
+    scales = scales.at[layer, wp].set(new, mode=mode)
     return pool, scales
 
 
-def _paged_decode_layer_step(cfg, lp, x, kp, vp, tables, lens,
-                             kscale=None, vscale=None, mp_axis=None,
-                             seq_axis=None, n_seq=1):
+def _paged_decode_layer_step(cfg, lp, x, pool, layer, tables, lens,
+                             mp_axis=None, seq_axis=None, n_seq=1):
     """One decoder layer for ONE token per row against the PAGED KV
-    cache: kp/vp [N, kvh, bs, hd] block pool, tables [b, max_blocks]
-    int32 page ids, lens [b] int32 = tokens already cached (the new
-    token's 0-based position). No left-pad: every row's history starts
-    at its own position 0, so admission needs no global fill. With
-    ``kscale``/``vscale`` ([N, kvh] f32) the pools are int8 codes:
-    writes go through :func:`_quantized_token_insert` and the attention
-    dequantizes inside the paged program. ``mp_axis``: inside a
-    shard_map region the pool/weights are kv-head shards and the
+    cache. ``pool`` is the engine's whole cache, stacked over layers:
+    (kp, vp), each [L, N, kvh, bs, hd], or for int8 pools (kp, vp,
+    kscale, vscale) with the scales [L, N, kvh] f32; ``layer`` is this
+    layer's index (an int32 scalar, data); tables [b, max_blocks] int32
+    page ids; lens [b] int32 = tokens already cached (the new token's
+    0-based position). Returns (x, pool): the same arrays with this
+    layer's token written at ``[layer, page, :, off]`` and nothing else
+    touched — the layer never holds a slice of a pool, and the attention
+    reads the layer's pages where they lie. No left-pad: every row's
+    history starts at its own position 0, so admission needs no global
+    fill. int8 writes go through :func:`_quantized_token_insert` and the
+    attention dequantizes inside the paged program. ``mp_axis``: inside
+    a shard_map region the pool/weights are kv-head shards and the
     wo/w_down matmuls finish with a psum (ISSUE 10, same Megatron
     pattern as _decoder_layer). ``seq_axis``/``n_seq``: the pools are
     additionally PAGE shards of a 2-D mesh (ISSUE 16) — writes route
@@ -1031,6 +1066,7 @@ def _paged_decode_layer_step(cfg, lp, x, kp, vp, tables, lens,
     h = lp["wq"].shape[-1] // hd
     kvh = lp["wk"].shape[-1] // hd
     b = x.shape[0]
+    kp, vp = pool[:2]
     bs = kp.shape[-2]
     g = h // kvh
     pos = lens[:, None]                      # per-row rope position
@@ -1055,28 +1091,21 @@ def _paged_decode_layer_step(cfg, lp, x, kp, vp, tables, lens,
     page = jnp.take_along_axis(tables, (lens // bs)[:, None],
                                axis=1)[:, 0]
     off = lens % bs
-    if kscale is not None:
+    if len(pool) == 4:
         kp, kscale = _quantized_token_insert(
-            kp, kscale, page, off, k[:, 0].astype(jnp.float32),
+            kp, pool[2], layer, page, off, k[:, 0].astype(jnp.float32),
             seq_axis=seq_axis)
         vp, vscale = _quantized_token_insert(
-            vp, vscale, page, off, v[:, 0].astype(jnp.float32),
+            vp, pool[3], layer, page, off, v[:, 0].astype(jnp.float32),
             seq_axis=seq_axis)
-        kv_scales = (kscale, vscale)
-    elif seq_axis is not None:
-        wp, _ = seq_local_pages(page, kp.shape[0], seq_axis)
-        kp = kp.at[wp, :, off].set(k[:, 0].astype(kp.dtype),
-                                   mode="drop")
-        vp = vp.at[wp, :, off].set(v[:, 0].astype(vp.dtype),
-                                   mode="drop")
-        kv_scales = None
+        pool = (kp, vp, kscale, vscale)
     else:
-        kp = kp.at[page, :, off].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[page, :, off].set(v[:, 0].astype(vp.dtype))
-        kv_scales = None
+        kp = _token_insert(kp, layer, page, off, k[:, 0], seq_axis)
+        vp = _token_insert(vp, layer, page, off, v[:, 0], seq_axis)
+        pool = (kp, vp)
     qg = q[:, 0].reshape(b, kvh, g, hd)
-    attn = paged_decode_attention(qg, kp, vp, tables, lens + 1,
-                                  kv_scales=kv_scales,
+    attn = paged_decode_attention(qg, kp, vp, tables, lens + 1, layer,
+                                  kv_scales=pool[2:] or None,
                                   seq_axis=seq_axis, n_seq=n_seq)
     attn = attn.astype(x.dtype).reshape(b, 1, h * hd)
     x = x + _mp_sum(attn @ lp["wo"])
@@ -1090,47 +1119,43 @@ def _paged_decode_layer_step(cfg, lp, x, kp, vp, tables, lens,
     else:
         gate = jax.nn.silu(y @ lp["w_gate"])
         x = x + _mp_sum((gate * (y @ lp["w_up"])) @ lp["w_down"])
-    return x, kp, vp, kscale, vscale
+    return x, pool
 
 
 def _paged_decode_step(cfg, stacked, embed, final_norm, lm_head, token,
-                       pages_k, pages_v, tables, lens, kscales=None,
-                       vscales=None, mp_axis=None, seq_axis=None,
+                       tables, lens, pool, mp_axis=None, seq_axis=None,
                        n_seq=1):
-    """Jittable paged single-token step: [b] token ids +
-    [L, N, kvh, bs, hd] block pools + [b, max_blocks] tables + [b] lens
-    -> (logits [b, V], updated pools). The tables/lens are DATA, so one
-    compiled program serves every admission pattern. int8 pools thread
-    ``kscales``/``vscales`` [L, N, kvh] through the layer scan and the
-    return grows to (logits, kps, vps, kscales, vscales)."""
+    """Jittable paged single-token step: [b] token ids + [b, max_blocks]
+    tables + [b] lens + the block pools ``pool`` = (kp, vp), each
+    [L, N, kvh, bs, hd], or (kp, vp, kscale, vscale) for int8 pools
+    (scales [L, N, kvh]) -> (logits [b, V], pool). The tables/lens are
+    DATA, so one compiled program serves every admission pattern.
+
+    The pools are loop-carried STATE of the layer scan, in the stacked
+    form the engine holds and donates: the scan runs over (weights,
+    layer index) and each layer writes its token's page and reads its
+    pages at ``[layer, ...]`` of the carry. Nothing here is of the size
+    of a pool or of one layer's slice of one, so a step's cost does not
+    grow with the pool. (The pools must not ride as the scan's
+    ``xs``/``ys``: ``xs`` hands each layer a copy of its slice, and
+    stacked ``ys`` are a new buffer, so every step would write both
+    pools out whole.)"""
     x = jnp.take(embed, token, axis=0)[:, None, :]       # [b, 1, d]
 
-    if kscales is None:
-        def layer_fn(carry, xs):
-            lp, kp, vp = xs
-            out, kp, vp, _, _ = _paged_decode_layer_step(
-                cfg, lp, carry, kp, vp, tables, lens, mp_axis=mp_axis,
-                seq_axis=seq_axis, n_seq=n_seq)
-            return out, (kp, vp)
-
-        x, (kps, vps) = jax.lax.scan(layer_fn, x,
-                                     (stacked, pages_k, pages_v))
-        x = _rms(x, final_norm, cfg.rms_norm_eps)
-        logits = (x[:, 0] @ lm_head).astype(jnp.float32)
-        return logits, kps, vps
-
     def layer_fn(carry, xs):
-        lp, kp, vp, ksc, vsc = xs
-        out, kp, vp, ksc, vsc = _paged_decode_layer_step(
-            cfg, lp, carry, kp, vp, tables, lens, ksc, vsc,
-            mp_axis=mp_axis, seq_axis=seq_axis, n_seq=n_seq)
-        return out, (kp, vp, ksc, vsc)
+        x, pool = carry
+        lp, layer = xs
+        return _paged_decode_layer_step(
+            cfg, lp, x, pool, layer, tables, lens, mp_axis=mp_axis,
+            seq_axis=seq_axis, n_seq=n_seq), None
 
-    x, (kps, vps, kscales, vscales) = jax.lax.scan(
-        layer_fn, x, (stacked, pages_k, pages_v, kscales, vscales))
+    n_layers = pool[0].shape[0]
+    (x, pool), _ = jax.lax.scan(
+        layer_fn, (x, tuple(pool)),
+        (stacked, jnp.arange(n_layers, dtype=jnp.int32)))
     x = _rms(x, final_norm, cfg.rms_norm_eps)
     logits = (x[:, 0] @ lm_head).astype(jnp.float32)
-    return logits, kps, vps, kscales, vscales
+    return logits, pool
 
 
 def _quantized_prefill_scatter(pool, scales, toks, page, off, valid,

@@ -11,7 +11,10 @@ the process-wide ``"high"`` matmul precision — in effect as in
 production. Nothing runs, so these say nothing about results or times.
 """
 
+import functools
 import os
+import re
+from unittest import mock
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -57,16 +60,22 @@ def _no_persistent_cache():
 # Each builder takes ``place(shape, dtype, spec=P())`` — a ShapeDtypeStruct
 # on the described device(s) — and returns (function, arguments).
 
-def _paged_decode(block, pool_dtype, n_pages, batch=8, s_max=2048):
+def _paged_decode(block, pool_dtype, n_pages, layers=2, batch=8,
+                  s_max=2048):
+    """The decode kernel as the layer scan launches it: the stacked
+    pools where they lie, the layer index as data."""
     def build(place):
         mb = s_max // block
-        pool = place((n_pages, KVH, block, HD), pool_dtype)
+        pool = place((layers, n_pages, KVH, block, HD), pool_dtype)
         args = [place((batch, KVH, G, HD), BF16), pool, pool,
-                place((batch, mb), I32), place((batch,), I32)]
+                place((batch, mb), I32), place((batch,), I32),
+                place((), I32)]
         if pool_dtype == I8:
-            sc = place((n_pages, KVH), F32)
-            return (lambda q, k, v, t, n, ks, vs: pa.paged_attention_pallas(
-                q, k, v, t, n, kv_scales=(ks, vs))), args + [sc, sc]
+            sc = place((layers, n_pages, KVH), F32)
+            return (lambda q, k, v, t, n, l, ks, vs:
+                    pa.paged_attention_pallas(
+                        q, k, v, t, n, l, kv_scales=(ks, vs))), \
+                args + [sc, sc]
         return pa.paged_attention_pallas, args
     return build
 
@@ -95,20 +104,118 @@ def _flash(batch, seq, heads, kv_heads, grad):
     return build
 
 
-def _paged_decode_tp4(block=16, n_pages=4096, batch=4, s_max=2048):
-    """The tp engine's form: the kernel inside ``shard_map``, pools and
-    query heads split over four chips (2 kv heads each)."""
+def _paged_decode_tp4(block=16, n_pages=4096, layers=2, batch=4,
+                      s_max=2048):
+    """The tp engine's form: the kernel inside ``shard_map``, stacked
+    pools and query heads split over four chips (2 kv heads each)."""
     def build(place, mesh):
         heads = P(None, "tp", None, None)
-        pool = place((n_pages, KVH, block, HD), BF16, heads)
+        pools = P(None, None, "tp", None, None)
+        pool = place((layers, n_pages, KVH, block, HD), BF16, pools)
         fn = jax.shard_map(
             pa.paged_attention_pallas, mesh=mesh,
-            in_specs=(heads, heads, heads, P(), P()), out_specs=heads)
+            in_specs=(heads, pools, pools, P(), P(), P()),
+            out_specs=heads)
         return fn, [place((batch, KVH, G, HD), BF16, heads), pool, pool,
                     place((batch, s_max // block), I32),
-                    place((batch,), I32)]
+                    place((batch,), I32), place((), I32)]
     build.mesh_axes = ((4,), ("tp",))
     return build
+
+
+def _engine_decode(kv_dtype, block, tp=1, layers=2, kv_heads=4,
+                   n_pages=4608, batch=8, s_max=512):
+    """The engine's OWN ``decode_chunk_paged`` program (a chunk of decode
+    steps, each a scan over the layers), at real head widths over pools
+    larger than the chip's fast memory, so that what the compiler does
+    with a pool here is what it does with a deployment's. ``tp`` > 1: the
+    tp engine's program, under ``shard_map`` with the pools split by kv
+    head. The pool's size must be no cost of a step: see
+    :func:`_assert_pools_stay_put`."""
+    def build(place, mesh=None):
+        import paddle_tpu as paddle
+        from paddle_tpu.inference import sharding
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=1024, hidden_size=8 * HD, intermediate_size=1024,
+            num_hidden_layers=layers, num_attention_heads=8,
+            num_key_value_heads=kv_heads, attention_bias=True,
+            dtype="bfloat16"))
+        model.eval()
+        # a described chip holds nothing: where the tp engine places its
+        # weights and pools on the mesh, keep the host arrays (only their
+        # shapes are read here)
+        with mock.patch.object(jax, "device_put", lambda a, *_, **__: a):
+            eng = DecodeEngine(model, capacity=batch, s_max=s_max,
+                               block_size=block, n_blocks=n_pages,
+                               kv_dtype=kv_dtype, mesh=mesh)
+            stacked, *rest = eng._weights()
+        wsp = sharding.stacked_weight_specs(eng._names, "tp")
+        ssp = sharding.quant_scale_specs(eng._scales, "tp")
+        psp = sharding.pool_specs(eng._n_pool, "tp")
+
+        def like(a, spec=P()):
+            return place(a.shape, a.dtype, spec)
+
+        return eng._decode, [
+            {n: like(v, wsp[n]) for n, v in stacked.items()},
+            *jax.tree.map(like, rest),
+            {n: like(v, ssp[n]) for n, v in eng._scales.items()},
+            *(like(jnp.asarray(a))
+              for a in (eng._tok, eng._tables, eng._lens)),
+            *map(like, eng._pool(), psp)]
+    if tp > 1:
+        build.mesh_axes = ((tp,), ("tp",))
+    build.check = functools.partial(
+        _assert_pools_stay_put,
+        pool=jax.ShapeDtypeStruct(
+            (layers, n_pages, kv_heads // tp, block, HD),
+            I8 if kv_dtype == "int8" else BF16))
+    return build
+
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(")
+# a tuple, a loop or a pointer to part of one computes and moves nothing
+_NO_WORK = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "conditional", "call", "optimization-barrier"}
+
+
+def _assert_pools_stay_put(compiled, pool):
+    """No instruction of the compiled module produces a value of the
+    size of a pool ``[L, N, kvh, bs, hd]`` or of one layer's slice of
+    one, except a write INTO the pool it is handed: the scatter of the
+    rows' pages (alone or as the root of a fusion), whose result is its
+    operand's own buffer. That the buffer is shared and not a second
+    one is what ``memory_analysis`` shows: the program's temporaries
+    stay under one layer's slice."""
+    text = compiled.as_text()
+    dims = [",".join(map(str, pool.shape[i:])) for i in (0, 1)]
+    roots, comp = {}, None            # computation name -> its ROOT's op
+    for line in text.splitlines():
+        head = re.match(r"^%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+        m = _HLO_OP.match(line)
+        if m and line.lstrip().startswith("ROOT"):
+            roots[comp] = m.group(3)
+    moved = []
+    for line in text.splitlines():
+        m = _HLO_OP.match(line)
+        if not m or m.group(3) in _NO_WORK:
+            continue
+        if not any(f"[{d}]" in m.group(2) for d in dims):
+            continue
+        op = m.group(3)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
+        if op != "scatter":
+            moved.append(line.strip()[:200])
+    assert not moved, "\n".join(moved)
+    slice_bytes = int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < slice_bytes, (temp, slice_bytes)
 
 
 def _flash_train_dp2_mp2(batch=6, seq=2048, heads=32):
@@ -137,7 +244,8 @@ CASES = {
     "paged_decode_bf16_block16": _paged_decode(16, BF16, 4096),
     # the pool an engine could really hold on 16 GB (2 GiB each of K and
     # V codes here): scalar memory must not grow with it
-    "paged_decode_int8_block32_64k_pages": _paged_decode(32, I8, 65536),
+    "paged_decode_int8_block32_64k_pages": _paged_decode(32, I8, 65536,
+                                                         layers=1),
     "mixed_bf16_window16": _mixed(16),
     "mixed_bf16_window256": _mixed(256),
     "flash_fwd_b6_s2048_h32_kv8": _flash(6, 2048, 32, 8, grad=False),
@@ -148,6 +256,10 @@ CASES = {
     "flash_bwd_b2_s8192_h16_kv16": _flash(2, 8192, 16, 16, grad=True),
     "paged_decode_bf16_tp4_shard_map": _paged_decode_tp4(),
     "flash_fwd_bwd_dp2_mp2_gspmd": _flash_train_dp2_mp2(),
+    "engine_decode_chunk_bf16_block16": _engine_decode("fp", 16),
+    "engine_decode_chunk_int8_block32": _engine_decode("int8", 32),
+    "engine_decode_chunk_bf16_tp4": _engine_decode("fp", 16, tp=4,
+                                                   kv_heads=8),
 }
 
 
@@ -175,5 +287,9 @@ def test_compiles_for_v5e(name, topo, monkeypatch):
                 shape, dtype, sharding=NamedSharding(mesh, spec))
 
         fn, args = build(place, mesh)
-    compiled = jax.jit(fn).lower(*args).compile()
+    # the engine hands over its program as it jitted it, donation and all
+    lower = fn.lower if hasattr(fn, "lower") else jax.jit(fn).lower
+    compiled = lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if hasattr(build, "check"):
+        build.check(compiled)

@@ -11,14 +11,18 @@ import jax.numpy as jnp
 from harness import drive, shared_model, solo_generate
 
 
+N_LAYERS = 3
+
+
 def _random_paged(seed=0, B=3, kvh=2, G=4, hd=128, n_blocks=9, bs=16,
                   max_blocks=4, lens=(37, 5, 64)):
-    """Random block pool + tables with ragged per-row lengths (one row
+    """Random STACKED block pools [L, N, kvh, bs, hd] (every layer its
+    own values) + tables with ragged per-row lengths (one row
     mid-block, one tiny, one exactly on a block boundary)."""
     rng = np.random.RandomState(seed)
     q = rng.randn(B, kvh, G, hd).astype(np.float32) * 0.5
-    kp = rng.randn(n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
-    vp = rng.randn(n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
+    kp = rng.randn(N_LAYERS, n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
+    vp = rng.randn(N_LAYERS, n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
     lens = np.asarray(lens, np.int32)
     table = np.zeros((B, max_blocks), np.int32)
     free = list(range(1, n_blocks))          # page 0 = NULL
@@ -29,20 +33,96 @@ def _random_paged(seed=0, B=3, kvh=2, G=4, hd=128, n_blocks=9, bs=16,
             jnp.asarray(table), jnp.asarray(lens))
 
 
+def _quantize_pool(pages):
+    """[L, N, kvh, bs, hd] f32 -> (int8 codes, [L, N, kvh] f32 scales),
+    one scale per (layer, page, kv head) as the engine keeps them."""
+    scales = jnp.maximum(jnp.abs(pages).max(axis=(-2, -1)) / 127.0, 1e-8)
+    codes = jnp.clip(jnp.round(pages / scales[..., None, None]),
+                     -127, 127).astype(jnp.int8)
+    return codes, scales
+
+
 class TestPagedKernel:
-    def test_interpret_matches_reference(self):
+    @pytest.mark.parametrize("layer", range(N_LAYERS))
+    @pytest.mark.parametrize("kv", ["fp", "int8"])
+    def test_interpret_matches_reference(self, kv, layer):
         """The Pallas kernel (double-buffered page DMA + online softmax)
-        must match the gather-then-masked-softmax reference on ragged
-        lengths — interpret mode executes the DMA faithfully on CPU."""
+        must match its reference on ragged lengths at EVERY layer of the
+        stacked pools — interpret mode executes the DMA and the scalar
+        prefetch (table, lengths, layer) faithfully on CPU. fp: the
+        gather-then-masked-softmax reference; int8: the block-looped
+        reference, which the kernel matches bit for bit."""
         from paddle_tpu.kernels.paged_attention import (
-            _paged_attn_reference, paged_attention_pallas)
+            _paged_attn_reference, _paged_attn_reference_int8,
+            paged_attention_pallas)
         q, kp, vp, table, lens = _random_paged()
-        out = paged_attention_pallas(q, kp, vp, table, lens,
-                                     interpret=True)
-        ref = _paged_attn_reference(q, kp, vp, table, lens)
+        if kv == "int8":
+            (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+            out = paged_attention_pallas(q, kp, vp, table, lens, layer,
+                                         interpret=True,
+                                         kv_scales=(ks, vs))
+            ref = _paged_attn_reference_int8(q, kp, vp, table, lens,
+                                             layer, (ks, vs))
+            np.testing.assert_array_equal(np.asarray(out),
+                                          np.asarray(ref))
+            return
+        # a traced layer, as the decode step's scan hands it over
+        out = jax.jit(lambda l: paged_attention_pallas(
+            q, kp, vp, table, lens, l, interpret=True))(jnp.int32(layer))
+        ref = _paged_attn_reference(q, kp, vp, table, lens, layer)
         assert np.allclose(np.asarray(out), np.asarray(ref),
                            atol=2e-5), \
             np.abs(np.asarray(out) - np.asarray(ref)).max()
+        # the layer's own pages were read, not a neighbour's
+        other = _paged_attn_reference(q, kp, vp, table, lens,
+                                      (layer + 1) % N_LAYERS)
+        assert not np.allclose(np.asarray(out), np.asarray(other),
+                               atol=1e-2)
+
+    @pytest.mark.parametrize("kv", ["fp", "int8"])
+    def test_token_write_touches_one_layer(self, kv):
+        """The decode step's write of one token per row at layer l of
+        the stacked pools lands at [l, page, :, off] and leaves every
+        other layer's pages (and scales), and every other page of layer
+        l, bit-identical."""
+        from paddle_tpu.models.llama import (_quantized_token_insert,
+                                             _token_insert)
+        _, kp, _, table, lens = _random_paged(seed=5)
+        bs = kp.shape[3]
+        lens = np.asarray(lens) - 1          # write cursors inside pages
+        page = np.asarray(table)[np.arange(len(lens)), lens // bs]
+        off = lens % bs
+        tok = jnp.asarray(np.random.RandomState(1).randn(
+            len(lens), kp.shape[2], kp.shape[4]).astype(np.float32))
+        for layer in range(N_LAYERS):
+            if kv == "int8":
+                pool, scales = _quantize_pool(kp)
+                new, new_sc = jax.jit(_quantized_token_insert)(
+                    pool, scales, jnp.int32(layer), page, off, tok)
+                new_sc, scales = np.asarray(new_sc), np.asarray(scales)
+                keep = np.ones(scales.shape[:2], bool)
+                keep[layer, page] = False
+                np.testing.assert_array_equal(new_sc[keep], scales[keep])
+                assert (new_sc[layer, page] >= scales[layer, page]).all()
+            else:
+                pool = kp
+                new = jax.jit(_token_insert)(pool, jnp.int32(layer), page,
+                                             off, tok)
+                np.testing.assert_array_equal(
+                    np.asarray(new)[layer, page, :, off],
+                    np.asarray(tok))
+            new, pool = np.asarray(new), np.asarray(pool)
+            keep = np.ones(pool.shape[:2], bool)
+            keep[layer, page] = False
+            np.testing.assert_array_equal(new[keep], pool[keep])
+            assert (new[layer, page] != pool[layer, page]).any()
+            if kv == "fp":
+                # within the written pages only the token's row moved
+                row = np.zeros(pool.shape[1:4] + (1,), bool)
+                row[page, :, off] = True
+                np.testing.assert_array_equal(
+                    np.where(row, 0, new[layer]),
+                    np.where(row, 0, pool[layer]))
 
     def test_reference_is_decode_attention_math(self):
         """The XLA fallback must be the EXACT math of
@@ -53,9 +133,11 @@ class TestPagedKernel:
             _paged_attn_reference, gather_pages)
         from paddle_tpu.models.llama import _decode_attention
         q, kp, vp, table, lens = _random_paged(seed=3)
-        out = _paged_attn_reference(q, kp, vp, table, lens)
-        ck = gather_pages(kp, table)
-        cv = gather_pages(vp, table)
+        out = _paged_attn_reference(q, kp, vp, table, lens, 1)
+        ck = gather_pages(kp, table, 1)
+        cv = gather_pages(vp, table, 1)
+        np.testing.assert_array_equal(       # the layer where it lies
+            np.asarray(ck), np.asarray(gather_pages(kp[1], table)))
         mask = jnp.arange(ck.shape[1])[None, :] < lens[:, None]
         ref = _decode_attention(q, ck, cv, mask)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -68,10 +150,10 @@ class TestPagedKernel:
         from paddle_tpu.kernels.paged_attention import \
             _paged_attn_reference
         q, kp, vp, table, lens = _random_paged(seed=7)
-        ref = _paged_attn_reference(q, kp, vp, table, lens)
-        kp2 = kp.at[0].set(1e3)
-        vp2 = vp.at[0].set(-1e3)
-        out = _paged_attn_reference(q, kp2, vp2, table, lens)
+        ref = _paged_attn_reference(q, kp, vp, table, lens, 2)
+        kp2 = kp.at[:, 0].set(1e3)
+        vp2 = vp.at[:, 0].set(-1e3)
+        out = _paged_attn_reference(q, kp2, vp2, table, lens, 2)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     def test_entry_gate_uses_reference_off_tpu(self):
@@ -80,8 +162,8 @@ class TestPagedKernel:
         if jax.default_backend() == "tpu":
             pytest.skip("CPU-only gate check")
         q, kp, vp, table, lens = _random_paged(seed=11)
-        out = paged_decode_attention(q, kp, vp, table, lens)
-        ref = _paged_attn_reference(q, kp, vp, table, lens)
+        out = paged_decode_attention(q, kp, vp, table, lens, 1)
+        ref = _paged_attn_reference(q, kp, vp, table, lens, 1)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -174,7 +256,7 @@ class TestMixedKernel:
         mixed = np.asarray(_mixed_attn_reference(
             q, kp, vp, table, kv_lens, q_lens))[:, 0]
         dec = np.asarray(_paged_attn_reference(
-            q[:, 0], kp, vp, table, kv_lens))
+            q[:, 0], kp[None], vp[None], table, kv_lens, 0))
         assert np.allclose(mixed, dec, atol=2e-5)
 
     def test_chunk_only_rows(self):

@@ -149,27 +149,38 @@ class TestSeqKernelEdgeRows:
         table[0] = [1, 3, 5, 7]
         return kp, vp, table
 
-    def _sharded(self, fn_name, q, kp, vp, table, *lens, n_seq=4):
+    def _sharded(self, fn_name, q, kp, vp, table, *lens, n_seq=4,
+                 layer=None):
+        """``layer``: the entry reads that layer of STACKED pools (the
+        decode entry); the pools then ride as layer ``layer`` of a
+        stack whose other layers hold noise."""
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
         import paddle_tpu.kernels.paged_attention as pa
         from jax import shard_map
         mesh = Mesh(np.asarray(jax.devices()[:n_seq]), ("seq",))
         kern = getattr(pa, fn_name)
+        # the oracle's pools are token-major [N, bs, kvh, hd]; the
+        # engine's (and the kernels') keep the kv head ahead of the page
+        kp, vp = np.swapaxes(kp, 1, 2), np.swapaxes(vp, 1, 2)
+        pool_spec, tail = P("seq"), ()
+        if layer is not None:
+            noise = np.random.default_rng(9).standard_normal(
+                (layer + 2, *kp.shape)).astype(kp.dtype)
+            kp, vp = (np.concatenate([noise[:layer], a[None],
+                                      noise[layer:]]) for a in (kp, vp))
+            pool_spec, tail = P(None, "seq"), (layer,)
 
         def prog(q, kp, vp, table, *lens):
-            return kern(q, kp, vp, table, *lens, seq_axis="seq",
+            return kern(q, kp, vp, table, *lens, *tail, seq_axis="seq",
                         n_seq=n_seq)
 
         sharded = shard_map(
             prog, mesh=mesh,
-            in_specs=(P(), P("seq"), P("seq"), P(),
+            in_specs=(P(), pool_spec, pool_spec, P(),
                       *([P()] * len(lens))),
             out_specs=P())
-        # the oracle's pools are token-major [N, bs, kvh, hd]; the
-        # engine's (and the kernels') keep the kv head ahead of the page
-        return np.asarray(sharded(q, np.swapaxes(kp, 1, 2),
-                                  np.swapaxes(vp, 1, 2), table, *lens))
+        return np.asarray(sharded(q, kp, vp, table, *lens))
 
     def _oracle_row(self, q_row, keys, vals, n_keys):
         """float64 causal-free softmax over the first n_keys keys for
@@ -193,7 +204,7 @@ class TestSeqKernelEdgeRows:
         q = rng.standard_normal((2, 2, 2, 8)).astype(np.float32)
         seq_lens = np.array([13, 0], np.int32)
         out = self._sharded("paged_decode_attention", q, kp, vp,
-                            table, seq_lens)
+                            table, seq_lens, layer=1)
         keys = kp[table[0]].reshape(-1, 2, 8)       # [16, kvh, hd]
         vals = vp[table[0]].reshape(-1, 2, 8)
         for n in range(2):                           # kv head

@@ -209,13 +209,13 @@ class TestInt8PagedKV:
         from paddle_tpu.models.llama import _quantized_token_insert
         rng = np.random.RandomState(20)
         tok = rng.randn(2, 3, 8).astype(np.float32)
-        pool = jnp.zeros((4, 3, 16, 8), jnp.int8)
-        scales = jnp.full((4, 3), KV_SCALE_EPS, jnp.float32)
+        pool = jnp.zeros((2, 4, 3, 16, 8), jnp.int8)
+        scales = jnp.full((2, 4, 3), KV_SCALE_EPS, jnp.float32)
         page = jnp.asarray([1, 2], jnp.int32)
         off = jnp.asarray([0, 5], jnp.int32)
         pool, scales = _quantized_token_insert(
-            pool, scales, page, off, jnp.asarray(tok))
-        pool, scales = np.asarray(pool), np.asarray(scales)
+            pool, scales, 1, page, off, jnp.asarray(tok))
+        pool, scales = np.asarray(pool)[1], np.asarray(scales)[1]
         for b, (pg, o) in enumerate([(1, 0), (2, 5)]):
             deq = pool[pg, :, o].astype(np.float32) * scales[pg][:, None]
             step = scales[pg][:, None]
@@ -233,19 +233,20 @@ class TestInt8PagedKV:
         rng = np.random.RandomState(21)
         big = (rng.randn(1, 2, 8) * 4).astype(np.float32)
         small = (rng.randn(1, 2, 8) * 0.01).astype(np.float32)
-        pool = jnp.zeros((3, 2, 16, 8), jnp.int8)
-        scales = jnp.full((3, 2), KV_SCALE_EPS, jnp.float32)
+        pool = jnp.zeros((1, 3, 2, 16, 8), jnp.int8)
+        scales = jnp.full((1, 3, 2), KV_SCALE_EPS, jnp.float32)
         page = jnp.asarray([1], jnp.int32)
         pool, scales = _quantized_token_insert(
-            pool, scales, page, jnp.asarray([0], jnp.int32),
+            pool, scales, 0, page, jnp.asarray([0], jnp.int32),
             jnp.asarray(big))
-        before = np.asarray(pool)[1, :, 0].copy()
-        s_before = np.asarray(scales)[1].copy()
+        before = np.asarray(pool)[0, 1, :, 0].copy()
+        s_before = np.asarray(scales)[0, 1].copy()
         pool, scales = _quantized_token_insert(
-            pool, scales, page, jnp.asarray([1], jnp.int32),
+            pool, scales, 0, page, jnp.asarray([1], jnp.int32),
             jnp.asarray(small))
-        np.testing.assert_array_equal(np.asarray(pool)[1, :, 0], before)
-        np.testing.assert_array_equal(np.asarray(scales)[1], s_before)
+        np.testing.assert_array_equal(np.asarray(pool)[0, 1, :, 0],
+                                      before)
+        np.testing.assert_array_equal(np.asarray(scales)[0, 1], s_before)
 
     def test_gather_dequant_pool_edge_scale_indexing(self):
         """Each block dequantizes with ITS page's per-head scale — pin
@@ -279,18 +280,18 @@ class TestInt8PagedKV:
         B, kvh, G, hd, N, bs = 3, 2, 2, 16, 8, 16
         q = jnp.asarray(rng.randn(B, kvh, G, hd).astype(np.float32))
         kp = jnp.asarray(
-            rng.randint(-127, 128, (N, kvh, bs, hd)).astype(np.int8))
+            rng.randint(-127, 128, (1, N, kvh, bs, hd)).astype(np.int8))
         vp = jnp.asarray(
-            rng.randint(-127, 128, (N, kvh, bs, hd)).astype(np.int8))
-        ks = jnp.asarray(rng.rand(N, kvh).astype(np.float32) * 0.1)
-        vs = jnp.asarray(rng.rand(N, kvh).astype(np.float32) * 0.1)
+            rng.randint(-127, 128, (1, N, kvh, bs, hd)).astype(np.int8))
+        ks = jnp.asarray(rng.rand(1, N, kvh).astype(np.float32) * 0.1)
+        vs = jnp.asarray(rng.rand(1, N, kvh).astype(np.float32) * 0.1)
         tables = jnp.asarray(rng.permutation(np.arange(1, 7))[:6]
                              .reshape(3, 2).astype(np.int32))
         lens = jnp.asarray([5, 16, 23], jnp.int32)
-        out_k = paged_attention_pallas(q, kp, vp, tables, lens,
+        out_k = paged_attention_pallas(q, kp, vp, tables, lens, 0,
                                        interpret=True,
                                        kv_scales=(ks, vs))
-        out_r = _paged_attn_reference_int8(q, kp, vp, tables, lens,
+        out_r = _paged_attn_reference_int8(q, kp, vp, tables, lens, 0,
                                            (ks, vs))
         np.testing.assert_array_equal(np.asarray(out_k),
                                       np.asarray(out_r))
