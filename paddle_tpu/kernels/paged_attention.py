@@ -13,25 +13,32 @@ Design (single-query decode, one token per row):
 - q: [B, kvh, G, hd] (grouped query heads for the token being decoded)
 - k_pages/v_pages: [L, N, kvh, bs, hd], the engine's stacked pools as
   they lie in HBM, and ``layer``, the index of the layer to read — the
-  kv head sits AHEAD of the ``[bs, hd]`` tile, so one (layer, page,
-  head) is one contiguous, tile-aligned DMA (the chip's tiler refuses a
-  copy that takes one head out of the second-minor axis). The decode
-  step carries the pools through its layer scan and hands every layer
-  the whole stack: a launch never sees a slice of a pool, so nothing of
-  a pool's size is cut out, copied or re-laid for it, and a step costs
-  the same whatever the pool holds. Page 0 is the reserved NULL page
-  (allocators never hand it out; padded table entries and inactive rows
-  write there, so fixed-shape programs need no masks)
+  kv head sits AHEAD of the ``[bs, hd]`` tile, so one (layer, page) is
+  one contiguous, tile-aligned ``[kvh, bs, hd]`` with every kv head of
+  its tokens. The decode step carries the pools through its layer scan
+  and hands every layer the whole stack: a launch never sees a slice of
+  a pool, so nothing of a pool's size is cut out, copied or re-laid for
+  it, and a step costs the same whatever the pool holds. Page 0 is the
+  reserved NULL page (allocators never hand it out; padded table entries
+  and inactive rows write there, so fixed-shape programs need no masks)
 - block_table: [B, max_blocks] int32 page ids (data argument — shapes
   stay fixed, so the two-compiled-programs serving discipline holds)
 - seq_lens: [B] int32 valid tokens per row (ragged lengths)
-- grid (B, kvh): each program owns one (row, kv head); the row's pages
-  stream HBM→VMEM through double-buffered ``make_async_copy`` DMA with
-  the page id scalar-prefetched from the table and the layer index
-  beside it (``PrefetchScalarGridSpec``) — the flash_attention.py
-  streaming idiom applied through one level of indirection
-- online softmax (f32 m/l/acc) over the row's ceil(len/bs) blocks; the
-  ragged tail masks positions >= seq_len
+- grid (B,): each program owns one ROW and walks its pages in blocks of
+  P (:func:`_pages_per_block`: 512 tokens' worth, from the operands'
+  shapes under a stated VMEM budget). A page is ONE ``make_async_copy``
+  with all its kv heads, the page id scalar-prefetched from the table
+  and the layer index beside it (``PrefetchScalarGridSpec``); the P
+  copies of the next block are in flight, into the other of two VMEM
+  slots ``[P, kvh, bs, hd]``, while the current block is folded. The
+  last block of a row is partial: pages past the row's last are neither
+  fetched nor waited for
+- online softmax (f32 m/l/acc per kv head) with one step a BLOCK: one
+  ``[G, hd] x [hd, P*bs]`` product, one mask (positions >= seq_len), one
+  ``exp``, one ``[G, P*bs] x [P*bs, hd]`` product, one rescale
+- a row that holds nothing (the engine's inactive lanes: a table that
+  starts with the NULL page) writes zeros, starts no copy and waits for
+  none — it costs a grid step
 - every dot pins ``precision=DEFAULT`` like flash_attention.py: the
   process-wide ``jax_default_matmul_precision="high"`` (flags.py) is a
   precision the kernel lowering refuses
@@ -59,9 +66,10 @@ with one f32 scale per (page, kv head) living beside the pool
 the attention program — the r6 weight-dequant-inside-the-kernel recipe
 applied to the KV stream, halving the bytes a decode step moves.
 The int8 XLA reference (:func:`_paged_attn_reference_int8`) is a
-block-looped online softmax built from the SAME
-:func:`_int8_block_update` helper the Pallas kernel body calls, so the
-interpret-mode kernel and the reference execute the identical op
+page-looped online softmax built from the SAME
+:func:`_int8_block_update` helper the Pallas kernel calls page by page
+inside each block it has fetched (every page has a scale of its own),
+so the interpret-mode kernel and the reference execute the identical op
 sequence on identical data and agree BIT-exactly — the parity
 contract the int8 tests pin. A verify chunk (self-speculative
 decoding's k-draft scoring step) is just a mixed-launch row whose
@@ -101,72 +109,137 @@ _NEG_INF = -1e30
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tables, lens, layer, q_ref, k_hbm, v_hbm, o_ref, k_s,
-                  v_s, ksem, vsem, *, bs, scale):
-    """One program = one (row, kv_head): G query rows against the row's
-    ragged page list in layer ``layer[0]`` of the stacked pools, pages
-    double-buffered HBM→VMEM."""
+#: Tokens one softmax step covers where the shapes allow it: the kernel
+#: walks a row in blocks of P = ``_BLOCK_TOKENS // bs`` pages. (On a v5e
+#: at the serving cells' shapes 512 took 0.8 of the time of 256 and of
+#: 128; a step's fixed work is what a longer block spreads.)
+_BLOCK_TOKENS = 512
+
+#: VMEM the page buffers of one launch may take: two slots (the block
+#: being folded and the one in flight) each of K and V, every slot
+#: ``[P, kvh, bs, hd]`` in the pool's dtype. A quarter of what the
+#: compiler grants a kernel on a v5e (16 MiB), so q, the output and the
+#: block's scores have room beside them.
+_PAGE_BUFFER_BYTES = 4 * 2 ** 20
+
+
+def _pages_per_block(kvh, bs, hd, dtype, max_blocks):
+    """P, the pages one softmax step covers, from the operands' shapes
+    alone: ``_BLOCK_TOKENS`` tokens' worth, fewer where four buffers of P
+    pages would pass ``_PAGE_BUFFER_BYTES`` or the table is narrower, and
+    never less than one."""
+    page_bytes = kvh * bs * hd * jnp.dtype(dtype).itemsize
+    return max(1, min(_BLOCK_TOKENS // bs,
+                      _PAGE_BUFFER_BYTES // (4 * page_bytes), max_blocks))
+
+
+def _stream_row(tables, lens, layer, k_hbm, v_hbm, o_ref, k_s, v_s, sem,
+                fold, *, bs):
+    """What the fp and int8 kernels share. One program = one ROW: its
+    pages of layer ``layer[0]`` of the stacked pools stream HBM->VMEM in
+    blocks of P pages, every page ONE copy ``[kvh, bs, hd]`` with all
+    its kv heads, the next block in flight while ``fold(j, slot, h,
+    n_pages, (m, l, acc))`` folds head ``h`` of block ``j`` into that
+    head's online-softmax state. Pages past the row's last are neither
+    fetched nor waited for. A row whose table starts with the NULL page
+    holds nothing: it writes zeros and starts no copy."""
     b = pl.program_id(0)
-    h = pl.program_id(1)
     lyr = layer[0]
-    q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
-    g, hd = q.shape
+    ppb = k_s.shape[1]
+    _, kvh, g, hd = o_ref.shape
+    n_pages = jnp.minimum(jax.lax.div(lens[b] + bs - 1, bs),
+                          tables.shape[1])
+    n_blk = jax.lax.div(n_pages + ppb - 1, ppb)
 
-    n = lens[b]                                        # ragged row length
-    n_blk = jax.lax.div(n + bs - 1, bs)                # pages this row
+    def copies(j, slot, op):
+        """Start, or wait for, the copies of block ``j`` into ``slot``:
+        one a page the block holds, the copies of a pool on one
+        semaphore. (A loop and not P copies spelled out: that kernel took
+        ten times as long to lower, in every process that serves.)"""
+        def page(i, _):
+            pg = tables[b, j * ppb + i]
+            for x, (hbm, buf) in enumerate(((k_hbm, k_s), (v_hbm, v_s))):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[lyr, pg], buf.at[slot, i], sem.at[x, slot]),
+                    op)()
+            return _
 
-    def kdma(slot, j):
-        return pltpu.make_async_copy(
-            k_hbm.at[lyr, tables[b, j], h], k_s.at[slot], ksem.at[slot])
+        jax.lax.fori_loop(0, jnp.minimum(ppb, n_pages - j * ppb), page, 0)
 
-    def vdma(slot, j):
-        return pltpu.make_async_copy(
-            v_hbm.at[lyr, tables[b, j], h], v_s.at[slot], vsem.at[slot])
+    live = tables[b, 0] != NULL_PAGE
 
-    m0 = jnp.full((g,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g,), jnp.float32)
-    acc0 = jnp.zeros((g, hd), jnp.float32)
+    @pl.when(jnp.logical_not(live))
+    def _nothing():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    @pl.when(n_blk > 0)
-    def _start():
-        kdma(0, 0).start()
-        vdma(0, 0).start()
+    @pl.when(live)
+    def _row():
+        copies(0, 0, "start")
 
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
+        def body(j, state):
+            slot = jax.lax.rem(j, 2)
 
-        @pl.when(j + 1 < n_blk)
-        def _prefetch():
-            kdma(nxt, j + 1).start()
-            vdma(nxt, j + 1).start()
+            copies(j + 1, 1 - slot, "start")   # none past the last block
+            copies(j, slot, "wait")
+            return tuple(fold(j, slot, h, n_pages, st)
+                         for h, st in enumerate(state))
 
-        kdma(slot, j).wait()
-        vdma(slot, j).wait()
-        k = k_s[slot]                                  # [bs, hd]
-        v = v_s[slot]
+        state = jax.lax.fori_loop(0, n_blk, body, ((
+            jnp.full((g,), _NEG_INF, jnp.float32),
+            jnp.zeros((g,), jnp.float32),
+            jnp.zeros((g, hd), jnp.float32)),) * kvh)
+        for h, (_, l, acc) in enumerate(state):
+            o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(
+                o_ref.dtype)
+
+
+def _paged_kernel(tables, lens, layer, q_ref, k_hbm, v_hbm, o_ref, k_s,
+                  v_s, sem, *, bs, scale):
+    """fp pools: one ``[G, hd] x [hd, P*bs]`` product, one mask, one
+    ``exp``, one ``[G, P*bs] x [P*bs, hd]`` product and one rescale per
+    head and block (:func:`_stream_row`)."""
+    b = pl.program_id(0)
+    ppb = k_s.shape[1]
+    g, hd = q_ref.shape[2:]
+    t = ppb * bs
+    n = lens[b]
+    # q and K meet in the dtype they share (bfloat16 in an engine) and V
+    # meets p in V's own: what precision=DEFAULT feeds the MXU anyway
+    dt = jnp.promote_types(q_ref.dtype, k_s.dtype)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (g, t), 1)
+
+    # The slots of a row's last block that hold no page keep what was
+    # there before, and p = 0 times a NaN is a NaN: the launch's first
+    # program (the grid runs in order) leaves both V buffers holding
+    # zeros, and after it they hold zeros or pages of the pool.
+    @pl.when(b == 0)
+    def _clean():
+        v_s[...] = jnp.zeros(v_s.shape, v_s.dtype)
+
+    def fold(j, slot, h, n_pages, state):
+        m, l, acc = state
+        k = k_s[slot, :, h].reshape(t, hd)
+        v = v_s[slot, :, h].reshape(t, hd)
         s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            q_ref[0, h].astype(dt), k.astype(dt),
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT) * scale   # [G, bs]
+            precision=jax.lax.Precision.DEFAULT) * scale   # [G, P*bs]
         # ragged tail: positions at or past the row's length are invalid
-        k_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
-        s = jnp.where(k_ids < n, s, _NEG_INF)
+        # (a block that runs holds at least one that is not, so m_new is
+        # finite and their exp is an exact zero)
+        s = jnp.where(j * t + pos < n, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(k_ids < n, p, 0.0)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=-1)
         acc = acc * alpha[:, None] + jnp.dot(
-            p, v.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.DEFAULT)
         return m_new, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(
-        o_ref.dtype)
+    _stream_row(tables, lens, layer, k_hbm, v_hbm, o_ref, k_s, v_s, sem,
+                fold, bs=bs)
 
 
 def _int8_block_update(q, kc, vc, ks, vs, m, l, acc, k_ids, n,
@@ -207,59 +280,35 @@ def _out_struct(shape, *operands):
 
 
 def _paged_kernel_int8(tables, lens, layer, q_ref, ks_ref, vs_ref, k_hbm,
-                       v_hbm, o_ref, k_s, v_s, ksem, vsem, *, bs, scale):
-    """int8 twin of :func:`_paged_kernel`: identical DMA structure, but
-    the streamed pages are int8 codes dequantized inside the program.
-    ``ks_ref``/``vs_ref`` are this (row, kv head)'s page scales in table
-    order ([1, 1, 1, max_blocks] f32, an SMEM block) — gathered through
-    the block table by the launch, so scalar memory holds one row's
-    scales and never the pool's."""
+                       v_hbm, o_ref, k_s, v_s, sem, *, bs, scale):
+    """int8 twin of :func:`_paged_kernel`: the same copies
+    (:func:`_stream_row`), but the pages are int8 codes dequantized
+    inside the program, each with its own scale, so a fetched block is
+    folded page by page through :func:`_int8_block_update` — the op
+    sequence of the reference. ``ks_ref``/``vs_ref`` are this row's page
+    scales in table order ([1, kvh, max_blocks] f32, an SMEM block) —
+    gathered through the block table by the launch, so scalar memory
+    holds one row's scales and never the pool's."""
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    lyr = layer[0]
-    q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
-    g, hd = q.shape
-
+    ppb = k_s.shape[1]
+    g = q_ref.shape[2]
     n = lens[b]
-    n_blk = jax.lax.div(n + bs - 1, bs)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
 
-    def kdma(slot, j):
-        return pltpu.make_async_copy(
-            k_hbm.at[lyr, tables[b, j], h], k_s.at[slot], ksem.at[slot])
+    def fold(j, slot, h, n_pages, state):
+        q = q_ref[0, h].astype(jnp.float32)            # [G, hd]
 
-    def vdma(slot, j):
-        return pltpu.make_async_copy(
-            v_hbm.at[lyr, tables[b, j], h], v_s.at[slot], vsem.at[slot])
+        def page(i, state):
+            pg = j * ppb + i
+            return _int8_block_update(
+                q, k_s[slot, i, h], v_s[slot, i, h], ks_ref[0, h, pg],
+                vs_ref[0, h, pg], *state, pg * bs + pos, n, scale)
 
-    m0 = jnp.full((g,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g,), jnp.float32)
-    acc0 = jnp.zeros((g, hd), jnp.float32)
+        return jax.lax.fori_loop(
+            0, jnp.minimum(ppb, n_pages - j * ppb), page, state)
 
-    @pl.when(n_blk > 0)
-    def _start():
-        kdma(0, 0).start()
-        vdma(0, 0).start()
-
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
-
-        @pl.when(j + 1 < n_blk)
-        def _prefetch():
-            kdma(nxt, j + 1).start()
-            vdma(nxt, j + 1).start()
-
-        kdma(slot, j).wait()
-        vdma(slot, j).wait()
-        k_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
-        return _int8_block_update(
-            q, k_s[slot], v_s[slot], ks_ref[0, 0, 0, j],
-            vs_ref[0, 0, 0, j], m, l, acc, k_ids, n, scale)
-
-    m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(
-        o_ref.dtype)
+    _stream_row(tables, lens, layer, k_hbm, v_hbm, o_ref, k_s, v_s, sem,
+                fold, bs=bs)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
@@ -270,21 +319,25 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
     (data: the decode step's layer scan hands it its counter). The pools
     stay in HBM where they lie (``pl.ANY``) and the layer rides the
     scalar-prefetch lane beside the table, so a page is read at
-    ``[layer, page, head]`` and nothing of a pool's size is sliced, moved
-    or re-laid for the launch. Returns [B, kvh, G, hd] f32.
+    ``[layer, page]`` and nothing of a pool's size is sliced, moved
+    or re-laid for the launch. One program a row, its pages in blocks
+    of :func:`_pages_per_block`. Returns [B, kvh, G, hd] f32; a row
+    whose table starts with the NULL page comes out zeros.
     ``kv_scales=(kscale, vscale)`` ([L, N, kvh] f32 each) switches to the
     int8 kernel: the pools hold int8 codes, dequantized inside the
     program."""
     B, kvh, G, hd = q.shape
     bs = k_pages.shape[3]
     block_table = jnp.asarray(block_table, jnp.int32)
+    mb = block_table.shape[1]
+    ppb = _pages_per_block(kvh, bs, hd, k_pages.dtype, mb)
     scale = 1.0 / (hd ** 0.5)
-    q_spec = pl.BlockSpec((1, 1, G, hd), lambda b, h, *_: (b, h, 0, 0))
+    q_spec = pl.BlockSpec((1, kvh, G, hd), lambda b, *_: (b, 0, 0, 0))
     if kv_scales is None:
         kernel, scales, sc_specs = _paged_kernel, (), []
     else:
         # The scales a row needs are gathered through its block table
-        # here and handed to each program as an SMEM block. (The whole
+        # here and handed to its program as an SMEM block. (The whole
         # ``[N, kvh]`` arrays used to ride the scalar-prefetch lane; the
         # chip pads each row to 128 lanes there, so 4096 pages already
         # asked for 2 MiB of its 1 MiB of scalar memory.)
@@ -292,20 +345,19 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
         scales = tuple(_row_page_scales(sc, block_table, layer)
                        for sc in kv_scales)
         sc_specs = [pl.BlockSpec(
-            (1, 1, 1, block_table.shape[1]),
-            lambda b, h, *_: (b, h, 0, 0), memory_space=pltpu.SMEM)] * 2
+            (1, kvh, mb), lambda b, *_: (b, 0, 0),
+            memory_space=pltpu.SMEM)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, kvh),
+        grid=(B,),
         in_specs=[q_spec, *sc_specs,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, bs, hd), k_pages.dtype),
-            pltpu.VMEM((2, bs, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((2, ppb, kvh, bs, hd), k_pages.dtype),
+            pltpu.VMEM((2, ppb, kvh, bs, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),          # [K/V, slot]
         ],
     )
     return pl.pallas_call(
@@ -319,11 +371,11 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
 
 
 def _row_page_scales(scales, block_table, layer):
-    """[L, N, kvh] page scales -> [B, kvh, 1, max_blocks], row b's
-    scales of layer ``layer`` in block-table order: what one (row, kv
-    head) program of the int8 kernel reads."""
+    """[L, N, kvh] page scales -> [B, kvh, max_blocks], row b's scales
+    of layer ``layer`` in block-table order: what one row's program of
+    the int8 kernel reads."""
     rows = jnp.asarray(scales, jnp.float32)[layer, block_table]
-    return jnp.swapaxes(rows, 1, 2)[:, :, None, :]    # from [B, mb, kvh]
+    return jnp.swapaxes(rows, 1, 2)                   # from [B, mb, kvh]
 
 
 # ---------------------------------------------------------------------------

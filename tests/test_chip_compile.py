@@ -61,17 +61,17 @@ def _no_persistent_cache():
 # on the described device(s) — and returns (function, arguments).
 
 def _paged_decode(block, pool_dtype, n_pages, layers=2, batch=8,
-                  s_max=2048):
+                  s_max=2048, kv_heads=KVH, group=G):
     """The decode kernel as the layer scan launches it: the stacked
     pools where they lie, the layer index as data."""
     def build(place):
         mb = s_max // block
-        pool = place((layers, n_pages, KVH, block, HD), pool_dtype)
-        args = [place((batch, KVH, G, HD), BF16), pool, pool,
+        pool = place((layers, n_pages, kv_heads, block, HD), pool_dtype)
+        args = [place((batch, kv_heads, group, HD), BF16), pool, pool,
                 place((batch, mb), I32), place((batch,), I32),
                 place((), I32)]
         if pool_dtype == I8:
-            sc = place((layers, n_pages, KVH), F32)
+            sc = place((layers, n_pages, kv_heads), F32)
             return (lambda q, k, v, t, n, l, ks, vs:
                     pa.paged_attention_pallas(
                         q, k, v, t, n, l, kv_scales=(ks, vs))), \
@@ -246,6 +246,11 @@ CASES = {
     # V codes here): scalar memory must not grow with it
     "paged_decode_int8_block32_64k_pages": _paged_decode(32, I8, 65536,
                                                          layers=1),
+    # the kernel at the doc_qa cell's own sizes (Qwen2-7B: 4 kv heads x
+    # 7, 16 rows, tables of 208 pages, 14 layers of 4141 pages)
+    "paged_decode_bf16_doc_qa_sizes": _paged_decode(
+        16, BF16, 4141, layers=14, batch=16, s_max=3328, kv_heads=4,
+        group=7),
     "mixed_bf16_window16": _mixed(16),
     "mixed_bf16_window256": _mixed(256),
     "flash_fwd_b6_s2048_h32_kv8": _flash(6, 2048, 32, 8, grad=False),
@@ -289,7 +294,18 @@ def test_compiles_for_v5e(name, topo, monkeypatch):
         fn, args = build(place, mesh)
     # the engine hands over its program as it jitted it, donation and all
     lower = fn.lower if hasattr(fn, "lower") else jax.jit(fn).lower
-    compiled = lower(*args).compile()
+    with mock.patch.object(pa, "_pages_per_block",
+                           wraps=pa._pages_per_block) as rule:
+        compiled = lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # every launch of the decode kernel keeps its page buffers (two slots
+    # each of K and V, P pages a slot) inside the budget the file states
+    assert rule.called == ("decode" in name)
+    for call in rule.call_args_list:
+        kvh, bs, hd, dtype, _ = call.args
+        pages = pa._pages_per_block(*call.args)
+        assert pages >= 1
+        assert 4 * pages * kvh * bs * hd * jnp.dtype(dtype).itemsize \
+            <= pa._PAGE_BUFFER_BYTES
     if hasattr(build, "check"):
         build.check(compiled)

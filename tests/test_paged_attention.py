@@ -14,19 +14,26 @@ from harness import drive, shared_model, solo_generate
 N_LAYERS = 3
 
 
-def _random_paged(seed=0, B=3, kvh=2, G=4, hd=128, n_blocks=9, bs=16,
-                  max_blocks=4, lens=(37, 5, 64)):
+def _random_paged(seed=0, kvh=2, G=4, hd=128, bs=16, max_blocks=4,
+                  lens=(37, 5, 64), inactive=()):
     """Random STACKED block pools [L, N, kvh, bs, hd] (every layer its
     own values) + tables with ragged per-row lengths (one row
-    mid-block, one tiny, one exactly on a block boundary)."""
+    mid-block, one tiny, one exactly on a block boundary). Rows named
+    in ``inactive`` are the engine's empty lanes: an all-NULL table and
+    the length 1 the decode step hands the kernel for them."""
     rng = np.random.RandomState(seed)
+    lens = np.asarray(lens, np.int32)
+    B = len(lens)
+    n_blocks = 1 + int(sum(-(-int(n) // bs) for n in lens))
     q = rng.randn(B, kvh, G, hd).astype(np.float32) * 0.5
     kp = rng.randn(N_LAYERS, n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
     vp = rng.randn(N_LAYERS, n_blocks, kvh, bs, hd).astype(np.float32) * 0.5
-    lens = np.asarray(lens, np.int32)
     table = np.zeros((B, max_blocks), np.int32)
-    free = list(range(1, n_blocks))          # page 0 = NULL
+    free = list(rng.permutation(np.arange(1, n_blocks)))  # page 0 = NULL
     for b in range(B):
+        if b in inactive:
+            lens[b] = 1
+            continue
         for j in range(-(-int(lens[b]) // bs)):
             table[b, j] = free.pop(0)
     return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
@@ -42,20 +49,50 @@ def _quantize_pool(pages):
     return codes, scales
 
 
+# The shapes the callers run and the edges of a block of P pages (P is
+# 32 at a page of 16 tokens, 16 at one of 32: 512 tokens). Lengths: one
+# token, exactly one page, exactly one block, one block plus a token,
+# several blocks with a partial last one; the table is wider than the
+# longest row needs and no multiple of P; the last row is an empty lane.
+_EDGES16 = dict(bs=16, max_blocks=75, lens=(1, 16, 512, 513, 1061, 1),
+                inactive=(5,))
+_EDGES32 = dict(bs=32, max_blocks=35, lens=(1, 32, 512, 513, 1061, 1),
+                inactive=(5,))
+KERNEL_SHAPES = {
+    "small": {},                                   # P = the table's 4
+    "qwen2_kvh4_g7": dict(kvh=4, G=7, **_EDGES16),
+    "llama3_kvh8_g4": dict(kvh=8, G=4, **_EDGES16),
+    "tp4_shard_kvh1_g7": dict(kvh=1, G=7, **_EDGES16),
+    "page32_kvh2_g4": dict(kvh=2, G=4, **_EDGES32),
+    "inactive_first_row": dict(kvh=2, G=4, bs=16, max_blocks=40,
+                               lens=(1, 600, 1, 40), inactive=(0, 2)),
+}
+KERNEL_CASES = [(kv, layer, "small") for kv in ("fp", "int8")
+                for layer in range(N_LAYERS)]
+KERNEL_CASES += [("fp", 1, s) for s in KERNEL_SHAPES if s != "small"]
+KERNEL_CASES += [("int8", 2, "page32_kvh2_g4"),
+                 ("int8", 0, "inactive_first_row")]
+
+
 class TestPagedKernel:
-    @pytest.mark.parametrize("layer", range(N_LAYERS))
-    @pytest.mark.parametrize("kv", ["fp", "int8"])
-    def test_interpret_matches_reference(self, kv, layer):
-        """The Pallas kernel (double-buffered page DMA + online softmax)
-        must match its reference on ragged lengths at EVERY layer of the
+    @pytest.mark.parametrize("kv,layer,shape", KERNEL_CASES)
+    def test_interpret_matches_reference(self, kv, layer, shape):
+        """The Pallas kernel (a row's pages in double-buffered blocks,
+        one copy a page with all its kv heads + online softmax) must
+        match its reference on ragged lengths at EVERY layer of the
         stacked pools — interpret mode executes the DMA and the scalar
-        prefetch (table, lengths, layer) faithfully on CPU. fp: the
-        gather-then-masked-softmax reference; int8: the block-looped
-        reference, which the kernel matches bit for bit."""
+        prefetch (table, lengths, layer) faithfully on CPU, and hands
+        the kernel its buffers uninitialised (NaN), as the chip may. fp:
+        the gather-then-masked-softmax reference; int8: the block-looped
+        reference, which the kernel matches bit for bit. An empty lane
+        (all-NULL table) comes out zeros and changes no neighbour."""
         from paddle_tpu.kernels.paged_attention import (
             _paged_attn_reference, _paged_attn_reference_int8,
             paged_attention_pallas)
-        q, kp, vp, table, lens = _random_paged()
+        spec = KERNEL_SHAPES[shape]
+        q, kp, vp, table, lens = _random_paged(**spec)
+        dead = list(spec.get("inactive", ()))
+        live = [b for b in range(len(lens)) if b not in dead]
         if kv == "int8":
             (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
             out = paged_attention_pallas(q, kp, vp, table, lens, layer,
@@ -63,21 +100,49 @@ class TestPagedKernel:
                                          kv_scales=(ks, vs))
             ref = _paged_attn_reference_int8(q, kp, vp, table, lens,
                                              layer, (ks, vs))
-            np.testing.assert_array_equal(np.asarray(out),
-                                          np.asarray(ref))
+            np.testing.assert_array_equal(np.asarray(out)[live],
+                                          np.asarray(ref)[live])
+            assert not np.asarray(out)[dead].any()
             return
         # a traced layer, as the decode step's scan hands it over
-        out = jax.jit(lambda l: paged_attention_pallas(
-            q, kp, vp, table, lens, l, interpret=True))(jnp.int32(layer))
-        ref = _paged_attn_reference(q, kp, vp, table, lens, layer)
-        assert np.allclose(np.asarray(out), np.asarray(ref),
-                           atol=2e-5), \
-            np.abs(np.asarray(out) - np.asarray(ref)).max()
+        out = np.asarray(jax.jit(lambda l: paged_attention_pallas(
+            q, kp, vp, table, lens, l, interpret=True))(jnp.int32(layer)))
+        ref = np.asarray(_paged_attn_reference(q, kp, vp, table, lens,
+                                               layer))
+        assert np.allclose(out[live], ref[live], atol=2e-5), \
+            np.abs(out[live] - ref[live]).max()
+        assert not out[dead].any()
         # the layer's own pages were read, not a neighbour's
-        other = _paged_attn_reference(q, kp, vp, table, lens,
-                                      (layer + 1) % N_LAYERS)
-        assert not np.allclose(np.asarray(out), np.asarray(other),
-                               atol=1e-2)
+        other = np.asarray(_paged_attn_reference(
+            q, kp, vp, table, lens, (layer + 1) % N_LAYERS))
+        assert not np.allclose(out[live], other[live], atol=1e-2)
+
+    # (kvh, bs, hd, pool dtype, table width) -> P
+    @pytest.mark.parametrize("shape,pages", [
+        ((4, 16, 128, "bfloat16", 160), 32),    # chat
+        ((4, 16, 128, "bfloat16", 208), 32),    # doc_qa
+        ((8, 16, 128, "bfloat16", 128), 32),    # llama3-8b, chip_smoke
+        ((8, 32, 128, "int8", 64), 16),         # its int8 pools
+        ((2, 16, 128, "bfloat16", 128), 32),    # a tp=4 shard of it
+        ((1, 16, 128, "bfloat16", 160), 32),    # a tp=4 shard of qwen2
+        ((2, 16, 128, "float32", 4), 4),        # a table narrower than P
+        ((2, 8, 128, "float32", 96), 64),       # the CPU engines' pages
+        ((32, 16, 128, "bfloat16", 512), 8),    # the budget binds
+        ((64, 128, 256, "float32", 512), 1),    # never 0
+    ])
+    def test_pages_per_block_rule(self, shape, pages):
+        """P comes from the operands' shapes alone: 512 tokens' worth of
+        pages, fewer where the four page buffers (two slots each of K
+        and V) would pass the VMEM budget the file states or the table
+        is narrower, and never 0. (``tests/test_chip_compile.py`` holds
+        every case it compiles to the same budget.)"""
+        from paddle_tpu.kernels import paged_attention as pa
+        kvh, bs, hd, dtype, mb = shape
+        got = pa._pages_per_block(kvh, bs, hd, jnp.dtype(dtype), mb)
+        assert got == pages
+        page_bytes = kvh * bs * hd * jnp.dtype(dtype).itemsize
+        assert got == 1 or 4 * got * page_bytes <= pa._PAGE_BUFFER_BYTES
+        assert 1 <= got <= mb
 
     @pytest.mark.parametrize("kv", ["fp", "int8"])
     def test_token_write_touches_one_layer(self, kv):
