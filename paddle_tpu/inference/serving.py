@@ -312,6 +312,14 @@ class DecodeEngine:
             "decode steps executed on device (stall-watchdog heartbeat)")
         self._c_prefills = r.counter(
             "engine_prefills_total", "admission prefill programs run")
+        # how far the cold prefill's block walk engages: blocks of rows
+        # it ran, beside what whole windows would have been
+        self._c_prefill_blocks = r.counter(
+            "engine_prefill_blocks_total",
+            "blocks of rows run by cold prefills")
+        self._c_prefill_window_blocks = r.counter(
+            "engine_prefill_window_blocks_total",
+            "blocks of rows in the windows of cold prefills")
         # ISSUE 10: device-call accounting — every compiled-program
         # launch (prefill/decode/verify/COW/mixed) bumps this, so the
         # single-launch mixed step's O(rows)->O(1) collapse is
@@ -486,19 +494,23 @@ class DecodeEngine:
         def _kv_scales_of(pool):
             return (pool[2], pool[3]) if len(pool) == 4 else None
 
+        self._prefill_block = self._prefill_block_rows(self.s_max)
+
         def prefill_paged(stacked, embed, fnorm, lm, scales, ids,
                           pad_len, table_row, *pool):
-            """ids [1, s_max] right-aligned; the prompt's K/V scatter
-            into the block pools THROUGH table_row inside the program
-            (pad positions route to the NULL page), so admission is one
-            device call."""
+            """ids [1, s_max] right-aligned; the forward runs only the
+            blocks of rows that hold prompt tokens (the trip count is
+            data: one program for every prompt length), and the
+            prompt's K/V scatter into the block pools THROUGH table_row
+            inside the program (pad positions route to the NULL page),
+            so admission is one device call."""
             stacked, lm = _llama._dequantize_weights(cfg, stacked, lm,
                                                      scales)
             if lm is None:
                 lm = embed.T
-            logits, ks, vs = _llama.masked_prefill(
+            logits, ks, vs = _llama.blockwise_prefill(
                 cfg, stacked, embed, fnorm, lm, ids, pad_len,
-                last_index=self.s_max - 1, mp_axis=mp)
+                self._prefill_block, mp_axis=mp)
             out = _llama.scatter_prefill_kv(
                 pool[0], pool[1], ks, vs, table_row, pad_len[0],
                 kv_scales=_kv_scales_of(pool), seq_axis=sq)
@@ -530,8 +542,8 @@ class DecodeEngine:
             """Prefix-hit prefill over a BUCKETED tail window of ``sc``
             slots: the cached prefix stays in the pool, only the
             uncached tail runs the forward — the TTFT win prefix
-            sharing exists for. One program per bucket (powers of two),
-            cold admissions keep the untouched full-window program."""
+            sharing exists for. One program per bucket (powers of two);
+            cold admissions run the one blockwise program."""
 
             def prefill_prefix(stacked, embed, fnorm, lm, scales, ids,
                                pad_len, prefix_len, table_row, *pool):
@@ -724,6 +736,20 @@ class DecodeEngine:
             self._decode_progs[n] = fn
         return fn
 
+    @staticmethod
+    def _prefill_block_rows(s_max: int) -> int:
+        """Rows the cold prefill runs at a time. 256 is where a block's
+        matmuls cost what reading their bfloat16 weights costs (two
+        operations a weight byte a row, against a v5e's 240 a byte): a
+        smaller block re-reads the weights for nothing, a larger one
+        pads a short prompt for nothing and is no cheaper a row
+        (``PERF.md``, Findings, PR 33: 128, 256 and 512 on the chip).
+        Halved until the window holds two blocks."""
+        rows = 256
+        while rows > 8 and 2 * rows > s_max:
+            rows //= 2
+        return rows
+
     def _bucket_window(self, n: int) -> int:
         """Tail-window bucket for prefix-hit prefill: powers of two from
         16, capped at s_max — mixed tail lengths share a few compiled
@@ -905,6 +931,9 @@ class DecodeEngine:
              "tp_degree": self._tp,
              "seq_degree": self._seq,
              "prefills": self.prefills,
+             "prefill_blocks": int(self._c_prefill_blocks.value),
+             "prefill_window_blocks":
+                 int(self._c_prefill_window_blocks.value),
              "resets": self.resets}
         if self.mesh is not None:
             s["mesh_shape"] = {k: int(v)
@@ -1355,7 +1384,8 @@ class DecodeEngine:
     def _prefill_row(self, slot, seq, m, pages):
         """Run the admission prefill for ``seq`` into ``pages`` (plus
         the match's shared pages), seeding the slot's block table.
-        Cold (no cached prefix): the untouched full-window program.
+        Cold (no cached prefix): the blockwise program over the
+        window's live blocks.
         Prefix hit: COW-copy the partially-shared page if any, then the
         position-offset tail prefill over a bucketed window. Returns
         the argmax token at the last real position."""
@@ -1383,6 +1413,9 @@ class DecodeEngine:
                 *self._pool())
             self._set_pool(pool)
             self._c_device_calls.inc()
+            self._c_prefill_blocks.inc(-(-ns // self._prefill_block))
+            self._c_prefill_window_blocks.inc(
+                -(-self.s_max // self._prefill_block))
         else:
             if m.cow_src is not None:
                 # private copy of the partially-shared page: the tail's

@@ -206,8 +206,10 @@ def _attention_keymask(q, k, v, key_mask):
     """Causal attention with an additional per-row VALID-KEY mask
     (serving prefill over a left-padded batch: pad positions must not be
     attended; reference masked_multihead_attention's mask input). XLA
-    path — serving prompts are short; the training path never pays for
-    the mask branch."""
+    path with [S, S] float32 scores: solo ``generate`` and the
+    contiguous engine, whose windows are their prompts. The paged
+    engine's cold prefill does not come here (:func:`blockwise_prefill`),
+    and the training path never pays for the mask branch."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     G = H // Hkv
@@ -1531,6 +1533,55 @@ def _attention_prefix(q, k, v, key_mask, pk, pv, prefix_mask):
     return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
 
 
+def _attention_prefix_span(q, k, v, key_mask, pk, pv, prefix_mask, span):
+    """:func:`_attention_prefix` that reads only the part of the prefix
+    that can hold a valid key: ``span`` = (lo, hi, width), the key blocks
+    ``lo..hi-1`` of ``width`` columns each (data, so the cost follows
+    the keys and not the prefix's length). Online softmax over the
+    window first and then block after block, with the same precision:
+    float32 scores, probabilities cast to the operands' type before the
+    PV product; the finite ``-1e30`` keeps empty rows free of NaNs."""
+    neg = -1e30
+    lo, hi, width = span
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    G = H // Hkv
+    qh = jnp.swapaxes(q, 1, 2).reshape(B, Hkv, G, S, D)
+    scale = D ** 0.5
+
+    def fold(state, kh, vh, ok):
+        """kh/vh [B, Hkv, T, D]; ok broadcastable to [B, 1, 1, S, T]."""
+        m, l, acc = state
+        s = jnp.einsum("bngsd,bntd->bngst", qh, kh).astype(jnp.float32)
+        s = jnp.where(ok, s / scale, neg)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        pv_ = jnp.einsum("bngst,bntd->bngsd", p.astype(q.dtype), vh)
+        return (m_new, alpha * l + p.sum(axis=-1),
+                alpha[..., None] * acc + pv_.astype(jnp.float32))
+
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    valid_w = causal[None, :, :] & key_mask[:, None, :].astype(bool)
+    state = (jnp.full((B, Hkv, G, S), neg, jnp.float32),
+             jnp.zeros((B, Hkv, G, S), jnp.float32),
+             jnp.zeros((B, Hkv, G, S, D), jnp.float32))
+    state = fold(state, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                 valid_w[:, None, None, :, :])
+
+    def body(j, state):
+        at = j * width
+        kj = jax.lax.dynamic_slice_in_dim(pk, at, width, 1)
+        vj = jax.lax.dynamic_slice_in_dim(pv, at, width, 1)
+        ok = jax.lax.dynamic_slice_in_dim(prefix_mask, at, width, 1)
+        return fold(state, jnp.swapaxes(kj, 1, 2), jnp.swapaxes(vj, 1, 2),
+                    ok[:, None, None, None, :].astype(bool))
+
+    _, l, acc = jax.lax.fori_loop(lo, hi, body, state)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]    # an empty row: 0 / 1e-30
+    return jnp.swapaxes(out.astype(q.dtype).reshape(B, H, S, D), 1, 2)
+
+
 def _attention_prefix_seq(q, k, v, key_mask, pk, pv, prefix_mask,
                           seq_axis):
     """Page-sharded :func:`_attention_prefix` (2-D mesh, ISSUE 16):
@@ -1581,12 +1632,16 @@ def _attention_prefix_seq(q, k, v, key_mask, pk, pv, prefix_mask,
 
 
 def _prefix_decoder_layer(cfg, lp, x, positions, key_mask, pk, pv,
-                          prefix_mask, mp_axis=None, seq_axis=None):
+                          prefix_mask, mp_axis=None, seq_axis=None,
+                          span=None):
     """One decoder layer over an uncached TAIL window attending to a
     cached paged prefix (single-program GSPMD path, mirrors
     _decoder_layer's math with _attention_prefix in place of
     _attention; ``mp_axis`` adds the manual-TP psum finishers for
-    shard_map regions, ISSUE 10). Returns (x, k, v) — the tail's
+    shard_map regions, ISSUE 10). ``span`` = (lo, hi, width): the
+    prefix's valid keys lie in its blocks ``lo..hi-1`` of ``width``
+    columns, and only those are read (:func:`_attention_prefix_span`;
+    the cold prefill's block walk). Returns (x, k, v) — the tail's
     post-rope K/V, scattered into the block pool by the caller."""
     hd = cfg.head_dim
     h = lp["wq"].shape[-1] // hd
@@ -1610,6 +1665,9 @@ def _prefix_decoder_layer(cfg, lp, x, positions, key_mask, pk, pv,
     if seq_axis is not None:
         attn = _attention_prefix_seq(q, k, v, key_mask, pk, pv,
                                      prefix_mask, seq_axis)
+    elif span is not None:
+        attn = _attention_prefix_span(q, k, v, key_mask, pk, pv,
+                                      prefix_mask, span)
     else:
         attn = _attention_prefix(q, k, v, key_mask, pk, pv,
                                  prefix_mask)
@@ -1709,6 +1767,71 @@ def prefix_prefill(cfg, stacked, embed, final_norm, lm_head, ids,
                              offset=prefix_len[0], kv_scales=kv_scales,
                              seq_axis=seq_axis)
     return (logits, *out)
+
+
+def blockwise_prefill(cfg, stacked, embed, final_norm, lm_head, ids,
+                      pad_len, block, mp_axis=None):
+    """:func:`masked_prefill` for ONE right-aligned row, at the cost of
+    its prompt and not of its window: ``ids`` [1, s], ``pad_len`` [1] ->
+    (last-position float32 logits [1, V], per-layer post-rope K/V stacks
+    [L, 1, s, kvh, hd], zeros left of the first block that holds a
+    token).
+
+    The window is walked left to right in blocks of ``block`` rows, from
+    the block that holds the first prompt token: the trip count is data,
+    so one compiled program serves every prompt length. Each block runs
+    the prefix program's layer (:func:`_prefix_decoder_layer`): causal
+    inside the block, plus the row's own K/V of the blocks before it,
+    which the loop carries contiguous ([L, s, kvh, hd]) and reads from
+    the first block run to this one (``span``), masked to the real
+    positions. Scores are [block, block] at a time. A window that is no
+    multiple of ``block`` is padded on the left for the walk; one that
+    a single block covers runs :func:`masked_prefill`."""
+    s = ids.shape[1]
+    if block >= s:
+        return masked_prefill(cfg, stacked, embed, final_norm, lm_head,
+                              ids, pad_len, mp_axis=mp_axis)
+    n_blocks = -(-s // block)
+    shift = n_blocks * block - s
+    ids = jnp.pad(ids, ((0, 0), (shift, 0)))
+    pad = pad_len + shift                                   # [1]
+    cols = jnp.arange(n_blocks * block)
+    real = cols[None, :] >= pad[:, None]
+    first = pad[0] // block             # the block of the first token
+    kvh = stacked["wk"].shape[-1] // cfg.head_dim
+    kv0 = jnp.zeros((cfg.num_hidden_layers, n_blocks * block, kvh,
+                     cfg.head_dim), embed.dtype)
+    if mp_axis is not None:     # each shard carries its own kv heads
+        kv0 = jax.lax.pcast(kv0, (mp_axis,), to="varying")
+
+    def run_block(i, carry):
+        kc, vc, _ = carry
+        start = i * block
+        at = start + jnp.arange(block)[None, :]
+        key_mask = at >= pad[:, None]
+        positions = jnp.maximum(at - pad[:, None], 0)
+        x = jnp.take(
+            embed, jax.lax.dynamic_slice_in_dim(ids, start, block, 1),
+            axis=0)
+
+        def layer_fn(h, xs):
+            lp, kl, vl = xs
+            h, k, v = _prefix_decoder_layer(
+                cfg, lp, h, positions, key_mask, kl[None], vl[None],
+                real, mp_axis=mp_axis, span=(first, i, block))
+            return h, (k[0], v[0])
+
+        x, (ks, vs) = jax.lax.scan(layer_fn, x, (stacked, kc, vc))
+        kc = jax.lax.dynamic_update_slice_in_dim(kc, ks, start, 1)
+        vc = jax.lax.dynamic_update_slice_in_dim(vc, vs, start, 1)
+        return kc, vc, x[:, -1]
+
+    kc, vc, last = jax.lax.fori_loop(
+        first, n_blocks, run_block,
+        (kv0, kv0, jnp.zeros((1, embed.shape[1]), embed.dtype)))
+    last = _rms(last, final_norm, cfg.rms_norm_eps)
+    logits = (last @ lm_head).astype(jnp.float32)
+    return logits, kc[:, None, shift:], vc[:, None, shift:]
 
 
 def _generate_all(cfg, max_new_tokens, greedy, top_k, has_mask, stacked,

@@ -14,6 +14,7 @@ production. Nothing runs, so these say nothing about results or times.
 import functools
 import os
 import re
+import time
 from unittest import mock
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -176,10 +177,63 @@ def _engine_decode(kv_dtype, block, tp=1, layers=2, kv_heads=4,
     return build
 
 
+def _engine_prefill(s_max=2560, n_pages=4577, layers=14, batch=32,
+                    block=16):
+    """The engine's OWN cold ``prefill_paged`` program at the chat
+    cell's sizes: Qwen2-7B's widths (3584 / 18944, 28 heads over 4 kv
+    heads of 128, q/k/v biases), 14 layers, a window of 2560, 4577
+    pages. The vocabulary is cut (it is one matmul after the loop). The
+    model is drawn one layer deep and handed over as shapes of 14."""
+    def build(place):
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=1024, hidden_size=28 * HD, intermediate_size=18944,
+            num_hidden_layers=1, num_attention_heads=28,
+            num_key_value_heads=4, attention_bias=True, rope_theta=1e6,
+            rms_norm_eps=1e-6, dtype="bfloat16"))
+        model.eval()
+        model.config.num_hidden_layers = layers
+        eng = DecodeEngine(model, capacity=batch, s_max=s_max,
+                           block_size=block, n_blocks=n_pages)
+        stacked, *rest = eng._weights()
+
+        def like(a):
+            return place(a.shape, a.dtype)
+
+        return eng._prefill, [
+            {n: place((layers,) + v.shape[1:], v.dtype)
+             for n, v in stacked.items()},
+            *jax.tree.map(like, rest), {},
+            place((1, s_max), I32), place((1,), I32),
+            place((eng._max_blocks,), I32), *map(like, eng._pool())]
+    build.kernel = False
+    build.lower_seconds = 1.0
+    build.check = functools.partial(
+        _assert_prefill_follows_the_prompt, s_max=s_max,
+        pool=jax.ShapeDtypeStruct((layers, n_pages, 4, block, HD), BF16))
+    return build
+
+
 _HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(")
 # a tuple, a loop or a pointer to part of one computes and moves nothing
 _NO_WORK = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
             "conditional", "call", "optimization-barrier"}
+
+
+def _hlo_instructions(text):
+    """(computation it stands in, result shape, operation, line) of each
+    instruction of a compiled module's text that does work."""
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+        m = _HLO_OP.match(line)
+        if m and m.group(3) not in _NO_WORK:
+            yield comp, m.group(2), m.group(3), line
 
 
 def _assert_pools_stay_put(compiled, pool):
@@ -201,13 +255,9 @@ def _assert_pools_stay_put(compiled, pool):
         if m and line.lstrip().startswith("ROOT"):
             roots[comp] = m.group(3)
     moved = []
-    for line in text.splitlines():
-        m = _HLO_OP.match(line)
-        if not m or m.group(3) in _NO_WORK:
+    for _, shape, op, line in _hlo_instructions(text):
+        if not any(f"[{d}]" in shape for d in dims):
             continue
-        if not any(f"[{d}]" in m.group(2) for d in dims):
-            continue
-        op = m.group(3)
         if op == "fusion":
             op = roots[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
         if op != "scatter":
@@ -216,6 +266,33 @@ def _assert_pools_stay_put(compiled, pool):
     slice_bytes = int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < slice_bytes, (temp, slice_bytes)
+
+
+def _assert_prefill_follows_the_prompt(compiled, s_max, pool):
+    """The cold prefill's work follows its prompt: no instruction
+    produces square scores (a value with two dimensions of the window's
+    width, give or take a block), and the program moves the pools no
+    more than its scatter did before the block walk (a copy in, the
+    scatter and a copy back for each pool: ROADMAP S4's to remove), one
+    pool-sized temporary at most."""
+    from paddle_tpu.inference.serving import DecodeEngine
+    wide = range(s_max, s_max + DecodeEngine._prefill_block_rows(s_max) + 1)
+    pool_elems = int(np.prod(pool.shape))
+    square, pool_sized = [], 0
+    for comp, shape, _, line in _hlo_instructions(compiled.as_text()):
+        for dims in re.findall(r"\w+\[([\d,]+)\]", shape):
+            dims = [int(d) for d in dims.split(",")]
+            if sum(d in wide for d in dims) >= 2:
+                square.append(line.strip()[:200])
+            # what a fusion computes inside is not a value in memory
+            if int(np.prod(dims)) == pool_elems \
+                    and not comp.startswith("fused_computation"):
+                pool_sized += 1
+    assert not square, "\n".join(square)
+    assert pool_sized <= 6, pool_sized
+    pool_bytes = pool_elems * pool.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * pool_bytes, (temp, pool_bytes)
 
 
 def _flash_train_dp2_mp2(batch=6, seq=2048, heads=32):
@@ -265,6 +342,7 @@ CASES = {
     "engine_decode_chunk_int8_block32": _engine_decode("int8", 32),
     "engine_decode_chunk_bf16_tp4": _engine_decode("fp", 16, tp=4,
                                                    kv_heads=8),
+    "engine_prefill_paged_bf16_chat_sizes": _engine_prefill(),
 }
 
 
@@ -296,8 +374,14 @@ def test_compiles_for_v5e(name, topo, monkeypatch):
     lower = fn.lower if hasattr(fn, "lower") else jax.jit(fn).lower
     with mock.patch.object(pa, "_pages_per_block",
                            wraps=pa._pages_per_block) as rule:
-        compiled = lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        t0 = time.perf_counter()
+        lowered = lower(*args)
+        lower_s = time.perf_counter() - t0
+        compiled = lowered.compile()
+    # a program that lowers slowly does so in every process that serves
+    assert lower_s < getattr(build, "lower_seconds", float("inf")), lower_s
+    assert ("tpu_custom_call" in compiled.as_text()) \
+        == getattr(build, "kernel", True)
     # every launch of the decode kernel keeps its page buffers (two slots
     # each of K and V, P pages a slot) inside the budget the file states
     assert rule.called == ("decode" in name)

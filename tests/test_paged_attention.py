@@ -8,7 +8,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from harness import drive, shared_model, solo_generate
+from harness import (drive, make_prompts, run_engine, shared_model,
+                     solo_generate)
 
 
 N_LAYERS = 3
@@ -468,6 +469,35 @@ class TestPagedEngine:
             np.testing.assert_array_equal(po, so)
             np.testing.assert_array_equal(po, co)
         assert paged_eng.resets == 1          # construction only
+
+    @pytest.mark.parametrize("kv", ["fp", "int8"])
+    def test_cold_prefill_either_side_of_a_block_edge(self, kv):
+        """At ``s_max`` 64 the cold prefill runs 32 rows at a time:
+        prompts of one block less a token, one block, one block and a
+        token and a block and a part each equal solo ``generate``, and
+        the engine counts the blocks it ran beside what whole windows
+        would have been."""
+        from paddle_tpu.inference.serving import DecodeEngine
+        m = shared_model()
+        sizes = (31, 32, 33, 40, 9)
+        prompts = make_prompts(np.random.RandomState(4), 128, sizes)
+        assert DecodeEngine._prefill_block_rows(64) == 32
+        outs, eng = run_engine(m, prompts, kv_dtype=kv,
+                               prefix_cache=False)
+        for p, o in zip(prompts, outs):
+            np.testing.assert_array_equal(o, solo_generate(m, p, 8))
+        st = eng.stats()
+        assert st["prefill_blocks"] == 1 + 1 + 2 + 2 + 1
+        assert st["prefill_window_blocks"] == 2 * len(sizes)
+
+    @pytest.mark.parametrize("s_max,rows", [
+        (2560, 256), (3328, 256), (2048, 256), (512, 256), (511, 128),
+        (144, 64), (96, 32), (64, 32), (16, 8), (10, 8)])
+    def test_prefill_block_rows_rule(self, s_max, rows):
+        """256 rows a block wherever the window holds two of them, else
+        the largest power of two that gives two blocks."""
+        from paddle_tpu.inference.serving import DecodeEngine
+        assert DecodeEngine._prefill_block_rows(s_max) == rows
 
     def test_sustained_admission_never_resets(self):
         """Continuous mixed arrivals far past the contiguous engine's

@@ -80,16 +80,19 @@ class TestShardedEngineParity:
         assert eng.stats()["tp_degree"] == 4
         assert eng.stats()["mesh_shape"] == {"tp": 4}
 
-    def test_tp2_matches_mp_sharded_generate(self):
+    @pytest.mark.parametrize("tokens", [10, 40])
+    def test_tp2_matches_mp_sharded_generate(self, tokens):
         """Two independent sharded implementations of the same math:
         the shard_map engine vs the GSPMD mp-sharded generate() path
-        must agree token-for-token (and with the unsharded model)."""
+        must agree token-for-token (and with the unsharded model). At
+        40 tokens the cold prefill's block walk runs two blocks, its
+        psum inside the loop."""
         import warnings
 
         import paddle_tpu.distributed as dist
         m = fresh_model()
         rng = np.random.RandomState(2)
-        p = rng.randint(1, 128, (10,)).astype(np.int32)
+        p = rng.randint(1, 128, (tokens,)).astype(np.int32)
         ref = solo_generate(m, p, 6)
         outs, _ = run_engine(m, [p], max_new=6, mesh=make_tp_mesh(2))
         np.testing.assert_array_equal(outs[0], ref)
