@@ -4,6 +4,7 @@ that one's own file, never from a table in code."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import pathlib
 
@@ -41,6 +42,19 @@ def cell_files(manifest, cell):
                      if c["name"] == cell["config"])
     return (load_json(ROOT / cfg_entry["file"]),
             load_json(traffic_path(cell["traffic"], manifest)))
+
+
+def serve_modules(cfg):
+    """(builder, reference) of a serve configuration: the modules under
+    benchmark/lib that its file names as ``program.build`` (default
+    ``program``; gives ``build_model(cfg, seed)`` and
+    ``kv_bytes_per_block(cfg, block_size)``) and ``program.reference``
+    (default ``reference``; gives ``served_gaps(seed, cfg, seq, n_prompt,
+    control)``). A later configuration brings modules of its own."""
+    names = cfg.get("program", {})
+    return tuple(
+        importlib.import_module(f"benchmark.lib.{names.get(key, default)}")
+        for key, default in (("build", "program"), ("reference", "reference")))
 
 
 def metric_reports_in(metric, cell_name, manifest):

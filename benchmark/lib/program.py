@@ -1,6 +1,12 @@
 """The few calls by which the benchmark stands the system under test up:
 the model object, its weights from the seed, the persistent compilation
-cache. Everything it touches is the program's public surface."""
+cache. Everything it touches is the program's public surface.
+
+This module is also the default builder of a serve configuration
+(``lib/manifest.py``, ``serve_modules``): it gives ``build_model(cfg,
+seed)`` and ``kv_bytes_per_block(cfg, block_size)`` for a model that is
+``LlamaForCausalLM``. A configuration whose model is not names a builder
+of its own in its file and edits nothing here."""
 
 from __future__ import annotations
 
@@ -24,6 +30,14 @@ def llama_config(cfg, **overrides):
     kw.update(cfg.get("program", {}).get("model", {}))
     kw.update(overrides)
     return LlamaConfig(**kw)
+
+
+def kv_bytes_per_block(cfg, block_size, itemsize=2):
+    """Bytes one block of the paged cache takes: keys and values of every
+    layer and kv head for ``block_size`` tokens."""
+    from . import weights
+    return (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
+            * block_size * weights.head_dim(cfg) * itemsize)
 
 
 def build_model(cfg, seed):

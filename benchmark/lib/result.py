@@ -3,6 +3,7 @@ profiles part of the window, and the result line."""
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 
@@ -43,35 +44,47 @@ def start_trace(ctx, t0, default_seconds=3.0):
     return tracer
 
 
+def check_line(name, value, limit):
+    return f"check: {name} {value:.6g} limit {limit:.6g}"
+
+
 def print_checks(checks):
     """Each number compared beside its limit; ``control.*`` lines are
     the control's readings and decide nothing."""
-    for name, value, limit in checks:
-        print(f"check: {name} {value:.6g} limit {limit:.6g}")
+    for check in checks:
+        print(check_line(*check))
     return all(v <= lim for name, v, lim in checks
                if not name.startswith("control."))
 
 
-def assemble(ctx, correct, attempted, failed, peak, e2e, reader_ctx):
+def assemble(ctx, correct, attempted, failed, peak, e2e, reader_ctx, checks=()):
     """The result object: end-to-end metrics, or with ``--trace 1`` the
-    per-layer metrics, the device's busy time and the breakdown."""
+    per-layer metrics, the device's busy time and the breakdown; last,
+    each number ``correct`` compared beside its limit, which are also
+    the run's last lines on standard error."""
     devices = ctx["devices"]
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed,
               "device": dev.describe(devices, memory_peak_bytes=peak)}
-    if not ctx["trace"]:
+    if ctx["trace"]:
+        reduced = trace_reduce.reduce_dir(ctx["trace_dir"])
+        reader_ctx = {
+            **reader_ctx, "cfg": ctx["cfg"], "mix": ctx["mix"],
+            "trace": reduced, "n_devices": len(devices),
+            "e2e": {k: v for k, (v, _) in e2e.items()},
+            "peaks": dev.peaks_for(devices[0].device_kind)
+            if devices[0].platform == "tpu" else None}
+        result["metrics"] = layer_metrics.read_all(
+            ctx["manifest"], ctx["cell"]["name"], reader_ctx)
+        result["device"].update(busy_s=reduced.busy_s,
+                                window_s=reduced.window_s)
+        result["breakdown"] = reduced.breakdown()
+    else:
         result["metrics"] = {k: {"value": v, "unit": u}
                              for k, (v, u) in e2e.items()}
-        return result
-    reduced = trace_reduce.reduce_dir(ctx["trace_dir"])
-    reader_ctx = {
-        **reader_ctx, "cfg": ctx["cfg"], "mix": ctx["mix"], "trace": reduced,
-        "n_devices": len(devices),
-        "e2e": {k: v for k, (v, _) in e2e.items()},
-        "peaks": dev.peaks_for(devices[0].device_kind)
-        if devices[0].platform == "tpu" else None}
-    result["metrics"] = layer_metrics.read_all(
-        ctx["manifest"], ctx["cell"]["name"], reader_ctx)
-    result["device"].update(busy_s=reduced.busy_s, window_s=reduced.window_s)
-    result["breakdown"] = reduced.breakdown()
+    result["checks"] = {name: {"value": value, "limit": limit}
+                        for name, value, limit in checks}
+    for check in checks:
+        print(check_line(*check), file=sys.stderr)
+    sys.stderr.flush()
     return result

@@ -54,7 +54,9 @@ def main(argv=None, allow_cpu=False, manifest_path=None, mix_override=None,
     if mix_override:
         mix = merge(mix, mix_override)
     program.enable_compile_cache()
+    t_imports = time.perf_counter()
     devices = device.require_chips(cell["chips"], allow_cpu=allow_cpu)
+    t_chip = time.perf_counter()
     # traces are written inside the checkout, under a name git ignores
     trace_dir = ROOT / "log" / "benchmark_trace" / cell["name"]
     if args.trace:
@@ -64,7 +66,9 @@ def main(argv=None, allow_cpu=False, manifest_path=None, mix_override=None,
     result = runner.run({
         "cell": cell, "cfg": cfg, "mix": mix, "manifest": manifest,
         "seed": args.seed, "seconds": args.seconds, "trace": bool(args.trace),
-        "devices": devices, "t_process": T_PROCESS, "trace_dir": trace_dir,
+        "devices": devices, "t_process": T_PROCESS,
+        "t_imports": t_imports, "t_chip": t_chip,
+        "trace_dir": trace_dir,
         "control": control})
     sys.stdout.flush()
     print(json.dumps(result), flush=True)

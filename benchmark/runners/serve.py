@@ -18,23 +18,20 @@ import time
 import numpy as np
 
 from ..lib import device as dev
-from ..lib import program, reference, result, stats, traffic, weights
+from ..lib import manifest as mf
+from ..lib import result, stats, traffic
 
 
 # -- the engine's sizes ------------------------------------------------------
-def kv_bytes_per_block(cfg, block_size, itemsize=2):
-    return (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
-            * block_size * weights.head_dim(cfg) * itemsize)
-
-
-def engine_kwargs(cfg, mix, trace):
+def engine_kwargs(cfg, mix, trace, builder):
     """Engine options: the configuration's, then the mix's (its slots and
-    ``s_max``). ``kv_pool_bytes`` becomes ``n_blocks``."""
+    ``s_max``). ``kv_pool_bytes`` becomes ``n_blocks`` by what the
+    configuration's builder says one block of cache takes."""
     kw = {**cfg.get("program", {}).get("engine", {}), **mix.get("engine", {})}
     capacity = kw.pop("capacity")
     pool = kw.pop("kv_pool_bytes", None)
     if pool is not None:
-        kw["n_blocks"] = int(pool) // kv_bytes_per_block(
+        kw["n_blocks"] = int(pool) // builder.kv_bytes_per_block(
             cfg, kw.get("block_size", 16))
     if trace:
         kw["profile"] = True
@@ -142,20 +139,28 @@ class StepTimer:
 # -- the run -----------------------------------------------------------------
 def run(ctx):
     """``ctx``: cell, cfg, mix, manifest, seed, seconds, trace, devices,
-    t_process, control. Returns the result object."""
+    t_process, t_imports, t_chip, control. Returns the result object."""
     import jax
     from paddle_tpu.inference.serving import (BatchingServer,
                                               GenerationPredictor)
     cfg, mix, seconds = ctx["cfg"], ctx["mix"], float(ctx["seconds"])
     devices = ctx["devices"]
+    builder, reference = mf.serve_modules(cfg)
     watch = dev.CompileWatch()
-    model = program.build_model(cfg, ctx["seed"])
-    capacity, kw = engine_kwargs(cfg, mix, ctx["trace"])
+    # where set-up's seconds go: a mark at the end of each phase
+    marks = [("imports", ctx["t_imports"]), ("chip", ctx["t_chip"]),
+             ("serving", time.perf_counter())]
+    model = builder.build_model(cfg, ctx["seed"])
+    marks.append(("weights", time.perf_counter()))
+    capacity, kw = engine_kwargs(cfg, mix, ctx["trace"], builder)
     server = BatchingServer(GenerationPredictor(model), max_batch=capacity,
                             continuous=True, engine_kwargs=kw)
     engine = server.engine
+    marks.append(("engine", time.perf_counter()))
     vocab = cfg["vocab_size"]
     warm_up(server, mix, vocab, capacity)
+    marks.append(("warm_up", time.perf_counter()))
+    compile_s, lowered_setup = watch.compile_s, watch.lowered
     sessions = traffic.schedule(mix, ctx["seed"], seconds, vocab)
     timer = StepTimer(engine) if ctx["trace"] else None
     before = engine.stats()
@@ -165,6 +170,7 @@ def run(ctx):
 
     t0 = time.perf_counter()
     setup_s = t0 - ctx["t_process"]
+    marks.append(("schedule", t0))
     tracer = result.start_trace(ctx, t0)
     sent = offer_load(server, sessions, t0, seconds)
     t_close = time.perf_counter()
@@ -203,26 +209,40 @@ def run(ctx):
     # tokens of each decode chunk (a rate over all the work of the window)
     emitted = sum(1 for r in sent if (r.first("first_token") or t_close) < t_close) \
         + sum(n for r in sent for t, n in decode_marks(r) if t < t_close)
-    e2e = {
-        "setup_s": (setup_s, "s"),
-        "ttft_p95_ms": (1e3 * stats.percentile(ttft_all, 95), "ms"),
-        # with no request finished, a token took the window
-        "tpot_p95_ms": (1e3 * stats.percentile(tpot or [window_s], 95), "ms"),
-        "serve_tok_s": (emitted / window_s, "tokens/s"),
-    }
+    # with no request finished, a token took the window
+    latencies = {"ttft": ttft_all, "tpot": tpot or [window_s]}
+    e2e = {"setup_s": (setup_s, "s"),
+           "serve_tok_s": (emitted / window_s, "tokens/s")}
+    for metric in ctx["manifest"]["end_to_end"]:
+        if metric["name"] not in e2e and mf.metric_reports_in(
+                metric, ctx["cell"]["name"], ctx["manifest"]):
+            e2e[metric["name"]] = (latency_metric(metric["name"], latencies), "ms")
     print(f"tokens: completed_requests {out_tokens} emitted_in_window {emitted} "
           f"emitted_tok_s {emitted / window_s:.3f}")
     print(f"samples: sent {len(sent)} first_token {len(ttft)} "
           f"finished {len(finished)} completed_in_window {len(completed)} "
-          f"failed {len(failed)} no_first_token {no_first}")
+          f"failed {len(failed)} no_first_token {no_first} "
+          f"beyond_p95 ttft {stats.beyond(len(ttft_all), 95)} "
+          f"tpot {stats.beyond(len(tpot), 95)}")
     print(f"backlog: mid_window {backlogs[0]} end_of_window {backlogs[1]}")
-    print(f"medians: ttft_p50_ms {1e3 * stats.median(ttft_all):.3f} "
-          f"tpot_p50_ms {1e3 * stats.median(tpot or [window_s]):.3f} "
-          f"window_s {window_s:.3f} setup_s {setup_s:.3f}")
+    print(f"window: window_s {window_s:.3f} setup_s {setup_s:.3f}")
+    # the whole ladder, for whoever has to choose a steadier percentile
+    for name, vals in latencies.items():
+        print(f"ladder: {name}_mean_ms {1e3 * sum(vals) / len(vals):.3f} "
+              + " ".join(f"{name}_p{q}_ms {1e3 * stats.percentile(vals, q):.3f}"
+                         for q in (50, 75, 80, 85, 90, 95, 99)))
+    prompt_admitted = sum(r.n_prompt for r in sent
+                          if (r.first("admitted") or t_close + 1) <= t_close)
+    starts = [ctx["t_process"]] + [t for _, t in marks]
+    print("setup: " + " ".join(f"{name} {t - t_prev:.3f}"
+                               for (name, t), t_prev in zip(marks, starts))
+          + f" compile_or_cache_read_s {compile_s:.3f} "
+          f"programs_lowered {lowered_setup}")
     print(f"engine: admitted {after['admitted'] - before['admitted']} "
           f"preempted {after['preempted'] - before['preempted']} "
           f"prefix_hit_tokens "
           f"{after['prefix_hit_tokens'] - before['prefix_hit_tokens']} "
+          f"prompt_tokens_admitted {prompt_admitted} "
           f"pool {after.get('pool')} n_blocks {engine.n_blocks} "
           f"s_max {engine.s_max} capacity {capacity}")
 
@@ -241,7 +261,7 @@ def run(ctx):
     jax.clear_caches()
 
     t_ref = time.perf_counter()
-    checks = check_served(cfg, mix, ctx["seed"], sequences,
+    checks = check_served(reference, cfg, mix, ctx["seed"], sequences,
                           control=ctx["control"])
     print(f"reference: took {time.perf_counter() - t_ref:.1f} s")
     checks.append(("programs_lowered_in_window", lowered_in_window, 0))
@@ -250,8 +270,21 @@ def run(ctx):
     return result.assemble(
         ctx, correct, len(records), len(failed) + no_first, peak, e2e,
         {"records": records, "window": (t0, t_close), "before": before,
-         "after": after, "step_walls": timer.walls if timer else None,
-         "trace_span": tracer.span if tracer else None})
+         "after": after, "latencies": latencies, "step_walls": timer.walls if timer else None,
+         "trace_span": tracer.span if tracer else None},
+        checks=checks)
+
+
+def latency_metric(name, latencies):
+    """Which statistic of the time to the first token or of the time per
+    token after it is an end-to-end metric is BENCHMARK.json's to say, by
+    the metric's name (``stats.latency_statistic``), over every request
+    of the window."""
+    value = stats.latency_statistic(name, latencies)
+    if value is None:
+        raise SystemExit(f"benchmark: the serve runner has no end-to-end "
+                         f"metric named {name!r}")
+    return value
 
 
 def decode_marks(r):
@@ -274,10 +307,11 @@ def pick_sample(completed, seed, n):
     return [longest] + [rest[i] for i in idx]
 
 
-def check_served(cfg, mix, seed, sequences, control=False):
+def check_served(reference, cfg, mix, seed, sequences, control=False):
     """The numbers ``correct`` compares, each with its limit: over the
     sampled requests, the widest and the mean gap by which a served
-    token's float32 reference logit lies below the reference's best."""
+    token's float32 logit in the configuration's ``reference`` lies
+    below that reference's best."""
     limits = mix.get("check", {}).get("limits", {})
     served, ctrl = [], []
     for seq, n_prompt in sequences:

@@ -162,7 +162,8 @@ def run(ctx):
     return result.assemble(
         ctx, correct, steps, 0, peak, e2e,
         {"window": (t0, t_close), "step_walls_train": walls,
-         "n_params": n_params, "tokens_per_step": tokens_per_step})
+         "n_params": n_params, "tokens_per_step": tokens_per_step},
+        checks=checks)
 
 
 def compare(got, ref, limits):

@@ -11,7 +11,7 @@ import pytest
 
 from benchmark.kernels import flash_fwd, flash_train, paged_decode, train_step
 from benchmark.lib import manifest as mf
-from benchmark.lib import stats, trace_reduce, traffic
+from benchmark.lib import layer_metrics, stats, trace_reduce, traffic
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 MANIFEST = mf.load_manifest()
@@ -126,6 +126,22 @@ def test_spread_is_the_quartile_distance_over_the_median():
     assert stats.iqr_share(v) == pytest.approx((14.25 - 10.75) / 12.5)
 
 
+def test_a_set_is_read_for_tightness_without_its_farthest_run():
+    # one far-off run does no harm, two do
+    one = stats.without_farthest([100, 101, 99, 100.5, 99.5, 140])
+    assert sorted(one) == [99, 99.5, 100, 100.5, 101]
+    assert stats.range_share(one) == pytest.approx(2 / 100)
+    two = stats.without_farthest([100, 101, 99, 100, 130, 140])
+    assert stats.range_share(two) == pytest.approx(31 / 100)
+    assert stats.iqr_share(two) > 10 * stats.iqr_share(one)
+    assert stats.without_farthest([7.0, 7.0]) == [7.0, 7.0]
+
+
+def test_samples_beyond_a_nearest_rank_percentile():
+    assert stats.beyond(200, 95) == 10 and stats.beyond(199, 95) == 9
+    assert stats.beyond(58, 95) == 2 and stats.beyond(0, 95) == 0
+
+
 # -- trace reduction ---------------------------------------------------------
 def test_union_and_gaps():
     iv = [(0, 10), (5, 12), (20, 30), (22, 25)]
@@ -221,6 +237,49 @@ def test_manifest_names_units_and_files():
     assert "setup_s" in {e["name"] for e in m["end_to_end"]}
     for e in m["end_to_end"]:
         assert 0 < e["bound"] <= 0.1
+    # the most that fits a full check of 24 cells (the contract)
+    assert 1 <= m["run_seconds"] <= 51
+
+
+SERVE_CELLS = [w for w in MANIFEST["workloads"]
+               if mix(w["traffic"])["runner"] == "serve"]
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS, ids=lambda w: w["name"])
+def test_a_serve_cell_offers_ten_samples_past_its_p95(cell):
+    """The rule of PERF.md, section 2: a tail is reported at a percentile
+    with ten samples beyond it, so the schedule of ``run_seconds`` holds
+    at least 200 requests (sessions x turns; a session's later turns are
+    due only if the system answers, which the chip runs show)."""
+    m = mix(cell["traffic"])
+    cfg = mf.cell_files(MANIFEST, cell)[0]
+    sessions = traffic.schedule(m, 1, float(MANIFEST["run_seconds"]),
+                                cfg["vocab_size"])
+    offered = sum(len(s.turns) for s in sessions)
+    assert stats.beyond(offered, 95) >= 10, offered
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS, ids=lambda w: w["name"])
+def test_a_why_that_names_a_rate_names_the_mix_files_own(cell):
+    rates = re.findall(r"(\d+(?:\.\d+)?) *(?:req|requests|sessions)/s",
+                       cell["why"])
+    own = mix(cell["traffic"])["arrivals"]["rate_per_s"]
+    assert rates and all(float(r) == own for r in rates), (rates, own)
+
+
+def test_a_configuration_names_its_builder_and_reference():
+    """Absent keys mean the Llama builder and decoder reference; written
+    out they find the same modules, each with what the runner asks for."""
+    default = mf.serve_modules({})
+    named = mf.serve_modules({"program": {"build": "program",
+                                          "reference": "reference"}})
+    assert default == named
+    builder, ref = default
+    assert callable(builder.build_model) and callable(ref.served_gaps)
+    cfg = mf.load_json(ROOT / "benchmark/configs/qwen2-7b-serve.json")
+    assert builder.kv_bytes_per_block(cfg, 16) == 2 * 14 * 4 * 16 * 128 * 2
+    with pytest.raises(ModuleNotFoundError):
+        mf.serve_modules({"program": {"build": "no_such_builder"}})
 
 
 def test_every_per_layer_metric_has_a_reader_and_a_target():
@@ -231,8 +290,29 @@ def test_every_per_layer_metric_has_a_reader_and_a_target():
         assert (ROOT / "benchmark/layer_metrics"
                 / f"{metric['name']}.py").is_file()
         assert set(metric.get("workloads", [])) <= cells
-        assert callable(__import__("benchmark.lib.layer_metrics", fromlist=["x"])
-                        .load_reader(metric["name"]))
+        assert callable(layer_metrics.load_reader(metric["name"]))
+
+
+def test_prefill_blocks_share_reads_the_counters_difference():
+    read = layer_metrics.load_reader("prefill_blocks_share")
+    before = {"prefill_blocks": 10, "prefill_window_blocks": 100}
+    after = {"prefill_blocks": 13, "prefill_window_blocks": 120}
+    assert read({"before": before, "after": after}) == pytest.approx(15.0)
+    assert read({"before": before, "after": before}) is None   # no cold prefill
+    assert read({"before": {}, "after": {"admitted": 3}}) is None
+    assert read({}) is None
+
+
+@pytest.mark.parametrize("name, whole, answered", [
+    ("ttft_p95_ms", 51000.0, 117.0), ("ttft_p50_ms", 109.0, 108.0)])
+def test_a_ttft_reader_takes_the_runners_own_list(name, whole, answered):
+    # 18 requests answered in 100-117 ms and 2 with no first token, which
+    # count as the window's length: the tail shows them, the median not
+    read = layer_metrics.load_reader(name)
+    ttft = stats.with_failures([0.1 + i / 1e3 for i in range(18)], 2, 51.0)
+    assert read({"latencies": {"ttft": ttft}}) == pytest.approx(whole)
+    assert read({"latencies": {"ttft": ttft[:18]}}) == pytest.approx(answered)
+    assert read({}) is None
 
 
 def test_unknown_device_kind_is_an_error():
