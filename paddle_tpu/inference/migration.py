@@ -126,6 +126,12 @@ def _check_compatible(src, dst) -> None:
     if not (src.paged and dst.paged):
         raise ValueError("transplant requires paged engines on both "
                          "ends")
+    if src._state_specs or dst._state_specs:
+        # pages are all a transplant moves; a recurrent state as it
+        # stood at the prefix's end would have to travel with them
+        raise ValueError("transplant cannot carry per-slot recurrent "
+                         "state: a model with PagedPrograms.slot_state "
+                         "keeps its prefix on one engine")
     if src.block_size != dst.block_size:
         raise ValueError(
             f"block_size mismatch: src={src.block_size} "

@@ -382,6 +382,23 @@ class DecodeEngine:
                         "prefix",
                         fn=lambda: (self._cache.hit_rate
                                     if self._cache is not None else 0.0))
+        self._c_decode_row_steps = r.counter(
+            "engine_decode_row_steps_total",
+            "rows summed over the steps of paged decode chunks (over "
+            "engine_device_steps_total: rows a step)")
+        # per-slot recurrent state (a family whose PagedPrograms has
+        # ``slot_state``; all three stay 0 for every other model)
+        self._c_ssm_row_steps = r.counter(
+            "engine_ssm_row_steps_total",
+            "live rows summed over the decode steps of a model with "
+            "per-slot state (over engine_device_steps_total: rows a "
+            "step)")
+        self._c_ssm_prefill_chunks = r.counter(
+            "engine_ssm_prefill_chunks_total",
+            "chunks of the recurrence run by cold prefills")
+        r.gauge("engine_state_slots_in_use",
+                "slots that hold a live row's recurrent state",
+                fn=lambda: len(self._state_slots))
         if self.paged and self.spec_decode:
             # ISSUE 8: speculation observability. accepted counts BONUS
             # tokens only (m-1 per verify step: the first token is what
@@ -480,63 +497,22 @@ class DecodeEngine:
 
             return decode_chunk
 
-        # Paged closures take the pool arrays LAST as ``*pool`` (ISSUE
-        # 8): fp engines pass (kp, vp), int8 engines (kp, vp, kscale,
-        # vscale) — one closure body serves both layouts, and the int8
-        # scale updates stay inside the compiled programs. The arrays
-        # are stacked over layers ([L, N, kvh, bs, hd]; scales
-        # [L, N, kvh]) and the decode program never takes them apart:
-        # it carries the tuple through its chunk scan and its layer
-        # scan, each layer writes its rows' pages and reads its pages
-        # in place, so the donated buffers are the only pool-sized
-        # values the program has.
-
-        def _kv_scales_of(pool):
-            return (pool[2], pool[3]) if len(pool) == 4 else None
-
+        # The two programs every paged engine runs, the cold prefill and
+        # the decode chunk, are the model family's (``paged_programs``,
+        # models.llama.PagedPrograms), with the geometry of its block
+        # pool and whatever a slot holds beside its pages. They take the
+        # pool arrays LAST as ``*pool`` (ISSUE 8): fp engines pass
+        # (kp, vp), int8 engines (kp, vp, kscale, vscale), and a family
+        # with per-slot state its state arrays behind them.
+        _kv_scales_of = _llama.kv_scales_of
         self._prefill_block = self._prefill_block_rows(self.s_max)
-
-        def prefill_paged(stacked, embed, fnorm, lm, scales, ids,
-                          pad_len, table_row, *pool):
-            """ids [1, s_max] right-aligned; the forward runs only the
-            blocks of rows that hold prompt tokens (the trip count is
-            data: one program for every prompt length), and the
-            prompt's K/V scatter into the block pools THROUGH table_row
-            inside the program (pad positions route to the NULL page),
-            so admission is one device call."""
-            stacked, lm = _llama._dequantize_weights(cfg, stacked, lm,
-                                                     scales)
-            if lm is None:
-                lm = embed.T
-            logits, ks, vs = _llama.blockwise_prefill(
-                cfg, stacked, embed, fnorm, lm, ids, pad_len,
-                self._prefill_block, mp_axis=mp)
-            out = _llama.scatter_prefill_kv(
-                pool[0], pool[1], ks, vs, table_row, pad_len[0],
-                kv_scales=_kv_scales_of(pool), seq_axis=sq)
-            return (jnp.argmax(logits, axis=-1), *out)
-
-        def decode_chunk_paged(stacked, embed, fnorm, lm, scales, tok,
-                               tables, lens, *pool):
-            """One chunk against the block pool; tables/lens are DATA,
-            so every admission pattern reuses this one program."""
-            stacked, lm = _llama._dequantize_weights(cfg, stacked, lm,
-                                                     scales)
-            if lm is None:
-                lm = embed.T
-
-            def body(carry, i):
-                tok, pool = carry
-                logits, pool = _llama._paged_decode_step(
-                    cfg, stacked, embed, fnorm, lm, tok, tables,
-                    lens + i, pool, mp_axis=mp, seq_axis=sq,
-                    n_seq=n_sq)
-                nxt = jnp.argmax(logits, axis=-1)
-                return (nxt, pool), nxt
-
-            (tok, pool), toks = jax.lax.scan(
-                body, (tok, pool), jnp.arange(self.chunk))
-            return (toks, *pool)
+        progs = m.paged_programs(
+            chunk=self.chunk, prefill_block=self._prefill_block,
+            mp_axis=mp, seq_axis=sq, n_seq=n_sq)
+        self._refuse_unsupported(progs.unsupported)
+        self._progs = progs
+        prefill_paged = progs.prefill_paged
+        decode_chunk_paged = progs.decode_chunk_paged
 
         def make_prefix_prefill(sc):
             """Prefix-hit prefill over a BUCKETED tail window of ``sc``
@@ -629,7 +605,9 @@ class DecodeEngine:
         self._prefix_progs = {}
         self._make_verify_prefill = make_verify_prefill
         self._verify_progs = {}
-        self._n_pool = 4 if self._kv_q else 2
+        self._state_specs = tuple(progs.slot_state(self.capacity)) \
+            if progs.slot_state is not None else ()
+        self._n_pool = (4 if self._kv_q else 2) + len(self._state_specs)
         if self.paged and self.mesh is not None:
             # ISSUE 10: lower every paged program through shard_map
             # over the kv-head axis. Weights shard Megatron column/row,
@@ -687,7 +665,14 @@ class DecodeEngine:
             cow_wrapped = cow_copy
         self._tp_wrap = _tp_wrap
         if self.paged:
-            self._prefill = jax.jit(_tp_wrap(prefill_paged, 3))
+            if progs.slot_state is None:
+                self._prefill = jax.jit(_tp_wrap(prefill_paged, 3))
+            else:
+                # the row's slot rides behind the table, and the state
+                # arrays are updated in place like the decode program's
+                self._prefill = jax.jit(
+                    prefill_paged,
+                    donate_argnums=tuple(range(9, 9 + self._n_pool)))
             self._decode = jax.jit(
                 _tp_wrap(decode_chunk_paged, 3),
                 donate_argnums=tuple(range(8, 8 + self._n_pool)))
@@ -717,11 +702,27 @@ class DecodeEngine:
                     "prefill", self._prefill)
             self._decode = self._decode_for(self.chunk)
         self._cfg = cfg
-        self._kvh = cfg.num_key_value_heads
-        self._hd = cfg.head_dim
-        self._L = cfg.num_hidden_layers
+        self._kvh = progs.kv_heads
+        self._hd = progs.head_dim
+        self._L = progs.kv_layers
         self._cache_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" \
             else jnp.float32
+
+    def _refuse_unsupported(self, unsupported):
+        """Raise for every option of this engine that the model family
+        says it cannot serve (``PagedPrograms.unsupported``), by name."""
+        asked = {"prefix_cache": self._prefix_on,
+                 "paged=False": not self.paged,
+                 "chunked_prefill": self.chunked_prefill,
+                 "spec_decode": self.spec_decode,
+                 "kv_dtype='int8'": self._kv_q,
+                 "mesh": self.mesh is not None}
+        hit = [f"{opt}: {why}" for opt, why in unsupported.items()
+               if asked.get(opt)]
+        if hit:
+            raise ValueError(
+                f"{type(self.model).__name__} cannot be served with "
+                + "; ".join(hit))
 
     def _decode_for(self, n):
         """Compiled contiguous decode program for an ``n``-step chunk
@@ -794,6 +795,11 @@ class DecodeEngine:
         import numpy as _np
         self.resets += 1
         B = self.capacity
+        # what a slot holds beside its pages (a recurrent model's
+        # states): zero until a prefill leaves a row's there
+        self._state = tuple(jnp.zeros(sp.shape, sp.dtype)
+                            for sp in self._state_specs)
+        self._state_slots = set()
         if self.paged:
             from .paged_cache import BlockAllocator
             from .prefix_cache import PrefixCache
@@ -848,16 +854,18 @@ class DecodeEngine:
     # -- pool plumbing (ISSUE 8) --------------------------------------------
     def _pool(self):
         """The device arrays every paged program takes LAST: (kp, vp)
-        for fp pools, (kp, vp, kscale, vscale) for int8."""
+        for fp pools, (kp, vp, kscale, vscale) for int8; behind them a
+        stateful family's per-slot state arrays."""
         if self._kv_q:
             return (self._kp, self._vp, self._kscale, self._vscale)
-        return (self._kp, self._vp)
+        return (self._kp, self._vp, *self._state)
 
     def _set_pool(self, vals):
         if self._kv_q:
             self._kp, self._vp, self._kscale, self._vscale = vals
         else:
-            self._kp, self._vp = vals
+            self._kp, self._vp, *state = vals
+            self._state = tuple(state)
 
     def _drain_scale_resets(self):
         """int8 only: reset the scales of pages the allocator handed
@@ -934,7 +942,12 @@ class DecodeEngine:
              "prefill_blocks": int(self._c_prefill_blocks.value),
              "prefill_window_blocks":
                  int(self._c_prefill_window_blocks.value),
+             "decode_row_steps": int(self._c_decode_row_steps.value),
              "resets": self.resets}
+        if self._state_specs:
+            s["ssm_row_steps"] = int(self._c_ssm_row_steps.value)
+            s["ssm_prefill_chunks"] = int(self._c_ssm_prefill_chunks.value)
+            s["state_slots_in_use"] = len(self._state_slots)
         if self.mesh is not None:
             s["mesh_shape"] = {k: int(v)
                                for k, v in self.mesh.shape.items()}
@@ -1165,6 +1178,17 @@ class DecodeEngine:
         for p in row["pages"]:
             self._alloc.decref(p)
 
+    def _release_slot_state(self, slot):
+        """A stateful family's slot gives its state up with its row: the
+        arrays keep the bytes, which the decode program no longer reads
+        (``lens`` 0) and the slot's next prefill overwrites. A preempted
+        request takes nothing along: it resumes by recomputing its
+        prefill."""
+        if slot in self._state_slots:
+            with RecordEvent("engine.state_release", "engine",
+                             worker=self.worker_id):
+                self._state_slots.discard(slot)
+
     def _cached_seq(self, row):
         """The token sequence whose KV is resident for the row right
         now: prompt plus all emitted tokens except the last (the last
@@ -1206,6 +1230,7 @@ class DecodeEngine:
                                        row["pages"][:-(-valid // bs)])
                 req._resume_toks = list(row["toks"])
             self._release_row_pages(row)
+            self._release_slot_state(slot)
             self._c_preempted.inc()
             _tmark(req, "preempted", worker=self.worker_id)
             self._tables[slot] = 0
@@ -1407,13 +1432,25 @@ class DecodeEngine:
             ids = _np.full((1, self.s_max), self.pad_id, _np.int32)
             ids[0, self.s_max - ns:] = seq
             pad = self.s_max - ns
+            where = (jnp.asarray(table_row),)
+            if self._state_specs:
+                where += (jnp.asarray(slot, jnp.int32),)
             first, *pool = self._prefill(
                 st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
-                jnp.asarray([pad], jnp.int32), jnp.asarray(table_row),
-                *self._pool())
+                jnp.asarray([pad], jnp.int32), *where, *self._pool())
             self._set_pool(pool)
+            if self._state_specs:
+                # the program started the row's states from zero and
+                # left them in ``slot``: whatever the slot's last tenant
+                # left there was overwritten, never read
+                with RecordEvent("engine.state_admit", "engine",
+                                 worker=self.worker_id):
+                    self._state_slots.add(slot)
             self._c_device_calls.inc()
-            self._c_prefill_blocks.inc(-(-ns // self._prefill_block))
+            blocks = -(-ns // self._prefill_block)
+            self._c_prefill_blocks.inc(blocks)
+            self._c_ssm_prefill_chunks.inc(
+                blocks * self._progs.chunks_per_block)
             self._c_prefill_window_blocks.inc(
                 -(-self.s_max // self._prefill_block))
         else:
@@ -1681,6 +1718,7 @@ class DecodeEngine:
         if publish:
             self._observe_retired(row["req"])
         self._release_row_pages(row)
+        self._release_slot_state(slot)
         self._tables[slot] = 0          # all-NULL: inactive lane
         self._lens[slot] = 0
         self._tok[slot] = 0
@@ -1828,6 +1866,9 @@ class DecodeEngine:
         self._h_chunk.observe(wall)
         n_busy = sum(r is not None for r in self._rows)
         self._g_occupancy.set(n_busy)
+        self._c_decode_row_steps.inc(self.chunk * n_busy)
+        if self._state_specs:
+            self._c_ssm_row_steps.inc(self.chunk * n_busy)
         log_event("engine_chunk", steps=self.chunk, rows=n_busy,
                   fill=int(self._lens.max()), wall_s=round(wall, 4),
                   tokens_per_s=round(self.chunk * n_busy

@@ -4,6 +4,8 @@ Vision models (LeNet/ResNet/VGG/MobileNet — configs 1-2) live in
 paddle_tpu.vision.models."""
 
 from .llama import LlamaConfig, LlamaForCausalLM, llama_loss_fn, LLAMA_PRESETS  # noqa: F401
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig, GraniteHybridForCausalLM, GRANITE_PRESETS)
 from .gpt import GPTConfig, GPTForCausalLM, GPT_PRESETS  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForMaskedLM, BertForSequenceClassification,
@@ -11,6 +13,7 @@ from .bert import (  # noqa: F401
 )
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "llama_loss_fn",
-           "LLAMA_PRESETS", "GPTConfig", "GPTForCausalLM", "GPT_PRESETS", "BertConfig", "BertModel",
+           "LLAMA_PRESETS", "GraniteHybridConfig",
+           "GraniteHybridForCausalLM", "GRANITE_PRESETS", "GPTConfig", "GPTForCausalLM", "GPT_PRESETS", "BertConfig", "BertModel",
            "BertForMaskedLM", "BertForSequenceClassification",
            "BERT_PRESETS"]

@@ -184,6 +184,39 @@ LLAMA_PRESETS = {
 }
 
 
+@dataclass(frozen=True)
+class PagedPrograms:
+    """What a model family gives ``DecodeEngine``'s paged mode: its two
+    programs, the geometry of its block pool, what a slot holds beside
+    its pages, and what it cannot serve.
+
+    ``prefill_paged(stacked, embed, final_norm, lm_head, scales, ids,
+    pad_len, table_row, [slot,] *pool)`` -> (first token [1], *pool) and
+    ``decode_chunk_paged(stacked, embed, final_norm, lm_head, scales, tok,
+    tables, lens, *pool)`` -> (tokens [chunk, b], *pool); the engine jits
+    them under these names. ``pool`` is (k pool, v pool[, k scales, v
+    scales]) followed by the arrays of ``slot_state``: per-slot state no
+    page table describes, one ``ShapeDtypeStruct`` an array for the
+    engine's ``slots``. A family with such state is handed the ``slot``
+    of the row it prefills, its prefill takes the pool donated, and the
+    engine refuses at construction every option in ``unsupported``
+    (option -> why)."""
+    prefill_paged: object
+    decode_chunk_paged: object
+    kv_layers: int
+    kv_heads: int
+    head_dim: int
+    slot_state: object = None
+    chunks_per_block: int = 0
+    unsupported: dict = field(default_factory=dict)
+
+
+def kv_scales_of(pool):
+    """The int8 pools' scales ``(kscale, vscale)`` of a paged program's
+    ``*pool``, None for float pools."""
+    return (pool[2], pool[3]) if len(pool) == 4 else None
+
+
 def _rope(x, positions, theta, head_dim):
     """Rotary embedding on [b, s, h, d] — same kernel as the public
     incubate.nn.functional.fused_rotary_position_embedding."""
@@ -691,6 +724,67 @@ class LlamaForCausalLM(nn.Layer):
                 moe += ["ws_gate", "ws_up", "ws_down"]
             return moe
         return base + ["w_gate", "w_up", "w_down"]
+
+    def paged_programs(self, chunk, prefill_block, mp_axis=None,
+                       seq_axis=None, n_seq=1):
+        """What ``DecodeEngine`` binds for this family
+        (:class:`PagedPrograms`): the cold prefill and the decode chunk
+        over ``chunk`` steps. The pools ride LAST as ``*pool``: fp
+        engines pass (kp, vp), int8 engines (kp, vp, kscale, vscale);
+        one body serves both layouts, and the int8 scale updates stay
+        inside the compiled programs. The arrays are stacked over layers
+        ([L, N, kvh, bs, hd]; scales [L, N, kvh]) and the decode program
+        never takes them apart: it carries the tuple through its chunk
+        scan and its layer scan, each layer writes its rows' pages and
+        reads its pages in place, so the donated buffers are the only
+        pool-sized values the program has."""
+        cfg = self.config
+
+        def prefill_paged(stacked, embed, fnorm, lm, scales, ids,
+                          pad_len, table_row, *pool):
+            """ids [1, s_max] right-aligned; the forward runs only the
+            blocks of rows that hold prompt tokens (the trip count is
+            data: one program for every prompt length), and the
+            prompt's K/V scatter into the block pools THROUGH table_row
+            inside the program (pad positions route to the NULL page),
+            so admission is one device call."""
+            stacked, lm = _dequantize_weights(cfg, stacked, lm, scales)
+            if lm is None:
+                lm = embed.T
+            logits, ks, vs = blockwise_prefill(
+                cfg, stacked, embed, fnorm, lm, ids, pad_len,
+                prefill_block, mp_axis=mp_axis)
+            out = scatter_prefill_kv(
+                pool[0], pool[1], ks, vs, table_row, pad_len[0],
+                kv_scales=kv_scales_of(pool), seq_axis=seq_axis)
+            return (jnp.argmax(logits, axis=-1), *out)
+
+        def decode_chunk_paged(stacked, embed, fnorm, lm, scales, tok,
+                               tables, lens, *pool):
+            """One chunk against the block pool; tables/lens are DATA,
+            so every admission pattern reuses this one program."""
+            stacked, lm = _dequantize_weights(cfg, stacked, lm, scales)
+            if lm is None:
+                lm = embed.T
+
+            def body(carry, i):
+                tok, pool = carry
+                logits, pool = _paged_decode_step(
+                    cfg, stacked, embed, fnorm, lm, tok, tables,
+                    lens + i, pool, mp_axis=mp_axis, seq_axis=seq_axis,
+                    n_seq=n_seq)
+                nxt = jnp.argmax(logits, axis=-1)
+                return (nxt, pool), nxt
+
+            (tok, pool), toks = jax.lax.scan(
+                body, (tok, pool), jnp.arange(chunk))
+            return (toks, *pool)
+
+        return PagedPrograms(
+            prefill_paged=prefill_paged,
+            decode_chunk_paged=decode_chunk_paged,
+            kv_layers=cfg.num_hidden_layers,
+            kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim)
 
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0,
                  top_k=0, seed=0, use_cache=True, attention_mask=None):

@@ -89,6 +89,14 @@ def timer_inference_paths() -> list[pathlib.Path]:
     return _glob(PKG / "inference")
 
 
+def timer_model_paths() -> list[pathlib.Path]:
+    """The model families the engine serves and their kernels: what
+    they compute rides inside the engine's spans, so a wall clock of
+    their own would fork the one the traces share."""
+    return [PKG / "models" / "llama.py",
+            PKG / "models" / "granite_hybrid.py"] + _glob(PKG / "kernels")
+
+
 def timer_shared_clock_paths() -> list[pathlib.Path]:
     return _glob(PKG / "observability") + [WATCHDOG]
 
@@ -106,7 +114,8 @@ def scan_paths() -> list[pathlib.Path]:
         _glob(PKG / "inference")
         + _glob(PKG / "observability")
         + [WATCHDOG]
-        + [PKG / "models" / "llama.py"]
+        + [PKG / "models" / "llama.py",
+           PKG / "models" / "granite_hybrid.py"]
         + _glob(PKG / "kernels")
         + [REPO_ROOT / "bench.py"]
     )

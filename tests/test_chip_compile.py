@@ -317,6 +317,61 @@ def _flash_train_dp2_mp2(batch=6, seq=2048, heads=32):
     return build
 
 
+def _ssm_update(layers=36, slots=64, heads=64, head_dim=64, state=128):
+    """The decode state update at granite-4.0-h-micro's widths: 36 layers
+    of 64 slots of [32, 128, 128] float32, a row's 2 MiB one block."""
+    def build(place):
+        from paddle_tpu.kernels import ssm_update as su
+        pack = su.lane_pack(heads, head_dim)
+        return su.ssm_update_pallas, [
+            place((layers, slots, heads // pack, state, pack * head_dim),
+                  F32), place((), I32),
+            place((slots, heads, head_dim), F32), place((slots, heads), F32),
+            place((slots, heads), F32), place((slots, state), F32),
+            place((slots, state), F32), place((slots,), jnp.bool_)]
+    return build
+
+
+def _engine_decode_hybrid(batch=8, s_max=512, block=16, n_pages=1024):
+    """The engine's decode program for a stack of state-space and
+    attention layers at granite-4.0-h-micro's widths (three layers of
+    it): the paged kernel over heads of 64 packed two to a pool head,
+    the state update kernel, both pools and both state arrays donated
+    and none of them moved."""
+    def build(place):
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models.granite_hybrid import (
+            GraniteHybridConfig, GraniteHybridForCausalLM)
+        paddle.seed(0)
+        model = GraniteHybridForCausalLM(GraniteHybridConfig(
+            vocab_size=1024, num_hidden_layers=3,
+            layer_types=("mamba", "attention", "mamba"), dtype="bfloat16"))
+        model.eval()
+        eng = DecodeEngine(model, capacity=batch, s_max=s_max,
+                           block_size=block, n_blocks=n_pages,
+                           prefix_cache=False)
+        stacked, *rest = eng._weights()
+
+        def like(a):
+            return place(a.shape, a.dtype)
+
+        return eng._decode, [
+            jax.tree.map(like, stacked), *jax.tree.map(like, rest), {},
+            *(like(jnp.asarray(a))
+              for a in (eng._tok, eng._tables, eng._lens)),
+            *map(like, eng._pool())]
+
+    def check(compiled):
+        text = compiled.as_text()
+        assert "ssm_decode_update" in text
+        # nothing of a state array's or a pool's size beside the donated
+        # buffers themselves
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    build.check = check
+    return build
+
+
 CASES = {
     "paged_decode_bf16_block16": _paged_decode(16, BF16, 4096),
     # the pool an engine could really hold on 16 GB (2 GiB each of K and
@@ -343,6 +398,8 @@ CASES = {
     "engine_decode_chunk_bf16_tp4": _engine_decode("fp", 16, tp=4,
                                                    kv_heads=8),
     "engine_prefill_paged_bf16_chat_sizes": _engine_prefill(),
+    "ssm_update_kernel_granite_widths": _ssm_update(),
+    "engine_decode_chunk_granite_hybrid": _engine_decode_hybrid(),
 }
 
 

@@ -26,6 +26,7 @@ the pre-port lint is asserted in ``tests/test_staticcheck.py``.
 from paddle_tpu.staticcheck import AdhocTimerChecker, run
 from paddle_tpu.staticcheck.config import (WATCHDOG,
                                            timer_inference_paths,
+                                           timer_model_paths,
                                            timer_shared_clock_paths)
 
 
@@ -72,6 +73,20 @@ def test_lint_covers_fleet_modules():
         assert required in scanned, (
             f"{required} missing from the timer-lint scan set "
             f"{sorted(scanned)}")
+
+
+def test_served_models_and_kernels_have_no_raw_timer():
+    """The families the engine binds programs from (``llama.py``,
+    ``granite_hybrid.py``) and the kernels they launch
+    (``ssm_update.py`` among them) are under the lint too."""
+    paths = timer_model_paths()
+    scanned = {p.name for p in paths}
+    for required in ("llama.py", "granite_hybrid.py", "ssm_update.py",
+                     "paged_attention.py"):
+        assert required in scanned and \
+            all(p.exists() for p in paths), sorted(scanned)
+    res = run(sources=paths, checkers=[AdhocTimerChecker])
+    assert res.ok, "\n".join(f.render() for f in res.findings)
 
 
 def test_lint_covers_observability_modules():
