@@ -665,11 +665,14 @@ class DecodeEngine:
             cow_wrapped = cow_copy
         self._tp_wrap = _tp_wrap
         if self.paged:
+            # every paged program takes the pools donated and leaves
+            # them where they lie; a family with slot state hands its
+            # prefill the row's slot behind the table
             if progs.slot_state is None:
-                self._prefill = jax.jit(_tp_wrap(prefill_paged, 3))
+                self._prefill = jax.jit(
+                    _tp_wrap(prefill_paged, 3),
+                    donate_argnums=tuple(range(8, 8 + self._n_pool)))
             else:
-                # the row's slot rides behind the table, and the state
-                # arrays are updated in place like the decode program's
                 self._prefill = jax.jit(
                     prefill_paged,
                     donate_argnums=tuple(range(9, 9 + self._n_pool)))

@@ -37,7 +37,8 @@ from ..core.tensor import Tensor
 from ..kernels.paged_attention import paged_decode_attention
 from ..kernels.ssm_update import lane_pack, pack_state, ssm_decode_update
 from .llama import (PagedPrograms, _attention_keymask,
-                    _attention_prefix_span, _rms, _token_insert)
+                    _attention_prefix_span, _rms, _row_pages,
+                    _token_insert)
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridForCausalLM",
            "GRANITE_PRESETS"]
@@ -356,18 +357,6 @@ def _logits(cfg, x, embed, final_norm):
     return logits.astype(jnp.float32) / cfg.logits_scaling
 
 
-def _pages_of(kc, pad, mb, bs, pack):
-    """One row's contiguous keys (or values) [La, s, kvh, hd], window
-    column ``pad`` holding its first token, as pool pages
-    [La, mb, kvh/pack, bs, pack*hd] from context position 0."""
-    la, s, kvh, hd = kc.shape
-    kc = jnp.roll(kc, -pad, axis=1)
-    if s < mb * bs:
-        kc = jnp.pad(kc, ((0, 0), (0, mb * bs - s), (0, 0), (0, 0)))
-    kc = kc[:, :mb * bs].reshape(la, mb, bs, kvh // pack, pack * hd)
-    return jnp.swapaxes(kc, 2, 3)
-
-
 def _prefill(cfg, w, embed, final_norm, ids, pad_len, table_row, slot, pool,
              block):
     """The cold prefill of ONE right-aligned row (ids [1, s], pad_len
@@ -448,8 +437,8 @@ def _prefill(cfg, w, embed, final_norm, ids, pad_len, table_row, slot, pool,
          jnp.zeros((1, embed.shape[1]), dtype)))
     logits = _logits(cfg, last, embed, final_norm)
     mb, bs = table_row.shape[0], kp.shape[-2]
-    kp = kp.at[:, table_row].set(_pages_of(kc, pad, mb, bs, pack))
-    vp = vp.at[:, table_row].set(_pages_of(vc, pad, mb, bs, pack))
+    kp = kp.at[:, table_row].set(_row_pages(kc, pad, mb, bs, pack))
+    vp = vp.at[:, table_row].set(_row_pages(vc, pad, mb, bs, pack))
     sc = pack_state(sc, cfg.mamba_n_heads // ssm.shape[2])
     ssm = jax.lax.dynamic_update_slice(ssm, sc[:, None], (0, slot, 0, 0, 0))
     conv = jax.lax.dynamic_update_slice(conv, cc[:, None], (0, slot, 0, 0))

@@ -745,9 +745,9 @@ class LlamaForCausalLM(nn.Layer):
             """ids [1, s_max] right-aligned; the forward runs only the
             blocks of rows that hold prompt tokens (the trip count is
             data: one program for every prompt length), and the
-            prompt's K/V scatter into the block pools THROUGH table_row
-            inside the program (pad positions route to the NULL page),
-            so admission is one device call."""
+            prompt's K/V are written page by page into the donated
+            block pools THROUGH table_row inside the program, so
+            admission is one device call and moves no pool."""
             stacked, lm = _dequantize_weights(cfg, stacked, lm, scales)
             if lm is None:
                 lm = embed.T
@@ -1254,107 +1254,113 @@ def _paged_decode_step(cfg, stacked, embed, final_norm, lm_head, token,
     return logits, pool
 
 
-def _quantized_prefill_scatter(pool, scales, toks, page, off, valid,
-                               table_row, seq_axis=None):
-    """int8 half of :func:`scatter_prefill_kv` for ONE pool. toks
-    [L, sp, kvh, hd] f32; page/off/valid [sp]; scales [L, N, kvh].
-    Scale update is a SCATTER-MAX (order-independent, so the multiple
-    tokens landing on one page update its scale deterministically),
-    then every page the row references is re-expressed in its new scale
-    — pages whose max didn't move get ratio exactly 1.0, i.e. their
-    codes survive bit-identical (this is what keeps SHARED prefix pages
-    unperturbed by a tail prefill: the tail never scatter-maxes into a
-    full shared page). ``seq_axis``: page-sharded pools — GLOBAL ids
-    rebase into the local stripe, reads clamp, writes drop non-owned
-    entries (scale growth and re-expression happen on the owning shard
-    only, which holds the authoritative codes and scales)."""
-    if seq_axis is not None:
-        n_local = pool.shape[1]
-        wp, owned = seq_local_pages(page, n_local, seq_axis)
-        rp = jnp.where(owned, wp, 0)
-        wt, owned_t = seq_local_pages(table_row, n_local, seq_axis)
-        rt = jnp.where(owned_t, wt, 0)
-    else:
-        wp = rp = page
-        wt = rt = table_row
-    amax = jnp.where(valid[None, :, None],
-                     jnp.abs(toks).max(axis=-1), 0.0)    # [L, sp, kvh]
-    old_all = scales
-    if seq_axis is not None:
-        scales = scales.at[:, wp].max(amax / 127.0, mode="drop")
-    else:
-        scales = scales.at[:, page].max(amax / 127.0)
-    # re-express the row's resident codes in the grown scales
-    codes = jnp.take(pool, rt, axis=1)       # [L, mb, kvh, bs, hd]
-    old = jnp.take(old_all, rt, axis=1)                  # [L, mb, kvh]
-    new = jnp.take(scales, rt, axis=1)
-    ratio = (old / new)[..., None, None]
-    req = jnp.clip(jnp.round(codes.astype(jnp.float32) * ratio),
+def _row_pages(kc, pad, mb, bs, pack=1):
+    """One row's contiguous keys (or values) [L, s, kvh, hd], window
+    column ``pad`` holding its first token, as pool pages
+    [L, mb, kvh/pack, bs, pack*hd] from context position 0."""
+    la, s, kvh, hd = kc.shape
+    kc = jnp.roll(kc, -pad, axis=1)
+    if s < mb * bs:
+        kc = jnp.pad(kc, ((0, 0), (0, mb * bs - s), (0, 0), (0, 0)))
+    kc = kc[:, :mb * bs].reshape(la, mb, bs, kvh // pack, pack * hd)
+    return jnp.swapaxes(kc, 2, 3)
+
+
+def _write_row_pages(pool, toks, win, new, scales=None, seq_axis=None):
+    """Write ONE row's pages ``win`` [nw] into the stacked pool
+    [L, N, kvh, bs, hd], whole, at ``[layer, page]`` where the pool
+    lies (what :func:`_token_insert` does for a decode step's one page
+    a row). toks [L, nw, kvh, bs, hd] holds the launch's tokens where
+    they belong in those pages; ``new`` [nw, bs] marks them. Positions
+    it does not mark keep what the pages hold (a read-modify-write by
+    a select, as :func:`_set_page_row`); ``new=None``: the pages are a
+    cold row's from position 0 and nothing they held is kept, so they
+    are not read. Every page of ``win`` is the row's own or NULL, so
+    no other row sees the write.
+
+    Returns (pool, scales). With ``scales`` ([L, N, kvh] f32, else
+    None) the pool is int8 codes: a page's scale grows to the largest new
+    token's (``max(old, amax / 127)``; multiple tokens on one page take
+    their max first, so the result has no order), its resident codes
+    are re-expressed in the grown scale (ratio exactly 1.0, codes
+    bit-identical, wherever the max did not move) and the new tokens
+    quantized against it. ``seq_axis``: :func:`_write_page_ids`
+    (global ids rebase, reads clamp, non-owned pages drop)."""
+    rp, wp, mode = _write_page_ids(win, pool.shape[1], seq_axis)
+    if scales is None:
+        toks = toks.astype(pool.dtype)
+        if new is not None:
+            toks = jnp.where(new[:, None, :, None], toks, pool[:, rp])
+        return pool.at[:, wp].set(toks, mode=mode), None
+    toks = toks.astype(jnp.float32)
+    new = new[:, None, :]                                # [nw, 1, bs]
+    amax = jnp.where(new, jnp.abs(toks).max(axis=-1), 0.0).max(axis=-1)
+    old = scales[:, rp]                                  # [L, nw, kvh]
+    grown = jnp.maximum(old, amax / 127.0)
+    req = jnp.clip(jnp.round(pool[:, rp].astype(jnp.float32)
+                             * (old / grown)[..., None, None]),
                    -127, 127)
-    if seq_axis is not None:
-        pool = pool.at[:, wt].set(req.astype(pool.dtype), mode="drop")
-    else:
-        pool = pool.at[:, table_row].set(req.astype(pool.dtype))
-    # quantize the new tokens against their page's (post-max) scale
-    sc_tok = jnp.take(scales, rp, axis=1)                # [L, sp, kvh]
-    qt = jnp.clip(jnp.round(toks / sc_tok[..., None]), -127, 127)
-    return _write_row_tokens(pool, wp, off, qt,
-                             drop=seq_axis is not None), scales
+    qt = jnp.clip(jnp.round(toks / grown[..., None, None]), -127, 127)
+    pages = jnp.where(new[..., None], qt, req).astype(pool.dtype)
+    return (pool.at[:, wp].set(pages, mode=mode),
+            scales.at[:, wp].set(grown, mode=mode))
 
 
-def _write_row_tokens(pool, page, off, toks, drop=False):
-    """Write ONE row's tokens into the stacked pools: pool
-    [L, N, kvh, bs, hd]; page/off [sp]; toks [L, sp, kvh, hd]. The two
-    index arrays sit either side of the kv-head slice, so the indexed
-    view leads with the token axis: [sp, L, kvh, hd]. ``drop``:
-    out-of-range pages (non-owned, on a page-sharded pool) are
-    discarded instead of clamped."""
-    toks = jnp.swapaxes(toks, 0, 1).astype(pool.dtype)
-    return pool.at[:, page, :, off].set(
-        toks, mode="drop" if drop else None)
-
-
-def scatter_prefill_kv(kp, vp, ks, vs, table_row, pad, offset=0,
+def scatter_prefill_kv(kp, vp, ks, vs, table_row, pad, offset=None,
                        kv_scales=None, seq_axis=None):
-    """Insert ONE row's prefill K/V into the block pools. ks/vs
-    [L, 1, sp, kvh, hd] (right-aligned, ``pad`` left pads); table_row
-    [max_blocks] int32. Pad positions are routed to the NULL page, so
-    the scatter is shape-static. ``offset`` shifts the write positions
-    by a cached-prefix length (prefix-hit admission: the tail's first
-    real token lands at context position ``offset``, which may sit
-    mid-page inside the row's private COW copy). With
-    ``kv_scales=(kscale, vscale)`` ([L, N, kvh] f32) the pools are int8
-    codes and the return grows to (kp, vp, kscale, vscale).
-    ``seq_axis``: page-sharded pools — each shard keeps only the
-    positions whose page it owns (drop-mode writes)."""
+    """Insert ONE row's prefill K/V into the block pools, page by page
+    (:func:`_write_row_pages`). ks/vs [L, 1, sp, kvh, hd]
+    (right-aligned, ``pad`` left pads); table_row [max_blocks] int32.
+
+    ``offset=None``: a cold row. Its first token is context position 0,
+    its pages are all private and table entries past its allocation are
+    the NULL page, so the whole table's pages are written without being
+    read; what lies past the prompt's end is masked by ``lens`` and
+    overwritten by decode. ``offset`` (a traced scalar): a tail behind
+    a cached prefix of that length (prefix-hit admission, a prefill
+    chunk, a verify window), whose first token may sit mid-page inside
+    the row's private COW copy: the window of ``sp / bs + 1`` pages
+    from ``offset // bs`` on is read, the tail placed in it and written
+    back. The window is taken out of a table padded with NULL entries,
+    so its start is never clamped.
+
+    With ``kv_scales=(kscale, vscale)`` ([L, N, kvh] f32) the pools are
+    int8 codes (always read-modify-write: the scales only grow) and the
+    return grows to (kp, vp, kscale, vscale). ``seq_axis``: page-sharded
+    pools, each shard keeps only the pages it owns."""
     bs = kp.shape[-2]
     sp = ks.shape[2]
-    j = jnp.arange(sp)
-    cpos = jnp.maximum(j - pad, 0) + offset
-    valid = j >= pad
-    page = jnp.where(valid, jnp.take(table_row, cpos // bs), 0)
-    off = jnp.where(valid, cpos % bs, 0)
-    if kv_scales is not None:
-        kscale, vscale = kv_scales
-        kp, kscale = _quantized_prefill_scatter(
-            kp, kscale, ks[:, 0].astype(jnp.float32), page, off, valid,
-            table_row, seq_axis=seq_axis)
-        vp, vscale = _quantized_prefill_scatter(
-            vp, vscale, vs[:, 0].astype(jnp.float32), page, off, valid,
-            table_row, seq_axis=seq_axis)
-        return kp, vp, kscale, vscale
-    drop = seq_axis is not None
-    if drop:
-        page, _ = seq_local_pages(page, kp.shape[1], seq_axis)
-    return (_write_row_tokens(kp, page, off, ks[:, 0], drop=drop),
-            _write_row_tokens(vp, page, off, vs[:, 0], drop=drop))
+    if offset is None:
+        win, at, nw, room = table_row, 0, table_row.shape[0], 0
+    else:
+        nw = -(-sp // bs) + 1
+        at = offset % bs
+        win = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(table_row, (0, nw)), offset // bs, nw)
+        # a page of room on the right: the roll that puts the tail's
+        # first token at in-page position ``at`` wraps only padding
+        room = nw * bs - sp
+    new = None
+    if offset is not None or kv_scales is not None:
+        in_win = jnp.arange(nw * bs).reshape(nw, bs)
+        new = (in_win >= at) & (in_win < at + sp - pad)
+
+    def write(pool, toks, scales):
+        toks = jnp.pad(toks[:, 0], ((0, 0), (0, room), (0, 0), (0, 0)))
+        return _write_row_pages(pool, _row_pages(toks, pad - at, nw, bs),
+                                win, new, scales, seq_axis)
+
+    kscale, vscale = kv_scales or (None, None)
+    kp, kscale = write(kp, ks, kscale)
+    vp, vscale = write(vp, vs, vscale)
+    return (kp, vp) if kv_scales is None else (kp, vp, kscale, vscale)
 
 
 def _quantized_mixed_scatter(pool, scales, toks, page, off, valid,
                              tables, seq_axis=None):
     """int8 write half of the MIXED step for ONE layer's pool (ISSUE
-    10): the [B, T] window generalization of
-    :func:`_quantized_prefill_scatter`. pool [N, kvh, bs, hd] int8;
+    10): the [B, T] window form of the int8 half of
+    :func:`_write_row_pages`, token by token. pool [N, kvh, bs, hd] int8;
     scales [N, kvh] f32; toks [B, T, kvh, hd] f32; page/off/valid
     [B, T]; tables [B, mb]. The scale update is the same
     order-independent scatter-max, then every page any row references
@@ -1791,9 +1797,12 @@ def prefix_prefill(cfg, stacked, embed, final_norm, lm_head, ids,
     pads); ``prefix_len`` [1]: cached tokens already in the pool through
     ``table_row`` [max_blocks] (shared full pages + the row's private
     COW page). Rope positions offset by ``prefix_len``; each layer
-    gathers its prefix K/V through the table (stale positions masked
-    with exact zeros), the tail attends over prefix + causal window,
-    and the tail's K/V scatter into the pool at ``offset=prefix_len``.
+    gathers its prefix K/V through the table at ``[layer, page]`` of
+    the stacked pools (stale positions masked with exact zeros), the
+    tail attends over prefix + causal window, and the tail's K/V are
+    written into the pages from ``prefix_len // bs`` on
+    (:func:`scatter_prefill_kv`). The pools are never taken apart or
+    re-laid: donated, they stay where they lie.
     Returns (last-real-position logits [1, V], kp, vp).
 
     ``all_logits=True`` returns logits at EVERY window position
@@ -1801,10 +1810,10 @@ def prefix_prefill(cfg, stacked, embed, final_norm, lm_head, ids,
     tail is the pending token + k drafts, and the caller reads the
     argmax chain off the last k+1 positions. ``kv_scales`` ([L, N, kvh]
     f32 pair) switches the pools to int8 codes — gathers dequantize,
-    the final scatter quantizes — and appends the updated scales to the
+    the final page write quantizes — and appends the updated scales to the
     return. ``seq_axis``/``n_seq``: page-sharded pools (2-D mesh) —
     each layer gathers only this shard's STRIDED prefix columns, the
-    attention merges per-shard partials, and the tail scatter keeps
+    attention merges per-shard partials, and the tail's write keeps
     only owned pages."""
     from ..kernels.paged_attention import gather_pages, \
         gather_pages_dequant, _seq_gather_ids
@@ -1824,31 +1833,27 @@ def prefix_prefill(cfg, stacked, embed, final_norm, lm_head, ids,
         prefix_mask = jnp.arange(mb * bs)[None, :] < prefix_len[:, None]
     x = jnp.take(embed, ids, axis=0)
 
-    if kv_scales is None:
-        def layer_fn(carry, xs):
-            lp, kpl, vpl = xs
-            pk = gather_pages(kpl, gather_row).astype(x.dtype)
-            pv = gather_pages(vpl, gather_row).astype(x.dtype)
-            out, k, v = _prefix_decoder_layer(
-                cfg, lp, carry, positions, key_mask, pk, pv,
-                prefix_mask, mp_axis=mp_axis, seq_axis=seq_axis)
-            return out, (k, v)
+    def layer_fn(carry, xs):
+        lp, layer = xs
+        if kv_scales is None:
+            pk = gather_pages(kp, gather_row, layer)
+            pv = gather_pages(vp, gather_row, layer)
+        else:
+            pk = gather_pages_dequant(kp, gather_row, kv_scales[0], layer)
+            pv = gather_pages_dequant(vp, gather_row, kv_scales[1], layer)
+        out, k, v = _prefix_decoder_layer(
+            cfg, lp, carry, positions, key_mask, pk.astype(x.dtype),
+            pv.astype(x.dtype), prefix_mask, mp_axis=mp_axis,
+            seq_axis=seq_axis)
+        return out, (k, v)
 
-        x, (ks, vs) = jax.lax.scan(layer_fn, x, (stacked, kp, vp))
-    else:
-        def layer_fn(carry, xs):
-            lp, kpl, vpl, kscl, vscl = xs
-            pk = gather_pages_dequant(
-                kpl, gather_row, kscl).astype(x.dtype)
-            pv = gather_pages_dequant(
-                vpl, gather_row, vscl).astype(x.dtype)
-            out, k, v = _prefix_decoder_layer(
-                cfg, lp, carry, positions, key_mask, pk, pv,
-                prefix_mask, mp_axis=mp_axis, seq_axis=seq_axis)
-            return out, (k, v)
-
-        x, (ks, vs) = jax.lax.scan(
-            layer_fn, x, (stacked, kp, vp, *kv_scales))
+    # The pools stay out of the scan's ``xs`` (which would hand each
+    # layer a copy of its slice): a layer reads the row's prefix at
+    # ``[layer, page]`` where the donated pools lie, and the tail is
+    # written once, behind the scan.
+    x, (ks, vs) = jax.lax.scan(
+        layer_fn, x,
+        (stacked, jnp.arange(kp.shape[0], dtype=jnp.int32)))
     x = _rms(x, final_norm, cfg.rms_norm_eps)
     if all_logits:
         logits = (x @ lm_head).astype(jnp.float32)       # [1, sc, V]

@@ -604,6 +604,215 @@ class TestPagedEngine:
         assert eng.resets == 1
 
 
+def _parent_scatter(kp, vp, ks, vs, table_row, pad, offset=None,
+                    kv_scales=None, seq_axis=None):
+    """The oracle of :class:`TestPrefillPageWriter`: the writer the
+    prefill programs had before they wrote whole pages, token by token
+    along the in-page axis (window column j to ``[:, page, :, off]``,
+    pad columns to the NULL page). fp pools, one device."""
+    assert kv_scales is None and seq_axis is None
+    bs = kp.shape[-2]
+    j = jnp.arange(ks.shape[2])
+    cpos = jnp.maximum(j - pad, 0) + (0 if offset is None else offset)
+    page = jnp.where(j >= pad, jnp.take(table_row, cpos // bs), 0)
+    off = jnp.where(j >= pad, cpos % bs, 0)
+    return tuple(pool.at[:, page, :, off].set(
+        jnp.swapaxes(toks[:, 0], 0, 1).astype(pool.dtype))
+        for pool, toks in ((kp, ks), (vp, vs)))
+
+
+@jax.jit
+def _parent_scatter_int8(pool, scales, toks, table_row, pad, offset):
+    """The same for ONE int8 pool: scatter-max of the page scales, the
+    row's resident codes re-expressed in the grown scales, the new
+    tokens quantized against them and written token by token. pool
+    [L, N, kvh, bs, hd] int8; scales [L, N, kvh]; toks [L, sp, kvh, hd]
+    float32."""
+    bs = pool.shape[-2]
+    j = jnp.arange(toks.shape[1])
+    cpos = jnp.maximum(j - pad, 0) + offset
+    page = jnp.where(j >= pad, jnp.take(table_row, cpos // bs), 0)
+    off = jnp.where(j >= pad, cpos % bs, 0)
+    amax = jnp.where((j >= pad)[None, :, None],
+                     jnp.abs(toks).max(axis=-1), 0.0)
+    new = scales.at[:, page].max(amax / 127.0)
+    ratio = (scales[:, table_row] / new[:, table_row])[..., None, None]
+    pool = pool.at[:, table_row].set(jnp.clip(jnp.round(
+        pool[:, table_row].astype(jnp.float32) * ratio),
+        -127, 127).astype(pool.dtype))
+    qt = jnp.clip(jnp.round(toks / new[:, page][..., None]), -127, 127)
+    return pool.at[:, page, :, off].set(
+        jnp.swapaxes(qt, 0, 1).astype(pool.dtype)), new
+
+
+# (cached prefix, tail tokens) of a row of s_max 64 on pages of 8: a
+# cold row; a hit whose prefix ends (i) on a page boundary, (ii)
+# mid-page, inside the row's copy-on-write page, (iii) such that the
+# tail ends in the table's last page (the window of pages reaches past
+# the table's end, into its NULL padding)
+_WRITER_ROWS = {"cold": (0, 45), "hit_on_page_edge": (16, 11),
+                "hit_mid_page": (13, 16), "hit_into_last_page": (43, 21)}
+
+
+class TestPrefillPageWriter:
+    """Both prefill programs write a row's keys and values as whole
+    pages at ``[layer, page]`` of the donated pools. What lands in the
+    pools is what the token-by-token scatter they had before put there
+    (:func:`_parent_scatter`), and nothing else is touched."""
+
+    S_MAX, BS, N_PAGES = 64, 8, 24
+    SHARED, OTHERS = [3, 7], [5, 11, 19]
+
+    def _table(self, cached, n):
+        """Shared full prefix pages, then the row's own, then NULL."""
+        own = [p for p in range(1, self.N_PAGES)
+               if p not in self.SHARED + self.OTHERS][::-1]
+        n_shared = cached // self.BS if cached else 0
+        pages = self.SHARED[:n_shared] \
+            + own[:-(-(cached + n) // self.BS) - n_shared]
+        row = np.zeros((self.S_MAX // self.BS + 1,), np.int32)
+        row[:len(pages)] = pages
+        return row, pages
+
+    def _run(self, monkeypatch, oracle, cached, n):
+        """One admission through the engine's own compiled program, on
+        pools filled with noise; returns (pools before, pools after)."""
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models import llama
+        if oracle:
+            monkeypatch.setattr(llama, "scatter_prefill_kv",
+                                _parent_scatter)
+        eng = DecodeEngine(shared_model("qwen2-debug"), capacity=2,
+                           s_max=self.S_MAX, chunk=4, block_size=self.BS,
+                           n_blocks=self.N_PAGES)
+        rng = np.random.RandomState(7)
+        before = tuple(rng.randn(*a.shape).astype(np.float32)
+                       for a in eng._pool())
+        seq = rng.randint(1, 128, (cached + n,)).astype(np.int32)
+        row, _ = self._table(cached, n)
+        st, embed, fnorm, lm = eng._weights()
+        pool = tuple(jnp.asarray(a, eng._kp.dtype) for a in before)
+        if cached == 0:
+            ids = np.zeros((1, self.S_MAX), np.int32)
+            ids[0, self.S_MAX - n:] = seq
+            _, *after = eng._prefill(
+                st, embed, fnorm, lm, eng._scales, jnp.asarray(ids),
+                jnp.asarray([self.S_MAX - n], jnp.int32),
+                jnp.asarray(row), *pool)
+        else:
+            sc = eng._bucket_window(n)
+            ids = np.zeros((1, sc), np.int32)
+            ids[0, sc - n:] = seq[cached:]
+            _, *after = eng._prefix_prefill_for(sc)(
+                st, embed, fnorm, lm, eng._scales, jnp.asarray(ids),
+                jnp.asarray([sc - n], jnp.int32),
+                jnp.asarray([cached], jnp.int32), jnp.asarray(row),
+                *pool)
+        return before, tuple(np.asarray(a, np.float32) for a in after)
+
+    @pytest.mark.parametrize("row", list(_WRITER_ROWS))
+    def test_programs_leave_what_the_token_scatter_left(self, row,
+                                                        monkeypatch):
+        cached, n = _WRITER_ROWS[row]
+        before, new = self._run(monkeypatch, False, cached, n)
+        _, old = self._run(monkeypatch, True, cached, n)
+        table, pages = self._table(cached, n)
+        if row == "hit_into_last_page":
+            assert cached + n > (len(table) - 2) * self.BS
+        untouched = [p for p in range(1, self.N_PAGES)
+                     if p not in pages[cached // self.BS:]]
+        assert set(self.SHARED[:cached // self.BS] + self.OTHERS) \
+            <= set(untouched)
+        for b, a, o in zip(before, new, old):
+            # pages of other rows and shared prefix pages: bit for bit
+            np.testing.assert_array_equal(a[:, untouched], b[:, untouched])
+            if cached:
+                # a tail is a read-modify-write: every page the table
+                # names (every page but NULL, even) is the oracle's
+                np.testing.assert_array_equal(a[:, 1:], o[:, 1:])
+                continue
+            # a cold row's pages are written whole and not read: every
+            # position ``lens`` lets anyone read is the oracle's; what
+            # lies past the prompt in its last page is masked
+            a, o = (np.swapaxes(x[:, pages], 2, 3).reshape(
+                x.shape[0], -1, *x.shape[2::2])[:, :n] for x in (a, o))
+            np.testing.assert_array_equal(a, o)
+
+    @pytest.mark.parametrize("row", list(_WRITER_ROWS))
+    def test_int8_writer_keeps_the_token_scatters_numbers(self, row):
+        """The int8 half: the same scales and the same codes as the
+        token-by-token scatter, on every page but NULL (a cold row's
+        pages are read-modify-written too there: scales only grow)."""
+        from paddle_tpu.models.llama import scatter_prefill_kv
+        cached, n = _WRITER_ROWS[row]
+        sp = self.S_MAX if cached == 0 else 32
+        rng = np.random.RandomState(3)
+        shape = (2, self.N_PAGES, 2, self.BS, 16)
+        pools = [rng.randint(-127, 128, shape).astype(np.int8)
+                 for _ in range(2)]
+        scales = [rng.uniform(0.001, 0.02, shape[:3]).astype(np.float32)
+                  for _ in range(2)]
+        toks = [rng.randn(2, 1, sp, 2, 16).astype(np.float32)
+                for _ in range(2)]
+        table, _ = self._table(cached, n)
+        out = jax.jit(scatter_prefill_kv)(
+            *map(jnp.asarray, pools + toks), jnp.asarray(table),
+            jnp.int32(sp - n), jnp.int32(cached) if cached else None,
+            kv_scales=tuple(map(jnp.asarray, scales)))
+        for i in range(2):
+            codes, grown = _parent_scatter_int8(
+                pools[i], scales[i], toks[i][:, 0], jnp.asarray(table),
+                sp - n, cached)
+            np.testing.assert_array_equal(np.asarray(out[i])[:, 1:],
+                                          np.asarray(codes)[:, 1:])
+            np.testing.assert_array_equal(np.asarray(out[2 + i])[:, 1:],
+                                          np.asarray(grown)[:, 1:])
+
+    @pytest.mark.parametrize("options", [
+        dict(), dict(chunked_prefill=True), dict(spec_decode=True),
+        dict(chunked_prefill=True, spec_decode=True)],
+        ids=lambda o: "+".join(o) or "prefix_cache_and_cow")
+    def test_engine_emits_the_token_scatters_tokens(self, options,
+                                                    monkeypatch):
+        """Prefix cache, copy-on-write, chunked prefill and speculative
+        decoding over the page writer: the tokens of an engine whose
+        programs still scatter token by token."""
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models import llama
+        m = shared_model("qwen2-debug")
+        rng = np.random.RandomState(11)
+        doc = rng.randint(1, 128, (21,)).astype(np.int32)
+        prompts = [np.concatenate([doc[:k], t]) for k, t in zip(
+            (21, 21, 16, 13, 0, 21),
+            make_prompts(rng, 128, (4, 9, 11, 16, 30, 3)))]
+        # repeats draft well: the verify windows accept and reject
+        prompts.append(
+            np.tile(rng.randint(1, 128, (5,)), 6).astype(np.int32))
+
+        def run():
+            eng = DecodeEngine(m, capacity=2, s_max=64, chunk=4,
+                               block_size=8, **options)
+            outs = []
+            for p in prompts:       # one by one: each retires into the cache
+                r = eng.submit(p, max_new_tokens=10)
+                drive(eng)
+                outs.append(np.asarray(r.wait(timeout=60)))
+            return outs, eng.stats()
+
+        new, stats = run()
+        monkeypatch.setattr(llama, "scatter_prefill_kv", _parent_scatter)
+        old, _ = run()
+        for a, b in zip(new, old):
+            np.testing.assert_array_equal(a, b)
+        # hits that end on a page edge and mid-page (copy-on-write)
+        assert stats["prefix_cache"]["hits"] >= 4
+        assert stats["prefix_hit_tokens"] % 8
+        if "chunked_prefill" in options:
+            assert stats["prefill_chunks"] > len(prompts)
+        if "spec_decode" in options:
+            assert 0 < stats["spec"]["accepted"] < stats["spec"]["proposed"]
+
+
 class TestContiguousClampedFinalChunk:
     """ADVICE r5 #3 (contiguous mode): at cache exhaustion, rows whose
     remaining max_new fits the leftover fill ride ONE clamped chunk out;
