@@ -19,7 +19,7 @@ from ... import nn
 from .mp_layers import shard_hint
 
 __all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate",
-           "moe_dispatch_combine"]
+           "moe_dispatch_combine", "moe_route_held"]
 
 
 class NaiveGate(nn.Layer):
@@ -145,33 +145,88 @@ def moe_route_dropless(logits, num_experts, top_k):
     top-k, order [N*k] expert-sorted permutation, group_sizes [E], aux).
     The reference's capacity semantics exist for fixed-size all-to-all
     buffers; on TPU lax.ragged_dot keeps shapes static with ragged
-    per-expert groups instead (MegaBlocks-style dropless)."""
+    per-expert groups instead (MegaBlocks-style dropless). The route is
+    :func:`moe_route_held` holding every expert; the GShard aux loss is
+    added here."""
+    topi, gates, order, group_sizes = moe_route_held(logits, top_k)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, top_k)
-    gates = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-    flat_e = topi.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)         # expert-major stream
-    group_sizes = jnp.bincount(flat_e, length=num_experts).astype(jnp.int32)
     me = probs.mean(axis=0)
     ce = jax.nn.one_hot(topi, num_experts, dtype=jnp.float32).sum(1).mean(0)
     aux = (me * ce).sum() * num_experts
     return topi, gates, order, group_sizes, aux
 
 
+def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
+                   rows=None):
+    """Dropless routing for a chip that holds a SHARE of the experts
+    (expert parallelism without its exchange): every token is routed
+    over all ``E`` experts the router has, and the (token, choice) pairs
+    that fall on the ``held = (first, count)`` experts come out
+    expert-sorted at the head of the stream; the pairs of experts that
+    live on other chips sort behind them, carry weight 0 and belong to
+    no group, so :func:`moe_dropless_ffn`'s grouped products run over
+    the held pairs only. ``held=None`` holds every expert.
+
+    ``scoring``: ``softmax`` or ``sigmoid`` scores over the E logits
+    (float32). ``bias`` [E]: a selection bias (DeepSeek-V3's
+    ``e_score_correction_bias``): the top-k is taken of ``scores +
+    bias``, the weights are the scores themselves, divided by their sum
+    over the chosen k (all k, wherever they live). ``rows`` [N] bool:
+    tokens that are none (padding, idle slots); their pairs sort behind
+    too.
+
+    Returns (topi [N, k] expert ids, gates [N, k] f32, 0 off the held
+    share, order [N*k] the stream's permutation, group_sizes [count]
+    int32). ``group_sizes.sum()`` pairs are computed here;
+    ``(group_sizes > 0).sum()`` experts are visited."""
+    x = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(x, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(x)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r}: softmax | sigmoid")
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, topi = jax.lax.top_k(choice, top_k)
+    gates = jnp.take_along_axis(scores, topi, axis=-1)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    first, count = held if held is not None else (0, logits.shape[-1])
+    local = topi - first
+    mine = (local >= 0) & (local < count)
+    if rows is not None:
+        mine = mine & rows[:, None]
+    key = jnp.where(mine, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+        jnp.int32)
+    return topi, jnp.where(mine, gates, 0.0), order, group_sizes
+
+
 def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
-                     we_gate, we_up, we_down):
+                     we_gate, we_up, we_down, precision=None):
     """SwiGLU expert FFN over the expert-sorted ragged stream: three
     lax.ragged_dot grouped GEMMs, then unsort + gate-combine. tokens
-    [N, d]; we_* [E, d, f]/[E, f, d]; returns [N, d]."""
+    [N, d]; we_* [E, d, f]/[E, f, d]; returns [N, d]. Rows of the stream
+    past the groups' total (:func:`moe_route_held`: pairs of experts
+    this chip does not hold) belong to no expert; whatever the grouped
+    product leaves there is dropped. ``precision``: the grouped
+    products' (None: the process's default, which at ``"high"`` makes
+    the chip's compiler spell a grouped product out as a dense one over
+    every group; ``Precision.DEFAULT`` keeps the grouped kernel, which
+    reads the experts that have rows)."""
     n, d = tokens.shape
     k = topi.shape[1]
     stream = jnp.repeat(tokens, k, axis=0) if k > 1 else tokens
     stream = jnp.take(stream, order, axis=0)              # [N*k, d]
     dt = we_gate.dtype
     gate = jax.nn.silu(jax.lax.ragged_dot(stream.astype(dt), we_gate,
-                                          group_sizes))
-    up = jax.lax.ragged_dot(stream.astype(dt), we_up, group_sizes)
-    out_sorted = jax.lax.ragged_dot(gate * up, we_down, group_sizes)
+                                          group_sizes, precision=precision))
+    up = jax.lax.ragged_dot(stream.astype(dt), we_up, group_sizes,
+                            precision=precision)
+    out_sorted = jax.lax.ragged_dot(gate * up, we_down, group_sizes,
+                                    precision=precision)
+    grouped = jnp.arange(out_sorted.shape[0]) < group_sizes.sum()
+    out_sorted = jnp.where(grouped[:, None], out_sorted, 0)
     unsorted = jnp.zeros_like(out_sorted).at[order].set(out_sorted)
     picked = unsorted.reshape(n, k, d)
     return jnp.sum(picked * gates[..., None].astype(picked.dtype), axis=1)

@@ -16,6 +16,7 @@ TPU-native pieces:
 
 from __future__ import annotations
 
+import collections
 import logging
 import queue
 import threading
@@ -291,6 +292,8 @@ class DecodeEngine:
             self.compiles = CompileTracker(registry=self.metrics,
                                            recorder=recorder,
                                            worker_id=self.worker_id)
+        # profile mode only: what each paged launch was handed (stats())
+        self._launches = collections.deque(maxlen=8192) if profile else None
         self._build()
         self._reset()
 
@@ -396,6 +399,10 @@ class DecodeEngine:
         self._c_ssm_prefill_chunks = r.counter(
             "engine_ssm_prefill_chunks_total",
             "chunks of the recurrence run by cold prefills")
+        self._c_decode_ctx = r.counter(
+            "engine_decode_ctx_tokens_total",
+            "context lengths of live rows summed over the steps of "
+            "paged decode chunks (what the paged reads walk)")
         r.gauge("engine_state_slots_in_use",
                 "slots that hold a live row's recurrent state",
                 fn=lambda: len(self._state_slots))
@@ -608,6 +615,10 @@ class DecodeEngine:
         self._state_specs = tuple(progs.slot_state(self.capacity)) \
             if progs.slot_state is not None else ()
         self._n_pool = (4 if self._kv_q else 2) + len(self._state_specs)
+        # the counters' vector (the last state array) goes in and comes
+        # out like the rest but is NOT donated: the one a launch returned
+        # stays readable from any thread while the next launch runs
+        n_donated = self._n_pool - bool(progs.device_counters)
         if self.paged and self.mesh is not None:
             # ISSUE 10: lower every paged program through shard_map
             # over the kv-head axis. Weights shard Megatron column/row,
@@ -675,10 +686,10 @@ class DecodeEngine:
             else:
                 self._prefill = jax.jit(
                     prefill_paged,
-                    donate_argnums=tuple(range(9, 9 + self._n_pool)))
+                    donate_argnums=tuple(range(9, 9 + n_donated)))
             self._decode = jax.jit(
                 _tp_wrap(decode_chunk_paged, 3),
-                donate_argnums=tuple(range(8, 8 + self._n_pool)))
+                donate_argnums=tuple(range(8, 8 + n_donated)))
             self._cow = jax.jit(
                 cow_wrapped,
                 donate_argnums=tuple(range(2, 2 + self._n_pool)))
@@ -707,7 +718,16 @@ class DecodeEngine:
         self._cfg = cfg
         self._kvh = progs.kv_heads
         self._hd = progs.head_dim
+        self._hdv = progs.v_head_dim or progs.head_dim
         self._L = progs.kv_layers
+        # what the family's programs count on the device: the last
+        # state array, read by stats() alone
+        self._c_device = tuple(
+            self.metrics.counter(
+                f"engine_{name}_total",
+                f"{name}, counted on the device by the paged programs")
+            for name in progs.device_counters)
+        self._counts_lock = threading.Lock()
         self._cache_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" \
             else jnp.float32
 
@@ -800,8 +820,14 @@ class DecodeEngine:
         B = self.capacity
         # what a slot holds beside its pages (a recurrent model's
         # states): zero until a prefill leaves a row's there
-        self._state = tuple(jnp.zeros(sp.shape, sp.dtype)
-                            for sp in self._state_specs)
+        self._sync_device_counters()
+        if self._launches:
+            self._launches.clear()      # its counts restart with the state
+        with self._counts_lock:
+            self._state = tuple(jnp.zeros(sp.shape, sp.dtype)
+                                for sp in self._state_specs)
+            self._device_counts_seen = _np.zeros(len(self._c_device),
+                                                 _np.int64)
         self._state_slots = set()
         if self.paged:
             from .paged_cache import BlockAllocator
@@ -810,7 +836,8 @@ class DecodeEngine:
             self._kp = jnp.zeros((self._L, self.n_blocks, self._kvh,
                                   self.block_size, self._hd),
                                  pool_dtype)
-            self._vp = jnp.zeros_like(self._kp)
+            self._vp = jnp.zeros(self._kp.shape[:-1] + (self._hdv,),
+                                 pool_dtype)
             if self._kv_q:
                 from ..kernels.paged_attention import KV_SCALE_EPS
                 self._kscale = jnp.full(
@@ -869,6 +896,49 @@ class DecodeEngine:
         else:
             self._kp, self._vp, *state = vals
             self._state = tuple(state)
+
+    def _sync_device_counters(self):
+        """Bring the counters the paged programs keep on the device
+        (``PagedPrograms.device_counters``) into the registry: one small
+        fetch, made by ``stats()`` and before a reset, never by a step.
+        The vector is never donated, so the one the last launch returned
+        can be read from any thread (the read waits for that launch).
+        The device's int32 may wrap; the difference since the last fetch
+        does not."""
+        if not self._c_device or not getattr(self, "_state", ()):
+            return                      # none kept, or the first reset
+        import numpy as _np
+        with self._counts_lock:
+            now = _np.asarray(self._state[-1]).astype(_np.int64)
+            for c, new, old in zip(self._c_device, now,
+                                   self._device_counts_seen):
+                c.inc(int((new - old) % (1 << 32)))
+            self._device_counts_seen = now
+
+    def _note_launch(self, t, kind, units, rows, tokens):
+        """Profile mode: one entry a launch of the two paged programs,
+        so that a reader of a device trace can count the work of the
+        launches it traced and not of the run's mean: when it was
+        dispatched (``observability.now``), ``"prefill"`` (``units``
+        blocks of ``tokens`` prompt tokens) or ``"decode"`` (``units``
+        steps of ``rows`` live rows that read ``tokens`` cached tokens
+        in all), and the device counters' vector as that launch
+        returned it, kept by reference: nothing is fetched here."""
+        if self._launches is not None:
+            self._launches.append(
+                (t, kind, units, rows, tokens,
+                 self._state[-1] if self._c_device else None))
+
+    def _launch_entries(self):
+        """``[t, kind, units, rows, tokens, *device counters]`` a
+        launch, oldest first; the counters run on from launch to launch
+        (int32, may wrap)."""
+        import jax
+        log = list(self._launches.copy())
+        counts = jax.device_get([e[5] for e in log]) if self._c_device \
+            else [()] * len(log)
+        return [[*e[:5], *(int(v) for v in c)]
+                for e, c in zip(log, counts)]
 
     def _drain_scale_resets(self):
         """int8 only: reset the scales of pages the allocator handed
@@ -946,10 +1016,17 @@ class DecodeEngine:
              "prefill_window_blocks":
                  int(self._c_prefill_window_blocks.value),
              "decode_row_steps": int(self._c_decode_row_steps.value),
+             "decode_ctx_tokens": int(self._c_decode_ctx.value),
              "resets": self.resets}
-        if self._state_specs:
+        self._sync_device_counters()
+        for name, c in zip(self._progs.device_counters, self._c_device):
+            s[name] = int(c.value)
+        if self._launches is not None:
+            s["launches"] = self._launch_entries()
+        if self._progs.chunks_per_block:    # a recurrent family's own
             s["ssm_row_steps"] = int(self._c_ssm_row_steps.value)
             s["ssm_prefill_chunks"] = int(self._c_ssm_prefill_chunks.value)
+        if self._state_specs:
             s["state_slots_in_use"] = len(self._state_slots)
         if self.mesh is not None:
             s["mesh_shape"] = {k: int(v)
@@ -1438,10 +1515,13 @@ class DecodeEngine:
             where = (jnp.asarray(table_row),)
             if self._state_specs:
                 where += (jnp.asarray(slot, jnp.int32),)
+            t0 = _now()
             first, *pool = self._prefill(
                 st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
                 jnp.asarray([pad], jnp.int32), *where, *self._pool())
             self._set_pool(pool)
+            self._note_launch(t0, "prefill",
+                              -(-ns // self._prefill_block), 1, ns)
             if self._state_specs:
                 # the program started the row's states from zero and
                 # left them in ``slot``: whatever the slot's last tenant
@@ -1870,7 +1950,12 @@ class DecodeEngine:
         n_busy = sum(r is not None for r in self._rows)
         self._g_occupancy.set(n_busy)
         self._c_decode_row_steps.inc(self.chunk * n_busy)
-        if self._state_specs:
+        # a live row's context grows by one a step of the chunk
+        ctx_tokens = self.chunk * int(self._lens.sum()) \
+            + n_busy * self.chunk * (self.chunk - 1) // 2
+        self._c_decode_ctx.inc(ctx_tokens)
+        self._note_launch(t0, "decode", self.chunk, n_busy, ctx_tokens)
+        if self._progs.chunks_per_block:
             self._c_ssm_row_steps.inc(self.chunk * n_busy)
         log_event("engine_chunk", steps=self.chunk, rows=n_busy,
                   fill=int(self._lens.max()), wall_s=round(wall, 4),

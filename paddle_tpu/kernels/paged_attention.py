@@ -104,6 +104,10 @@ KV_SCALE_EPS = 1e-8
 
 _NEG_INF = -1e30
 
+#: what a trace calls the decode kernel: ``paged_decode_qk<width of a
+#: key head>`` (``name=`` of the launch)
+KERNEL_NAME = "paged_decode"
+
 
 # ---------------------------------------------------------------------------
 # Pallas kernel
@@ -201,6 +205,7 @@ def _paged_kernel(tables, lens, layer, q_ref, k_hbm, v_hbm, o_ref, k_s,
     b = pl.program_id(0)
     ppb = k_s.shape[1]
     g, hd = q_ref.shape[2:]
+    hdv = v_s.shape[-1]         # V's heads may be narrower than K's
     t = ppb * bs
     n = lens[b]
     # q and K meet in the dtype they share (bfloat16 in an engine) and V
@@ -219,7 +224,7 @@ def _paged_kernel(tables, lens, layer, q_ref, k_hbm, v_hbm, o_ref, k_s,
     def fold(j, slot, h, n_pages, state):
         m, l, acc = state
         k = k_s[slot, :, h].reshape(t, hd)
-        v = v_s[slot, :, h].reshape(t, hd)
+        v = v_s[slot, :, h].reshape(t, hdv)
         s = jax.lax.dot_general(
             q_ref[0, h].astype(dt), k.astype(dt),
             (((1,), (1,)), ((), ())),
@@ -312,27 +317,34 @@ def _paged_kernel_int8(tables, lens, layer, q_ref, ks_ref, vs_ref, k_hbm,
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
-                           layer, interpret=False, kv_scales=None):
+                           layer, interpret=False, kv_scales=None,
+                           name=None):
     """Raw Pallas launch against layer ``layer`` of the STACKED pools.
-    q [B, kvh, G, hd]; k/v_pages [L, N, kvh, bs, hd]; block_table
-    [B, max_blocks] int32; seq_lens [B] int32; layer an int32 scalar
+    q [B, kvh, G, hd]; k_pages [L, N, kvh, bs, hd]; v_pages
+    [L, N, kvh, bs, hdv], whose heads may have another width than K's
+    (scores over ``hd``, the accumulator and the output ``hdv`` wide);
+    block_table [B, max_blocks] int32; seq_lens [B] int32; layer an int32 scalar
     (data: the decode step's layer scan hands it its counter). The pools
     stay in HBM where they lie (``pl.ANY``) and the layer rides the
     scalar-prefetch lane beside the table, so a page is read at
     ``[layer, page]`` and nothing of a pool's size is sliced, moved
     or re-laid for the launch. One program a row, its pages in blocks
-    of :func:`_pages_per_block`. Returns [B, kvh, G, hd] f32; a row
+    of :func:`_pages_per_block`. Returns [B, kvh, G, hdv] f32; a row
     whose table starts with the NULL page comes out zeros.
     ``kv_scales=(kscale, vscale)`` ([L, N, kvh] f32 each) switches to the
     int8 kernel: the pools hold int8 codes, dequantized inside the
-    program."""
+    program. ``name``: what a trace calls the launch (default
+    ``paged_decode_qk<hd>``; a model that pads its key heads to a lane
+    tile names the width it has)."""
     B, kvh, G, hd = q.shape
     bs = k_pages.shape[3]
+    hdv = v_pages.shape[-1]
     block_table = jnp.asarray(block_table, jnp.int32)
     mb = block_table.shape[1]
-    ppb = _pages_per_block(kvh, bs, hd, k_pages.dtype, mb)
+    ppb = _pages_per_block(kvh, bs, max(hd, hdv), k_pages.dtype, mb)
     scale = 1.0 / (hd ** 0.5)
     q_spec = pl.BlockSpec((1, kvh, G, hd), lambda b, *_: (b, 0, 0, 0))
+    o_spec = pl.BlockSpec((1, kvh, G, hdv), lambda b, *_: (b, 0, 0, 0))
     if kv_scales is None:
         kernel, scales, sc_specs = _paged_kernel, (), []
     else:
@@ -353,18 +365,18 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
         in_specs=[q_spec, *sc_specs,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=q_spec,
+        out_specs=o_spec,
         scratch_shapes=[
             pltpu.VMEM((2, ppb, kvh, bs, hd), k_pages.dtype),
-            pltpu.VMEM((2, ppb, kvh, bs, hd), v_pages.dtype),
+            pltpu.VMEM((2, ppb, kvh, bs, hdv), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),          # [K/V, slot]
         ],
     )
     return pl.pallas_call(
         functools.partial(kernel, bs=bs, scale=scale),
         grid_spec=grid_spec,
-        out_shape=_out_struct((B, kvh, G, hd), q, k_pages, v_pages),
-        interpret=interpret,
+        out_shape=_out_struct((B, kvh, G, hdv), q, k_pages, v_pages),
+        interpret=interpret, name=name or f"{KERNEL_NAME}_qk{hd}",
     )(block_table, jnp.asarray(seq_lens, jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q, *scales, k_pages,
       v_pages)
@@ -493,19 +505,27 @@ def _paged_attn_reference_int8(q, k_pages, v_pages, block_table,
     return jnp.stack(rows)
 
 
-def _kernel_serves(pages):
+def _kernel_serves(pages, v_pages=None):
     """The ONE place the paged entries choose between the Pallas kernels
     and the XLA references: the kernels on the TPU backend when a
     (page, kv head) slab ``[bs, hd]`` is tile-aligned for the pool's
-    dtype, the references everywhere else (the CPU tests' oracle)."""
+    dtype, the references everywhere else (the CPU tests' oracle). The
+    decode kernel takes float pools whose V heads have another width
+    than K's (``v_pages``), each a whole number of lane tiles (the chip
+    lays a head of 192 out 256 lanes wide in any case, and a page cut
+    at 192 is refused: a model with such heads pads K itself)."""
     bs, hd = pages.shape[-2:]
     min_bs = 32 if pages.dtype == jnp.int8 else 8
+    hdv = hd if v_pages is None else v_pages.shape[-1]
+    if hdv != hd and (pages.dtype == jnp.int8 or hdv % 128):
+        return False
     return (jax.default_backend() == "tpu" and hd % 128 == 0
             and bs % min_bs == 0)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
-                           layer, kv_scales=None, seq_axis=None, n_seq=1):
+                           layer, kv_scales=None, seq_axis=None, n_seq=1,
+                           name=None):
     """Entry used by the llama paged decode step, against layer
     ``layer`` (an int32 scalar, data) of the STACKED pools
     [L, N, kvh, bs, hd] (scales [L, N, kvh]): the Pallas kernel on
@@ -523,10 +543,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
         return _paged_decode_attention_seq(
             q, k_pages, v_pages, block_table, seq_lens, layer,
             seq_axis, n_seq, kv_scales=kv_scales)
-    if _kernel_serves(k_pages):
+    if _kernel_serves(k_pages, v_pages):
         return paged_attention_pallas(q, k_pages, v_pages, block_table,
                                       seq_lens, layer,
-                                      kv_scales=kv_scales)
+                                      kv_scales=kv_scales, name=name)
     if kv_scales is not None:
         return _paged_attn_reference_int8(
             q, k_pages, v_pages, block_table, seq_lens, layer,

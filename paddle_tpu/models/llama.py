@@ -200,7 +200,14 @@ class PagedPrograms:
     engine's ``slots``. A family with such state is handed the ``slot``
     of the row it prefills, its prefill takes the pool donated, and the
     engine refuses at construction every option in ``unsupported``
-    (option -> why)."""
+    (option -> why). ``v_head_dim``: the width of a value head in the
+    pool where it is not a key head's (0: ``head_dim``).
+    ``device_counters`` names the entries of the LAST array of
+    ``slot_state``, an int32 vector the two programs add to on the
+    device (what only the device knows: which experts a step's rows
+    chose); the engine hands it over like the rest but does not donate
+    it, fetches it in ``stats()`` alone and keeps
+    ``engine_<name>_total``."""
     prefill_paged: object
     decode_chunk_paged: object
     kv_layers: int
@@ -209,6 +216,8 @@ class PagedPrograms:
     slot_state: object = None
     chunks_per_block: int = 0
     unsupported: dict = field(default_factory=dict)
+    v_head_dim: int = 0
+    device_counters: tuple = ()
 
 
 def kv_scales_of(pool):

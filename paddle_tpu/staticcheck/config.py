@@ -94,7 +94,8 @@ def timer_model_paths() -> list[pathlib.Path]:
     they compute rides inside the engine's spans, so a wall clock of
     their own would fork the one the traces share."""
     return [PKG / "models" / "llama.py",
-            PKG / "models" / "granite_hybrid.py"] + _glob(PKG / "kernels")
+            PKG / "models" / "granite_hybrid.py",
+            PKG / "models" / "mimo_v2.py"] + _glob(PKG / "kernels")
 
 
 def timer_shared_clock_paths() -> list[pathlib.Path]:
@@ -115,7 +116,8 @@ def scan_paths() -> list[pathlib.Path]:
         + _glob(PKG / "observability")
         + [WATCHDOG]
         + [PKG / "models" / "llama.py",
-           PKG / "models" / "granite_hybrid.py"]
+           PKG / "models" / "granite_hybrid.py",
+           PKG / "models" / "mimo_v2.py"]
         + _glob(PKG / "kernels")
         + [REPO_ROOT / "bench.py"]
     )

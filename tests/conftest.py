@@ -58,3 +58,29 @@ def _seed_all():
     paddle.seed(2024)
     np.random.seed(2024)  # staticcheck: disable=SC04 — the fixture that seeds replay
     yield
+
+
+#: Memory mappings the process may hold before the programs it compiled
+#: are let go. Every compiled program keeps three or four (its code, its
+#: constants), the engines and models that tests share keep theirs alive,
+#: and the kernel refuses a process its 65530th (``vm.max_map_count``):
+#: the next compile then dies in the middle of a test with a
+#: segmentation fault, nine tenths of the way through the suite.
+MAPS_LIMIT = 40000
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _bounded_memory_maps():
+    """After a module, let the compiled programs go if the process holds
+    too many mappings; the session's persistent cache reads back what a
+    later test asks for again."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:
+        return
+    if held > MAPS_LIMIT:
+        import gc
+        jax.clear_caches()
+        gc.collect()

@@ -57,6 +57,17 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
+def _shapes_only():
+    """While a model is built whose weights are handed to the compiler
+    as shapes, its leaves are zeros: drawing 100 M normal values a case
+    was most of what the engine cases took."""
+    from paddle_tpu.nn import initializer as I
+    from paddle_tpu.core.dtype import convert_dtype
+    zeros = lambda self, shape, dtype=None: jnp.zeros(
+        tuple(shape), convert_dtype(dtype or "float32"))
+    return mock.patch.object(I.Normal, "__call__", zeros)
+
+
 # -- the programs ------------------------------------------------------------
 # Each builder takes ``place(shape, dtype, spec=P())`` — a ShapeDtypeStruct
 # on the described device(s) — and returns (function, arguments).
@@ -139,11 +150,12 @@ def _engine_decode(kv_dtype, block, tp=1, layers=2, kv_heads=4,
         from paddle_tpu.inference.serving import DecodeEngine
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         paddle.seed(0)
-        model = LlamaForCausalLM(LlamaConfig(
-            vocab_size=1024, hidden_size=8 * HD, intermediate_size=1024,
-            num_hidden_layers=layers, num_attention_heads=8,
-            num_key_value_heads=kv_heads, attention_bias=True,
-            dtype="bfloat16"))
+        with _shapes_only():
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=1024, hidden_size=8 * HD, intermediate_size=1024,
+                num_hidden_layers=layers, num_attention_heads=8,
+                num_key_value_heads=kv_heads, attention_bias=True,
+                dtype="bfloat16"))
         model.eval()
         # a described chip holds nothing: where the tp engine places its
         # weights and pools on the mesh, keep the host arrays (only their
@@ -195,11 +207,12 @@ def _engine_prefill(s_max=2560, n_pages=4577, layers=14, batch=32,
         from paddle_tpu.inference.serving import DecodeEngine
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         paddle.seed(0)
-        model = LlamaForCausalLM(LlamaConfig(
-            vocab_size=1024, hidden_size=d, intermediate_size=ff,
-            num_hidden_layers=1, num_attention_heads=28,
-            num_key_value_heads=4, attention_bias=True, rope_theta=1e6,
-            rms_norm_eps=1e-6, dtype="bfloat16"))
+        with _shapes_only():
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=1024, hidden_size=d, intermediate_size=ff,
+                num_hidden_layers=1, num_attention_heads=28,
+                num_key_value_heads=4, attention_bias=True, rope_theta=1e6,
+                rms_norm_eps=1e-6, dtype="bfloat16"))
         model.eval()
         model.config.num_hidden_layers = layers
         eng = DecodeEngine(model, capacity=batch, s_max=s_max,
@@ -355,9 +368,11 @@ def _engine_decode_hybrid(batch=8, s_max=512, block=16, n_pages=1024):
         from paddle_tpu.models.granite_hybrid import (
             GraniteHybridConfig, GraniteHybridForCausalLM)
         paddle.seed(0)
-        model = GraniteHybridForCausalLM(GraniteHybridConfig(
-            vocab_size=1024, num_hidden_layers=3,
-            layer_types=("mamba", "attention", "mamba"), dtype="bfloat16"))
+        with _shapes_only():
+            model = GraniteHybridForCausalLM(GraniteHybridConfig(
+                vocab_size=1024, num_hidden_layers=3,
+                layer_types=("mamba", "attention", "mamba"),
+                dtype="bfloat16"))
         model.eval()
         eng = DecodeEngine(model, capacity=batch, s_max=s_max,
                            block_size=block, n_blocks=n_pages,
@@ -380,6 +395,76 @@ def _engine_decode_hybrid(batch=8, s_max=512, block=16, n_pages=1024):
         # buffers themselves
         assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
     build.check = check
+    return build
+
+
+def _engine_mimo(which, slots=48, s_max=9216, n_pages=27649, block=16):
+    """The engine's two programs for MiMo-V2.5 at the long_in cell's
+    sizes: the published widths, layers 0-6, 16 of the router's 256
+    experts held, the vocabulary cut (one matmul behind the stack). The
+    model is drawn at debug size and handed over as shapes."""
+    def build(place):
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models import mimo_v2 as mv
+        paddle.seed(0)
+        with _shapes_only():
+            model = mv.MimoV2ForCausalLM("debug")
+        model.eval()
+        model.config = full = mv.MimoV2Config(
+            vocab_size=1024, num_hidden_layers=7,
+            hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1),
+            moe_layer_freq=(0, 1, 1, 1, 1, 1, 1), held_experts=(0, 16),
+            dtype="bfloat16")
+        eng = DecodeEngine(model, capacity=slots, s_max=s_max,
+                           block_size=block, n_blocks=n_pages,
+                           prefix_cache=False)
+        shapes = mv.leaf_shapes(full)
+        leaf = lambda n: place(shapes[n][0], BF16 if shapes[n][1] in
+                               ("matrix", "one") else F32)
+
+        def like(a):
+            return place(a.shape, a.dtype)
+
+        if which == "prefill":
+            fn, data = eng._prefill, [
+                place((1, s_max), I32), place((1,), I32),
+                place((eng._max_blocks,), I32), place((), I32)]
+        else:
+            fn, data = eng._decode, [
+                like(jnp.asarray(a))
+                for a in (eng._tok, eng._tables, eng._lens)]
+        return fn, [{n: leaf(n) for n in model._stacked_names()},
+                    leaf("embed_tokens"), leaf("final_norm"),
+                    leaf("lm_head"), {}, *data, *map(like, eng._pool())]
+
+    def check(compiled):
+        text = compiled.as_text()
+        # the expert products are the grouped ones, over the pairs routed
+        # to held experts: nothing else reads the stack of experts, and
+        # nothing has its shape or a [held, rows, width] buffer's
+        stacks = ("bf16[96,4096,2048]", "bf16[96,2048,4096]")
+        for _, shape, op, line in _hlo_instructions(text):
+            assert not any(st in shape for st in stacks), line[:200]
+            if any(st in line for st in stacks):
+                assert op == "custom-call" and "ragged-dot" in line, \
+                    line[:200]
+            assert not re.match(r"\w+\[(16|96),\d+,(2048|4096)\]", shape), \
+                line[:200]
+        assert "ragged-dot" in text
+        for wide in (256, 128):       # the K pool, the V pool, the rings
+            _assert_pools_stay_put(
+                compiled, jax.ShapeDtypeStruct(
+                    (2, n_pages, 4, block, wide), BF16),
+                temp_below=build.temp_below)
+        if which == "prefill":
+            _assert_no_square_scores(compiled, s_max)
+        else:
+            assert "paged_decode_qk192" in text
+    build.check = check
+    # temporaries: 10 MB of the decode step's, 96 MB of a block's (read
+    # when the cases were written), far under a layer's weights or rings
+    build.temp_below = (64 << 20) if which == "decode" else (256 << 20)
     return build
 
 
@@ -414,6 +499,8 @@ CASES = {
         s_max=3328, n_pages=4141, batch=16, tail=128),
     "ssm_update_kernel_granite_widths": _ssm_update(),
     "engine_decode_chunk_granite_hybrid": _engine_decode_hybrid(),
+    "engine_decode_chunk_mimo_v2_long_in_sizes": _engine_mimo("decode"),
+    "engine_prefill_paged_mimo_v2_long_in_sizes": _engine_mimo("prefill"),
 }
 
 

@@ -23,7 +23,7 @@ class TestResNet:
     def test_resnet50_forward_backward(self):
         from paddle_tpu.vision.models import resnet50
         m = resnet50(num_classes=4)
-        out = m(paddle.randn([1, 3, 64, 64]))
+        out = m(paddle.randn([1, 3, 32, 32]))
         loss = paddle.mean(out ** 2)
         loss.backward()
         grads = [p.grad for p in m.parameters() if not p.stop_gradient]
