@@ -10,6 +10,8 @@ sharded so XLA inserts the all-to-all the reference issued manually."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -19,7 +21,7 @@ from ... import nn
 from .mp_layers import shard_hint
 
 __all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate",
-           "moe_dispatch_combine", "moe_route_held"]
+           "moe_dispatch_combine", "moe_route_held", "moe_full_stream"]
 
 
 class NaiveGate(nn.Layer):
@@ -148,12 +150,15 @@ def moe_route_dropless(logits, num_experts, top_k):
     per-expert groups instead (MegaBlocks-style dropless). The route is
     :func:`moe_route_held` holding every expert; the GShard aux loss is
     added here."""
-    topi, gates, order, group_sizes = moe_route_held(logits, top_k)
+    topi, gates, order, group_sizes, _ = moe_route_held(logits, top_k)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     me = probs.mean(axis=0)
     ce = jax.nn.one_hot(topi, num_experts, dtype=jnp.float32).sum(1).mean(0)
     aux = (me * ce).sum() * num_experts
     return topi, gates, order, group_sizes, aux
+
+
+_STREAM_TILE = 128      # rows: a short stream is whole tiles of the chip
 
 
 def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
@@ -177,8 +182,16 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
 
     Returns (topi [N, k] expert ids, gates [N, k] f32, 0 off the held
     share, order [N*k] the stream's permutation, group_sizes [count]
-    int32). ``group_sizes.sum()`` pairs are computed here;
-    ``(group_sizes > 0).sum()`` experts are visited."""
+    int32, stream_rows). ``group_sizes.sum()`` pairs are computed here;
+    ``(group_sizes > 0).sum()`` experts are visited.
+
+    ``stream_rows`` (P, a Python int, from the shapes and ``held``
+    alone) is how many rows of the stream a share's pairs are expected
+    to fit: an even router brings ``N * k * count / E`` pairs; P is
+    twice that, rounded up to whole tiles of 128 rows, at most ``N *
+    k``. Because the held pairs lie at the HEAD of ``order``,
+    :func:`moe_dropless_ffn` may read ``order[:P]`` alone whenever
+    ``group_sizes.sum() <= P``; it relies on that place."""
     x = logits.astype(jnp.float32)
     if scoring == "softmax":
         scores = jax.nn.softmax(x, axis=-1)
@@ -190,7 +203,8 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
     _, topi = jax.lax.top_k(choice, top_k)
     gates = jnp.take_along_axis(scores, topi, axis=-1)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    first, count = held if held is not None else (0, logits.shape[-1])
+    n_experts = logits.shape[-1]
+    first, count = held if held is not None else (0, n_experts)
     local = topi - first
     mine = (local >= 0) & (local < count)
     if rows is not None:
@@ -199,11 +213,26 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
     order = jnp.argsort(key, stable=True)
     group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
         jnp.int32)
-    return topi, jnp.where(mine, gates, 0.0), order, group_sizes
+    n_pairs = key.shape[0]
+    stream_rows = min(n_pairs, -(-2 * n_pairs * count
+                                 // (n_experts * _STREAM_TILE)) * _STREAM_TILE)
+    return topi, jnp.where(mine, gates, 0.0), order, group_sizes, stream_rows
+
+
+def moe_full_stream(group_sizes, n_pairs, stream_rows=None):
+    """Whether :func:`moe_dropless_ffn` runs its products over the whole
+    stream of ``n_pairs`` rows: ``True`` (a Python bool) where the short
+    stream of ``stream_rows`` is not at most half of it (every expert
+    held: never shorter), else a traced bool, true when the pairs of
+    this call outgrow it."""
+    if stream_rows is None or 2 * stream_rows > n_pairs:
+        return True
+    return group_sizes.sum() > stream_rows
 
 
 def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
-                     we_gate, we_up, we_down, precision=None):
+                     we_gate, we_up, we_down, precision=None,
+                     stream_rows=None):
     """SwiGLU expert FFN over the expert-sorted ragged stream: three
     lax.ragged_dot grouped GEMMs, then unsort + gate-combine. tokens
     [N, d]; we_* [E, d, f]/[E, f, d]; returns [N, d]. Rows of the stream
@@ -213,23 +242,62 @@ def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
     products' (None: the process's default, which at ``"high"`` makes
     the chip's compiler spell a grouped product out as a dense one over
     every group; ``Precision.DEFAULT`` keeps the grouped kernel, which
-    reads the experts that have rows)."""
+    reads the experts that have rows).
+
+    ``stream_rows``: the P that :func:`moe_route_held` returned with
+    ``order`` (never a caller's choice). Where P is at most half of the
+    stream's ``N * k`` rows (a chip that holds a share of the experts),
+    the stream, the three products, the mask and the combine are built
+    over the HEAD of the expert-sorted order, where the held pairs lie:
+    over ``order[:P / 2]`` (what an even router brings, where that is
+    whole tiles) when this call's pairs fit it, else over ``order[:P]``;
+    a call whose pairs outgrow P takes the whole stream
+    (:func:`moe_full_stream`), so nothing is dropped. The grouped kernel
+    computes a whole tile of rows for every expert it visits, so a
+    stream no longer than its pairs need is what keeps it at the
+    experts' bytes. Elsewhere (``None``, every expert held) the whole
+    stream is the only program traced."""
     n, d = tokens.shape
     k = topi.shape[1]
-    stream = jnp.repeat(tokens, k, axis=0) if k > 1 else tokens
-    stream = jnp.take(stream, order, axis=0)              # [N*k, d]
     dt = we_gate.dtype
-    gate = jax.nn.silu(jax.lax.ragged_dot(stream.astype(dt), we_gate,
-                                          group_sizes, precision=precision))
-    up = jax.lax.ragged_dot(stream.astype(dt), we_up, group_sizes,
-                            precision=precision)
-    out_sorted = jax.lax.ragged_dot(gate * up, we_down, group_sizes,
-                                    precision=precision)
-    grouped = jnp.arange(out_sorted.shape[0]) < group_sizes.sum()
-    out_sorted = jnp.where(grouped[:, None], out_sorted, 0)
-    unsorted = jnp.zeros_like(out_sorted).at[order].set(out_sorted)
-    picked = unsorted.reshape(n, k, d)
-    return jnp.sum(picked * gates[..., None].astype(picked.dtype), axis=1)
+
+    def products(stream):
+        gate = jax.nn.silu(jax.lax.ragged_dot(stream, we_gate, group_sizes,
+                                              precision=precision))
+        up = jax.lax.ragged_dot(stream, we_up, group_sizes,
+                                precision=precision)
+        out = jax.lax.ragged_dot(gate * up, we_down, group_sizes,
+                                 precision=precision)
+        grouped = jnp.arange(out.shape[0]) < group_sizes.sum()
+        return jnp.where(grouped[:, None], out, 0)
+
+    def full():
+        stream = jnp.repeat(tokens, k, axis=0) if k > 1 else tokens
+        stream = jnp.take(stream, order, axis=0)              # [N*k, d]
+        out_sorted = products(stream.astype(dt))
+        unsorted = jnp.zeros_like(out_sorted).at[order].set(out_sorted)
+        picked = unsorted.reshape(n, k, d)
+        return jnp.sum(picked * gates[..., None].astype(picked.dtype),
+                       axis=1)
+
+    def head(rows):
+        pairs = order[:rows]                        # each n * k + choice
+        token = pairs // k
+        out = products(jnp.take(tokens, token, axis=0).astype(dt))
+        weight = jnp.take(gates.reshape(-1), pairs)     # 0 past the groups
+        out = (out.astype(jnp.float32) * weight[:, None]).astype(dt)
+        # a token's pairs added up in float32: [N, rows] ones at its pairs
+        at = (token[None, :] == jnp.arange(n)[:, None]).astype(dt)
+        return jnp.dot(at, out, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32).astype(dt)
+
+    if moe_full_stream(group_sizes, n * k, stream_rows) is True:
+        return full()
+    rungs = [r for r in (stream_rows // 2, stream_rows)
+             if r % _STREAM_TILE == 0]
+    rung = sum((group_sizes.sum() > r).astype(jnp.int32) for r in rungs)
+    return jax.lax.switch(
+        rung, [functools.partial(head, r) for r in rungs] + [full])
 
 
 def moe_permute(x, slot, num_experts, capacity):

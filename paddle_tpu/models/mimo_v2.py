@@ -28,7 +28,8 @@ of fixed size**: a ring ``[window layers, slots, kv heads, window,
 width]`` written at ``position % window``, beside the pool, donated
 through both programs. Behind the rings rides one small int32 vector,
 the counters only the device can keep (pairs routed to held experts,
-held experts visited).
+held experts visited, expert-layer passes whose products took the whole
+stream and not its head).
 
 The stack is driven by the two patterns as data: runs of like layers are
 scanned, each run indexing the stacked weights where they lie.
@@ -42,7 +43,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..distributed.fleet.moe import moe_dropless_ffn, moe_route_held
+from ..distributed.fleet.moe import (moe_dropless_ffn, moe_full_stream,
+                                     moe_route_held)
 from ..kernels.paged_attention import paged_decode_attention
 from .llama import PagedPrograms, _rms, _rope, _row_pages, _token_insert
 
@@ -205,14 +207,14 @@ def _qkv(cfg, lp, h, positions, theta):
 def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     """x + ffn(rms(x)); an expert layer routes over all the router's
     experts and computes the held ones' part. ``rows`` [n] marks real
-    tokens; ``counts`` int32 [2] gains (pairs computed, held experts
-    visited)."""
+    tokens; ``counts`` int32 [3] gains (pairs computed, held experts
+    visited, 1 if the expert products took the whole stream)."""
     y = _rms(x, lp["post_ln"], cfg.layernorm_epsilon)
     if f_kind == "dense":
         return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
             @ lp["w_down"], counts
     logits = jnp.dot(y.astype(jnp.float32), lp["router"], precision=_HI)
-    topi, gates, order, sizes = moe_route_held(
+    topi, gates, order, sizes, stream_rows = moe_route_held(
         logits, cfg.num_experts_per_tok, cfg.held_experts,
         scoring=cfg.scoring_func, bias=lp["router_bias"], rows=rows)
     # this layer's experts by their place in the one stack of all layers'
@@ -222,8 +224,11 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     with jax.named_scope("moe_expert_ffn"):
         out = moe_dropless_ffn(y, topi, gates, order, groups, w["we_gate"],
                                w["we_up"], w["we_down"],
-                               precision=jax.lax.Precision.DEFAULT)
-    counts = counts + jnp.stack([sizes.sum(), (sizes > 0).sum()])
+                               precision=jax.lax.Precision.DEFAULT,
+                               stream_rows=stream_rows)
+    whole = moe_full_stream(sizes, order.shape[0], stream_rows)
+    counts = counts + jnp.stack([sizes.sum(), (sizes > 0).sum(),
+                                 jnp.asarray(whole, jnp.int32)])
     return x + out.astype(x.dtype), counts
 
 
@@ -599,8 +604,9 @@ class MimoV2ForCausalLM(nn.Layer):
                                      dtype),
                 jax.ShapeDtypeStruct((nw, slots, kvw, win, cfg.v_head_dim),
                                      dtype),
-                jax.ShapeDtypeStruct((2,), jnp.int32)),
-            device_counters=("moe_pairs", "moe_expert_visits"),
+                jax.ShapeDtypeStruct((3,), jnp.int32)),
+            device_counters=("moe_pairs", "moe_expert_visits",
+                             "moe_full_stream"),
             unsupported={
                 "prefix_cache": "a prefix hit needs the window layers' "
                                 "last keys and values at the page "

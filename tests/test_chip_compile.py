@@ -441,8 +441,10 @@ def _engine_mimo(which, slots=48, s_max=9216, n_pages=27649, block=16):
     def check(compiled):
         text = compiled.as_text()
         # the expert products are the grouped ones, over the pairs routed
-        # to held experts: nothing else reads the stack of experts, and
-        # nothing has its shape or a [held, rows, width] buffer's
+        # to held experts: nothing else reads the stack of experts (the
+        # conditional that hands it to its branches and their tuples move
+        # nothing: _NO_WORK), and nothing has its shape or a [held, rows,
+        # width] buffer's
         stacks = ("bf16[96,4096,2048]", "bf16[96,2048,4096]")
         for _, shape, op, line in _hlo_instructions(text):
             assert not any(st in shape for st in stacks), line[:200]
@@ -451,7 +453,16 @@ def _engine_mimo(which, slots=48, s_max=9216, n_pages=27649, block=16):
                     line[:200]
             assert not re.match(r"\w+\[(16|96),\d+,(2048|4096)\]", shape), \
                 line[:200]
-        assert "ragged-dot" in text
+        # ... over the head of the expert-sorted stream, as long as an
+        # even router's pairs or twice that (P) in whole tiles of 128,
+        # beside the whole stream's for a call whose pairs outgrow P
+        streams = (256 * 8, 256, 128) if which == "prefill" \
+            else (slots * 8, 128)
+        products = {shape.split("{")[0] for _, shape, op, line in
+                    _hlo_instructions(text)
+                    if op == "custom-call" and "ragged-dot-none" in line}
+        assert products == {f"bf16[{r},{width}]" for r in streams
+                            for width in (2048, 4096)}, products
         for wide in (256, 128):       # the K pool, the V pool, the rings
             _assert_pools_stay_put(
                 compiled, jax.ShapeDtypeStruct(
@@ -462,8 +473,10 @@ def _engine_mimo(which, slots=48, s_max=9216, n_pages=27649, block=16):
         else:
             assert "paged_decode_qk192" in text
     build.check = check
-    # temporaries: 10 MB of the decode step's, 96 MB of a block's (read
-    # when the cases were written), far under a layer's weights or rings
+    # temporaries: 10 MB of the decode step's, 125 MB of a block's (96
+    # before the conditional between the short streams and the whole
+    # one: its branches' buffers stand side by side), far under a
+    # layer's weights or rings
     build.temp_below = (64 << 20) if which == "decode" else (256 << 20)
     return build
 

@@ -141,7 +141,8 @@ def stats_case():
     decode = [e for e in log if e[1] == "decode"]
     assert sum(e[4] for e in decode) == stats["decode_ctx_tokens"]
     assert sum(e[2] * e[3] for e in decode) == stats["decode_row_steps"]
-    assert log[-1][5:] == [stats["moe_pairs"], stats["moe_expert_visits"]]
+    assert log[-1][5:] == [stats["moe_pairs"], stats["moe_expert_visits"],
+                           stats["moe_full_stream"]]
     assert all(a[0] <= b[0] and a[5] < b[5] for a, b in zip(log, log[1:]))
 
 
@@ -190,7 +191,7 @@ def share_case():
         w = {k: jnp.concatenate([jnp.zeros_like(held[k])] * 2 + [held[k]])
              for k in ("we_gate", "we_up", "we_down")}
         out, counts = M._ffn(mcfg, w, held, "moe", 2, x,
-                             jnp.ones((24,), bool), jnp.zeros((2,), jnp.int32))
+                             jnp.ones((24,), bool), jnp.zeros((3,), jnp.int32))
         parts.append(np.asarray(out - x))
         assert 0 < int(counts[0]) < 2 * 24 and 0 < int(counts[1]) <= 4
     np.testing.assert_allclose(sum(parts), whole, atol=2e-6)
@@ -206,7 +207,7 @@ def router_case():
     bias = jax.random.uniform(jax.random.key(2), (16,), minval=-0.5,
                               maxval=0.5)
     plain = moe_route_held(logits, 2, (4, 4), scoring="sigmoid")
-    topi, gates, order, sizes = moe_route_held(
+    topi, gates, order, sizes, _ = moe_route_held(
         logits, 2, (4, 4), scoring="sigmoid", bias=bias)
     assert (np.sort(topi, -1) != np.sort(plain[0], -1)).any(-1).mean() > 0.1
     scores = np.asarray(jax.nn.sigmoid(logits))
@@ -309,6 +310,109 @@ def test_what_the_family_cannot_serve_raises_at_construction(option, kw):
     with pytest.raises(ValueError, match="cannot be served with") as err:
         DecodeEngine(model(), **{**ENGINE, **kw})
     assert option in str(err.value)
+
+
+def _share_layer(n=256, d=64, f=32):
+    """One expert layer at the routing shapes of the long_in cell (256
+    rows, 8 of 256 experts a token, 16 held) and a small width: the
+    second of two expert layers, so that the layer's groups lie behind
+    another layer's in the one stack. (config, stack, layer leaves, x)."""
+    cfg = M.MimoV2Config(
+        vocab_size=256, hidden_size=d, intermediate_size=2 * d,
+        moe_intermediate_size=f, num_hidden_layers=3, num_attention_heads=4,
+        num_key_value_heads=1, swa_num_key_value_heads=2, head_dim=24,
+        v_head_dim=16, hybrid_layer_pattern=(0, 1, 1),
+        moe_layer_freq=(0, 1, 1), n_routed_experts=256,
+        held_experts=(32, 16), num_experts_per_tok=8, sliding_window=8)
+    ks = jax.random.split(jax.random.key(11), 6)
+    w = {name: jax.random.normal(key, (32, *shape)) * 0.1
+         for name, key, shape in (("we_gate", ks[0], (d, f)),
+                                  ("we_up", ks[1], (d, f)),
+                                  ("we_down", ks[2], (f, d)))}
+    lp = {"post_ln": jnp.ones((d,)),
+          "router": jax.random.normal(ks[3], (d, 256)) * 0.3,
+          "router_bias": jnp.zeros((256,))}
+    return cfg, w, lp, jax.random.normal(ks[4], (n, d))
+
+
+def _dense_share(cfg, w, y, topi, gates, layer=1):
+    """What the held experts of ``layer`` add for the normed tokens
+    ``y``: every one of them computed for every token and weighted by
+    the route (0 where not chosen)."""
+    first, count = cfg.held_experts
+    weight = (gates[..., None] * (topi[..., None] == first + jnp.arange(count))
+              ).sum(1)                                          # [n, count]
+    mine = slice(layer * count, (layer + 1) * count)
+    h = jax.nn.silu(jnp.einsum("nd,edf->enf", y, w["we_gate"][mine])) \
+        * jnp.einsum("nd,edf->enf", y, w["we_up"][mine])
+    return jnp.einsum("ne,enf,efd->nd", weight, h, w["we_down"][mine])
+
+
+# the whole-stream program with every expert held, as the parent of the
+# PR that brought the short stream lowered it (sha256 of the StableHLO)
+WHOLE_STREAM_PINNED = "15c774aeb92595e3"
+# case -> (selection bias on which experts, rows of padding ahead, the
+# pairs it must bring (above, at most), whether the whole stream runs);
+# P = 256 of 2048 rows, an even router brings 128 pairs
+STREAM_CASES = {
+    "pairs_fit_an_even_share": (None, 0, (64, 128), 0),
+    "pairs_fit_twice_it": ((slice(32, 48), 0.005), 0, (128, 256), 0),
+    "pairs_overflow": ((slice(32, 40), 10.0), 0, (2047, 2048), 1),
+    "padded_first_block": ((slice(32, 48), 0.005), 100, (64, 128), 0),
+}
+
+
+@pytest.mark.parametrize("case", [*STREAM_CASES, "every_expert_held"])
+def test_expert_products_over_the_head_of_the_stream(case):
+    """``moe_dropless_ffn`` over ``order[:P / 2]`` and ``order[:P]``
+    against the whole stream and against every held expert computed
+    densely, where the rule engages: nothing is dropped when the pairs
+    outgrow P (every token favours held experts), and the third device
+    counter says when the whole stream ran."""
+    from paddle_tpu.distributed.fleet import moe
+    cfg, w, lp, x = _share_layer()
+    n, k = x.shape[0], cfg.num_experts_per_tok
+    if case == "every_expert_held":
+        topi, gates, order, sizes, rows_p = moe_route_held(
+            x @ lp["router"][:, :16], 2)
+        assert rows_p == n * 2 \
+            and moe.moe_full_stream(sizes, n * 2, rows_p) is True
+        args = (x, topi, gates, order, sizes,
+                *(w[name][:16] for name in ("we_gate", "we_up", "we_down")))
+
+        def moe_dropless_ffn(*a):
+            return moe.moe_dropless_ffn(*a, stream_rows=rows_p)
+
+        text = str(jax.make_jaxpr(moe_dropless_ffn)(*args))
+        assert "cond" not in text and "while" not in text
+        lowered = jax.jit(moe_dropless_ffn).lower(*args).as_text()
+        assert hashlib.sha256(lowered.encode()).hexdigest()[:16] \
+            == WHOLE_STREAM_PINNED
+        return
+    bias, pad, (above, at_most), whole_stream = STREAM_CASES[case]
+    if bias is not None:
+        lp["router_bias"] = lp["router_bias"].at[bias[0]].set(bias[1])
+    rows = jnp.arange(n) >= pad
+    got, counts = jax.jit(
+        lambda x, rows: M._ffn(cfg, w, lp, "moe", 1, x, rows,
+                               jnp.zeros((3,), jnp.int32)))(x, rows)
+    y = M._rms(x, lp["post_ln"], cfg.layernorm_epsilon)
+    topi, gates, order, sizes, rows_p = moe_route_held(
+        y @ lp["router"], k, cfg.held_experts, scoring=cfg.scoring_func,
+        bias=lp["router_bias"], rows=rows)
+    assert rows_p == 256 and order.shape == (n * k,)
+    assert above < int(sizes.sum()) <= at_most
+    assert counts.tolist() == [int(sizes.sum()), int((sizes > 0).sum()),
+                               whole_stream]
+    want = _dense_share(cfg, w, y, topi, gates)
+    assert np.abs(want).max() > 0.01
+    np.testing.assert_allclose(got - x, want, atol=2e-6)
+    assert float(jnp.abs(got - x)[:pad].sum()) == 0.0
+    # the same inputs through the whole stream
+    groups = jnp.zeros((32,), jnp.int32).at[16:].set(sizes)
+    whole = moe.moe_dropless_ffn(y, topi, gates, order, groups, w["we_gate"],
+                                 w["we_up"], w["we_down"])
+    np.testing.assert_allclose(got - x, whole, atol=2e-6)
 
 
 # sha256 of the StableHLO granite_hybrid.py's two paged programs lower to
