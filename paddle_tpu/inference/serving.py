@@ -50,10 +50,12 @@ class _NullSpan:
 _NOPROF = _NullSpan()
 
 
-def _phase(prof, name):
+def _phase(prof, name, args=None):
     """Phase guard for ``with`` — a real profiler span when a
-    StepProfiler is attached, the no-op singleton otherwise."""
-    return _NOPROF if prof is None else prof.phase(name)
+    StepProfiler is attached, the no-op singleton otherwise. ``args``
+    (a launch's identity, ``DecodeEngine._launch_args``) go on the
+    span's trace annotation."""
+    return _NOPROF if prof is None else prof.phase(name, args)
 
 
 def _tmark(req, state, worker=None, n_tokens=None):
@@ -292,8 +294,10 @@ class DecodeEngine:
             self.compiles = CompileTracker(registry=self.metrics,
                                            recorder=recorder,
                                            worker_id=self.worker_id)
-        # profile mode only: what each paged launch was handed (stats())
+        # profile mode only: what each paged launch was handed (stats()),
+        # and how many there were over the engine's life
         self._launches = collections.deque(maxlen=8192) if profile else None
+        self._n_launches = 0
         self._build()
         self._reset()
 
@@ -915,18 +919,32 @@ class DecodeEngine:
                 c.inc(int((new - old) % (1 << 32)))
             self._device_counts_seen = now
 
-    def _note_launch(self, t, kind, units, rows, tokens):
+    def _launch_args(self, kind, units, rows, tokens):
+        """Profile mode: what the next launch of one of the two paged
+        programs is handed, for its ``launch`` span's trace annotation
+        and, once it has returned, for ``_note_launch``: ``launch``
+        counts on from launch to launch over the engine's life, decode
+        and prefill alike, so it orders them; ``"prefill"`` (``units``
+        blocks of ``tokens`` prompt tokens) or ``"decode"`` (``units``
+        steps of ``rows`` live rows that read ``tokens`` cached tokens
+        in all). None with profiling off."""
+        if self._launches is None:
+            return None
+        self._n_launches += 1
+        return {"launch": self._n_launches - 1, "kind": kind,
+                "units": units, "rows": rows, "tokens": tokens}
+
+    def _note_launch(self, t, launch):
         """Profile mode: one entry a launch of the two paged programs,
         so that a reader of a device trace can count the work of the
         launches it traced and not of the run's mean: when it was
-        dispatched (``observability.now``), ``"prefill"`` (``units``
-        blocks of ``tokens`` prompt tokens) or ``"decode"`` (``units``
-        steps of ``rows`` live rows that read ``tokens`` cached tokens
-        in all), and the device counters' vector as that launch
-        returned it, kept by reference: nothing is fetched here."""
-        if self._launches is not None:
+        dispatched (``observability.now``), what ``_launch_args`` said
+        of it, and the device counters' vector as that launch returned
+        it, kept by reference: nothing is fetched here."""
+        if launch is not None:
             self._launches.append(
-                (t, kind, units, rows, tokens,
+                (t, launch["kind"], launch["units"], launch["rows"],
+                 launch["tokens"],
                  self._state[-1] if self._c_device else None))
 
     def _launch_entries(self):
@@ -1512,25 +1530,28 @@ class DecodeEngine:
             ids = _np.full((1, self.s_max), self.pad_id, _np.int32)
             ids[0, self.s_max - ns:] = seq
             pad = self.s_max - ns
-            where = (jnp.asarray(table_row),)
-            if self._state_specs:
-                where += (jnp.asarray(slot, jnp.int32),)
-            t0 = _now()
-            first, *pool = self._prefill(
-                st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
-                jnp.asarray([pad], jnp.int32), *where, *self._pool())
-            self._set_pool(pool)
-            self._note_launch(t0, "prefill",
-                              -(-ns // self._prefill_block), 1, ns)
-            if self._state_specs:
-                # the program started the row's states from zero and
-                # left them in ``slot``: whatever the slot's last tenant
-                # left there was overwritten, never read
-                with RecordEvent("engine.state_admit", "engine",
-                                 worker=self.worker_id):
-                    self._state_slots.add(slot)
-            self._c_device_calls.inc()
             blocks = -(-ns // self._prefill_block)
+            launch = self._launch_args("prefill", blocks, 1, ns)
+            t0 = _now()
+            with _phase(self.profile, "launch", launch):
+                where = (jnp.asarray(table_row),)
+                if self._state_specs:
+                    where += (jnp.asarray(slot, jnp.int32),)
+                first, *pool = self._prefill(
+                    st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
+                    jnp.asarray([pad], jnp.int32), *where, *self._pool())
+                self._set_pool(pool)
+            with _phase(self.profile, "host_sync"):
+                first_tok = int(first[0])       # (fetch = sync)
+                if self._state_specs:
+                    # the program started the row's states from zero
+                    # and left them in ``slot``: whatever the slot's
+                    # last tenant left there was overwritten, never read
+                    with RecordEvent("engine.state_admit", "engine",
+                                     worker=self.worker_id):
+                        self._state_slots.add(slot)
+            self._note_launch(t0, launch)
+            self._c_device_calls.inc()
             self._c_prefill_blocks.inc(blocks)
             self._c_ssm_prefill_chunks.inc(
                 blocks * self._progs.chunks_per_block)
@@ -1540,9 +1561,10 @@ class DecodeEngine:
             if m.cow_src is not None:
                 # private copy of the partially-shared page: the tail's
                 # first write lands mid-page at position ``cached``
-                self._set_pool(self._cow(
-                    jnp.asarray(m.cow_src, jnp.int32),
-                    jnp.asarray(pages[0], jnp.int32), *self._pool()))
+                with _phase(self.profile, "launch"):
+                    self._set_pool(self._cow(
+                        jnp.asarray(m.cow_src, jnp.int32),
+                        jnp.asarray(pages[0], jnp.int32), *self._pool()))
                 self._cache.release_cow(m)
                 self._c_device_calls.inc()
             tail = seq[cached:]
@@ -1550,15 +1572,19 @@ class DecodeEngine:
             ids = _np.full((1, sc), self.pad_id, _np.int32)
             ids[0, sc - tail.size:] = tail
             pad = sc - tail.size
-            first, *pool = self._prefix_prefill_for(sc)(
-                st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
-                jnp.asarray([pad], jnp.int32),
-                jnp.asarray([cached], jnp.int32),
-                jnp.asarray(table_row), *self._pool())
-            self._set_pool(pool)
+            prefill_prefix = self._prefix_prefill_for(sc)
+            with _phase(self.profile, "launch"):
+                first, *pool = prefill_prefix(
+                    st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
+                    jnp.asarray([pad], jnp.int32),
+                    jnp.asarray([cached], jnp.int32),
+                    jnp.asarray(table_row), *self._pool())
+                self._set_pool(pool)
+            with _phase(self.profile, "host_sync"):
+                first_tok = int(first[0])       # (fetch = sync)
             self._c_device_calls.inc()
         self._tables[slot] = table_row
-        return int(first[0])
+        return first_tok
 
     # -- chunked prefill (ISSUE 7 tentpole) ---------------------------------
     def _begin_chunked_prefill(self, slot, req, prompt, seq, m, pages,
@@ -1711,27 +1737,28 @@ class DecodeEngine:
             if self.spec_decode:
                 return self._decode_once_spec()
             return self._decode_once_paged()
-        steps = self.chunk
-        if self._g + steps > self.s_max:
-            # cache exhaustion: fail ONLY rows whose remaining demand
-            # cannot fit in the leftover fill; survivors ride one final
-            # CLAMPED chunk out instead of getting the exhaustion error
-            space = self.s_max - self._g
-            for slot, row in enumerate(self._rows):
-                if row is None:
-                    continue
-                need = row["req"].max_new - len(row["toks"])
-                if need > space:
-                    self._fail_request(row["req"], RuntimeError(
-                        f"engine cache exhausted at fill {self._g} "
-                        f"(s_max={self.s_max}): {need} tokens still "
-                        f"needed, {space} slots left"))
-                    self._rows[slot] = None
-            if space <= 0 or self.idle():
-                self._reset()  # a wedged fill must not brick later
-                return 0       # bursts
-            steps = space      # every survivor finishes inside it
-        st, embed, fnorm, lm = self._weights()
+        with _phase(self.profile, "prepare"):
+            steps = self.chunk
+            if self._g + steps > self.s_max:
+                # cache exhaustion: fail ONLY rows whose remaining demand
+                # cannot fit in the leftover fill; survivors ride one final
+                # CLAMPED chunk out instead of getting the exhaustion error
+                space = self.s_max - self._g
+                for slot, row in enumerate(self._rows):
+                    if row is None:
+                        continue
+                    need = row["req"].max_new - len(row["toks"])
+                    if need > space:
+                        self._fail_request(row["req"], RuntimeError(
+                            f"engine cache exhausted at fill {self._g} "
+                            f"(s_max={self.s_max}): {need} tokens still "
+                            f"needed, {space} slots left"))
+                        self._rows[slot] = None
+                if space <= 0 or self.idle():
+                    self._reset()  # a wedged fill must not brick later
+                    return 0       # bursts
+                steps = space      # every survivor finishes inside it
+            st, embed, fnorm, lm = self._weights()
         t0 = _now()                # decode-only window: admit()'s
         #                            prefill/compile must not read as a
         #                            phantom throughput collapse
@@ -1743,18 +1770,19 @@ class DecodeEngine:
                     self._g, jnp.asarray(self._pad))
             with _phase(self.profile, "host_sync"):
                 toks = _np.asarray(toks)   # [steps, B] (fetch = sync)
-        wall = _now() - t0
-        self._g += steps
-        self.device_steps += steps
-        self._c_steps.inc(steps)
-        self._c_device_calls.inc()
-        self._h_chunk.observe(wall)
-        n_busy = sum(r is not None for r in self._rows)
-        self._g_occupancy.set(n_busy)
-        log_event("engine_chunk", steps=steps, rows=n_busy,
-                  fill=self._g, wall_s=round(wall, 4),
-                  tokens_per_s=round(steps * n_busy
-                                     / max(wall, 1e-9), 1))
+        with _phase(self.profile, "account"):
+            wall = _now() - t0
+            self._g += steps
+            self.device_steps += steps
+            self._c_steps.inc(steps)
+            self._c_device_calls.inc()
+            self._h_chunk.observe(wall)
+            n_busy = sum(r is not None for r in self._rows)
+            self._g_occupancy.set(n_busy)
+            log_event("engine_chunk", steps=steps, rows=n_busy,
+                      fill=self._g, wall_s=round(wall, 4),
+                      tokens_per_s=round(steps * n_busy
+                                         / max(wall, 1e-9), 1))
         alive = 0
         with _phase(self.profile, "publish"):
             for slot, row in enumerate(self._rows):
@@ -1826,9 +1854,12 @@ class DecodeEngine:
             self._mig_debt = 0
         return budget
 
-    def _decode_once_paged(self):
-        import jax.numpy as jnp
-        import numpy as _np
+    def _prepare_decode_paged(self):
+        """The decode prologue up to the page tables: fund and run this
+        step's prefill chunks (chunked prefill), then grow each live
+        row's page list to cover the chunk's writes. Returns None when
+        decode lanes are ready to launch, else what ``decode_once``
+        returns without one (the rows alive)."""
         bs = self.block_size
         if self.chunked_prefill:
             # ISSUE 7: one mixed step. Decode lanes claim their tokens
@@ -1930,11 +1961,26 @@ class DecodeEngine:
         if self.chunked_prefill and not any(
                 r is not None and "pf_seq" not in r for r in self._rows):
             return sum(r is not None for r in self._rows)
-        st, embed, fnorm, lm = self._weights()
-        self._drain_scale_resets()
+        return None
+
+    def _decode_once_paged(self):
+        import jax.numpy as jnp
+        import numpy as _np
+        with _phase(self.profile, "prepare"):
+            alive = self._prepare_decode_paged()
+            if alive is not None:
+                return alive            # no decode lanes this step
+            st, embed, fnorm, lm = self._weights()
+            self._drain_scale_resets()
+            n_busy = sum(r is not None for r in self._rows)
+            # a live row's context grows by one a step of the chunk
+            ctx_tokens = self.chunk * int(self._lens.sum()) \
+                + n_busy * self.chunk * (self.chunk - 1) // 2
+            launch = self._launch_args("decode", self.chunk, n_busy,
+                                       ctx_tokens)
         t0 = _now()
         with RecordEvent("engine.decode_chunk", "engine", worker=self.worker_id):
-            with _phase(self.profile, "launch"):
+            with _phase(self.profile, "launch", launch):
                 toks, *pool = self._decode(
                     st, embed, fnorm, lm, self._scales,
                     jnp.asarray(self._tok), jnp.asarray(self._tables),
@@ -1942,27 +1988,24 @@ class DecodeEngine:
                 self._set_pool(pool)
             with _phase(self.profile, "host_sync"):
                 toks = _np.asarray(toks)   # [chunk, B] (fetch = sync)
-        wall = _now() - t0
-        self.device_steps += self.chunk
-        self._c_steps.inc(self.chunk)
-        self._c_device_calls.inc()
-        self._h_chunk.observe(wall)
-        n_busy = sum(r is not None for r in self._rows)
-        self._g_occupancy.set(n_busy)
-        self._c_decode_row_steps.inc(self.chunk * n_busy)
-        # a live row's context grows by one a step of the chunk
-        ctx_tokens = self.chunk * int(self._lens.sum()) \
-            + n_busy * self.chunk * (self.chunk - 1) // 2
-        self._c_decode_ctx.inc(ctx_tokens)
-        self._note_launch(t0, "decode", self.chunk, n_busy, ctx_tokens)
-        if self._progs.chunks_per_block:
-            self._c_ssm_row_steps.inc(self.chunk * n_busy)
-        log_event("engine_chunk", steps=self.chunk, rows=n_busy,
-                  fill=int(self._lens.max()), wall_s=round(wall, 4),
-                  tokens_per_s=round(self.chunk * n_busy
-                                     / max(wall, 1e-9), 1),
-                  blocks_used=self._alloc.num_used,
-                  blocks_free=self._alloc.num_free)
+        with _phase(self.profile, "account"):
+            wall = _now() - t0
+            self.device_steps += self.chunk
+            self._c_steps.inc(self.chunk)
+            self._c_device_calls.inc()
+            self._h_chunk.observe(wall)
+            self._g_occupancy.set(n_busy)
+            self._c_decode_row_steps.inc(self.chunk * n_busy)
+            self._c_decode_ctx.inc(ctx_tokens)
+            self._note_launch(t0, launch)
+            if self._progs.chunks_per_block:
+                self._c_ssm_row_steps.inc(self.chunk * n_busy)
+            log_event("engine_chunk", steps=self.chunk, rows=n_busy,
+                      fill=int(self._lens.max()), wall_s=round(wall, 4),
+                      tokens_per_s=round(self.chunk * n_busy
+                                         / max(wall, 1e-9), 1),
+                      blocks_used=self._alloc.num_used,
+                      blocks_free=self._alloc.num_free)
         alive = 0
         with _phase(self.profile, "publish"):
             for slot, row in enumerate(self._rows):
@@ -2150,20 +2193,21 @@ class DecodeEngine:
         emitted history and resumes losslessly."""
         import jax.numpy as jnp
         import numpy as _np
-        req = row["req"]
-        k = int(draft.size)
-        lens0 = int(self._lens[slot])
-        if not self._grow_decode_row(slot, row, k + 1):
-            return
-        st, embed, fnorm, lm = self._weights()
-        self._drain_scale_resets()
-        tail = _np.empty((k + 1,), _np.int32)
-        tail[0] = self._tok[slot]
-        tail[1:] = draft
-        sc = self._bucket_window(k + 1)
-        ids = _np.full((1, sc), self.pad_id, _np.int32)
-        ids[0, sc - (k + 1):] = tail
-        pad = sc - (k + 1)
+        with _phase(self.profile, "prepare"):
+            req = row["req"]
+            k = int(draft.size)
+            lens0 = int(self._lens[slot])
+            if not self._grow_decode_row(slot, row, k + 1):
+                return
+            st, embed, fnorm, lm = self._weights()
+            self._drain_scale_resets()
+            tail = _np.empty((k + 1,), _np.int32)
+            tail[0] = self._tok[slot]
+            tail[1:] = draft
+            sc = self._bucket_window(k + 1)
+            ids = _np.full((1, sc), self.pad_id, _np.int32)
+            ids[0, sc - (k + 1):] = tail
+            pad = sc - (k + 1)
         t0 = _now()
         with RecordEvent("engine.spec_verify", "engine",
                          worker=self.worker_id):
@@ -2177,11 +2221,12 @@ class DecodeEngine:
             with _phase(self.profile, "host_sync"):
                 # [k+1] greedy chain
                 preds = _np.asarray(preds)[0, pad:]
-        wall = _now() - t0
-        self.device_steps += 1
-        self._c_steps.inc(1)
-        self._c_device_calls.inc()
-        self._h_chunk.observe(wall)
+        with _phase(self.profile, "account"):
+            wall = _now() - t0
+            self.device_steps += 1
+            self._c_steps.inc(1)
+            self._c_device_calls.inc()
+            self._h_chunk.observe(wall)
         with _phase(self.profile, "publish"):
             out = [int(preds[0])]
             for i in range(k):
@@ -2215,20 +2260,10 @@ class DecodeEngine:
                 self._lens[slot] = lens0 + m_len
 
     # -- single-launch mixed step (ISSUE 10 tentpole) -----------------------
-    def _decode_once_mixed(self):
-        """ONE device launch per engine step: every decode-ready row's
-        verify window (its pending token + k drafts; k=0 without spec
-        decode) and every budget-funded prefill chunk ride a single
-        ``mixed_paged_attention`` program with per-row ``q_lens`` —
-        the O(rows)→O(1) launch collapse the ragged kernel was built
-        for (the bench counts device calls to prove it). Token outputs
-        are bit-identical to the per-row paths: every emitted token is
-        the program's argmax at its position, and acceptance walks the
-        same greedy chain ``_verify_row`` does. Schedule differs (a row
-        finishing its last chunk decodes from the NEXT step, and plain
-        decode lanes advance one token per launch instead of a chunk)
-        but per-request greedy sequences cannot."""
-        import jax.numpy as jnp
+    def _mixed_windows(self):
+        """The mixed step's plan: draft, fund prefill chunks from the
+        step budget, grow the decode lanes, and give the ragged window
+        batch as ``(slot, row, kind, tail, kv_len, table)`` a lane."""
         import numpy as _np
 
         def _draft(slot, row):
@@ -2295,23 +2330,42 @@ class DecodeEngine:
             windows.append((slot, row, "decode", tail,
                             int(self._lens[slot]) + tail.size,
                             self._tables[slot]))
-        n_busy = sum(r is not None for r in self._rows)
-        self._g_occupancy.set(n_busy)
-        if not windows:
-            return n_busy
-        B = self.capacity
-        T = self._bucket_window(max(t[3].size for t in windows))
-        ids = _np.full((B, T), self.pad_id, _np.int32)
-        q_lens = _np.zeros((B,), _np.int32)
-        kv_lens = _np.zeros((B,), _np.int32)
-        tabs = _np.zeros((B, self._max_blocks), _np.int32)
-        for slot, row, kind, tail, kvl, table in windows:
-            ids[slot, :tail.size] = tail
-            q_lens[slot] = tail.size
-            kv_lens[slot] = kvl
-            tabs[slot] = table
-        st, embed, fnorm, lm = self._weights()
-        self._drain_scale_resets()
+        return windows
+
+    def _decode_once_mixed(self):
+        """ONE device launch per engine step: every decode-ready row's
+        verify window (its pending token + k drafts; k=0 without spec
+        decode) and every budget-funded prefill chunk ride a single
+        ``mixed_paged_attention`` program with per-row ``q_lens`` —
+        the O(rows)→O(1) launch collapse the ragged kernel was built
+        for (the bench counts device calls to prove it). Token outputs
+        are bit-identical to the per-row paths: every emitted token is
+        the program's argmax at its position, and acceptance walks the
+        same greedy chain ``_verify_row`` does. Schedule differs (a row
+        finishing its last chunk decodes from the NEXT step, and plain
+        decode lanes advance one token per launch instead of a chunk)
+        but per-request greedy sequences cannot."""
+        import jax.numpy as jnp
+        import numpy as _np
+        with _phase(self.profile, "prepare"):
+            windows = self._mixed_windows()
+            n_busy = sum(r is not None for r in self._rows)
+            self._g_occupancy.set(n_busy)
+            if not windows:
+                return n_busy
+            B = self.capacity
+            T = self._bucket_window(max(t[3].size for t in windows))
+            ids = _np.full((B, T), self.pad_id, _np.int32)
+            q_lens = _np.zeros((B,), _np.int32)
+            kv_lens = _np.zeros((B,), _np.int32)
+            tabs = _np.zeros((B, self._max_blocks), _np.int32)
+            for slot, row, kind, tail, kvl, table in windows:
+                ids[slot, :tail.size] = tail
+                q_lens[slot] = tail.size
+                kv_lens[slot] = kvl
+                tabs[slot] = table
+            st, embed, fnorm, lm = self._weights()
+            self._drain_scale_resets()
         t0 = _now()
         with RecordEvent("engine.mixed_step", "engine",
                          worker=self.worker_id):
@@ -2325,15 +2379,16 @@ class DecodeEngine:
             with _phase(self.profile, "host_sync"):
                 # [B, T] argmax per position
                 preds = _np.asarray(preds)
-        wall = _now() - t0
-        self.device_steps += 1
-        self._c_steps.inc(1)
-        self._c_device_calls.inc()
-        self._h_chunk.observe(wall)
-        log_event("engine_mixed_step", rows=len(windows),
-                  window=T, wall_s=round(wall, 4),
-                  blocks_used=self._alloc.num_used,
-                  blocks_free=self._alloc.num_free)
+        with _phase(self.profile, "account"):
+            wall = _now() - t0
+            self.device_steps += 1
+            self._c_steps.inc(1)
+            self._c_device_calls.inc()
+            self._h_chunk.observe(wall)
+            log_event("engine_mixed_step", rows=len(windows),
+                      window=T, wall_s=round(wall, 4),
+                      blocks_used=self._alloc.num_used,
+                      blocks_free=self._alloc.num_free)
         with _phase(self.profile, "publish"):
             for slot, row, kind, tail, kvl, table in windows:
                 if self._rows[slot] is not row:
@@ -2394,7 +2449,6 @@ class DecodeEngine:
                 else:
                     self._lens[slot] = kvl - tail.size + m_len
         return sum(r is not None for r in self._rows)
-
 
 class GenerationPredictor:
     """Causal-LM predictor: wraps a model with .generate() (llama/gpt
@@ -2686,14 +2740,15 @@ class BatchingServer:
         every chunk boundary, never at generation granularity."""
         eng = self.engine
         while not self._stop.is_set():
-            busy = self._pending or not eng.idle()
-            try:
-                self._pending.append(
-                    self._q.get(timeout=0.001 if busy else 0.05))
-                while True:
-                    self._pending.append(self._q.get_nowait())
-            except queue.Empty:
-                pass
+            with _phase(eng.profile, "poll"):
+                busy = self._pending or not eng.idle()
+                try:
+                    self._pending.append(
+                        self._q.get(timeout=0.001 if busy else 0.05))
+                    while True:
+                        self._pending.append(self._q.get_nowait())
+                except queue.Empty:
+                    pass
             if not self._pending and eng.idle():
                 continue
             try:

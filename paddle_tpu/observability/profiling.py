@@ -5,11 +5,18 @@ Two runtime instruments for the serving step loop, both OFF by default
 bit-identical):
 
 - :class:`StepProfiler` — a low-overhead per-step phase timer. The
-  engine wraps each phase of a step (:data:`PHASES`: admission /
-  schedule / prefill-chunk / spec-draft / launch / host-sync /
-  publish / telemetry) in a prebuilt context-manager span; durations
-  land in fixed-size rings keyed by the injected ``observability.now``
-  clock. ``summary()`` computes per-phase p50/p99 through the shared
+  engine wraps each phase of a step (:data:`PHASES`: poll / admission /
+  schedule / prefill-chunk / spec-draft / prepare / launch / host-sync /
+  account / publish / telemetry) in a prebuilt context-manager span;
+  durations land in fixed-size rings keyed by the injected
+  ``observability.now`` clock. Every span, and the step, also opens a
+  ``jax.profiler.TraceAnnotation`` (``engine.<phase>``, ``engine.step``),
+  so that a device trace taken meanwhile holds the host's phases on its
+  own clock; a span given arguments at entry (a launch's identity)
+  writes them on its annotation with ``t_ns``, ``observability.now`` at
+  entry in nanoseconds, which ties the ``RequestTrace`` marks (that
+  clock's) to the trace's timeline.
+  ``summary()`` computes per-phase p50/p99 through the shared
   :func:`~paddle_tpu.observability.metrics.quantile_from_buckets`
   bucket math; ``to_events()`` emits chrome ``ph="X"`` slices in the
   same perf_counter-µs timebase as the r10 trace/span lanes, so
@@ -45,29 +52,48 @@ __all__ = ["PHASES", "StepProfiler", "CompileTracker"]
 
 _log = get_logger("paddle_tpu.observability.profiling")
 
-#: canonical step-phase vocabulary (ISSUE 13) — the engine owns
-#: admission..publish, the fleet router owns schedule + telemetry
-PHASES = ("admission", "schedule", "prefill_chunk", "spec_draft",
-          "launch", "host_sync", "publish", "telemetry")
+#: canonical step-phase vocabulary (ISSUE 13), in loop order — the
+#: server owns poll, the engine admission..publish (launch and host_sync
+#: also lie inside admission, round a prefill's own program), the fleet
+#: router schedule + telemetry
+PHASES = ("poll", "admission", "schedule", "prefill_chunk", "spec_draft",
+          "prepare", "launch", "host_sync", "account", "publish",
+          "telemetry")
+
+
+def _annotation(name, **args):
+    """The host span a ``jax.profiler`` trace holds beside the device's
+    operations (a no-op while nothing traces). Imported late: the
+    package has no import-time dependency."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **args)
 
 
 class _PhaseSpan:
-    """Prebuilt, reusable (non-reentrant) timing context for ONE phase
-    — the hot path allocates nothing per step."""
+    """Prebuilt, reusable (non-reentrant) timing context for ONE phase.
+    ``args``, set by :meth:`StepProfiler.phase` for the next entry, go
+    on the trace annotation with ``t_ns``."""
 
-    __slots__ = ("_prof", "_name", "_t0")
+    __slots__ = ("_prof", "_name", "_label", "_t0", "_args", "_ann")
 
     def __init__(self, prof, name):
         self._prof = prof
         self._name = name
+        self._label = "engine." + name
         self._t0 = 0.0
+        self._args = self._ann = None
 
     def __enter__(self):
+        args, self._args = self._args, None
+        self._ann = _annotation(self._label) if args is None else \
+            _annotation(self._label, t_ns=int(now() * 1e9), **args)
+        self._ann.__enter__()
         self._t0 = self._prof._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self._prof._observe_phase(self._name, self._t0)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -95,6 +121,7 @@ class StepProfiler:
         self._t_step0 = None                  # guarded-by: _lock
         self._ewma = None                     # guarded-by: _lock
         self._spans = {p: _PhaseSpan(self, p) for p in PHASES}
+        self._step_ann = None                 # serving thread only
         self._h_phase = self._c_outliers = None
         if registry is not None:
             self._h_phase = registry.histogram(
@@ -120,9 +147,12 @@ class StepProfiler:
             return 0.0 if self._ewma is None else self._ewma
 
     # -- hot path -----------------------------------------------------------
-    def phase(self, name: str) -> _PhaseSpan:
-        """The prebuilt span for ``name`` — ``with prof.phase("launch"):``."""
-        return self._spans[name]
+    def phase(self, name: str, args=None) -> _PhaseSpan:
+        """The prebuilt span for ``name`` — ``with prof.phase("launch"):``;
+        ``args`` (a dict) is written on this entry's trace annotation."""
+        span = self._spans[name]
+        span._args = args
+        return span
 
     def _observe_phase(self, name, t0) -> None:
         dur = self._clock() - t0
@@ -132,6 +162,8 @@ class StepProfiler:
             self._h_phase.observe(dur)
 
     def begin_step(self) -> None:
+        self._step_ann = _annotation("engine.step")
+        self._step_ann.__enter__()
         with self._lock:
             self._t_step0 = self._clock()
 
@@ -140,6 +172,9 @@ class StepProfiler:
         no ``begin_step`` was pending). Outlier steps (wall beyond
         ``outlier_factor`` × the EWMA, after ``outlier_min_steps``
         warmup) are flagged into the flight ring."""
+        ann, self._step_ann = self._step_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
         with self._lock:
             t0 = self._t_step0
             if t0 is None:

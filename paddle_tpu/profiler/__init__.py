@@ -395,161 +395,3 @@ def export_protobuf(dir_name: str, worker_name: str | None = None):
 
 
 __all__ += ["SortedKeys", "SummaryView", "export_protobuf"]
-
-
-# ---------------------------------------------------------------------------
-# xplane parsing: device-time attribution without TensorBoard
-# ---------------------------------------------------------------------------
-def _pb_varint(buf, i):
-    v = s = 0
-    while True:
-        b = buf[i]
-        v |= (b & 0x7F) << s
-        i += 1
-        if not b & 0x80:
-            return v, i
-        s += 7
-
-
-def _pb_fields(buf):
-    """Minimal protobuf wire-format walker: yields (field_num, wire_type,
-    value) — enough to read the tsl xplane schema without a TF/TSL
-    dependency (the reference links the full TF profiler; here the trace
-    IS jax's xplane and only the aggregation is ours)."""
-    i, n = 0, len(buf)
-    while i < n:
-        tag, i = _pb_varint(buf, i)
-        fnum, wt = tag >> 3, tag & 7
-        if wt == 0:
-            v, i = _pb_varint(buf, i)
-        elif wt == 1:
-            v = buf[i:i + 8]
-            i += 8
-        elif wt == 2:
-            ln, i = _pb_varint(buf, i)
-            v = buf[i:i + ln]
-            i += ln
-        elif wt == 5:
-            v = buf[i:i + 4]
-            i += 4
-        else:
-            raise ValueError(f"wire type {wt}")
-        yield fnum, wt, v
-
-
-def _xplane_planes(space_bytes):
-    """XSpace.planes=1 -> (name, lines, event_metadata) per plane.
-    Schema: tsl/profiler/protobuf/xplane.proto — XPlane{name=2, lines=3,
-    event_metadata=4}, XLine{id=1, name=2, timestamp_ns=3, events=4},
-    XEvent{metadata_id=1, duration_ps=3, num_occurrences=5},
-    XEventMetadata{id=1, name=2}."""
-    for fnum, _, plane in _pb_fields(space_bytes):
-        if fnum != 1:
-            continue
-        name, lines, emeta = "", [], {}
-        for pf, _, pv in _pb_fields(plane):
-            if pf == 2:
-                name = pv.decode("utf-8", "replace")
-            elif pf == 3:
-                lines.append(pv)
-            elif pf == 4:
-                mid, mname = 0, ""
-                for ef, _, ev in _pb_fields(pv):
-                    if ef == 1:
-                        mid = ev
-                    elif ef == 2:
-                        for mf, _, mv in _pb_fields(ev):
-                            if mf == 1:
-                                mid = mv
-                            elif mf == 2:
-                                mname = mv.decode("utf-8", "replace")
-                emeta[mid] = mname
-        yield name, lines, emeta
-
-
-def xplane_op_breakdown(trace_dir, top=20):
-    """Aggregate per-op device time from a jax.profiler xplane trace
-    (Profiler(trace_dir=...) or jax.profiler.start_trace). Returns
-    {"device": plane_name, "total_ms": T, "ops": [(name, ms, share), ...],
-    "groups": {category: (ms, share)}} for the busiest device plane's
-    'XLA Ops' line — the attribution the reference reads out of its CUPTI
-    timeline (SURVEY §5 tracing)."""
-    import glob
-    import os
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    space = open(max(paths, key=os.path.getmtime), "rb").read()
-    best = None
-    for pname, lines, emeta in _xplane_planes(space):
-        if "TPU" not in pname and "GPU" not in pname \
-                and "device" not in pname.lower():
-            continue
-        per_op: dict[str, float] = {}
-        for line in lines:
-            lname, events = "", []
-            for lf, wt, lv in _pb_fields(line):
-                if lf == 2 and wt == 2:
-                    lname = lv.decode("utf-8", "replace")
-                elif lf == 4 and wt == 2:
-                    events.append(lv)
-            if "Ops" not in lname:
-                continue
-            for ev in events:
-                mid = dur = 0
-                occ = 1
-                for ef, _, evv in _pb_fields(ev):
-                    if ef == 1:
-                        mid = evv
-                    elif ef == 3:
-                        dur = evv
-                    elif ef == 5:
-                        occ = evv
-                nm = emeta.get(mid, str(mid))
-                per_op[nm] = per_op.get(nm, 0.0) + dur * max(occ, 1)
-        total = sum(per_op.values())
-        if best is None or total > best[1]:
-            best = (pname, total, per_op)
-    if best is None or not best[2]:
-        raise ValueError("no device 'XLA Ops' line found in the trace")
-    pname, total_ps, per_op = best
-
-    def short(op_name):
-        # "%fusion.123 = bf16[...] ..." -> "fusion.123"
-        n = op_name.split(" = ")[0].strip()
-        return n[1:] if n.startswith("%") else n
-
-    def category(op_name):
-        n = short(op_name).lower()
-        if any(t in n for t in ("dot", "conv", "einsum")):
-            return "matmul"
-        if any(t in n for t in ("all-reduce", "all-gather", "collective",
-                                "reduce-scatter", "all-to-all",
-                                "permute")):
-            return "collective"
-        if any(t in n for t in ("flash", "attention")):
-            return "attention_kernel"
-        if any(t in n for t in ("copy", "transpose", "reshape", "bitcast",
-                                "slice", "concatenate", "pad")):
-            return "data_movement"
-        if "fusion" in n:
-            return "fusion(elementwise+)"
-        return "other"
-
-    groups: dict[str, float] = {}
-    for nm, ps in per_op.items():
-        groups[category(nm)] = groups.get(category(nm), 0.0) + ps
-    ops_sorted = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
-    return {
-        "device": pname,
-        "total_ms": total_ps / 1e9,
-        "ops": [(short(nm), ps / 1e9, ps / total_ps)
-                for nm, ps in ops_sorted],
-        "groups": {g: (ps / 1e9, ps / total_ps)
-                   for g, ps in sorted(groups.items(),
-                                       key=lambda kv: -kv[1])},
-    }
-
-
-__all__ += ["xplane_op_breakdown"]

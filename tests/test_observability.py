@@ -903,6 +903,215 @@ class TestStepProfiler:
         assert steps[0]["tid"] == 0 and phases[0]["tid"] == 1
 
 
+class TestHostPhasesInTheTrace:
+    """ISSUE 40: the serving loop's phases tile the serving thread's
+    time, every paged launch's annotation says which launch it is, and
+    a device trace taken meanwhile holds both (on the CPU: the host
+    events alone)."""
+
+    PROMPTS = (5, 12, 9, 7, 11)
+    NEW = (21, 29, 17, 25, 19)
+    TOP = ("poll", "admission", "prepare", "launch", "host_sync",
+           "account", "publish")
+
+    @staticmethod
+    def _prompts(seed=40):
+        rng = np.random.RandomState(seed)
+        return [rng.randint(1, 128, (n,)).astype(np.int32)
+                for n in TestHostPhasesInTheTrace.PROMPTS]
+
+    def _serve(self, profile):
+        """The prompts through an engine of two slots, three at once and
+        two behind them; (engine, served sequences)."""
+        from paddle_tpu.inference.serving import DecodeEngine
+        from harness import ENGINE_KW
+        eng = DecodeEngine(shared_model(), **ENGINE_KW, profile=profile)
+        reqs = [eng.submit(p, max_new_tokens=n)
+                for p, n in zip(self._prompts(), self.NEW)]
+        drive(eng)
+        return eng, [np.asarray(r.wait(timeout=120)) for r in reqs]
+
+    @staticmethod
+    def _recording(monkeypatch):
+        """Stand in for ``jax.profiler.TraceAnnotation``: every span the
+        profiler opens, as (name, arguments)."""
+        import contextlib
+        from paddle_tpu.observability import profiling
+        made = []
+
+        def factory(name, **args):
+            made.append((name, args))
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(profiling, "_annotation", factory)
+        return made
+
+    def test_top_level_phases_tile_the_serving_loop(self, monkeypatch):
+        import time
+        from paddle_tpu.inference.serving import (BatchingServer,
+                                                  GenerationPredictor)
+        from paddle_tpu.observability import profiling
+        from harness import ENGINE_KW
+        clock = time.perf_counter
+        made = []
+
+        class Stamped:
+            """What a trace would hold of a span, on the test's clock."""
+
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                self.t0 = clock()
+
+            def __exit__(self, *exc):
+                made.append((self.t0, clock(), self.name))
+
+        monkeypatch.setattr(profiling, "_annotation",
+                            lambda name, **args: Stamped(name))
+        kw = {k: v for k, v in ENGINE_KW.items() if k != "capacity"}
+        srv = BatchingServer(
+            GenerationPredictor(shared_model()), max_batch=2,
+            continuous=True, engine_kwargs={**kw, "profile": True})
+        try:
+            # warm: every program compiled before the loop is read
+            for p in self._prompts(seed=41)[:2]:
+                srv.submit(p, max_new_tokens=5).wait(timeout=120)
+            # a decode chunk as long as a chip's, so that the host's
+            # microseconds between two phases weigh what they weigh there
+            decode = srv.engine._decode
+            srv.engine._decode = lambda *a: (time.sleep(0.03), decode(*a))[1]
+            t_warm = clock()
+            for h in [srv.submit(p, max_new_tokens=n) for p, n
+                      in zip(self._prompts(), self.NEW)]:
+                h.wait(timeout=120)
+        finally:
+            srv.close()
+        spans = sorted(s for s in made if s[0] >= t_warm)
+        steps = [s for s in spans if s[2] == "engine.step"]
+        spans = [(a, b, n[len("engine."):]) for a, b, n in spans
+                 if n != "engine.step"]
+        assert {n for _, _, n in spans} == set(self.TOP)
+        top, end = [], float("-inf")
+        for a, b, name in spans:
+            if b <= end:
+                continue                # nested: a prefill's launch
+            assert a >= end, f"{name} opens inside {top[-1][2]}"
+            top.append((a, b, name))
+            end = b
+        first = next(i for i, s in enumerate(top) if s[2] == "poll")
+        last = max(i for i, s in enumerate(top) if s[2] == "publish")
+        top = top[first:last + 1]
+        names = [n for _, _, n in top]
+        assert names.count("launch") >= 12 <= names.count("admission")
+        # loop order: a step's five phases behind its admission
+        for i in (i for i, n in enumerate(names) if n == "prepare"):
+            assert names[i - 1:i + 5] == [
+                "admission", "prepare", "launch", "host_sync", "account",
+                "publish"]
+            assert any(a <= top[i][0] and top[i + 4][1] <= b
+                       for a, b, _ in steps)
+        # a prefill's own launch and read-back lie inside admission
+        inside = [(a, b, n) for a, b, n in spans
+                  if any(x <= a and b <= y and (a, b, n) != (x, y, m)
+                         for x, y, m in top if m == "admission")]
+        assert {n for _, _, n in inside} == {"launch", "host_sync"}
+        covered = sum(b - a for a, b, _ in top)
+        whole = top[-1][1] - top[0][0]
+        assert covered <= whole
+        assert (whole - covered) / whole < 0.01
+
+    def test_launch_annotations_carry_the_launch(self, monkeypatch):
+        made = self._recording(monkeypatch)
+        eng, _ = self._serve(profile=True)
+        launches = [a for n, a in made if n == "engine.launch" and a]
+        log = eng.stats()["launches"]
+        assert len(launches) == len(log) >= 12
+        assert {a["kind"] for a in launches} == {"decode", "prefill"}
+        for i, (a, entry) in enumerate(zip(launches, log)):
+            t, kind, units, rows, tokens = entry[:5]
+            assert a["launch"] == i
+            assert (a["kind"], a["units"], a["rows"], a["tokens"]) == \
+                (kind, units, rows, tokens)
+            assert 0 <= a["t_ns"] / 1e9 - t < 0.05
+        names = {n for n, _ in made}
+        assert names >= {"engine.step", "engine.admission",
+                         "engine.prepare", "engine.host_sync",
+                         "engine.account", "engine.publish"}
+        # every step that was opened was closed
+        assert eng.profile._step_ann is None
+
+    def test_profile_off_opens_no_annotation(self, monkeypatch):
+        from paddle_tpu.inference import serving
+        made = self._recording(monkeypatch)
+        eng, served = self._serve(profile=None)
+        assert made == []
+        assert eng.profile is None and eng._launches is None
+        for name in self.TOP:
+            assert serving._phase(eng.profile, name) is serving._NOPROF
+        assert eng._launch_args("decode", 4, 2, 80) is None
+        _, profiled = self._serve(profile=True)
+        assert made
+        for a, b in zip(served, profiled):
+            np.testing.assert_array_equal(a, b)
+
+    def test_a_span_takes_arguments_for_one_entry(self, monkeypatch):
+        from paddle_tpu.observability import StepProfiler
+        made = self._recording(monkeypatch)
+        prof = StepProfiler()
+        with prof.phase("launch", {"launch": 7, "kind": "decode"}):
+            pass
+        with prof.phase("launch"):
+            pass
+        (n1, a1), (n2, a2) = made
+        assert n1 == n2 == "engine.launch"
+        assert a1["launch"] == 7 and a1["kind"] == "decode"
+        assert a1["t_ns"] > 0 and a2 == {}
+
+    def test_a_trace_holds_the_spans_with_their_arguments(self, tmp_path):
+        import jax
+        from benchmark.lib import host_spans
+        from paddle_tpu.inference.serving import DecodeEngine
+        from harness import ENGINE_KW
+        eng = DecodeEngine(shared_model(), **ENGINE_KW, profile=True)
+        eng.submit(self._prompts(seed=41)[0], max_new_tokens=5)
+        drive(eng)                      # compiled before the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            reqs = [eng.submit(p, max_new_tokens=9)
+                    for p in self._prompts()[:2]]
+            drive(eng)
+        finally:
+            jax.profiler.stop_trace()
+        assert all(r.wait(timeout=120) is not None for r in reqs)
+        path, = tmp_path.rglob("*.xplane.pb")
+        spans = host_spans.serving_line(host_spans.load(str(path)))
+        by = {}
+        for e in spans:
+            by.setdefault(e.name, []).append(e)
+        assert {"step", "admission", "prepare", "launch", "host_sync",
+                "account", "publish"} <= set(by)
+        launches = [e for e in by["launch"] if "kind" in e.args]
+        log = eng.stats()["launches"][-len(launches):]
+        assert len(launches) >= 4
+        for e, entry in zip(launches, log):
+            assert [e.args[k] for k in ("kind", "units", "rows",
+                                        "tokens")] == entry[1:5]
+        idx = [e.args["launch"] for e in launches]
+        assert idx == list(range(idx[0], idx[0] + len(idx)))
+        # t_ns ties perf_counter to the trace's clock: one offset
+        offs = [e.start_ns - e.args["t_ns"] for e in launches]
+        assert max(offs) - min(offs) < 5e6
+        # a decode launch lies in its step, its host_sync behind it
+        d = next(e for e in launches if e.args["kind"] == "decode")
+        assert any(s.start_ns <= d.start_ns and d.end_ns <= s.end_ns
+                   for s in by["step"])
+        assert any(d.end_ns <= h.start_ns < d.end_ns + 1e6
+                   for h in by["host_sync"])
+        pieces = host_spans.innermost(spans)
+        assert all(p[1] <= q[0] for p, q in zip(pieces, pieces[1:]))
+
+
 class TestCompileTracker:
     def _tracker(self, **kw):
         from paddle_tpu.observability import CompileTracker
