@@ -1207,7 +1207,6 @@ class ServingFleet:
         src._release_row_pages(row)
         src._tables[slot] = 0
         src._lens[slot] = 0
-        src._tok[slot] = 0
         src._rows[slot] = None
         tr = getattr(req, "trace", None)
         if tr is not None:

@@ -403,6 +403,16 @@ class DecodeEngine:
         self._c_ssm_prefill_chunks = r.counter(
             "engine_ssm_prefill_chunks_total",
             "chunks of the recurrence run by cold prefills")
+        # the order of the loop's work: a paged program is handed to the
+        # chip before the last one's result is read back
+        self._c_launch_ahead = r.counter(
+            "engine_launch_ahead_total",
+            "paged launches issued with a result still unread: a "
+            "prefill left unread, a decode chunk behind one")
+        self._c_settle_early = r.counter(
+            "engine_settle_early_total",
+            "times a path had to read the unread first tokens before "
+            "it could launch")
         self._c_decode_ctx = r.counter(
             "engine_decode_ctx_tokens_total",
             "context lengths of live rows summed over the steps of "
@@ -700,6 +710,16 @@ class DecodeEngine:
             self._mixed = jax.jit(
                 _tp_wrap(mixed_step, 4),
                 donate_argnums=tuple(range(9, 9 + self._n_pool)))
+            # the next input token of every slot is a device vector the
+            # launches hand on: a prefill leaves its first token at
+            # [slot] (or ``known``, what the host has of a resumed row;
+            # ``at`` is [slot, known or -1], one upload), a decode chunk
+            # its last row. Dispatched behind the launch, while the chip
+            # is busy; never donated
+            self._tok_put = jax.jit(
+                lambda tok, at, first: tok.at[at[0]].set(
+                    jnp.where(at[1] >= 0, at[1], first[0])))
+            self._tok_last = jax.jit(lambda toks: toks[-1])
             if self.compiles is not None:
                 # ISSUE 13 recompile observatory: each wrapped program
                 # logs (name, abstract shapes, wall) on every NEW
@@ -818,8 +838,10 @@ class DecodeEngine:
         return fn
 
     def _reset(self):
+        import jax
         import jax.numpy as jnp
         import numpy as _np
+        from jax.sharding import NamedSharding, PartitionSpec
         self.resets += 1
         B = self.capacity
         # what a slot holds beside its pages (a recurrent model's
@@ -853,8 +875,6 @@ class DecodeEngine:
                 # ISSUE 10: the pools live pre-sharded over the kv-head
                 # axis — donated through every program, they stay
                 # sharded for the engine's lifetime.
-                import jax
-                from jax.sharding import NamedSharding
                 from .sharding import pool_specs
                 psp = pool_specs(
                     4 if self._kv_q else 2, self.tp_axis,
@@ -882,7 +902,17 @@ class DecodeEngine:
             self._cv = jnp.zeros_like(self._ck)
             self._g = 0
             self._pad = _np.zeros((B,), _np.int32)
+        # every slot's next input token: on the device in paged mode
+        # (``_tok_put`` / ``_tok_last``), on the host in contiguous mode
         self._tok = _np.zeros((B,), _np.int32)
+        if self.paged:
+            self._tok = jnp.asarray(self._tok)
+            if self.mesh is not None:
+                self._tok = jax.device_put(
+                    self._tok, NamedSharding(self.mesh, PartitionSpec()))
+        # prefills launched whose first token is not on the host yet:
+        # (first, slot, row) in launch order, read by ``_settle``
+        self._unread = collections.deque()
         self._rows = [None] * B         # per-slot host state
 
     # -- pool plumbing (ISSUE 8) --------------------------------------------
@@ -1035,6 +1065,8 @@ class DecodeEngine:
                  int(self._c_prefill_window_blocks.value),
              "decode_row_steps": int(self._c_decode_row_steps.value),
              "decode_ctx_tokens": int(self._c_decode_ctx.value),
+             "launch_ahead": int(self._c_launch_ahead.value),
+             "settle_early": int(self._c_settle_early.value),
              "resets": self.resets}
         self._sync_device_counters()
         for name, c in zip(self._progs.device_counters, self._c_device):
@@ -1071,6 +1103,60 @@ class DecodeEngine:
                         self._h_spec_accept.sum / steps if steps else 0.0,
                 }
         return s
+
+    # -- launch ahead of the read-back --------------------------------------
+    @staticmethod
+    def _fetch(arr):
+        """A device array's value on the host (blocks until it is
+        there; a launch that failed raises here)."""
+        return np.asarray(arr)
+
+    def _settle(self, early=False):
+        """Read, in launch order, the first token of every prefill that
+        was launched and left unread: the row gets its token, and its
+        ``first_token`` mark is made now, when the value is on the host.
+        The plain paged step calls this behind its decode launch;
+        whatever needs a token's value BEFORE it can launch (drafting,
+        the mixed step, a chunked prompt's last chunk, a preemption that
+        parks ``row["toks"]``) calls it ``early``. A read that raises
+        fails its own request, as a launch that raises does."""
+        if not self._unread:
+            return
+        if early:
+            self._c_settle_early.inc()
+        while self._unread:
+            first, slot, row = self._unread.popleft()
+            if self._rows[slot] is not row:
+                continue                # failed or cleared meanwhile
+            try:
+                with _phase(self.profile, "host_sync"):
+                    tok = int(self._fetch(first)[0])
+            except Exception as e:  # noqa: BLE001 — fail THIS request,
+                self._fail_row_paged(slot, e)  # not the whole engine
+                continue
+            row["toks"].append(tok)
+            self._observe_first_token(row["req"])
+
+    def _hand_on(self, slot, first, known=None):
+        """Behind a prefill's launch: ``slot``'s next input token goes
+        into the device's vector, the prefill's ``first`` (``[1]``, on
+        the device; its copy to the host is asked for now, so that it
+        leaves when the prefill ends whatever is queued behind) or, if
+        the host has it already (a resumed row's), ``known``."""
+        if known is None:
+            first.copy_to_host_async()
+        self._tok = self._tok_put(
+            self._tok,
+            np.array([slot, -1 if known is None else known], np.int32),
+            first)
+
+    def _hand_on_known(self, slot, tok):
+        """The mixed step read ``slot``'s next input itself: it goes
+        into the device's vector for the chunk program, which serves
+        the steps with no prompt under way (and never a speculative
+        engine)."""
+        if not self.spec_decode:
+            self._hand_on(slot, np.zeros((1,), np.int32), tok)
 
     # -- lifecycle telemetry (ISSUE 3) --------------------------------------
     def _trace_admission(self, req):
@@ -1307,6 +1393,10 @@ class DecodeEngine:
         bs = self.block_size
         row = self._rows[slot]
         req = row["req"]
+        if not row["toks"] and "pf_seq" not in row:
+            self._settle(early=True)    # its first token is still unread
+            if self._rows[slot] is not row:
+                return                  # the read failed the row
         with RecordEvent("engine.preempt", "engine", worker=self.worker_id):
             if "pf_seq" in row:
                 # mid-prefill victim (ISSUE 7): publish only COMPLETED
@@ -1333,7 +1423,6 @@ class DecodeEngine:
             _tmark(req, "preempted", worker=self.worker_id)
             self._tables[slot] = 0
             self._lens[slot] = 0
-            self._tok[slot] = 0
             self._rows[slot] = None
             self._sched.add(req)
         tr = getattr(req, "trace", None)
@@ -1474,7 +1563,8 @@ class DecodeEngine:
                     self._fail_request(req, e)
                 continue
             try:
-                first_tok = self._prefill_row(slot, seq, m, pages)
+                first = self._prefill_row(
+                    slot, seq, m, pages, resume[-1] if resume else None)
             except Exception as e:  # noqa: BLE001 — fail THIS request,
                 if m is not None:   # not the whole engine
                     self._cache.release(m)
@@ -1482,7 +1572,9 @@ class DecodeEngine:
                 self._fail_request(req, e)
                 continue
             all_pages = (m.pages if m is not None else []) + pages
-            toks = list(resume) if resume else [first_tok]
+            # the first token stays unread (``_settle``): what follows
+            # needs the row's length and pages, never the token's value
+            toks = list(resume) if resume else []
             req._resume_toks = None
             self.prefills += 1
             self._c_prefills.inc()
@@ -1492,7 +1584,6 @@ class DecodeEngine:
             # suffix it actually prefilled (prefix hits are free, same
             # as the page-charging rule)
             self._qos_charge(req, ns - hit_tokens)
-            self._observe_first_token(req)
             tr = getattr(req, "trace", None)
             log_kv(_log, "admitted", level=logging.DEBUG,
                    worker=self.worker_id,
@@ -1500,23 +1591,28 @@ class DecodeEngine:
                    slot=slot, tokens=int(ns), cached_tokens=hit_tokens,
                    pages=len(all_pages), resumed=bool(resume))
             self._lens[slot] = ns
-            self._tok[slot] = toks[-1]
-            self._rows[slot] = {"req": req, "prompt": prompt,
-                                "toks": toks, "pages": all_pages}
+            self._rows[slot] = row = {"req": req, "prompt": prompt,
+                                      "toks": toks, "pages": all_pages}
+            if resume:
+                self._observe_first_token(req)  # made before, as a rule
+            else:
+                self._unread.append((first, slot, row))
 
-    def _prefill_row(self, slot, seq, m, pages):
-        """Run the admission prefill for ``seq`` into ``pages`` (plus
+    def _prefill_row(self, slot, seq, m, pages, known=None):
+        """Launch the admission prefill for ``seq`` into ``pages`` (plus
         the match's shared pages), seeding the slot's block table.
         Cold (no cached prefix): the blockwise program over the
         window's live blocks.
         Prefix hit: COW-copy the partially-shared page if any, then the
-        position-offset tail prefill over a bucketed window. Returns
-        the argmax token at the last real position."""
+        position-offset tail prefill over a bucketed window. Nothing is
+        read back: the argmax token at the last real position (or
+        ``known``, a resumed row's next input) goes into the device's
+        token vector at ``slot``, and the ``[1]`` array that holds it is
+        returned, its copy to the host asked for."""
         with RecordEvent("engine.prefill", "engine", worker=self.worker_id):
-            return self._prefill_row_inner(slot, seq, m, pages)
+            return self._prefill_row_inner(slot, seq, m, pages, known)
 
-    def _prefill_row_inner(self, slot, seq, m, pages):
-        import jax.numpy as jnp
+    def _prefill_row_inner(self, slot, seq, m, pages, known):
         import numpy as _np
         bs = self.block_size
         ns = seq.size
@@ -1534,15 +1630,14 @@ class DecodeEngine:
             launch = self._launch_args("prefill", blocks, 1, ns)
             t0 = _now()
             with _phase(self.profile, "launch", launch):
-                where = (jnp.asarray(table_row),)
+                where = (table_row,)
                 if self._state_specs:
-                    where += (jnp.asarray(slot, jnp.int32),)
+                    where += (_np.int32(slot),)
                 first, *pool = self._prefill(
-                    st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
-                    jnp.asarray([pad], jnp.int32), *where, *self._pool())
+                    st, embed, fnorm, lm, self._scales, ids,
+                    _np.array([pad], _np.int32), *where, *self._pool())
                 self._set_pool(pool)
-            with _phase(self.profile, "host_sync"):
-                first_tok = int(first[0])       # (fetch = sync)
+                self._hand_on(slot, first, known)
                 if self._state_specs:
                     # the program started the row's states from zero
                     # and left them in ``slot``: whatever the slot's
@@ -1563,8 +1658,8 @@ class DecodeEngine:
                 # first write lands mid-page at position ``cached``
                 with _phase(self.profile, "launch"):
                     self._set_pool(self._cow(
-                        jnp.asarray(m.cow_src, jnp.int32),
-                        jnp.asarray(pages[0], jnp.int32), *self._pool()))
+                        _np.int32(m.cow_src), _np.int32(pages[0]),
+                        *self._pool()))
                 self._cache.release_cow(m)
                 self._c_device_calls.inc()
             tail = seq[cached:]
@@ -1575,16 +1670,16 @@ class DecodeEngine:
             prefill_prefix = self._prefix_prefill_for(sc)
             with _phase(self.profile, "launch"):
                 first, *pool = prefill_prefix(
-                    st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
-                    jnp.asarray([pad], jnp.int32),
-                    jnp.asarray([cached], jnp.int32),
-                    jnp.asarray(table_row), *self._pool())
+                    st, embed, fnorm, lm, self._scales, ids,
+                    _np.array([pad], _np.int32),
+                    _np.array([cached], _np.int32), table_row,
+                    *self._pool())
                 self._set_pool(pool)
-            with _phase(self.profile, "host_sync"):
-                first_tok = int(first[0])       # (fetch = sync)
+                self._hand_on(slot, first, known)
             self._c_device_calls.inc()
+        self._c_launch_ahead.inc()
         self._tables[slot] = table_row
-        return first_tok
+        return first
 
     # -- chunked prefill (ISSUE 7 tentpole) ---------------------------------
     def _begin_chunked_prefill(self, slot, req, prompt, seq, m, pages,
@@ -1597,7 +1692,6 @@ class DecodeEngine:
         ``self._tables[slot]`` stays all-NULL, so the decode program's
         writes for this lane route to the NULL page instead of
         clobbering chunk-scattered K/V."""
-        import jax.numpy as jnp
         import numpy as _np
         cached = m.cached_len if m is not None else 0
         self._drain_scale_resets()      # before COW: keep copied scales
@@ -1605,8 +1699,8 @@ class DecodeEngine:
             with RecordEvent("engine.prefill", "engine",
                              worker=self.worker_id):
                 self._set_pool(self._cow(
-                    jnp.asarray(m.cow_src, jnp.int32),
-                    jnp.asarray(pages[0], jnp.int32), *self._pool()))
+                    _np.int32(m.cow_src), _np.int32(pages[0]),
+                    *self._pool()))
             self._cache.release_cow(m)
             self._c_device_calls.inc()
         all_pages = (m.pages if m is not None else []) + pages
@@ -1661,7 +1755,6 @@ class DecodeEngine:
         scattered at the offset. Windows bucket through
         ``_bucket_window`` — with the default page-sized chunk every
         window is the 16-slot bucket, one already-documented shape."""
-        import jax.numpy as jnp
         import numpy as _np
         req = row["req"]
         seq, pos = row["pf_seq"], int(row["pf_pos"])
@@ -1676,10 +1769,9 @@ class DecodeEngine:
                 RecordEvent("engine.prefill_chunk", "engine",
                             worker=self.worker_id):
             first, *pool = self._prefix_prefill_for(sc)(
-                st, embed, fnorm, lm, self._scales, jnp.asarray(ids),
-                jnp.asarray([pad], jnp.int32),
-                jnp.asarray([pos], jnp.int32),
-                jnp.asarray(row["pf_table"]), *self._pool())
+                st, embed, fnorm, lm, self._scales, ids,
+                _np.array([pad], _np.int32), _np.array([pos], _np.int32),
+                row["pf_table"], *self._pool())
             self._set_pool(pool)
         self._c_device_calls.inc()
         row["pf_pos"] = pos + tail.size
@@ -1693,17 +1785,21 @@ class DecodeEngine:
         if row["pf_pos"] >= seq.size:
             # last chunk: its last-real-position logits ARE the prompt
             # logits — first-token emission, table install, decode from
-            # the next program on
+            # the next program on. The funding of this very step reads
+            # the row's tokens, so the read is made now (today's order)
             resume = row.pop("pf_resume")
-            toks = list(resume) if resume else [int(first[0])]
+            self._hand_on(slot, first, resume[-1] if resume else None)
             self._tables[slot] = row.pop("pf_table")
             self._lens[slot] = seq.size
-            self._tok[slot] = toks[-1]
-            row["toks"] = toks
+            row["toks"] = list(resume) if resume else []
             del row["pf_seq"], row["pf_pos"]
             self.prefills += 1
             self._c_prefills.inc()
-            self._observe_first_token(req)
+            if resume:
+                self._observe_first_token(req)
+            else:
+                self._unread.append((first, slot, row))
+                self._settle(early=True)
 
     def decode_once(self):
         """Run ONE bounded decode chunk, collect tokens, retire finished
@@ -1832,7 +1928,6 @@ class DecodeEngine:
         self._release_slot_state(slot)
         self._tables[slot] = 0          # all-NULL: inactive lane
         self._lens[slot] = 0
-        self._tok[slot] = 0
         self._rows[slot] = None
 
     def _fail_row_paged(self, slot, err):
@@ -1897,7 +1992,9 @@ class DecodeEngine:
         for slot, row in enumerate(self._rows):
             if row is None or "pf_seq" in row:
                 continue
-            use = min(self.chunk, row["req"].max_new - len(row["toks"]))
+            # a row whose first token is still unread has emitted one
+            use = min(self.chunk,
+                      row["req"].max_new - max(1, len(row["toks"])))
             target = int(self._lens[slot]) + use
             grow.append((slot, row, target,
                          -(-target // bs) - len(row["pages"])))
@@ -1964,11 +2061,11 @@ class DecodeEngine:
         return None
 
     def _decode_once_paged(self):
-        import jax.numpy as jnp
         import numpy as _np
         with _phase(self.profile, "prepare"):
             alive = self._prepare_decode_paged()
             if alive is not None:
+                self._settle()          # of rows that growth just failed
                 return alive            # no decode lanes this step
             st, embed, fnorm, lm = self._weights()
             self._drain_scale_resets()
@@ -1981,13 +2078,22 @@ class DecodeEngine:
         t0 = _now()
         with RecordEvent("engine.decode_chunk", "engine", worker=self.worker_id):
             with _phase(self.profile, "launch", launch):
+                if self._unread:
+                    self._c_launch_ahead.inc()
+                # copies: the host goes on writing both while the
+                # launch is in flight
                 toks, *pool = self._decode(
-                    st, embed, fnorm, lm, self._scales,
-                    jnp.asarray(self._tok), jnp.asarray(self._tables),
-                    jnp.asarray(self._lens), *self._pool())
+                    st, embed, fnorm, lm, self._scales, self._tok,
+                    self._tables.copy(), self._lens.copy(),
+                    *self._pool())
                 self._set_pool(pool)
+                toks.copy_to_host_async()
+                self._tok = self._tok_last(toks)
+            # only now does the host read, in launch order: the first
+            # token of each prefill left unread, then the chunk
+            self._settle()
             with _phase(self.profile, "host_sync"):
-                toks = _np.asarray(toks)   # [chunk, B] (fetch = sync)
+                toks = self._fetch(toks)   # [chunk, B] (fetch = sync)
         with _phase(self.profile, "account"):
             wall = _now() - t0
             self.device_steps += self.chunk
@@ -2015,8 +2121,7 @@ class DecodeEngine:
                     alive += 1      # mid-prefill: alive, not decoding
                     continue        # (its lane wrote to the NULL page)
                 emitted_before = len(row["toks"])
-                row["toks"].extend(int(t) for t in toks[:, slot])
-                self._tok[slot] = int(toks[-1, slot])
+                row["toks"].extend(toks[:, slot].tolist())
                 req = row["req"]
                 useful = min(self.chunk, req.max_new - emitted_before)
                 _tmark(req, "decode_chunk", worker=self.worker_id,
@@ -2077,6 +2182,7 @@ class DecodeEngine:
         remainder, and rows whose last chunk lands this step verify
         too. Tenants, by contrast, are charged for ACCEPTED tokens only
         (inside _verify_row)."""
+        self._settle(early=True)        # drafting reads the tokens
         drafts = {}
         if self.chunked_prefill:
             budget = self._step_budget()
@@ -2191,7 +2297,6 @@ class DecodeEngine:
         BETWEEN drafting and verifying is skipped by the caller's
         ``self._rows[slot]`` re-check — it re-queues with its full
         emitted history and resumes losslessly."""
-        import jax.numpy as jnp
         import numpy as _np
         with _phase(self.profile, "prepare"):
             req = row["req"]
@@ -2202,7 +2307,7 @@ class DecodeEngine:
             st, embed, fnorm, lm = self._weights()
             self._drain_scale_resets()
             tail = _np.empty((k + 1,), _np.int32)
-            tail[0] = self._tok[slot]
+            tail[0] = row["toks"][-1]   # the pending next input
             tail[1:] = draft
             sc = self._bucket_window(k + 1)
             ids = _np.full((1, sc), self.pad_id, _np.int32)
@@ -2213,10 +2318,10 @@ class DecodeEngine:
                          worker=self.worker_id):
             with _phase(self.profile, "launch"):
                 preds, *pool = self._verify_prefill_for(sc)(
-                    st, embed, fnorm, lm, self._scales,
-                    jnp.asarray(ids), jnp.asarray([pad], jnp.int32),
-                    jnp.asarray([lens0], jnp.int32),
-                    jnp.asarray(self._tables[slot]), *self._pool())
+                    st, embed, fnorm, lm, self._scales, ids,
+                    _np.array([pad], _np.int32),
+                    _np.array([lens0], _np.int32),
+                    self._tables[slot].copy(), *self._pool())
                 self._set_pool(pool)
             with _phase(self.profile, "host_sync"):
                 # [k+1] greedy chain
@@ -2238,8 +2343,9 @@ class DecodeEngine:
             self._c_spec_accepted.inc(m_len - 1)
             self._h_spec_accept.observe(m_len)
             _tmark(req, "spec_verify", worker=self.worker_id)
+            # (a speculative engine never runs the chunk program: the
+            # device's token vector is left as it is)
             row["toks"].extend(out)
-            self._tok[slot] = out[-1]
             # the draft clamp guarantees len(toks) never passes
             # max_new, so every accepted token is useful — the tenant
             # pays for exactly what it got, never for rejected
@@ -2325,7 +2431,7 @@ class DecodeEngine:
                 continue        # preempted/failed during growth
             d = drafts[slot]
             tail = _np.empty((int(d.size) + 1,), _np.int32)
-            tail[0] = self._tok[slot]
+            tail[0] = row["toks"][-1]   # the pending next input
             tail[1:] = d
             windows.append((slot, row, "decode", tail,
                             int(self._lens[slot]) + tail.size,
@@ -2345,8 +2451,8 @@ class DecodeEngine:
         finishing its last chunk decodes from the NEXT step, and plain
         decode lanes advance one token per launch instead of a chunk)
         but per-request greedy sequences cannot."""
-        import jax.numpy as jnp
         import numpy as _np
+        self._settle(early=True)        # windows are built from tokens
         with _phase(self.profile, "prepare"):
             windows = self._mixed_windows()
             n_busy = sum(r is not None for r in self._rows)
@@ -2371,10 +2477,8 @@ class DecodeEngine:
                          worker=self.worker_id):
             with _phase(self.profile, "launch"):
                 preds, *pool = self._mixed(
-                    st, embed, fnorm, lm, self._scales,
-                    jnp.asarray(ids), jnp.asarray(q_lens),
-                    jnp.asarray(kv_lens), jnp.asarray(tabs),
-                    *self._pool())
+                    st, embed, fnorm, lm, self._scales, ids, q_lens,
+                    kv_lens, tabs, *self._pool())
                 self._set_pool(pool)
             with _phase(self.profile, "host_sync"):
                 # [B, T] argmax per position
@@ -2409,7 +2513,7 @@ class DecodeEngine:
                             else [int(preds[slot, take - 1])]
                         self._tables[slot] = row.pop("pf_table")
                         self._lens[slot] = row["pf_seq"].size
-                        self._tok[slot] = toks[-1]
+                        self._hand_on_known(slot, toks[-1])
                         row["toks"] = toks
                         del row["pf_seq"], row["pf_pos"]
                         self.prefills += 1
@@ -2431,7 +2535,7 @@ class DecodeEngine:
                     self._h_spec_accept.observe(m_len)
                     _tmark(req, "spec_verify", worker=self.worker_id)
                 row["toks"].extend(out)
-                self._tok[slot] = out[-1]
+                self._hand_on_known(slot, out[-1])
                 _tmark(req, "decode_chunk", worker=self.worker_id,
                        n_tokens=m_len)
                 self._qos_charge(req, m_len)
@@ -2741,10 +2845,12 @@ class BatchingServer:
         eng = self.engine
         while not self._stop.is_set():
             with _phase(eng.profile, "poll"):
-                busy = self._pending or not eng.idle()
+                # a live row or a pending request: take what has come
+                # and go on (what comes during the chunk is found at the
+                # next iteration); only the idle engine waits
                 try:
-                    self._pending.append(
-                        self._q.get(timeout=0.001 if busy else 0.05))
+                    if not self._pending and eng.idle():
+                        self._pending.append(self._q.get(timeout=0.05))
                     while True:
                         self._pending.append(self._q.get_nowait())
                 except queue.Empty:
@@ -2755,6 +2861,7 @@ class BatchingServer:
                 eng.admit(self._pending)
                 eng.decode_once()
             except Exception as e:  # noqa: BLE001 — resolve futures
+                eng._unread.clear()
                 for slot, row in enumerate(eng._rows):
                     if row is not None:
                         row["req"].error = e

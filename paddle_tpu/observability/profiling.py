@@ -53,9 +53,10 @@ __all__ = ["PHASES", "StepProfiler", "CompileTracker"]
 _log = get_logger("paddle_tpu.observability.profiling")
 
 #: canonical step-phase vocabulary (ISSUE 13), in loop order — the
-#: server owns poll, the engine admission..publish (launch and host_sync
-#: also lie inside admission, round a prefill's own program), the fleet
-#: router schedule + telemetry
+#: server owns poll, the engine admission..publish (a prefill's own launch
+#: lies inside admission; the read of its first token is a host_sync of
+#: the step, behind the decode launch), the fleet router schedule +
+#: telemetry
 PHASES = ("poll", "admission", "schedule", "prefill_chunk", "spec_draft",
           "prepare", "launch", "host_sync", "account", "publish",
           "telemetry")
