@@ -298,6 +298,7 @@ class DecodeEngine:
         # and how many there were over the engine's life
         self._launches = collections.deque(maxlen=8192) if profile else None
         self._n_launches = 0
+        self._scopes = {}       # program -> {instruction: named scope}
         self._build()
         self._reset()
 
@@ -751,6 +752,13 @@ class DecodeEngine:
                 f"engine_{name}_total",
                 f"{name}, counted on the device by the paged programs")
             for name in progs.device_counters)
+        # what the family has the engine count at every decode launch
+        self._c_host = tuple(
+            (fn, self.metrics.counter(
+                f"engine_{name}_total",
+                f"{name}, counted by the engine at every decode launch "
+                f"from the live rows' contexts"))
+            for name, fn in progs.host_counters.items())
         self._counts_lock = threading.Lock()
         self._cache_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" \
             else jnp.float32
@@ -975,17 +983,46 @@ class DecodeEngine:
             self._launches.append(
                 (t, launch["kind"], launch["units"], launch["rows"],
                  launch["tokens"],
-                 self._state[-1] if self._c_device else None))
+                 self._state[-1] if self._c_device else None,
+                 tuple(int(c.value) for _, c in self._c_host)))
+
+    def _scope_avals(self, name, args):
+        """Profile mode, for a family that names ``trace_scopes``: the
+        shapes of a launch's arguments while program ``name``'s scopes
+        are still to be read (taken before the launch donates them);
+        None otherwise."""
+        if self._launches is None or name in self._scopes \
+                or not self._progs.trace_scopes:
+            return None
+        import jax
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+
+    def _note_scopes(self, name, fn, avals):
+        """Once a paged program: which named scope each instruction of
+        the compiled program lies under, read from the text of the
+        executable its first launch built: lowering the same shapes
+        again finds that executable in the jit's own cache and compiles
+        nothing (``tests/test_glm_moe_dsa.py`` counts the backend's
+        compiles round this call). Profile mode only, and a failure
+        raises: a table that silently came out empty would leave every
+        reader of it with nothing to read."""
+        if avals is None:
+            return
+        from ..observability.profiling import scope_map
+        fn = getattr(fn, "__wrapped__", fn)     # under CompileTracker.wrap
+        self._scopes[name] = scope_map(fn.lower(*avals).compile().as_text(),
+                                       self._progs.trace_scopes)
 
     def _launch_entries(self):
-        """``[t, kind, units, rows, tokens, *device counters]`` a
-        launch, oldest first; the counters run on from launch to launch
-        (int32, may wrap)."""
+        """``[t, kind, units, rows, tokens, *device counters, *host
+        counters]`` a launch, oldest first; the counters run on from
+        launch to launch (the device's are int32 and may wrap)."""
         import jax
         log = list(self._launches.copy())
         counts = jax.device_get([e[5] for e in log]) if self._c_device \
             else [()] * len(log)
-        return [[*e[:5], *(int(v) for v in c)]
+        return [[*e[:5], *(int(v) for v in c), *e[6]]
                 for e, c in zip(log, counts)]
 
     def _drain_scale_resets(self):
@@ -1071,8 +1108,12 @@ class DecodeEngine:
         self._sync_device_counters()
         for name, c in zip(self._progs.device_counters, self._c_device):
             s[name] = int(c.value)
+        for name, (_, c) in zip(self._progs.host_counters, self._c_host):
+            s[name] = int(c.value)
         if self._launches is not None:
             s["launches"] = self._launch_entries()
+            if self._scopes:
+                s["scopes"] = dict(self._scopes)
         if self._progs.chunks_per_block:    # a recurrent family's own
             s["ssm_row_steps"] = int(self._c_ssm_row_steps.value)
             s["ssm_prefill_chunks"] = int(self._c_ssm_prefill_chunks.value)
@@ -1633,9 +1674,10 @@ class DecodeEngine:
                 where = (table_row,)
                 if self._state_specs:
                     where += (_np.int32(slot),)
-                first, *pool = self._prefill(
-                    st, embed, fnorm, lm, self._scales, ids,
-                    _np.array([pad], _np.int32), *where, *self._pool())
+                args = (st, embed, fnorm, lm, self._scales, ids,
+                        _np.array([pad], _np.int32), *where, *self._pool())
+                avals = self._scope_avals("jit_prefill_paged", args)
+                first, *pool = self._prefill(*args)
                 self._set_pool(pool)
                 self._hand_on(slot, first, known)
                 if self._state_specs:
@@ -1646,6 +1688,7 @@ class DecodeEngine:
                                      worker=self.worker_id):
                         self._state_slots.add(slot)
             self._note_launch(t0, launch)
+            self._note_scopes("jit_prefill_paged", self._prefill, avals)
             self._c_device_calls.inc()
             self._c_prefill_blocks.inc(blocks)
             self._c_ssm_prefill_chunks.inc(
@@ -2075,6 +2118,10 @@ class DecodeEngine:
                 + n_busy * self.chunk * (self.chunk - 1) // 2
             launch = self._launch_args("decode", self.chunk, n_busy,
                                        ctx_tokens)
+            if self._c_host:    # [steps, live rows], before the host
+                ctx_rows = (    # goes on writing ``_lens``
+                    self._lens[self._lens > 0].astype(_np.int64)[None, :]
+                    + _np.arange(self.chunk)[:, None])
         t0 = _now()
         with RecordEvent("engine.decode_chunk", "engine", worker=self.worker_id):
             with _phase(self.profile, "launch", launch):
@@ -2082,10 +2129,11 @@ class DecodeEngine:
                     self._c_launch_ahead.inc()
                 # copies: the host goes on writing both while the
                 # launch is in flight
-                toks, *pool = self._decode(
-                    st, embed, fnorm, lm, self._scales, self._tok,
-                    self._tables.copy(), self._lens.copy(),
-                    *self._pool())
+                args = (st, embed, fnorm, lm, self._scales, self._tok,
+                        self._tables.copy(), self._lens.copy(),
+                        *self._pool())
+                avals = self._scope_avals("jit_decode_chunk_paged", args)
+                toks, *pool = self._decode(*args)
                 self._set_pool(pool)
                 toks.copy_to_host_async()
                 self._tok = self._tok_last(toks)
@@ -2103,7 +2151,10 @@ class DecodeEngine:
             self._g_occupancy.set(n_busy)
             self._c_decode_row_steps.inc(self.chunk * n_busy)
             self._c_decode_ctx.inc(ctx_tokens)
+            for fn, c in self._c_host:
+                c.inc(fn(ctx_rows))
             self._note_launch(t0, launch)
+            self._note_scopes("jit_decode_chunk_paged", self._decode, avals)
             if self._progs.chunks_per_block:
                 self._c_ssm_row_steps.inc(self.chunk * n_busy)
             log_event("engine_chunk", steps=self.chunk, rows=n_busy,

@@ -1,0 +1,722 @@
+"""GLM-5 family (``model_type: glm_moe_dsa``): a decoder whose attention
+keeps ONE low-rank latent a token and layer for all its heads (multi-head
+latent attention) and reads, at every position, only the ``index_topk``
+earlier tokens that a second, small attention (the indexer) scores
+highest; the first ``first_k_dense_replace`` layers are dense SwiGLU, the
+others routed experts (sigmoid scores with a selection bias, top-k,
+weights renormalised over the chosen and scaled by
+``routed_scaling_factor``) beside one shared expert. Serving only,
+through the paged ``DecodeEngine``.
+
+With ``n = RMSNorm(h)`` at a layer's entry (no biases anywhere but the
+indexer key's LayerNorm):
+
+    query    c_q = RMSNorm(n W_dq) [q_lora_rank]; q = c_q W_uq -> H heads
+             of q_nope [qk_nope_head_dim] | q_rope [qk_rope_head_dim]
+    latent   [c_kv | k_r] = n W_dkv [kv_lora_rank | qk_rope_head_dim];
+             c_kv = RMSNorm(c_kv); k_r is one rotary key for all heads;
+             [k_nope | v] = c_kv W_ukv -> H heads of qk_nope_head_dim |
+             v_head_dim
+    rope     theta ``rope_theta``, interleaved pairs (x0,x1),(x2,x3),..,
+             on q_rope and k_r
+    scores   s[t,u] = (q_nope[t].k_nope[u] + q_rope[t].k_r[u])
+             / sqrt(qk_nope_head_dim + qk_rope_head_dim); softmax over the
+             ALLOWED u; o = sum p v; out = concat(o) W_o
+    indexer  qI = c_q W_qI -> index_n_heads heads of index_head_dim;
+             kI = LayerNorm(n W_kI) [index_head_dim, gain and bias]; rope
+             (interleaved) on the first qk_rope_head_dim of each;
+             w = n W_w [index_n_heads] x index_n_heads^-0.5 x
+             index_head_dim^-0.5; I[t,u] = sum_j w[t,j] relu(qI[t,j].kI[u])
+             for u <= t; the allowed set of t is the index_topk largest
+             I[t,.] (every u <= t while t < index_topk)
+    experts  y = shared(n') + routed_scaling_factor x sum over the chosen
+             of g_e expert_e(n'); scores = sigmoid(n' W_r) in float32,
+             top-k of scores + e_score_correction_bias, g the scores
+             renormalised over the chosen
+
+(the indexer is the published DeepSeek-V3.2-Exp lightning indexer, which
+``glm_moe_dsa`` names by its ``index_*`` keys; its Hadamard rotation of
+qI and kI is orthogonal and drops out of the product; its FP8 cache of
+kI is not modelled: bfloat16). The multi-token-prediction layer adds no
+term to the next token's logits and is not held.
+
+What the program computes is the ABSORBED form of the same scores:
+``q_nope[t].k_nope[u] = (q_nope[t] W_uk^T).c_kv[u]`` and ``sum p v =
+(sum p c_kv) W_uv`` a head, so that a query meets the cached latent as it
+lies, 64 heads against one ``kv_lora_rank + qk_rope_head_dim`` wide key
+and one ``kv_lora_rank`` wide value a token. The model therefore holds
+``W_ukv``'s two halves a head as leaves of their own (``w_uk`` [H, nope,
+rank], ``w_uv`` [H, rank, v]).
+
+What a slot of the engine holds are two caches of different kind under
+the engine's ONE block table, neither of them per-head keys and values:
+the engine's key pool carries the **latent page** (one "head" of
+``[c_kv | k_r]`` padded to whole lane tiles, ``latent_lanes``) and its
+value pool the **indexer's key page** (one "head" of
+``index_head_dim``). There is no per-slot state besides the device
+counters' vector, so allocation, eviction and preemption are the
+allocator's.
+
+A chip may hold a SHARE of the experts (``held_experts = (first,
+count)``), as ``models/mimo_v2.py`` does: the held experts of all expert
+layers lie in ONE stack, the router keeps all its outputs, and what the
+absent experts would add is left out; the shared expert is computed
+whole.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import nn
+from ..distributed.fleet.moe import (moe_dropless_ffn, moe_full_stream,
+                                     moe_route_held)
+from .llama import PagedPrograms, _rms, _row_pages, _token_insert
+
+__all__ = ["GlmMoeDsaConfig", "GlmMoeDsaForCausalLM", "GLM_MOE_DSA_PRESETS"]
+
+_HI = jax.lax.Precision.HIGHEST
+_NEG = -1e30
+_LANES = 128
+SCORE_KEYS = 4096       # keys a piece of the indexer's scores takes at most
+ATTEND_KEYS = 2048      # keys a piece of the causal pass takes at most
+
+
+@dataclass
+class GlmMoeDsaConfig:
+    vocab_size: int = 154880
+    hidden_size: int = 6144
+    intermediate_size: int = 12288          # a dense layer's SwiGLU
+    moe_intermediate_size: int = 2048       # one expert's, routed or shared
+    num_hidden_layers: int = 78
+    first_k_dense_replace: int = 3
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 64           # the expanded form's; unused
+    q_lora_rank: int = 2048
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    n_routed_experts: int = 256             # the router's outputs
+    held_experts: tuple = None              # (first, count) held here
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.5
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
+    n_group: int = 1
+    topk_group: int = 1
+    rope_theta: float = 1e6
+    rope_type: str = "default"
+    rms_norm_eps: float = 1e-5
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        if self.held_experts is None:
+            self.held_experts = (0, self.n_routed_experts)
+        first, count = self.held_experts = tuple(self.held_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_routed_experts):
+            raise ValueError(f"held_experts={self.held_experts!r} is no "
+                             f"share of {self.n_routed_experts} experts")
+        # keys the published glm_moe_dsa configurations give one value
+        for name, value in (("n_group", 1), ("topk_group", 1),
+                            ("scoring_func", "sigmoid"),
+                            ("rope_type", "default"),
+                            ("norm_topk_prob", True)):
+            if getattr(self, name) != value:
+                raise ValueError(
+                    f"GlmMoeDsa supports {name}={value!r} alone (the "
+                    f"published glm_moe_dsa configurations), got "
+                    f"{getattr(self, name)!r}")
+        if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError(
+                f"first_k_dense_replace={self.first_k_dense_replace} of "
+                f"{self.num_hidden_layers} layers")
+        if self.qk_rope_head_dim % 2 \
+                or self.qk_rope_head_dim > self.index_head_dim:
+            raise ValueError(
+                f"rotary width {self.qk_rope_head_dim} of index_head_dim "
+                f"{self.index_head_dim}")
+
+    @property
+    def latent_dim(self):
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self):
+        """The width of a token's latent in the page pool: ``[c_kv |
+        k_r]`` rounded up to whole lane tiles, zero past it (which adds
+        nothing to a score)."""
+        return -(-self.latent_dim // _LANES) * _LANES
+
+    @property
+    def n_moe(self):
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    def runs(self):
+        """The stack as runs of like layers: (ffn kind, first layer,
+        length); a layer's index within its kind is its distance from
+        the run's first."""
+        k, n = self.first_k_dense_replace, self.num_hidden_layers
+        return [r for r in (("dense", 0, k), ("moe", k, n - k)) if r[2]]
+
+
+GLM_MOE_DSA_PRESETS = {
+    # one dense layer and four expert layers at debug widths: 16 experts
+    # of which 4 are held, the top 8 of the indexer's scores
+    "debug": dict(vocab_size=256, hidden_size=64, intermediate_size=128,
+                  moe_intermediate_size=32, num_hidden_layers=5,
+                  first_k_dense_replace=1, num_attention_heads=4,
+                  q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=24,
+                  qk_rope_head_dim=8, v_head_dim=16, index_n_heads=2,
+                  index_head_dim=16, index_topk=8, n_routed_experts=16,
+                  held_experts=(0, 4), num_experts_per_tok=2,
+                  n_shared_experts=1),
+}
+
+_ATTN = ("input_ln", "post_ln", "w_dq", "q_ln", "w_uq", "w_dkv", "kv_ln",
+         "w_uk", "w_uv", "wo", "w_qi", "w_ki", "ki_ln_g", "ki_ln_b", "w_wi")
+_FFN = {"dense": ("w_gate", "w_up", "w_down"),
+        "moe": ("router", "router_bias", "ws_gate", "ws_up", "ws_down")}
+_EXPERTS = ("we_gate", "we_up", "we_down")      # never indexed by layer
+
+
+def _layer_params(w, f_kind, l, f):
+    """Layer ``l``'s leaves under their names: the attention's and the
+    norms' at ``l``, the ffn's at index ``f`` of its kind (both data)."""
+    lp = {n: w[n][l] for n in _ATTN}
+    lp.update({n: w[n][f] for n in _FFN[f_kind]})
+    return lp
+
+
+def _rope_pairs(x, positions, theta):
+    """x [n, .., r] with every dimension turned: interleaved pairs
+    (x0,x1),(x2,x3),.. at ``positions`` [n], float32 angles."""
+    r = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    ang = positions.astype(jnp.float32)[:, None] * freqs      # [n, r/2]
+    ang = ang.reshape(ang.shape[0], *(1,) * (x.ndim - 2), r // 2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], r // 2, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _layer_norm(x, g, b, eps):
+    xf = x.astype(jnp.float32)
+    mu = xf.mean(-1, keepdims=True)
+    var = ((xf - mu) ** 2).mean(-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def _project(cfg, lp, x, positions):
+    """One layer's attention inputs for rows x [n, d] at ``positions``
+    [n]: the absorbed query qc [n, H, lanes] (``q_nope W_uk^T | q_rope``,
+    zero past ``latent_dim``), the latent lat [n, lanes] (``RMSNorm(c_kv)
+    | k_r``, zero past it), the indexer's queries qi [n, Hi, di], key ki
+    [n, di] and head weights wi [n, Hi] float32. The barriers keep a
+    projection a plain [n, d] x [d, columns] product (models/mimo_v2.py,
+    ``_qkv``)."""
+    eps, n = cfg.rms_norm_eps, x.shape[0]
+    H, rope, rank = (cfg.num_attention_heads, cfg.qk_rope_head_dim,
+                     cfg.kv_lora_rank)
+    h = _rms(x, lp["input_ln"], eps)
+    plain = lambda w, y=h: jax.lax.optimization_barrier(y @ w)
+    cq = _rms(plain(lp["w_dq"]), lp["q_ln"], eps)
+    q = plain(lp["w_uq"], cq).reshape(n, H, -1)
+    q_nope = q[..., :cfg.qk_nope_head_dim]
+    q_rope = _rope_pairs(q[..., cfg.qk_nope_head_dim:], positions,
+                         cfg.rope_theta)
+    q_abs = jnp.einsum("nhk,hkr->nhr", q_nope, lp["w_uk"])
+    widen = lambda t: jnp.pad(t, ((0, 0),) * (t.ndim - 1)
+                              + ((0, cfg.latent_lanes - cfg.latent_dim),))
+    qc = widen(jnp.concatenate([q_abs.astype(x.dtype), q_rope], axis=-1))
+    ckv = plain(lp["w_dkv"])
+    lat = widen(jnp.concatenate(
+        [_rms(ckv[:, :rank], lp["kv_ln"], eps),
+         _rope_pairs(ckv[:, rank:], positions, cfg.rope_theta)], axis=-1))
+
+    def turn_first(t):
+        return jnp.concatenate(
+            [_rope_pairs(t[..., :rope], positions, cfg.rope_theta),
+             t[..., rope:]], axis=-1)
+
+    qi = turn_first(plain(lp["w_qi"], cq).reshape(n, cfg.index_n_heads, -1))
+    ki = turn_first(_layer_norm(plain(lp["w_ki"]), lp["ki_ln_g"],
+                                lp["ki_ln_b"], 1e-6))
+    wi = plain(lp["w_wi"]).astype(jnp.float32) * (
+        cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+    return qc, lat, qi, ki, wi
+
+
+def _index_scores(qi, wi, keys):
+    """I[t, u] = sum_j w[t,j] relu(qI[t,j] . kI[u]), float32: qi
+    [.., Hi, di], wi [.., Hi], keys [.., u, di] -> [.., u] (``..`` the
+    same leading axes, or none on the keys)."""
+    s = jnp.einsum("nhd,ud->nhu" if keys.ndim == 2 else "nhd,nud->nhu",
+                   qi, keys, preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * wi[..., None]).sum(axis=-2)
+
+
+def _out_proj(cfg, lp, o_lat):
+    """o_lat [n, H, rank] float32, the probabilities' sum of latents a
+    head -> the layer's attention output [n, d]."""
+    o = jnp.einsum("nhr,hrv->nhv", o_lat.astype(lp["w_uv"].dtype),
+                   lp["w_uv"])
+    return o.reshape(o.shape[0], -1) @ lp["wo"]
+
+
+def _sparse_attend(cfg, qc, sel, ok):
+    """qc [n, H, lanes] against each row's own chosen latents sel
+    [n, k, lanes] where ``ok`` [n, k]: the probabilities' sum of the
+    latents' first ``kv_lora_rank`` values, float32 [n, H, rank]."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+    s = jnp.einsum("nhc,nkc->nhk", qc, sel,
+                   preferred_element_type=jnp.float32) / scale
+    s = jnp.where(ok[:, None, :], s, _NEG)
+    p = jnp.where(ok[:, None, :],
+                  jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("nhk,nkr->nhr", p.astype(sel.dtype),
+                      sel[..., :cfg.kv_lora_rank],
+                      preferred_element_type=jnp.float32)
+
+
+def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
+    """x + ffn(rms(x)); an expert layer adds the shared expert, computed
+    whole, to the held experts' part of the routed sum. ``rows`` [n]
+    marks real tokens; ``counts`` int32 [3] gains (pairs computed, held
+    experts visited, 1 if the expert products took the whole stream)."""
+    y = _rms(x, lp["post_ln"], cfg.rms_norm_eps)
+    if f_kind == "dense":
+        return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
+            @ lp["w_down"], counts
+    with jax.named_scope("moe_shared_ffn"):
+        shared = (jax.nn.silu(y @ lp["ws_gate"]) * (y @ lp["ws_up"])) \
+            @ lp["ws_down"]
+    logits = jnp.dot(y.astype(jnp.float32), lp["router"], precision=_HI)
+    topi, gates, order, sizes, stream_rows = moe_route_held(
+        logits, cfg.num_experts_per_tok, cfg.held_experts,
+        scoring=cfg.scoring_func, bias=lp["router_bias"], rows=rows)
+    # this layer's experts by their place in the one stack of all layers'
+    held = cfg.held_experts[1]
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((w["we_gate"].shape[0],), jnp.int32), sizes, (f * held,))
+    with jax.named_scope("moe_expert_ffn"):
+        out = moe_dropless_ffn(y, topi, gates * cfg.routed_scaling_factor,
+                               order, groups, w["we_gate"], w["we_up"],
+                               w["we_down"],
+                               precision=jax.lax.Precision.DEFAULT,
+                               stream_rows=stream_rows)
+    whole = moe_full_stream(sizes, order.shape[0], stream_rows)
+    counts = counts + jnp.stack([sizes.sum(), (sizes > 0).sum(),
+                                 jnp.asarray(whole, jnp.int32)])
+    return x + shared + out.astype(x.dtype), counts
+
+
+def _logits(cfg, x, final_norm, lm_head):
+    x = _rms(x, final_norm, cfg.rms_norm_eps)
+    return (x @ lm_head).astype(jnp.float32)
+
+
+def _key_chunk(n_blocks, block, at_most):
+    """Keys a piece of a prefill block's loops over the row's earlier
+    tokens takes: whole blocks, as many as divide the window and hold at
+    most ``at_most`` keys."""
+    return block * max(m for m in range(1, n_blocks + 1)
+                       if n_blocks % m == 0 and (m == 1
+                                                 or m * block <= at_most))
+
+
+def _sortable(scores):
+    """float32 scores as uint32 keys of the same order (-inf lowest)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(key, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+def _chosen_mask(scores, k):
+    """[n, t] bool: each row's ``k`` largest scores, of equal scores the
+    earliest (what ``lax.top_k`` keeps), found without a sort: the
+    ``k``-th largest key bit by bit, 32 counting passes over the scores,
+    then the ties at that key in order (a pass of its own, taken only
+    when some row has more of them than it has room for)."""
+    u = _sortable(scores)
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where((u >= cand[:, None]).sum(axis=1) >= k, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros((u.shape[0],), jnp.uint32))[:, None]
+    above, tie = u > kth, u == kth
+    room = k - above.sum(axis=1, keepdims=True)
+    return jax.lax.cond(
+        (tie.sum(axis=1, keepdims=True) > room).any(),
+        lambda: above | (tie & (jnp.cumsum(tie, axis=1) <= room)),
+        lambda: above | tie)
+
+
+def _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad, first):
+    """One layer's attention for one block of a cold prefill: queries at
+    columns ``start..`` of the window (column ``pad`` holds position 0)
+    against the row's latents lat_c [L, total, lanes] and indexer keys
+    ki_c [L, total, di] of layer ``l``, the block's own among them.
+    Two ways, by where the block's last row lies. Under ``index_topk``
+    every earlier token is allowed: nothing is scored, the pass is
+    causal over the row so far. From there on the indexer scores the row
+    so far, each query keeps its ``index_topk`` best (a query with fewer
+    earlier tokens keeps them all), and the kept set is a MASK over the
+    causal pass, whose work still follows the context: on a v5e the
+    compiler's gather of a query's chosen latents costs more than the
+    pass over the whole row under 98 k of context, past every window
+    the engine serves (PERF.md, Findings PR 42). Returns the
+    probabilities' sum of latents [blk, H, rank] float32."""
+    blk, H = qc.shape[0], qc.shape[1]
+    total, lanes = lat_c.shape[1:]
+    rank = cfg.kv_lora_rank
+    k_top = min(cfg.index_topk, total)
+    qcol = start + jnp.arange(blk)
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+
+    def pieces(at_most):
+        """(keys a piece, first piece, one past the last) of a loop over
+        the row's tokens from its first to the block's own."""
+        kc = _key_chunk(total // blk, blk, at_most)
+        return kc, first * blk // kc, (start + blk - 1) // kc + 1
+
+    def seen(at, n):
+        """[blk, n]: the keys at columns ``at..`` a query may see."""
+        kcol = at + jnp.arange(n)
+        return (kcol[None, :] <= qcol[:, None]) & (kcol[None, :] >= pad)
+
+    def scores():
+        """The indexer's scores of the block's queries against the row
+        so far, [blk, total] float32, a piece of keys at a time; -inf
+        where a query may not look."""
+        kc, lo, hi = pieces(SCORE_KEYS)
+
+        def piece(j, buf):
+            keys = jax.lax.dynamic_slice(
+                ki_c, (l, j * kc, 0), (1, kc, ki_c.shape[-1]))[0]
+            return jax.lax.dynamic_update_slice(
+                buf, _index_scores(qi, wi, keys), (0, j * kc))
+
+        with jax.named_scope("dsa_index_scores"):
+            buf = jax.lax.fori_loop(
+                lo, hi, piece, jnp.full((blk, total), -jnp.inf, jnp.float32))
+            return jnp.where(seen(0, total), buf, -jnp.inf)
+
+    def causal(allowed=None):
+        """Online softmax over the row's latents, a piece of keys at a
+        time; ``allowed`` [blk, total] narrows what a query sees."""
+        kc, lo, hi = pieces(ATTEND_KEYS)
+
+        def fold(j, state):
+            m, den, acc = state
+            at = j * kc
+            keys = jax.lax.dynamic_slice(lat_c, (l, at, 0),
+                                         (1, kc, lanes))[0]
+            ok = seen(at, kc)
+            if allowed is not None:
+                ok &= jax.lax.dynamic_slice(allowed, (0, at), (blk, kc))
+            s = jnp.einsum("nhc,uc->hnu", qc, keys,
+                           preferred_element_type=jnp.float32)
+            s = jnp.where(ok[None], s / scale, _NEG)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            pv = jnp.einsum("hnu,ur->hnr", p.astype(keys.dtype),
+                            keys[:, :rank],
+                            preferred_element_type=jnp.float32)
+            return (m_new, alpha * den + p.sum(axis=-1),
+                    alpha[..., None] * acc + pv)
+
+        with jax.named_scope("mla_prefill_attn"):
+            _, den, acc = jax.lax.fori_loop(
+                lo, hi, fold,
+                (jnp.full((H, blk), _NEG, jnp.float32),
+                 jnp.zeros((H, blk), jnp.float32),
+                 jnp.zeros((H, blk, rank), jnp.float32)))
+            return jnp.swapaxes(
+                acc / jnp.maximum(den, 1e-30)[..., None], 0, 1)
+
+    def masked():
+        sc = scores()
+        with jax.named_scope("dsa_topk"):
+            allowed = _chosen_mask(sc, k_top)
+        return causal(allowed)
+
+    last = start + blk - 1 - pad            # the last row's position
+    return jax.lax.cond(last >= cfg.index_topk, masked, causal)
+
+
+def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
+             pool, block):
+    """The cold prefill of ONE right-aligned row (ids [1, s], pad_len
+    [1]): the window is walked in blocks of ``block`` rows from the block
+    of the first token (the trip count is data). The row's latents and
+    indexer keys lie in a contiguous carry, every layer's, which a block
+    reads from the row's first token to its own rows; at the window's
+    end they are written page by page through ``table_row``, whole
+    pages, both pools under the one table. Returns (float32 logits
+    [1, V] of the last token, pool)."""
+    kp, vp, counts = pool
+    s = ids.shape[1]
+    block = min(block, s)
+    n_blocks = -(-s // block)
+    total = n_blocks * block
+    shift = total - s
+    ids = jnp.pad(ids[0], (shift, 0))
+    pad = pad_len[0] + shift
+    first = pad // block
+    dtype = embed.dtype
+    L = cfg.num_hidden_layers
+
+    def run_block(i, carry):
+        lat_c, ki_c, counts, _ = carry
+        start = i * block
+        cols = start + jnp.arange(block)
+        rows = cols >= pad
+        positions = jnp.maximum(cols - pad, 0)
+        x = jnp.take(embed, jax.lax.dynamic_slice_in_dim(ids, start, block),
+                     axis=0)
+        for f_kind, l0, n in cfg.runs():
+            def layer(carry, j, f_kind=f_kind, l0=l0):
+                x, lat_c, ki_c, counts = carry
+                l = l0 + j
+                lp = _layer_params(w, f_kind, l, j)
+                qc, lat, qi, ki, wi = _project(cfg, lp, x, positions)
+                lat_c = jax.lax.dynamic_update_slice(
+                    lat_c, lat[None], (l, start, 0))
+                ki_c = jax.lax.dynamic_update_slice(
+                    ki_c, ki[None], (l, start, 0))
+                o_lat = _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c,
+                                         l, start, pad, first)
+                x, counts = _ffn(cfg, w, lp, f_kind, j,
+                                 x + _out_proj(cfg, lp, o_lat), rows, counts)
+                return (x, lat_c, ki_c, counts), None
+
+            (x, lat_c, ki_c, counts), _ = jax.lax.scan(
+                layer, (x, lat_c, ki_c, counts),
+                jnp.arange(n, dtype=jnp.int32))
+        return lat_c, ki_c, counts, x[-1:]
+
+    lat_c, ki_c, counts, last = jax.lax.fori_loop(
+        first, n_blocks, run_block,
+        (jnp.zeros((L, total, cfg.latent_lanes), dtype),
+         jnp.zeros((L, total, cfg.index_head_dim), dtype),
+         counts, jnp.zeros((1, embed.shape[1]), dtype)))
+    logits = _logits(cfg, last, final_norm, lm_head)
+    mb, bs = table_row.shape[0], kp.shape[-2]
+    kp = kp.at[:, table_row].set(_row_pages(lat_c[:, :, None], pad, mb, bs))
+    vp = vp.at[:, table_row].set(_row_pages(ki_c[:, :, None], pad, mb, bs))
+    return logits, (kp, vp, counts)
+
+
+def _decode_attention(cfg, lp, x, l, kp, vp, tables, lens):
+    """One layer's attention for one token per slot at position ``lens``
+    [b]: the token's latent and indexer key go into the row's pages at
+    ``[layer, page]``; the indexer scores the row's indexer pages, the
+    ``index_topk`` best are kept, and the absorbed query reads the
+    chosen latents alone, token by token through the table."""
+    b = x.shape[0]
+    n_layers, n_pages, _, bs, lanes = kp.shape
+    s = tables.shape[1] * bs
+    k_top = min(cfg.index_topk, s)
+    qc, lat, qi, ki, wi = _project(cfg, lp, x, lens)
+    page = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]
+    off = lens % bs
+    kp = _token_insert(kp, l, page, off, lat[:, None])
+    vp = _token_insert(vp, l, page, off, ki[:, None])
+    with jax.named_scope("dsa_index_scores"):
+        keys = jnp.take(vp.reshape(n_layers * n_pages, bs, vp.shape[-1]),
+                        l * n_pages + tables, axis=0)    # [b, mb, bs, di]
+        sc = _index_scores(qi, wi, keys.reshape(b, s, -1))
+        sc = jnp.where(jnp.arange(s)[None, :] <= lens[:, None], sc,
+                       -jnp.inf)
+    with jax.named_scope("dsa_topk"):
+        vals, idx = jax.lax.top_k(sc, k_top)
+    with jax.named_scope("mla_sparse_decode"):
+        at = jnp.take_along_axis(tables, idx // bs, axis=1)
+        sel = jnp.take(kp.reshape(n_layers * n_pages * bs, lanes),
+                       (l * n_pages + at) * bs + idx % bs, axis=0)
+        o_lat = _sparse_attend(cfg, qc, sel, vals > -jnp.inf)
+    return _out_proj(cfg, lp, o_lat), kp, vp
+
+
+def _decode_step(cfg, w, embed, final_norm, lm_head, tok, tables, lens,
+                 pool, live):
+    """One token per slot through the whole stack: tok [b] -> (float32
+    logits [b, V], pool); pool = (latent pages, indexer pages,
+    counters)."""
+    x = jnp.take(embed, tok, axis=0)
+    for f_kind, l0, n in cfg.runs():
+        def layer(carry, j, f_kind=f_kind, l0=l0):
+            x, (kp, vp, counts) = carry
+            lp = _layer_params(w, f_kind, l0 + j, j)
+            o, kp, vp = _decode_attention(cfg, lp, x, l0 + j, kp, vp,
+                                          tables, lens)
+            x, counts = _ffn(cfg, w, lp, f_kind, j, x + o, live, counts)
+            return (x, (kp, vp, counts)), None
+
+        (x, pool), _ = jax.lax.scan(layer, (x, tuple(pool)),
+                                    jnp.arange(n, dtype=jnp.int32))
+    return _logits(cfg, x, final_norm, lm_head), pool
+
+
+def leaf_shapes(cfg):
+    """name -> (shape, kind of leaf) of every parameter. The router and
+    its selection bias are float32 whatever the model's dtype: a near-tie
+    between two experts' scores is settled in the precision the scores
+    are stated in."""
+    d, ff, fe = (cfg.hidden_size, cfg.intermediate_size,
+                 cfg.moe_intermediate_size)
+    H, qr, rank = cfg.num_attention_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, hdv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                       cfg.v_head_dim)
+    Hi, di = cfg.index_n_heads, cfg.index_head_dim
+    L, E = cfg.num_hidden_layers, cfg.n_routed_experts
+    nd, ne = cfg.first_k_dense_replace, cfg.n_moe
+    fs = fe * cfg.n_shared_experts
+    held = ne * cfg.held_experts[1]
+    return {"embed_tokens": ((cfg.vocab_size, d), "matrix"),
+            "input_ln": ((L, d), "one"), "post_ln": ((L, d), "one"),
+            "w_dq": ((L, d, qr), "matrix"), "q_ln": ((L, qr), "one"),
+            "w_uq": ((L, qr, H * (nope + rope)), "matrix"),
+            "w_dkv": ((L, d, rank + rope), "matrix"),
+            "kv_ln": ((L, rank), "one"),
+            "w_uk": ((L, H, nope, rank), "matrix"),
+            "w_uv": ((L, H, rank, hdv), "matrix"),
+            "wo": ((L, H * hdv, d), "matrix"),
+            "w_qi": ((L, qr, Hi * di), "matrix"),
+            "w_ki": ((L, d, di), "matrix"),
+            "ki_ln_g": ((L, di), "one"), "ki_ln_b": ((L, di), "zero"),
+            "w_wi": ((L, d, Hi), "matrix"),
+            "w_gate": ((nd, d, ff), "matrix"),
+            "w_up": ((nd, d, ff), "matrix"),
+            "w_down": ((nd, ff, d), "matrix"),
+            "router": ((ne, d, E), "router"),
+            "router_bias": ((ne, E), "bias"),
+            "ws_gate": ((ne, d, fs), "matrix"),
+            "ws_up": ((ne, d, fs), "matrix"),
+            "ws_down": ((ne, fs, d), "matrix"),
+            "we_gate": ((held, d, fe), "matrix"),
+            "we_up": ((held, d, fe), "matrix"),
+            "we_down": ((held, fe, d), "matrix"),
+            "final_norm": ((d,), "one"),
+            "lm_head": ((d, cfg.vocab_size), "matrix")}
+
+
+def dsa_tokens(layers, index_topk):
+    """What the engine counts on the host at every decode launch
+    (``PagedPrograms.host_counters``), from the contexts of the live
+    rows at each of the launch's steps (int64 [steps, rows], as
+    ``engine_decode_ctx_tokens_total`` counts them): the tokens the
+    indexer scores and the tokens the attention then reads, a row, layer
+    and step."""
+    return {"dsa_scored_tokens": lambda ctx: layers * int(ctx.sum()),
+            "dsa_selected_tokens":
+                lambda ctx: layers * int(np.minimum(ctx, index_topk).sum())}
+
+
+class GlmMoeDsaForCausalLM(nn.Layer):
+    """Stacked-parameter GLM-5: the attention's, the indexer's and the
+    norms' leaves stacked over all layers, the dense SwiGLU, the routers
+    and the shared experts over the layers of their kind, and the held
+    experts of every expert layer in one stack ``[expert layers * held,
+    ...]``."""
+
+    def __init__(self, config: GlmMoeDsaConfig | str = "debug"):
+        super().__init__()
+        if isinstance(config, str):
+            config = GlmMoeDsaConfig(**GLM_MOE_DSA_PRESETS[config])
+        self.config = cfg = config
+        from ..nn import initializer as I
+        inits = {"matrix": I.Normal(0.0, 0.02), "one": I.Constant(1.0),
+                 "zero": I.Constant(0.0), "router": I.Normal(0.0, 0.1),
+                 "bias": I.Uniform(-0.05, 0.05)}
+        for name, (shape, how) in leaf_shapes(cfg).items():
+            p = self.create_parameter(shape=list(shape),
+                                      default_initializer=inits[how])
+            if cfg.dtype != "float32" and how in ("matrix", "one", "zero"):
+                p._in_place_update(p._value.astype(cfg.dtype))
+            self.add_parameter(name, p)
+
+    def _stacked_names(self):
+        return [*_ATTN, *_FFN["dense"], *_FFN["moe"], *_EXPERTS]
+
+    def forward(self, input_ids):
+        raise NotImplementedError(
+            "GlmMoeDsaForCausalLM is served through DecodeEngine "
+            "(paged_programs); it has no cache-free forward")
+
+    def paged_programs(self, chunk, prefill_block, mp_axis=None,
+                       seq_axis=None, n_seq=1):
+        """What ``DecodeEngine`` binds for this family."""
+        cfg = self.config
+
+        def prefill_paged(stacked, embed, fnorm, lm, scales, ids, pad_len,
+                          table_row, slot, *pool):
+            """ids [1, s_max] right-aligned; the row's latents and
+            indexer keys go into its pages inside the program (``slot``
+            names no state: the family keeps none)."""
+            logits, pool = _prefill(cfg, stacked, embed, fnorm, lm, ids,
+                                    pad_len, table_row, pool, prefill_block)
+            return (jnp.argmax(logits, axis=-1), *pool)
+
+        def decode_chunk_paged(stacked, embed, fnorm, lm, scales, tok,
+                               tables, lens, *pool):
+            """One chunk; a slot with ``lens == 0`` holds no row: its
+            tokens are routed to no expert and counted nowhere."""
+            live = lens > 0
+
+            def body(carry, i):
+                tok, pool = carry
+                logits, pool = _decode_step(cfg, stacked, embed, fnorm, lm,
+                                            tok, tables, lens + i, pool,
+                                            live)
+                nxt = jnp.argmax(logits, axis=-1)
+                return (nxt, pool), nxt
+
+            (tok, pool), toks = jax.lax.scan(body, (tok, pool),
+                                             jnp.arange(chunk))
+            return (toks, *pool)
+
+        family = "a program of this family's own (latent and indexer pages)"
+        return PagedPrograms(
+            prefill_paged=prefill_paged,
+            decode_chunk_paged=decode_chunk_paged,
+            kv_layers=cfg.num_hidden_layers, kv_heads=1,
+            head_dim=cfg.latent_lanes, v_head_dim=cfg.index_head_dim,
+            slot_state=lambda slots: (
+                jax.ShapeDtypeStruct((3,), jnp.int32),),
+            device_counters=("moe_pairs", "moe_expert_visits",
+                             "moe_full_stream"),
+            host_counters=dsa_tokens(cfg.num_hidden_layers, cfg.index_topk),
+            trace_scopes=("dsa_index_scores", "dsa_topk", "mla_prefill_attn",
+                          "mla_sparse_decode", "moe_shared_ffn",
+                          "moe_expert_ffn"),
+            unsupported={
+                "prefix_cache": f"a prefix hit needs {family} that starts "
+                                "behind the cached pages",
+                "paged=False": "the latent and the indexer's keys are "
+                               "pages of the pool",
+                "chunked_prefill": f"a prompt's chunks need {family}",
+                "spec_decode": f"verifying a draft needs {family}",
+                "kv_dtype='int8'": "the pools hold a latent and an "
+                                   "indexer key, not per-head keys and "
+                                   "values with a scale a page",
+                "mesh": "the latent is shared by all heads and the held "
+                        "experts have no sharding rule"})

@@ -207,7 +207,18 @@ class PagedPrograms:
     device (what only the device knows: which experts a step's rows
     chose); the engine hands it over like the rest but does not donate
     it, fetches it in ``stats()`` alone and keeps
-    ``engine_<name>_total``."""
+    ``engine_<name>_total``. ``host_counters`` (name -> function) is
+    what the engine counts itself at every decode launch for a family
+    whose decode work is no plain function of the context: each function
+    is handed the contexts of the live rows at each of the launch's
+    steps (int64 [steps, rows]) and gives what ``engine_<name>_total``
+    grows by; the totals ride in each launch's entry behind the device
+    counters. ``trace_scopes`` names the ``jax.named_scope``s of the two
+    programs that a reader of a device trace should be able to find: a
+    trace names an event by its compiled instruction and carries no
+    scope, so with ``profile`` on the engine reads each program's
+    compiled text once and gives ``stats()["scopes"]``: program ->
+    {instruction name: scope}."""
     prefill_paged: object
     decode_chunk_paged: object
     kv_layers: int
@@ -218,6 +229,8 @@ class PagedPrograms:
     unsupported: dict = field(default_factory=dict)
     v_head_dim: int = 0
     device_counters: tuple = ()
+    host_counters: dict = field(default_factory=dict)
+    trace_scopes: tuple = ()
 
 
 def kv_scales_of(pool):

@@ -42,6 +42,7 @@ bit-identical):
 from __future__ import annotations
 
 import logging
+import re
 import threading
 from collections import deque
 
@@ -262,6 +263,37 @@ class StepProfiler:
         return evts
 
 
+_HLO_HEAD = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTR = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def scope_map(hlo_text, scopes):
+    """instruction name -> the innermost of ``scopes`` (names given to
+    ``jax.named_scope``) its ``op_name`` lies under, over the instructions
+    of a compiled program's text that run as operations of their own
+    (those inside a fusion are the fusion's). A device trace names an
+    event ``%<instruction> = ...`` and carries no scope; this is the
+    table by which a reader finds a scope's events."""
+    out, inside_fusion = {}, False
+    for line in hlo_text.splitlines():
+        head = _HLO_HEAD.match(line)
+        if head:
+            inside_fusion = head.group(1).startswith("fused_computation")
+            continue
+        instr = _HLO_INSTR.match(line)
+        if inside_fusion or not instr:
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        if not op_name:
+            continue
+        innermost = next((part for part in reversed(
+            op_name.group(1).split("/")) if part in scopes), None)
+        if innermost is not None:
+            out[instr.group(1)] = innermost
+    return out
+
+
 class CompileTracker:
     """Recompile observatory: wraps compiled-program callables and
     records every first-seen abstract signature as one compilation
@@ -323,6 +355,7 @@ class CompileTracker:
             self.note(program, sig, self._clock() - t0, key=key)
             return out
 
+        wrapped.__wrapped__ = fn        # the jitted program, for its text
         return wrapped
 
     def note(self, program: str, sig, wall_s: float, key=None) -> bool:

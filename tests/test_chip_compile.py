@@ -481,6 +481,80 @@ def _engine_mimo(which, slots=48, s_max=9216, n_pages=27649, block=16):
     return build
 
 
+def _engine_glm(which, slots=8, s_max=50176, n_pages=25089, block=16):
+    """The engine's two programs for GLM-5 at the long_ctx cell's sizes:
+    the published widths, layer 0 dense and 5 expert layers, 16 of the
+    router's 256 experts held, the vocabulary cut (one matmul behind the
+    stack); a latent page of 640 lanes and an indexer-key page of 128
+    under one table. The model is drawn at debug size and handed over as
+    shapes."""
+    def build(place):
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models import glm_moe_dsa as gm
+        paddle.seed(0)
+        with _shapes_only():
+            model = gm.GlmMoeDsaForCausalLM("debug")
+        model.eval()
+        model.config = full = gm.GlmMoeDsaConfig(
+            vocab_size=1024, num_hidden_layers=6, first_k_dense_replace=1,
+            held_experts=(0, 16), dtype="bfloat16")
+        eng = DecodeEngine(model, capacity=slots, s_max=s_max,
+                           block_size=block, n_blocks=n_pages,
+                           prefix_cache=False)
+        shapes = gm.leaf_shapes(full)
+        leaf = lambda n: place(shapes[n][0], BF16 if shapes[n][1] in
+                               ("matrix", "one", "zero") else F32)
+
+        def like(a):
+            return place(a.shape, a.dtype)
+
+        if which == "prefill":
+            fn, data = eng._prefill, [
+                place((1, s_max), I32), place((1,), I32),
+                place((eng._max_blocks,), I32), place((), I32)]
+        else:
+            fn, data = eng._decode, [
+                like(jnp.asarray(a))
+                for a in (eng._tok, eng._tables, eng._lens)]
+        return fn, [{n: leaf(n) for n in model._stacked_names()},
+                    leaf("embed_tokens"), leaf("final_norm"),
+                    leaf("lm_head"), {}, *data, *map(like, eng._pool())]
+
+    def check(compiled):
+        text = compiled.as_text()
+        # the routed experts' products are the grouped ones and nothing
+        # has the shape of the stack of experts or of an [held, rows,
+        # width] buffer
+        stacks = ("bf16[80,6144,2048]", "bf16[80,2048,6144]")
+        for _, shape, op, line in _hlo_instructions(text):
+            assert not any(st in shape for st in stacks), line[:200]
+            if any(st in line for st in stacks):
+                assert op == "custom-call" and "ragged-dot" in line, \
+                    line[:200]
+            assert not re.match(r"\w+\[(16|80),\d+,(2048|6144)\]", shape), \
+                line[:200]
+            # the indexer's [rows, heads, keys] scores come in pieces
+            assert not re.match(rf"\w+\[\d+,32,{s_max}\]", shape), line[:200]
+        assert "ragged-dot-none" in text
+        for wide in (640, 128):     # the latent pages, the indexer's
+            _assert_pools_stay_put(
+                compiled, jax.ShapeDtypeStruct(
+                    (6, n_pages, 1, block, wide), BF16),
+                temp_below=build.temp_below)
+        if which == "prefill":
+            _assert_no_square_scores(compiled, s_max)
+    build.check = check
+    # the custom calls are the grouped expert products; the decode step
+    # reads its pages through the compiler's gathers, no paged kernel
+    build.paged_kernel = False
+    # temporaries: the cold program's carry of one row's latents and
+    # indexer keys (0.46 GB) and a block's scores; the decode step's
+    # gathered indexer pages
+    build.temp_below = (512 << 20) if which == "decode" else (2048 << 20)
+    return build
+
+
 CASES = {
     "paged_decode_bf16_block16": _paged_decode(16, BF16, 4096),
     # the pool an engine could really hold on 16 GB (2 GiB each of K and
@@ -514,6 +588,8 @@ CASES = {
     "engine_decode_chunk_granite_hybrid": _engine_decode_hybrid(),
     "engine_decode_chunk_mimo_v2_long_in_sizes": _engine_mimo("decode"),
     "engine_prefill_paged_mimo_v2_long_in_sizes": _engine_mimo("prefill"),
+    "engine_decode_chunk_glm_moe_dsa_long_ctx_sizes": _engine_glm("decode"),
+    "engine_prefill_paged_glm_moe_dsa_long_ctx_sizes": _engine_glm("prefill"),
 }
 
 
@@ -555,7 +631,8 @@ def test_compiles_for_v5e(name, topo, monkeypatch):
         == getattr(build, "kernel", True)
     # every launch of the decode kernel keeps its page buffers (two slots
     # each of K and V, P pages a slot) inside the budget the file states
-    assert rule.called == ("decode" in name)
+    assert rule.called == ("decode" in name
+                           and getattr(build, "paged_kernel", True))
     for call in rule.call_args_list:
         kvh, bs, hd, dtype, _ = call.args
         pages = pa._pages_per_block(*call.args)
