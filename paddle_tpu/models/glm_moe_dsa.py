@@ -57,6 +57,20 @@ value pool the **indexer's key page** (one "head" of
 counters' vector, so allocation, eviction and preemption are the
 allocator's.
 
+What a decode step pays for follows what its slots hold, which the
+program reads from its own arguments (``lens``, 0 = no row, and the
+table): the live rows are walked one by one and a slot without a row is
+not visited; a live row is scored over the least of a few widths
+that covers its context (``decode_widths``: 4 x ``index_topk`` columns,
+doubled up to the table's length), its ``index_topk`` best are found by
+counting passes and not by a sort (``_row_chosen``: the prefill's
+``_chosen_mask`` for one row, on the chip one launch of
+``kernels/topk_mask.py``), and the absorbed query either passes once
+over the row's own latent pages under that mask or, for a row of
+``GATHER_FROM`` columns or more, reads the chosen latents alone, fetched
+by their columns. The widest way is the same path at the table's
+length.
+
 A chip may hold a SHARE of the experts (``held_experts = (first,
 count)``), as ``models/mimo_v2.py`` does: the held experts of all expert
 layers lie in ONE stack, the router keeps all its outputs, and what the
@@ -66,6 +80,7 @@ whole.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -75,6 +90,7 @@ import numpy as np
 from .. import nn
 from ..distributed.fleet.moe import (moe_dropless_ffn, moe_full_stream,
                                      moe_route_held)
+from ..kernels import topk_mask
 from .llama import PagedPrograms, _rms, _row_pages, _token_insert
 
 __all__ = ["GlmMoeDsaConfig", "GlmMoeDsaForCausalLM", "GLM_MOE_DSA_PRESETS"]
@@ -84,6 +100,10 @@ _NEG = -1e30
 _LANES = 128
 SCORE_KEYS = 4096       # keys a piece of the indexer's scores takes at most
 ATTEND_KEYS = 2048      # keys a piece of the causal pass takes at most
+# columns from which a decode row's chosen latents are fetched by index;
+# a narrower row's latent pages are passed over once under the mask,
+# which costs less than the fetch up to here (PERF.md, Findings PR 43)
+GATHER_FROM = 32768
 
 
 @dataclass
@@ -368,6 +388,41 @@ def _chosen_mask(scores, k):
         lambda: above | tie)
 
 
+def _row_chosen(scores, pos, k):
+    """[1, w] bool: the ``k`` largest of ONE row's scores [1, w] over
+    columns ``0..pos`` ([1]: its last token's), of equal scores the
+    earliest: on the chip one launch of a kernel that keeps the row in
+    fast memory for all its passes, elsewhere ``_chosen_mask``, its
+    oracle."""
+    if topk_mask.kernel_serves():
+        return topk_mask.chosen_mask_pallas(scores, pos, k)
+    seen = jnp.arange(scores.shape[1])[None, :] <= pos[:, None]
+    return seen & _chosen_mask(jnp.where(seen, scores, -jnp.inf), k)
+
+
+def _mask_columns(mask, k):
+    """mask [t] bool with at most ``k`` set -> (their columns in order
+    [k] int32, which of the ``k`` stand for one [k] bool), without a
+    sort or a scatter: by counts over pieces of 128 columns, the piece
+    an entry falls in, then its place within that piece."""
+    piece = _LANES
+    m = jnp.pad(mask, (0, -mask.shape[0] % piece)).reshape(-1, piece)
+    count = m.sum(axis=1, dtype=jnp.int32)
+    start = jnp.cumsum(count) - count
+    nth = jnp.arange(k, dtype=jnp.int32)
+    at = (start[None, :] <= nth[:, None]).sum(axis=1) - 1
+    hot = at[:, None] == jnp.arange(m.shape[0])[None, :]
+    # an entry's piece of the mask and the running count along it, by
+    # two products: 0 / 1 and counts to 128 are exact
+    ones = lambda t: t.astype(jnp.bfloat16)
+    own = jnp.dot(ones(hot), ones(m), preferred_element_type=jnp.float32)
+    upto = jnp.dot(ones(own), ones(jnp.triu(jnp.ones((piece, piece), bool))),
+                   preferred_element_type=jnp.float32)
+    rank = (nth - (hot * start[None, :]).sum(axis=1)).astype(jnp.float32)
+    within = (upto <= rank[:, None]).sum(axis=1)
+    return at * piece + jnp.minimum(within, piece - 1), nth < count.sum()
+
+
 def _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad, first):
     """One layer's attention for one block of a cold prefill: queries at
     columns ``start..`` of the window (column ``pad`` holds position 0)
@@ -525,34 +580,88 @@ def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
     return logits, (kp, vp, counts)
 
 
-def _decode_attention(cfg, lp, x, l, kp, vp, tables, lens):
+def decode_widths(s, index_topk, block):
+    """The column counts a decode step may score one row over, ascending:
+    four times ``index_topk`` in whole pages, doubled while that stays
+    under the table's ``s`` columns, then ``s`` itself."""
+    w = -(-4 * index_topk // block) * block
+    out = []
+    while w < s:
+        out.append(w)
+        w *= 2
+    return (*out, s)
+
+
+def _width_index(pos, widths):
+    """pos [..] (a row's new token's position, NumPy on the host or
+    traced in the program): which of ``widths`` serves the row, the least
+    that holds columns ``0..pos``."""
+    return (pos[..., None] >= np.asarray(widths)).sum(axis=-1)
+
+
+def _live_rows(live):
+    """live [b] bool -> (the slots with the live ones first, in order;
+    how many are live): what the walk over a step's rows visits."""
+    return jnp.argsort(~live, stable=True), live.sum()
+
+
+def _decode_attention(cfg, lp, x, l, kp, vp, tables, lens, rows):
     """One layer's attention for one token per slot at position ``lens``
     [b]: the token's latent and indexer key go into the row's pages at
-    ``[layer, page]``; the indexer scores the row's indexer pages, the
-    ``index_topk`` best are kept, and the absorbed query reads the
-    chosen latents alone, token by token through the table."""
+    ``[layer, page]``. Then the live rows are walked one by one (``rows``
+    = ``_live_rows``: a slot without a row is not visited and adds
+    nothing), and what a row costs follows its context: over the least
+    of ``decode_widths`` that covers it, its indexer pages are taken
+    through the table and scored, the ``index_topk`` best kept as a mask
+    (``_row_chosen``: no sort), and the absorbed query either passes
+    once over the row's own latent pages under that mask or, from
+    ``GATHER_FROM`` columns on, reads the chosen latents alone, fetched
+    by their columns (``_mask_columns``)."""
     b = x.shape[0]
     n_layers, n_pages, _, bs, lanes = kp.shape
-    s = tables.shape[1] * bs
-    k_top = min(cfg.index_topk, s)
+    widths = decode_widths(tables.shape[1] * bs, cfg.index_topk, bs)
     qc, lat, qi, ki, wi = _project(cfg, lp, x, lens)
     page = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]
     off = lens % bs
     kp = _token_insert(kp, l, page, off, lat[:, None])
     vp = _token_insert(vp, l, page, off, ki[:, None])
-    with jax.named_scope("dsa_index_scores"):
-        keys = jnp.take(vp.reshape(n_layers * n_pages, bs, vp.shape[-1]),
-                        l * n_pages + tables, axis=0)    # [b, mb, bs, di]
-        sc = _index_scores(qi, wi, keys.reshape(b, s, -1))
-        sc = jnp.where(jnp.arange(s)[None, :] <= lens[:, None], sc,
-                       -jnp.inf)
-    with jax.named_scope("dsa_topk"):
-        vals, idx = jax.lax.top_k(sc, k_top)
+
+    def row_pages(pool, r, w):
+        """Slot ``r``'s first ``w`` columns of this layer, [1, w, lanes]."""
+        at = jax.lax.dynamic_slice(tables, (r, 0), (1, w // bs))[0]
+        flat = pool.reshape(n_layers * n_pages, bs, pool.shape[-1])
+        # a table names pages of the pool: no fill behind the gather
+        return flat.at[l * n_pages + at].get(
+            mode="promise_in_bounds").reshape(1, w, -1)
+
+    def attend(w, r):
+        one = lambda t: jax.lax.dynamic_slice_in_dim(t, r, 1)
+        with jax.named_scope("dsa_index_scores"):
+            sc = _index_scores(one(qi), one(wi), row_pages(vp, r, w))
+        k_top = min(cfg.index_topk, w)
+        with jax.named_scope("dsa_topk"):
+            allowed = _row_chosen(sc, one(lens), k_top)
+            if w >= GATHER_FROM:
+                cols, real = _mask_columns(allowed[0], k_top)
+        with jax.named_scope("mla_sparse_decode"):
+            if w < GATHER_FROM:
+                return _sparse_attend(cfg, one(qc), row_pages(kp, r, w),
+                                      allowed)
+            at = jnp.take(one(tables)[0], cols // bs)
+            sel = jnp.take(kp.reshape(n_layers * n_pages * bs, lanes),
+                           (l * n_pages + at) * bs + cols % bs, axis=0)
+            return _sparse_attend(cfg, one(qc), sel[None], real[None])
+
+    def visit(j, o_lat):
+        r = rows[0][j]
+        o = jax.lax.switch(_width_index(lens[r], widths),
+                           [lambda r, w=w: attend(w, r) for w in widths], r)
+        return jax.lax.dynamic_update_slice(o_lat, o, (r, 0, 0))
+
     with jax.named_scope("mla_sparse_decode"):
-        at = jnp.take_along_axis(tables, idx // bs, axis=1)
-        sel = jnp.take(kp.reshape(n_layers * n_pages * bs, lanes),
-                       (l * n_pages + at) * bs + idx % bs, axis=0)
-        o_lat = _sparse_attend(cfg, qc, sel, vals > -jnp.inf)
+        o_lat = jax.lax.fori_loop(
+            0, rows[1], visit,
+            jnp.zeros((b, qc.shape[1], cfg.kv_lora_rank), jnp.float32))
     return _out_proj(cfg, lp, o_lat), kp, vp
 
 
@@ -562,12 +671,16 @@ def _decode_step(cfg, w, embed, final_norm, lm_head, tok, tables, lens,
     logits [b, V], pool); pool = (latent pages, indexer pages,
     counters)."""
     x = jnp.take(embed, tok, axis=0)
+    rows = _live_rows(live)
+    # traced and lowered once for both kinds of layer run (its ways by
+    # width are most of the program's text)
+    attention = jax.jit(functools.partial(_decode_attention, cfg))
     for f_kind, l0, n in cfg.runs():
         def layer(carry, j, f_kind=f_kind, l0=l0):
             x, (kp, vp, counts) = carry
             lp = _layer_params(w, f_kind, l0 + j, j)
-            o, kp, vp = _decode_attention(cfg, lp, x, l0 + j, kp, vp,
-                                          tables, lens)
+            o, kp, vp = attention({name: lp[name] for name in _ATTN}, x,
+                                  l0 + j, kp, vp, tables, lens, rows)
             x, counts = _ffn(cfg, w, lp, f_kind, j, x + o, live, counts)
             return (x, (kp, vp, counts)), None
 
@@ -619,16 +732,23 @@ def leaf_shapes(cfg):
             "lm_head": ((d, cfg.vocab_size), "matrix")}
 
 
-def dsa_tokens(layers, index_topk):
+def dsa_tokens(layers, index_topk, widths):
     """What the engine counts on the host at every decode launch
     (``PagedPrograms.host_counters``), from the contexts of the live
     rows at each of the launch's steps (int64 [steps, rows], as
     ``engine_decode_ctx_tokens_total`` counts them): the tokens the
     indexer scores and the tokens the attention then reads, a row, layer
-    and step."""
+    and step; and, third, the columns the program's way scores for them
+    (``widths``: the decode program's ``decode_widths``, by the rule the
+    program itself takes a row's way by), so that scored tokens over
+    scored columns is the share of the scored columns that held a
+    token."""
     return {"dsa_scored_tokens": lambda ctx: layers * int(ctx.sum()),
             "dsa_selected_tokens":
-                lambda ctx: layers * int(np.minimum(ctx, index_topk).sum())}
+                lambda ctx: layers * int(np.minimum(ctx, index_topk).sum()),
+            "dsa_scored_columns":
+                lambda ctx: layers * int(np.asarray(widths)[
+                    _width_index(ctx, widths)].sum())}
 
 
 class GlmMoeDsaForCausalLM(nn.Layer):
@@ -666,6 +786,10 @@ class GlmMoeDsaForCausalLM(nn.Layer):
                        seq_axis=None, n_seq=1):
         """What ``DecodeEngine`` binds for this family."""
         cfg = self.config
+        # the decode program's widths follow from its table's length,
+        # which it learns when it is traced: ahead of the first launch,
+        # and the host counts after one
+        widths = []
 
         def prefill_paged(stacked, embed, fnorm, lm, scales, ids, pad_len,
                           table_row, slot, *pool):
@@ -679,8 +803,12 @@ class GlmMoeDsaForCausalLM(nn.Layer):
         def decode_chunk_paged(stacked, embed, fnorm, lm, scales, tok,
                                tables, lens, *pool):
             """One chunk; a slot with ``lens == 0`` holds no row: its
-            tokens are routed to no expert and counted nowhere."""
+            tokens are scored against nothing, routed to no expert and
+            counted nowhere."""
             live = lens > 0
+            bs = pool[0].shape[-2]
+            widths[:] = decode_widths(tables.shape[1] * bs, cfg.index_topk,
+                                      bs)
 
             def body(carry, i):
                 tok, pool = carry
@@ -704,7 +832,8 @@ class GlmMoeDsaForCausalLM(nn.Layer):
                 jax.ShapeDtypeStruct((3,), jnp.int32),),
             device_counters=("moe_pairs", "moe_expert_visits",
                              "moe_full_stream"),
-            host_counters=dsa_tokens(cfg.num_hidden_layers, cfg.index_topk),
+            host_counters=dsa_tokens(cfg.num_hidden_layers, cfg.index_topk,
+                                     widths),
             trace_scopes=("dsa_index_scores", "dsa_topk", "mla_prefill_attn",
                           "mla_sparse_decode", "moe_shared_ffn",
                           "moe_expert_ffn"),

@@ -544,14 +544,36 @@ def _engine_glm(which, slots=8, s_max=50176, n_pages=25089, block=16):
                 temp_below=build.temp_below)
         if which == "prefill":
             _assert_no_square_scores(compiled, s_max)
+            return
+        # the decode step's selection follows its rows: no value holds
+        # every slot's indexer keys to the table's length (a row's own,
+        # in the widest way alone, is [pages, block, 128]) or the
+        # pool-wide gather of them, nothing under the selection is
+        # sorted, and each width of the rule has its way, the row's
+        # selection one launch of the kernel in it
+        from paddle_tpu.models import glm_moe_dsa as gm
+        pages = -(-(s_max + 8) // block)    # the table: a chunk past s_max
+        cols = pages * block
+        everyone = re.compile(
+            rf"\w+\[({slots},{cols}|{slots},{pages},{block}"
+            rf"|{slots * pages},{block}),128\]")
+        for _, shape, op, line in _hlo_instructions(text):
+            assert not everyone.match(shape), line[:200]
+            assert not (op == "sort" and ("dsa_topk" in line
+                                          or f"[{slots},{cols}]" in line)), \
+                line[:200]
+        assert "dsa_topk_mask" in text
+        for w in gm.decode_widths(cols, 2048, block):
+            assert f"s32[{-(-w // 1024) * 8},128]" in text, w
     build.check = check
-    # the custom calls are the grouped expert products; the decode step
-    # reads its pages through the compiler's gathers, no paged kernel
+    # the custom calls are the grouped expert products and a decode
+    # row's selection; the decode step reads its pages through the
+    # compiler's gathers, no paged kernel
     build.paged_kernel = False
     # temporaries: the cold program's carry of one row's latents and
     # indexer keys (0.46 GB) and a block's scores; the decode step's
-    # gathered indexer pages
-    build.temp_below = (512 << 20) if which == "decode" else (2048 << 20)
+    # pages of ONE row to the table's length (64 MB of latents)
+    build.temp_below = (128 << 20) if which == "decode" else (2048 << 20)
     return build
 
 

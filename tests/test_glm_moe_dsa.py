@@ -8,10 +8,13 @@ mask, a dense sum over the held experts; no pages, no absorption, no
 gather) on weights from a seed; the absorbed form against the expanded;
 the chosen set against ``top_k``; a wrong selection seen; the share of
 the experts against the uncut layer; a pool too small for its rows; what
-the engine refuses for such a model; the counters."""
+the engine refuses for such a model; the counters; and the decode step's
+ways by width (a table of 168 columns: 32 | 64 | 128 | 168) against the
+reference and against the full-width path it replaced."""
 
 import pathlib
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +50,12 @@ CFG = dict(
 # (a cold prefill walks the window in blocks of 32 rows)
 ENGINE = dict(capacity=2, s_max=64, chunk=4, block_size=8, n_blocks=17,
               prefix_cache=False)
-_MODEL, _SERVED = [], []
+# a table long enough for three widths under its own length: 21 pages of
+# 8 columns, so a row is scored over 32, 64, 128 or 168 of them
+WIDE = dict(capacity=4, s_max=160, chunk=4, block_size=8, n_blocks=85,
+            prefix_cache=False)
+WIDTHS = (32, 64, 128, 168)
+_MODEL, _SERVED, _WIDE = [], [], []
 
 
 def model():
@@ -187,7 +195,8 @@ def counters_case():
     assert log[-1][5:] == [stats["moe_pairs"], stats["moe_expert_visits"],
                            stats["moe_full_stream"],
                            stats["dsa_scored_tokens"],
-                           stats["dsa_selected_tokens"]]
+                           stats["dsa_selected_tokens"],
+                           stats["dsa_scored_columns"]]
     assert all(b[8] - a[8] == (5 * b[4] if b[1] == "decode" else 0)
                for a, b in zip(log, log[1:]))
     fed = sum(p.size + n - 1 for p, n in zip(ps, news))
@@ -204,10 +213,178 @@ def counters_case():
         <= set(scopes["jit_prefill_paged"].values())
     snap = str(eng.metrics.snapshot())
     for name in ("engine_dsa_scored_tokens_total",
-                 "engine_dsa_selected_tokens_total", "engine_moe_pairs_total",
+                 "engine_dsa_selected_tokens_total",
+                 "engine_dsa_scored_columns_total", "engine_moe_pairs_total",
                  "engine_moe_expert_visits_total",
                  "engine_moe_full_stream_total"):
         assert name in snap
+
+
+def wide_served():
+    """One profiled engine whose table holds three widths under its own
+    length, with every decode launch's ``lens`` kept: a prompt under
+    ``index_topk`` (slot 0), one that retires after its first chunk and
+    leaves slot 1 empty between live ones, one whose context crosses the
+    edge of the first width in the middle of a chunk (30, 31 | 32, 33)
+    and one in the third width, all in one launch; then, alone, a row
+    whose last chunk starts at ``s_max - chunk``."""
+    if not _WIDE:
+        eng = DecodeEngine(model(), **WIDE, profile=True)
+        lens_log, decode = [], eng._decode
+
+        def noting(*args):
+            lens_log.append(np.array(args[7]))
+            return decode(*args)
+
+        noting.__wrapped__ = getattr(decode, "__wrapped__", decode)
+        eng._decode = noting
+        ps = prompts(5, 12, 30, 100, 148, seed=7)
+        news = (12, 3, 10, 9, 12)
+        reqs = [eng.submit(p, max_new_tokens=n)
+                for p, n in zip(ps[:4], news)]
+        drive(eng)
+        reqs.append(eng.submit(ps[4], max_new_tokens=news[4]))
+        drive(eng)
+        _WIDE.append((eng, ps, news, reqs, lens_log))
+    return _WIDE[0]
+
+
+def widths_case():
+    """Rows of different widths in one launch, an empty slot between
+    live ones, a row that changes its width mid-chunk, a context under
+    ``index_topk`` and one at ``s_max - chunk``: every served token is
+    the reference's."""
+    eng, ps, news, reqs, lens_log = wide_served()
+    assert G.decode_widths(21 * 8, TOPK, 8) == WIDTHS
+    for r, p in zip(reqs, ps):
+        assert served_gap(r.wait(1), p.size) < 1e-6
+    starts = {tuple(int(v) for v in lens) for lens in lens_log}
+    assert (5, 12, 30, 100) in starts           # three widths, one launch
+    assert (9, 0, 34, 104) in starts            # an empty slot between
+    assert any(max(lens) == WIDE["s_max"] - WIDE["chunk"]
+               for lens in lens_log)
+
+
+def columns_case():
+    """``engine_dsa_scored_columns_total`` is the rule's own sum: a live
+    row, layer and step counts the least width that holds its context
+    and its new token; the two counters ahead of it stand where they
+    stood in a launch's entry."""
+    eng, ps, news, reqs, lens_log = wide_served()
+    stats = eng.stats()
+    want = sum(next(w for w in WIDTHS if w > pos + i)
+               for lens in lens_log for pos in lens if pos
+               for i in range(WIDE["chunk"]))
+    assert stats["dsa_scored_columns"] == 5 * want
+    assert stats["dsa_scored_tokens"] == 5 * stats["decode_ctx_tokens"]
+    assert stats["dsa_scored_tokens"] < stats["dsa_scored_columns"]
+    log = [e for e in stats["launches"] if e[1] == "decode"]
+    assert len(log) == len(lens_log)
+    assert log[-1][8:] == [stats["dsa_scored_tokens"],
+                           stats["dsa_selected_tokens"],
+                           stats["dsa_scored_columns"]]
+    assert "engine_dsa_scored_columns_total" in str(eng.metrics.snapshot())
+    # on the host as in the program: one rule
+    assert G._width_index(np.asarray([0, 31, 32, 127, 128, 167]),
+                          WIDTHS).tolist() == [0, 0, 1, 2, 3, 3]
+
+
+def full_width_attention(cfg, lp, x, l, kp, vp, tables, lens):
+    """The decode step's attention as it stood before the ways by width:
+    every slot's indexer pages to the table's length, ``top_k`` over all
+    of them, the chosen latents read token by token."""
+    b = x.shape[0]
+    n_layers, n_pages, _, bs, lanes = kp.shape
+    s = tables.shape[1] * bs
+    qc, lat, qi, ki, wi = G._project(cfg, lp, x, lens)
+    page = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]
+    kp = G._token_insert(kp, l, page, lens % bs, lat[:, None])
+    vp = G._token_insert(vp, l, page, lens % bs, ki[:, None])
+    keys = jnp.take(vp.reshape(n_layers * n_pages, bs, vp.shape[-1]),
+                    l * n_pages + tables, axis=0)
+    sc = jnp.where(jnp.arange(s)[None, :] <= lens[:, None],
+                   G._index_scores(qi, wi, keys.reshape(b, s, -1)), -jnp.inf)
+    vals, idx = jax.lax.top_k(sc, min(cfg.index_topk, s))
+    at = jnp.take_along_axis(tables, idx // bs, axis=1)
+    sel = jnp.take(kp.reshape(n_layers * n_pages * bs, lanes),
+                   (l * n_pages + at) * bs + idx % bs, axis=0)
+    o_lat = G._sparse_attend(cfg, qc, sel, vals > -jnp.inf)
+    return G._out_proj(cfg, lp, o_lat), idx, vals
+
+
+def ties_case(gather_from=None):
+    """Equal scores at the cut: with three distinct indexer keys over a
+    row's columns the best class holds more than ``index_topk`` tokens,
+    and the step keeps the earliest of them, as ``top_k`` does and as
+    ``chosen_case`` holds for the prefill; in every width, beside an
+    empty slot, to the full-width path's output."""
+    if gather_from is None:     # every row by the masked pass, then the
+        for columns in (10 ** 6, 64):   # rows from 64 columns by index
+            with mock.patch.object(G, "GATHER_FROM", columns):
+                ties_case(columns)
+        return
+    cfg = model().config
+    _, prog = layer_leaves(2, "moe")
+    layer, n_pages, bs = 2, 64, 8
+    lens = jnp.asarray([20, 0, 50, 100, 140], jnp.int32)
+    tables = jnp.asarray(1 + np.arange(5 * 21).reshape(5, 21) % (n_pages - 1),
+                         jnp.int32)
+    key = jax.random.key(9)
+    x = jax.random.normal(key, (5, CFG["hidden_size"]))
+    kp = jax.random.normal(key, (5, n_pages, 1, bs, cfg.latent_lanes))
+    three = jax.random.normal(key, (3, cfg.index_head_dim))
+    vp = jnp.broadcast_to(three[jnp.arange(n_pages * bs) % 3].reshape(
+        n_pages, 1, bs, -1), (5, n_pages, 1, bs, cfg.index_head_dim))
+    got, _, _ = jax.jit(lambda *a: G._decode_attention(
+        cfg, prog, x, layer, *a, lens, G._live_rows(lens > 0)))(kp, vp,
+                                                                 tables)
+    want, idx, vals = jax.jit(lambda *a: full_width_attention(
+        cfg, prog, x, layer, *a, lens))(kp, vp, tables)
+    live = np.flatnonzero(np.asarray(lens))
+    # the cut does fall among equals, and the kept of them are the
+    # earliest: columns u, u + 3, u + 6, .. share a key
+    for r in live:
+        v, i = np.asarray(vals[r]), np.asarray(idx[r])
+        cut = np.sort(i[v == v.min()])
+        equals = np.arange(cut[0] % 3, int(lens[r]), 3)
+        assert equals.size > cut.size, r
+        assert cut.tolist() == equals[:cut.size].tolist(), r
+    assert np.abs(np.asarray(want)[live]).max() > 0.01
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-6)
+    assert not np.asarray(got)[1].any()         # nothing read, nothing added
+
+
+def kernel_case():
+    """The chip's selection of one row (``kernels/topk_mask.py``, here
+    in interpret mode) against ``_chosen_mask``, the same passes in XLA
+    and what the decode step takes on the CPU: a width that fills whole
+    tiles and one that does not, a context under ``k`` (everything
+    seen is kept, nothing past it), and scores rounded so that the cut
+    falls among equals (the earliest kept)."""
+    from paddle_tpu.kernels import topk_mask
+    for w, k, pos, equals in ((168, TOPK, 100, False), (168, TOPK, 5, False),
+                              (168, TOPK, 150, True), (2048, 64, 2047, True),
+                              (1030, 300, 1000, True)):
+        sc = jax.random.normal(jax.random.key(w + pos), (1, w))
+        sc = jnp.round(sc * 4) / 4 if equals else sc
+        at = jnp.asarray([pos], jnp.int32)
+        seen = jnp.arange(w)[None, :] <= pos
+        want = np.asarray(seen & G._chosen_mask(
+            jnp.where(seen, sc, -jnp.inf), k))
+        assert want.sum() == min(k, pos + 1)
+        got = topk_mask.chosen_mask_pallas(sc, at, k, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_array_equal(np.asarray(G._row_chosen(sc, at, k)),
+                                      want)
+        if equals:      # the cut does fall among equals
+            kth = np.sort(np.asarray(sc)[0, :pos + 1])[-k]
+            assert (np.asarray(sc)[0, :pos + 1] == kth).sum() \
+                > (np.asarray(sc)[0][want[0]] == kth).sum()
+        # and the mask's columns without a sort, for the way by index
+        cols, real = G._mask_columns(jnp.asarray(want[0]), k)
+        assert np.asarray(cols)[np.asarray(real)].tolist() \
+            == np.flatnonzero(want[0]).tolist()
 
 
 def _layer_inputs(s=24, seed=3):
@@ -330,7 +507,8 @@ def small_pool_case():
 
 @pytest.mark.parametrize("case", [
     logits_case, engine_case, counters_case, absorbed_case, chosen_case,
-    wrong_selection_case, share_case, small_pool_case],
+    wrong_selection_case, share_case, small_pool_case, widths_case,
+    columns_case, ties_case, kernel_case],
     ids=lambda f: f.__name__)
 def test_glm_moe_dsa(case):
     case()
