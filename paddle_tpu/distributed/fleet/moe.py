@@ -162,7 +162,7 @@ _STREAM_TILE = 128      # rows: a short stream is whole tiles of the chip
 
 
 def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
-                   rows=None):
+                   rows=None, n_group=1, topk_group=1):
     """Dropless routing for a chip that holds a SHARE of the experts
     (expert parallelism without its exchange): every token is routed
     over all ``E`` experts the router has, and the (token, choice) pairs
@@ -178,7 +178,11 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
     bias``, the weights are the scores themselves, divided by their sum
     over the chosen k (all k, wherever they live). ``rows`` [N] bool:
     tokens that are none (padding, idle slots); their pairs sort behind
-    too.
+    too. ``n_group`` > 1: group-limited selection (DeepSeek-V3's
+    ``n_group`` / ``topk_group``): the E experts lie in ``n_group``
+    groups of consecutive experts, a group's score is the sum of its two
+    largest ``scores + bias``, the ``topk_group`` best groups stay and
+    the top-k is taken among their experts alone.
 
     Returns (topi [N, k] expert ids, gates [N, k] f32, 0 off the held
     share, order [N*k] the stream's permutation, group_sizes [count]
@@ -200,6 +204,9 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
     else:
         raise ValueError(f"unknown scoring {scoring!r}: softmax | sigmoid")
     choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        with jax.named_scope("moe_group_route"):
+            choice = _keep_best_groups(choice, n_group, topk_group)
     _, topi = jax.lax.top_k(choice, top_k)
     gates = jnp.take_along_axis(scores, topi, axis=-1)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
@@ -217,6 +224,19 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
     stream_rows = min(n_pairs, -(-2 * n_pairs * count
                                  // (n_experts * _STREAM_TILE)) * _STREAM_TILE)
     return topi, jnp.where(mine, gates, 0.0), order, group_sizes, stream_rows
+
+
+def _keep_best_groups(choice, n_group, topk_group):
+    """choice [N, E] with -inf at the experts outside each token's
+    ``topk_group`` best of ``n_group`` groups of consecutive experts (a
+    group's score: the sum of its two largest entries; of equal groups
+    the first)."""
+    n, e = choice.shape
+    per = choice.reshape(n, n_group, e // n_group)
+    score = jax.lax.top_k(per, 2)[0].sum(axis=-1)               # [N, groups]
+    _, best = jax.lax.top_k(score, topk_group)
+    kept = (best[:, :, None] == jnp.arange(n_group)).any(axis=1)
+    return jnp.where(kept[:, :, None], per, -jnp.inf).reshape(n, e)
 
 
 def moe_full_stream(group_sizes, n_pairs, stream_rows=None):

@@ -629,7 +629,8 @@ class DecodeEngine:
         self._verify_progs = {}
         self._state_specs = tuple(progs.slot_state(self.capacity)) \
             if progs.slot_state is not None else ()
-        self._n_pool = (4 if self._kv_q else 2) + len(self._state_specs)
+        self._n_pool = (4 if self._kv_q else 1 + progs.value_pool) \
+            + len(self._state_specs)
         # the counters' vector (the last state array) goes in and comes
         # out like the rest but is NOT donated: the one a launch returned
         # stays readable from any thread while the next launch runs
@@ -870,8 +871,10 @@ class DecodeEngine:
             self._kp = jnp.zeros((self._L, self.n_blocks, self._kvh,
                                   self.block_size, self._hd),
                                  pool_dtype)
+            # a family with one kind of page has no second pool
             self._vp = jnp.zeros(self._kp.shape[:-1] + (self._hdv,),
-                                 pool_dtype)
+                                 pool_dtype) \
+                if self._progs.value_pool else None
             if self._kv_q:
                 from ..kernels.paged_attention import KV_SCALE_EPS
                 self._kscale = jnp.full(
@@ -926,17 +929,21 @@ class DecodeEngine:
     # -- pool plumbing (ISSUE 8) --------------------------------------------
     def _pool(self):
         """The device arrays every paged program takes LAST: (kp, vp)
-        for fp pools, (kp, vp, kscale, vscale) for int8; behind them a
-        stateful family's per-slot state arrays."""
+        for fp pools ((kp,) for a family with one kind of page), (kp, vp,
+        kscale, vscale) for int8; behind them a stateful family's
+        per-slot state arrays."""
         if self._kv_q:
             return (self._kp, self._vp, self._kscale, self._vscale)
-        return (self._kp, self._vp, *self._state)
+        pages = (self._kp,) if self._vp is None else (self._kp, self._vp)
+        return (*pages, *self._state)
 
     def _set_pool(self, vals):
         if self._kv_q:
             self._kp, self._vp, self._kscale, self._vscale = vals
         else:
-            self._kp, self._vp, *state = vals
+            self._kp, *state = vals
+            if self._vp is not None:
+                self._vp, *state = state
             self._state = tuple(state)
 
     def _sync_device_counters(self):

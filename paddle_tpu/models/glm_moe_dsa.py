@@ -81,6 +81,7 @@ whole.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 
 import jax
@@ -104,6 +105,26 @@ ATTEND_KEYS = 2048      # keys a piece of the causal pass takes at most
 # a narrower row's latent pages are passed over once under the mask,
 # which costs less than the fetch up to here (PERF.md, Findings PR 43)
 GATHER_FROM = 32768
+
+
+def check_stack(cfg):
+    """What every configuration of a latent family with a held share of
+    experts has to hold: ``held_experts`` (first, count), by default all
+    the router's, a share of them; the leading dense layers within the
+    stack; an even rotary width."""
+    if cfg.held_experts is None:
+        cfg.held_experts = (0, cfg.n_routed_experts)
+    first, count = cfg.held_experts = tuple(cfg.held_experts)
+    if not (0 <= first and count >= 1
+            and first + count <= cfg.n_routed_experts):
+        raise ValueError(f"held_experts={cfg.held_experts!r} is no "
+                         f"share of {cfg.n_routed_experts} experts")
+    if not 0 <= cfg.first_k_dense_replace <= cfg.num_hidden_layers:
+        raise ValueError(
+            f"first_k_dense_replace={cfg.first_k_dense_replace} of "
+            f"{cfg.num_hidden_layers} layers")
+    if cfg.qk_rope_head_dim % 2:
+        raise ValueError(f"rotary width {cfg.qk_rope_head_dim}")
 
 
 @dataclass
@@ -139,13 +160,7 @@ class GlmMoeDsaConfig:
     dtype: str = "float32"
 
     def __post_init__(self):
-        if self.held_experts is None:
-            self.held_experts = (0, self.n_routed_experts)
-        first, count = self.held_experts = tuple(self.held_experts)
-        if not (0 <= first and count >= 1
-                and first + count <= self.n_routed_experts):
-            raise ValueError(f"held_experts={self.held_experts!r} is no "
-                             f"share of {self.n_routed_experts} experts")
+        check_stack(self)
         # keys the published glm_moe_dsa configurations give one value
         for name, value in (("n_group", 1), ("topk_group", 1),
                             ("scoring_func", "sigmoid"),
@@ -156,15 +171,20 @@ class GlmMoeDsaConfig:
                     f"GlmMoeDsa supports {name}={value!r} alone (the "
                     f"published glm_moe_dsa configurations), got "
                     f"{getattr(self, name)!r}")
-        if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
-            raise ValueError(
-                f"first_k_dense_replace={self.first_k_dense_replace} of "
-                f"{self.num_hidden_layers} layers")
-        if self.qk_rope_head_dim % 2 \
-                or self.qk_rope_head_dim > self.index_head_dim:
+        if self.qk_rope_head_dim > self.index_head_dim:
             raise ValueError(
                 f"rotary width {self.qk_rope_head_dim} of index_head_dim "
                 f"{self.index_head_dim}")
+
+    @property
+    def logit_divisor(self):
+        """What a score is divided by ahead of the softmax."""
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** 0.5
+
+    def inv_freq(self):
+        """The rotary frequencies of the ``qk_rope_head_dim`` turned
+        dimensions, float32 [qk_rope_head_dim / 2]."""
+        return rope_inv_freq(self.qk_rope_head_dim, self.rope_theta)
 
     @property
     def latent_dim(self):
@@ -202,8 +222,9 @@ GLM_MOE_DSA_PRESETS = {
                   n_shared_experts=1),
 }
 
+_INDEXER = ("w_qi", "w_ki", "ki_ln_g", "ki_ln_b", "w_wi")
 _ATTN = ("input_ln", "post_ln", "w_dq", "q_ln", "w_uq", "w_dkv", "kv_ln",
-         "w_uk", "w_uv", "wo", "w_qi", "w_ki", "ki_ln_g", "ki_ln_b", "w_wi")
+         "w_uk", "w_uv", "wo", *_INDEXER)
 _FFN = {"dense": ("w_gate", "w_up", "w_down"),
         "moe": ("router", "router_bias", "ws_gate", "ws_up", "ws_down")}
 _EXPERTS = ("we_gate", "we_up", "we_down")      # never indexed by layer
@@ -211,17 +232,49 @@ _EXPERTS = ("we_gate", "we_up", "we_down")      # never indexed by layer
 
 def _layer_params(w, f_kind, l, f):
     """Layer ``l``'s leaves under their names: the attention's and the
-    norms' at ``l``, the ffn's at index ``f`` of its kind (both data)."""
-    lp = {n: w[n][l] for n in _ATTN}
+    norms' at ``l`` (the indexer's where the family has one), the ffn's
+    at index ``f`` of its kind (both data)."""
+    lp = {n: w[n][l] for n in _ATTN if n in w}
     lp.update({n: w[n][f] for n in _FFN[f_kind]})
     return lp
 
 
-def _rope_pairs(x, positions, theta):
-    """x [n, .., r] with every dimension turned: interleaved pairs
-    (x0,x1),(x2,x3),.. at ``positions`` [n], float32 angles."""
-    r = x.shape[-1]
+def rope_inv_freq(r, theta, yarn=None):
+    """The frequencies of ``r`` turned dimensions, float32 [r / 2]:
+    ``theta^(-2i/r)``; with ``yarn`` = (factor, original context,
+    beta_fast, beta_slow) YaRN's blend of them with the same divided by
+    ``factor``: dimension ``i`` takes the divided one by ``clip((i -
+    low) / (high - low), 0, 1)``, ``low`` and ``high`` the dimensions
+    that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context (rounded down and up)."""
     freqs = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    if yarn is None:
+        return freqs
+    factor, original, beta_fast, beta_slow = yarn
+    low, high = yarn_range(r, theta, original, beta_fast, beta_slow)
+    ramp = jnp.clip((jnp.arange(r // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_range(r, theta, original, beta_fast, beta_slow):
+    """(low, high): the turned dimensions between which YaRN blends."""
+    at = lambda turns: r * math.log(original / (turns * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), r - 1))
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's magnitude correction ``0.1 mscale ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_pairs(x, positions, freqs):
+    """x [n, .., r] with every dimension turned: interleaved pairs
+    (x0,x1),(x2,x3),.. at ``positions`` [n] by ``freqs`` [r / 2],
+    float32 angles."""
+    r = x.shape[-1]
     ang = positions.astype(jnp.float32)[:, None] * freqs      # [n, r/2]
     ang = ang.reshape(ang.shape[0], *(1,) * (x.ndim - 2), r // 2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -239,24 +292,28 @@ def _layer_norm(x, g, b, eps):
     return (y * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def _project(cfg, lp, x, positions):
-    """One layer's attention inputs for rows x [n, d] at ``positions``
-    [n]: the absorbed query qc [n, H, lanes] (``q_nope W_uk^T | q_rope``,
-    zero past ``latent_dim``), the latent lat [n, lanes] (``RMSNorm(c_kv)
-    | k_r``, zero past it), the indexer's queries qi [n, Hi, di], key ki
-    [n, di] and head weights wi [n, Hi] float32. The barriers keep a
-    projection a plain [n, d] x [d, columns] product (models/mimo_v2.py,
-    ``_qkv``)."""
+def _plain(y, w):
+    """A projection kept a plain [n, d] x [d, columns] product
+    (models/mimo_v2.py, ``_qkv``)."""
+    return jax.lax.optimization_barrier(y @ w)
+
+
+def _project_latent(cfg, lp, x, positions):
+    """One layer's latent attention inputs for rows x [n, d] at
+    ``positions`` [n]: the absorbed query qc [n, H, lanes] (``q_nope
+    W_uk^T | q_rope``, zero past ``latent_dim``), the latent lat [n,
+    lanes] (``RMSNorm(c_kv) | k_r``, zero past it); and, for a family
+    that projects more from them, the normed input h [n, d] and the
+    query's latent cq [n, q_lora_rank]."""
     eps, n = cfg.rms_norm_eps, x.shape[0]
-    H, rope, rank = (cfg.num_attention_heads, cfg.qk_rope_head_dim,
-                     cfg.kv_lora_rank)
+    H, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     h = _rms(x, lp["input_ln"], eps)
-    plain = lambda w, y=h: jax.lax.optimization_barrier(y @ w)
+    plain = lambda w, y=h: _plain(y, w)
     cq = _rms(plain(lp["w_dq"]), lp["q_ln"], eps)
     q = plain(lp["w_uq"], cq).reshape(n, H, -1)
     q_nope = q[..., :cfg.qk_nope_head_dim]
     q_rope = _rope_pairs(q[..., cfg.qk_nope_head_dim:], positions,
-                         cfg.rope_theta)
+                         cfg.inv_freq())
     q_abs = jnp.einsum("nhk,hkr->nhr", q_nope, lp["w_uk"])
     widen = lambda t: jnp.pad(t, ((0, 0),) * (t.ndim - 1)
                               + ((0, cfg.latent_lanes - cfg.latent_dim),))
@@ -264,11 +321,21 @@ def _project(cfg, lp, x, positions):
     ckv = plain(lp["w_dkv"])
     lat = widen(jnp.concatenate(
         [_rms(ckv[:, :rank], lp["kv_ln"], eps),
-         _rope_pairs(ckv[:, rank:], positions, cfg.rope_theta)], axis=-1))
+         _rope_pairs(ckv[:, rank:], positions, cfg.inv_freq())], axis=-1))
+    return qc, lat, h, cq
+
+
+def _project(cfg, lp, x, positions):
+    """:func:`_project_latent`'s qc and lat, then the indexer's queries
+    qi [n, Hi, di], key ki [n, di] and head weights wi [n, Hi]
+    float32."""
+    n, rope = x.shape[0], cfg.qk_rope_head_dim
+    qc, lat, h, cq = _project_latent(cfg, lp, x, positions)
+    plain = lambda w, y=h: _plain(y, w)
 
     def turn_first(t):
         return jnp.concatenate(
-            [_rope_pairs(t[..., :rope], positions, cfg.rope_theta),
+            [_rope_pairs(t[..., :rope], positions, cfg.inv_freq()),
              t[..., rope:]], axis=-1)
 
     qi = turn_first(plain(lp["w_qi"], cq).reshape(n, cfg.index_n_heads, -1))
@@ -300,7 +367,7 @@ def _sparse_attend(cfg, qc, sel, ok):
     """qc [n, H, lanes] against each row's own chosen latents sel
     [n, k, lanes] where ``ok`` [n, k]: the probabilities' sum of the
     latents' first ``kv_lora_rank`` values, float32 [n, H, rank]."""
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+    scale = cfg.logit_divisor
     s = jnp.einsum("nhc,nkc->nhk", qc, sel,
                    preferred_element_type=jnp.float32) / scale
     s = jnp.where(ok[:, None, :], s, _NEG)
@@ -316,7 +383,9 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     """x + ffn(rms(x)); an expert layer adds the shared expert, computed
     whole, to the held experts' part of the routed sum. ``rows`` [n]
     marks real tokens; ``counts`` int32 [3] gains (pairs computed, held
-    experts visited, 1 if the expert products took the whole stream)."""
+    experts visited, 1 if the expert products took the whole stream),
+    and under a router with groups a fourth entry the groups that hold
+    a chosen expert of some real token."""
     y = _rms(x, lp["post_ln"], cfg.rms_norm_eps)
     if f_kind == "dense":
         return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
@@ -327,7 +396,8 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     logits = jnp.dot(y.astype(jnp.float32), lp["router"], precision=_HI)
     topi, gates, order, sizes, stream_rows = moe_route_held(
         logits, cfg.num_experts_per_tok, cfg.held_experts,
-        scoring=cfg.scoring_func, bias=lp["router_bias"], rows=rows)
+        scoring=cfg.scoring_func, bias=lp["router_bias"], rows=rows,
+        n_group=cfg.n_group, topk_group=cfg.topk_group)
     # this layer's experts by their place in the one stack of all layers'
     held = cfg.held_experts[1]
     groups = jax.lax.dynamic_update_slice(
@@ -339,8 +409,13 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
                                precision=jax.lax.Precision.DEFAULT,
                                stream_rows=stream_rows)
     whole = moe_full_stream(sizes, order.shape[0], stream_rows)
-    counts = counts + jnp.stack([sizes.sum(), (sizes > 0).sum(),
-                                 jnp.asarray(whole, jnp.int32)])
+    gained = [sizes.sum(), (sizes > 0).sum(), jnp.asarray(whole, jnp.int32)]
+    if cfg.n_group > 1:
+        group = topi // (cfg.n_routed_experts // cfg.n_group)
+        seen = (group[:, :, None] == jnp.arange(cfg.n_group)) \
+            & rows[:, None, None]
+        gained.append(seen.any(axis=(0, 1)).sum().astype(jnp.int32))
+    counts = counts + jnp.stack(gained)
     return x + shared + out.astype(x.dtype), counts
 
 
@@ -423,6 +498,66 @@ def _mask_columns(mask, k):
     return at * piece + jnp.minimum(within, piece - 1), nth < count.sum()
 
 
+def _block_pieces(total, blk, start, first, at_most):
+    """(keys a piece, first piece, one past the last) of a loop over a
+    prefilled row's tokens from its first to the block's own."""
+    kc = _key_chunk(total // blk, blk, at_most)
+    return kc, first * blk // kc, (start + blk - 1) // kc + 1
+
+
+def _block_seen(qcol, pad, at, n):
+    """[blk, n]: the keys at columns ``at..`` the queries at columns
+    ``qcol`` may see (column ``pad`` holds position 0)."""
+    kcol = at + jnp.arange(n)
+    return (kcol[None, :] <= qcol[:, None]) & (kcol[None, :] >= pad)
+
+
+def _causal_latent_pass(cfg, qc, lat_c, l, start, qcol, pad, first,
+                        allowed=None, at_most=None):
+    """The absorbed queries qc [blk, H, lanes] of one block of a cold
+    prefill (columns ``qcol`` = ``start..`` of the window) against the row's
+    latents lat_c [L, total, lanes] of layer ``l`` from the row's first
+    token to the block's own: an online softmax, a piece of at most
+    ``at_most`` keys at a time (``ATTEND_KEYS``); ``allowed`` [blk,
+    total] narrows what a query sees. Returns the probabilities' sum of
+    latents [blk, H, rank] float32."""
+    blk, H = qc.shape[0], qc.shape[1]
+    total, lanes = lat_c.shape[1:]
+    rank = cfg.kv_lora_rank
+    scale = cfg.logit_divisor
+    kc, lo, hi = _block_pieces(total, blk, start, first,
+                               at_most or ATTEND_KEYS)
+
+    def fold(j, state):
+        m, den, acc = state
+        at = j * kc
+        keys = jax.lax.dynamic_slice(lat_c, (l, at, 0),
+                                     (1, kc, lanes))[0]
+        ok = _block_seen(qcol, pad, at, kc)
+        if allowed is not None:
+            ok &= jax.lax.dynamic_slice(allowed, (0, at), (blk, kc))
+        s = jnp.einsum("nhc,uc->hnu", qc, keys,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(ok[None], s / scale, _NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("hnu,ur->hnr", p.astype(keys.dtype),
+                        keys[:, :rank],
+                        preferred_element_type=jnp.float32)
+        return (m_new, alpha * den + p.sum(axis=-1),
+                alpha[..., None] * acc + pv)
+
+    with jax.named_scope("mla_prefill_attn"):
+        _, den, acc = jax.lax.fori_loop(
+            lo, hi, fold,
+            (jnp.full((H, blk), _NEG, jnp.float32),
+             jnp.zeros((H, blk), jnp.float32),
+             jnp.zeros((H, blk, rank), jnp.float32)))
+        return jnp.swapaxes(
+            acc / jnp.maximum(den, 1e-30)[..., None], 0, 1)
+
+
 def _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad, first):
     """One layer's attention for one block of a cold prefill: queries at
     columns ``start..`` of the window (column ``pad`` holds position 0)
@@ -438,29 +573,18 @@ def _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad, first):
     pass over the whole row under 98 k of context, past every window
     the engine serves (PERF.md, Findings PR 42). Returns the
     probabilities' sum of latents [blk, H, rank] float32."""
-    blk, H = qc.shape[0], qc.shape[1]
-    total, lanes = lat_c.shape[1:]
-    rank = cfg.kv_lora_rank
+    blk, total = qc.shape[0], lat_c.shape[1]
     k_top = min(cfg.index_topk, total)
     qcol = start + jnp.arange(blk)
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
-
-    def pieces(at_most):
-        """(keys a piece, first piece, one past the last) of a loop over
-        the row's tokens from its first to the block's own."""
-        kc = _key_chunk(total // blk, blk, at_most)
-        return kc, first * blk // kc, (start + blk - 1) // kc + 1
-
-    def seen(at, n):
-        """[blk, n]: the keys at columns ``at..`` a query may see."""
-        kcol = at + jnp.arange(n)
-        return (kcol[None, :] <= qcol[:, None]) & (kcol[None, :] >= pad)
+    seen = functools.partial(_block_seen, qcol, pad)
+    causal = functools.partial(_causal_latent_pass, cfg, qc, lat_c, l, start,
+                               qcol, pad, first)
 
     def scores():
         """The indexer's scores of the block's queries against the row
         so far, [blk, total] float32, a piece of keys at a time; -inf
         where a query may not look."""
-        kc, lo, hi = pieces(SCORE_KEYS)
+        kc, lo, hi = _block_pieces(total, blk, start, first, SCORE_KEYS)
 
         def piece(j, buf):
             keys = jax.lax.dynamic_slice(
@@ -473,40 +597,6 @@ def _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad, first):
                 lo, hi, piece, jnp.full((blk, total), -jnp.inf, jnp.float32))
             return jnp.where(seen(0, total), buf, -jnp.inf)
 
-    def causal(allowed=None):
-        """Online softmax over the row's latents, a piece of keys at a
-        time; ``allowed`` [blk, total] narrows what a query sees."""
-        kc, lo, hi = pieces(ATTEND_KEYS)
-
-        def fold(j, state):
-            m, den, acc = state
-            at = j * kc
-            keys = jax.lax.dynamic_slice(lat_c, (l, at, 0),
-                                         (1, kc, lanes))[0]
-            ok = seen(at, kc)
-            if allowed is not None:
-                ok &= jax.lax.dynamic_slice(allowed, (0, at), (blk, kc))
-            s = jnp.einsum("nhc,uc->hnu", qc, keys,
-                           preferred_element_type=jnp.float32)
-            s = jnp.where(ok[None], s / scale, _NEG)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            p = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
-            alpha = jnp.exp(m - m_new)
-            pv = jnp.einsum("hnu,ur->hnr", p.astype(keys.dtype),
-                            keys[:, :rank],
-                            preferred_element_type=jnp.float32)
-            return (m_new, alpha * den + p.sum(axis=-1),
-                    alpha[..., None] * acc + pv)
-
-        with jax.named_scope("mla_prefill_attn"):
-            _, den, acc = jax.lax.fori_loop(
-                lo, hi, fold,
-                (jnp.full((H, blk), _NEG, jnp.float32),
-                 jnp.zeros((H, blk), jnp.float32),
-                 jnp.zeros((H, blk, rank), jnp.float32)))
-            return jnp.swapaxes(
-                acc / jnp.maximum(den, 1e-30)[..., None], 0, 1)
-
     def masked():
         sc = scores()
         with jax.named_scope("dsa_topk"):
@@ -517,17 +607,34 @@ def _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad, first):
     return jax.lax.cond(last >= cfg.index_topk, masked, causal)
 
 
+def _prefill_attend(cfg, lp, x, positions, caches, l, start, pad, first):
+    """One layer's attention for one block of this family's cold prefill
+    (:func:`_prefill`'s ``attend``): the block's latents and indexer keys
+    go into the row's carried caches, then :func:`_block_attention`."""
+    lat_c, ki_c = caches
+    qc, lat, qi, ki, wi = _project(cfg, lp, x, positions)
+    lat_c = jax.lax.dynamic_update_slice(lat_c, lat[None], (l, start, 0))
+    ki_c = jax.lax.dynamic_update_slice(ki_c, ki[None], (l, start, 0))
+    o_lat = _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c, l, start, pad,
+                             first)
+    return o_lat, (lat_c, ki_c)
+
+
 def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
-             pool, block):
+             pool, block, attend=_prefill_attend):
     """The cold prefill of ONE right-aligned row (ids [1, s], pad_len
     [1]): the window is walked in blocks of ``block`` rows from the block
-    of the first token (the trip count is data). The row's latents and
-    indexer keys lie in a contiguous carry, every layer's, which a block
-    reads from the row's first token to its own rows; at the window's
-    end they are written page by page through ``table_row``, whole
-    pages, both pools under the one table. Returns (float32 logits
-    [1, V] of the last token, pool)."""
-    kp, vp, counts = pool
+    of the first token (the trip count is data). What the row caches a
+    token and layer (this family: latents and indexer keys; one carried
+    array a page pool, as wide as its pages) lies in contiguous carries,
+    every layer's, which a block reads from the row's first token to its
+    own rows: ``attend(cfg, lp, x, positions, caches, l, start, pad,
+    first)`` writes the block's own and gives the probabilities' sum of
+    latents [block, H, rank] and the carries. At the window's end they
+    are written page by page through ``table_row``, whole pages, every
+    pool under the one table. Returns (float32 logits [1, V] of the last
+    token, pool)."""
+    *pages, counts = pool
     s = ids.shape[1]
     block = min(block, s)
     n_blocks = -(-s // block)
@@ -540,7 +647,7 @@ def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
     L = cfg.num_hidden_layers
 
     def run_block(i, carry):
-        lat_c, ki_c, counts, _ = carry
+        caches, counts, _ = carry
         start = i * block
         cols = start + jnp.arange(block)
         rows = cols >= pad
@@ -549,35 +656,28 @@ def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
                      axis=0)
         for f_kind, l0, n in cfg.runs():
             def layer(carry, j, f_kind=f_kind, l0=l0):
-                x, lat_c, ki_c, counts = carry
+                x, caches, counts = carry
                 l = l0 + j
                 lp = _layer_params(w, f_kind, l, j)
-                qc, lat, qi, ki, wi = _project(cfg, lp, x, positions)
-                lat_c = jax.lax.dynamic_update_slice(
-                    lat_c, lat[None], (l, start, 0))
-                ki_c = jax.lax.dynamic_update_slice(
-                    ki_c, ki[None], (l, start, 0))
-                o_lat = _block_attention(cfg, lp, qc, qi, wi, lat_c, ki_c,
-                                         l, start, pad, first)
+                o_lat, caches = attend(cfg, lp, x, positions, caches, l,
+                                       start, pad, first)
                 x, counts = _ffn(cfg, w, lp, f_kind, j,
                                  x + _out_proj(cfg, lp, o_lat), rows, counts)
-                return (x, lat_c, ki_c, counts), None
+                return (x, caches, counts), None
 
-            (x, lat_c, ki_c, counts), _ = jax.lax.scan(
-                layer, (x, lat_c, ki_c, counts),
-                jnp.arange(n, dtype=jnp.int32))
-        return lat_c, ki_c, counts, x[-1:]
+            (x, caches, counts), _ = jax.lax.scan(
+                layer, (x, caches, counts), jnp.arange(n, dtype=jnp.int32))
+        return caches, counts, x[-1:]
 
-    lat_c, ki_c, counts, last = jax.lax.fori_loop(
+    caches, counts, last = jax.lax.fori_loop(
         first, n_blocks, run_block,
-        (jnp.zeros((L, total, cfg.latent_lanes), dtype),
-         jnp.zeros((L, total, cfg.index_head_dim), dtype),
+        (tuple(jnp.zeros((L, total, p.shape[-1]), dtype) for p in pages),
          counts, jnp.zeros((1, embed.shape[1]), dtype)))
     logits = _logits(cfg, last, final_norm, lm_head)
-    mb, bs = table_row.shape[0], kp.shape[-2]
-    kp = kp.at[:, table_row].set(_row_pages(lat_c[:, :, None], pad, mb, bs))
-    vp = vp.at[:, table_row].set(_row_pages(ki_c[:, :, None], pad, mb, bs))
-    return logits, (kp, vp, counts)
+    mb, bs = table_row.shape[0], pages[0].shape[-2]
+    pages = [p.at[:, table_row].set(_row_pages(c[:, :, None], pad, mb, bs))
+             for p, c in zip(pages, caches)]
+    return logits, (*pages, counts)
 
 
 def decode_widths(s, index_topk, block):
@@ -675,22 +775,37 @@ def _decode_step(cfg, w, embed, final_norm, lm_head, tok, tables, lens,
     # traced and lowered once for both kinds of layer run (its ways by
     # width are most of the program's text)
     attention = jax.jit(functools.partial(_decode_attention, cfg))
-    for f_kind, l0, n in cfg.runs():
-        def layer(carry, j, f_kind=f_kind, l0=l0):
-            x, (kp, vp, counts) = carry
-            lp = _layer_params(w, f_kind, l0 + j, j)
-            o, kp, vp = attention({name: lp[name] for name in _ATTN}, x,
-                                  l0 + j, kp, vp, tables, lens, rows)
-            x, counts = _ffn(cfg, w, lp, f_kind, j, x + o, live, counts)
-            return (x, (kp, vp, counts)), None
 
-        (x, pool), _ = jax.lax.scan(layer, (x, tuple(pool)),
-                                    jnp.arange(n, dtype=jnp.int32))
+    def attend(lp, x, l, pages):
+        o, *pages = attention({name: lp[name] for name in _ATTN}, x, l,
+                              *pages, tables, lens, rows)
+        return o, pages
+
+    x, pool = _decode_layers(cfg, w, x, pool, live, attend)
     return _logits(cfg, x, final_norm, lm_head), pool
 
 
-def leaf_shapes(cfg):
-    """name -> (shape, kind of leaf) of every parameter. The router and
+def _decode_layers(cfg, w, x, pool, live, attend):
+    """One token per slot, x [b, d], through every layer: ``attend(lp,
+    x, l, pages)`` -> (the layer's attention output [b, d], the page
+    pools with the tokens' own written); pool = (*page pools, device
+    counters). Returns (x, pool)."""
+    for f_kind, l0, n in cfg.runs():
+        def layer(carry, j, f_kind=f_kind, l0=l0):
+            x, (*pages, counts) = carry
+            lp = _layer_params(w, f_kind, l0 + j, j)
+            o, pages = attend(lp, x, l0 + j, pages)
+            x, counts = _ffn(cfg, w, lp, f_kind, j, x + o, live, counts)
+            return (x, (*pages, counts)), None
+
+        (x, pool), _ = jax.lax.scan(layer, (x, tuple(pool)),
+                                    jnp.arange(n, dtype=jnp.int32))
+    return x, pool
+
+
+def leaf_shapes(cfg, indexer=True):
+    """name -> (shape, kind of leaf) of every parameter (``indexer``:
+    with the indexer's four, ``_INDEXER``). The router and
     its selection bias are float32 whatever the model's dtype: a near-tie
     between two experts' scores is settled in the precision the scores
     are stated in."""
@@ -699,11 +814,18 @@ def leaf_shapes(cfg):
     H, qr, rank = cfg.num_attention_heads, cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, hdv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                        cfg.v_head_dim)
-    Hi, di = cfg.index_n_heads, cfg.index_head_dim
     L, E = cfg.num_hidden_layers, cfg.n_routed_experts
     nd, ne = cfg.first_k_dense_replace, cfg.n_moe
     fs = fe * cfg.n_shared_experts
     held = ne * cfg.held_experts[1]
+    if indexer:
+        Hi, di = cfg.index_n_heads, cfg.index_head_dim
+        index = {"w_qi": ((L, qr, Hi * di), "matrix"),
+                 "w_ki": ((L, d, di), "matrix"),
+                 "ki_ln_g": ((L, di), "one"), "ki_ln_b": ((L, di), "zero"),
+                 "w_wi": ((L, d, Hi), "matrix")}
+    else:
+        index = {}
     return {"embed_tokens": ((cfg.vocab_size, d), "matrix"),
             "input_ln": ((L, d), "one"), "post_ln": ((L, d), "one"),
             "w_dq": ((L, d, qr), "matrix"), "q_ln": ((L, qr), "one"),
@@ -713,10 +835,7 @@ def leaf_shapes(cfg):
             "w_uk": ((L, H, nope, rank), "matrix"),
             "w_uv": ((L, H, rank, hdv), "matrix"),
             "wo": ((L, H * hdv, d), "matrix"),
-            "w_qi": ((L, qr, Hi * di), "matrix"),
-            "w_ki": ((L, d, di), "matrix"),
-            "ki_ln_g": ((L, di), "one"), "ki_ln_b": ((L, di), "zero"),
-            "w_wi": ((L, d, Hi), "matrix"),
+            **index,
             "w_gate": ((nd, d, ff), "matrix"),
             "w_up": ((nd, d, ff), "matrix"),
             "w_down": ((nd, ff, d), "matrix"),
@@ -758,16 +877,18 @@ class GlmMoeDsaForCausalLM(nn.Layer):
     experts of every expert layer in one stack ``[expert layers * held,
     ...]``."""
 
-    def __init__(self, config: GlmMoeDsaConfig | str = "debug"):
+    config_class, presets, indexer = GlmMoeDsaConfig, GLM_MOE_DSA_PRESETS, True
+
+    def __init__(self, config="debug"):
         super().__init__()
         if isinstance(config, str):
-            config = GlmMoeDsaConfig(**GLM_MOE_DSA_PRESETS[config])
+            config = self.config_class(**self.presets[config])
         self.config = cfg = config
         from ..nn import initializer as I
         inits = {"matrix": I.Normal(0.0, 0.02), "one": I.Constant(1.0),
                  "zero": I.Constant(0.0), "router": I.Normal(0.0, 0.1),
                  "bias": I.Uniform(-0.05, 0.05)}
-        for name, (shape, how) in leaf_shapes(cfg).items():
+        for name, (shape, how) in leaf_shapes(cfg, self.indexer).items():
             p = self.create_parameter(shape=list(shape),
                                       default_initializer=inits[how])
             if cfg.dtype != "float32" and how in ("matrix", "one", "zero"):
@@ -775,11 +896,12 @@ class GlmMoeDsaForCausalLM(nn.Layer):
             self.add_parameter(name, p)
 
     def _stacked_names(self):
-        return [*_ATTN, *_FFN["dense"], *_FFN["moe"], *_EXPERTS]
+        return [*(n for n in _ATTN if self.indexer or n not in _INDEXER),
+                *_FFN["dense"], *_FFN["moe"], *_EXPERTS]
 
     def forward(self, input_ids):
         raise NotImplementedError(
-            "GlmMoeDsaForCausalLM is served through DecodeEngine "
+            f"{type(self).__name__} is served through DecodeEngine "
             "(paged_programs); it has no cache-free forward")
 
     def paged_programs(self, chunk, prefill_block, mp_axis=None,

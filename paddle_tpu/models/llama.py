@@ -202,6 +202,10 @@ class PagedPrograms:
     engine refuses at construction every option in ``unsupported``
     (option -> why). ``v_head_dim``: the width of a value head in the
     pool where it is not a key head's (0: ``head_dim``).
+    ``value_pool=False``: the family keeps ONE kind of page (a latent
+    that is key and value at once); the engine then builds no second
+    pool, ``pool`` is (page pool, *``slot_state``) and a block costs one
+    page a layer.
     ``device_counters`` names the entries of the LAST array of
     ``slot_state``, an int32 vector the two programs add to on the
     device (what only the device knows: which experts a step's rows
@@ -228,6 +232,7 @@ class PagedPrograms:
     chunks_per_block: int = 0
     unsupported: dict = field(default_factory=dict)
     v_head_dim: int = 0
+    value_pool: bool = True
     device_counters: tuple = ()
     host_counters: dict = field(default_factory=dict)
     trace_scopes: tuple = ()

@@ -577,6 +577,85 @@ def _engine_glm(which, slots=8, s_max=50176, n_pages=25089, block=16):
     return build
 
 
+def _engine_deepseek(which, slots=16, s_max=33792, n_pages=33793, block=16):
+    """The engine's two programs for DeepSeek-V3 at the code_ctx cell's
+    sizes: the published widths, layer 0 dense and 4 expert layers, 16 of
+    the router's 256 experts held, the vocabulary cut (one matmul behind
+    the stack); ONE pool, of latent pages 640 lanes wide. The model is
+    drawn at debug size and handed over as shapes."""
+    def build(place):
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models import deepseek_v3 as dv
+        from paddle_tpu.models.glm_moe_dsa import leaf_shapes
+        paddle.seed(0)
+        with _shapes_only():
+            model = dv.DeepseekV3ForCausalLM("debug")
+        model.eval()
+        model.config = full = dv.DeepseekV3Config(
+            vocab_size=1024, num_hidden_layers=5, first_k_dense_replace=1,
+            held_experts=(0, 16), dtype="bfloat16")
+        eng = DecodeEngine(model, capacity=slots, s_max=s_max,
+                           block_size=block, n_blocks=n_pages,
+                           prefix_cache=False)
+        assert len(eng._pool()) == 2        # the pages and the counters
+        shapes = leaf_shapes(full, indexer=False)
+        leaf = lambda n: place(shapes[n][0], BF16 if shapes[n][1] in
+                               ("matrix", "one", "zero") else F32)
+
+        def like(a):
+            return place(a.shape, a.dtype)
+
+        if which == "prefill":
+            fn, data = eng._prefill, [
+                place((1, s_max), I32), place((1,), I32),
+                place((eng._max_blocks,), I32), place((), I32)]
+        else:
+            fn, data = eng._decode, [
+                like(jnp.asarray(a))
+                for a in (eng._tok, eng._tables, eng._lens)]
+        return fn, [{n: leaf(n) for n in model._stacked_names()},
+                    leaf("embed_tokens"), leaf("final_norm"),
+                    leaf("lm_head"), {}, *data, *map(like, eng._pool())]
+
+    def check(compiled):
+        text = compiled.as_text()
+        # the routed experts' products are the grouped ones and nothing
+        # has the shape of the stack of experts or of an [held, rows,
+        # width] buffer
+        stacks = ("bf16[64,7168,2048]", "bf16[64,2048,7168]")
+        for _, shape, op, line in _hlo_instructions(text):
+            assert not any(st in shape for st in stacks), line[:200]
+            if any(st in line for st in stacks):
+                assert op == "custom-call" and "ragged-dot" in line, \
+                    line[:200]
+            # ([16, 8, 7168] is the 16 slots' 8 choices, not 16 experts')
+            assert not re.match(r"\w+\[(16,(?!8,)|64,)\d+,(2048|7168)\]",
+                                shape), line[:200]
+        assert "ragged-dot-none" in text
+        _assert_pools_stay_put(
+            compiled, jax.ShapeDtypeStruct((5, n_pages, 1, block, 640), BF16),
+            temp_below=build.temp_below)
+        if which == "prefill":
+            _assert_no_square_scores(compiled, s_max)
+            return
+        # the decode step reads its latents by the kernel: no value holds
+        # a row's pages to the table's length, let alone every slot's
+        assert "mla_latent_decode" in text
+        pages = -(-(s_max + 8) // block)
+        gathered = re.compile(rf"\w+\[(\d+,)?({pages * block}|{pages},{block})"
+                              rf",640\]")
+        for _, shape, op, line in _hlo_instructions(text):
+            assert not gathered.match(shape), line[:200]
+    build.check = check
+    build.paged_kernel = False      # latent_attention.py's, not paged_attention's
+    # temporaries: the cold program's carry of one row's latents (0.22
+    # GB) and a piece of a block's scores (128 heads x 256 x 1024
+    # float32, 0.13 GB, a few alive); the decode step's are a step's
+    build.temp_below = (64 << 20) if which == "decode" else (2048 << 20)
+    return build
+
+
 CASES = {
     "paged_decode_bf16_block16": _paged_decode(16, BF16, 4096),
     # the pool an engine could really hold on 16 GB (2 GiB each of K and
@@ -612,6 +691,10 @@ CASES = {
     "engine_prefill_paged_mimo_v2_long_in_sizes": _engine_mimo("prefill"),
     "engine_decode_chunk_glm_moe_dsa_long_ctx_sizes": _engine_glm("decode"),
     "engine_prefill_paged_glm_moe_dsa_long_ctx_sizes": _engine_glm("prefill"),
+    "engine_decode_chunk_deepseek_v3_code_ctx_sizes": _engine_deepseek(
+        "decode"),
+    "engine_prefill_paged_deepseek_v3_code_ctx_sizes": _engine_deepseek(
+        "prefill"),
 }
 
 
