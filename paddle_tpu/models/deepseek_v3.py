@@ -44,8 +44,13 @@ layer loop, the leaves and the model object. This file's own: the
 configuration (YaRN, groups), and the two attends, both DENSE over the
 row's whole context. A slot holds ONE kind of page, the latent's
 (``PagedPrograms.value_pool`` false: the engine builds no second pool),
-and a decode step reads it by ``kernels/latent_attention.py``: the live
-rows' own pages, each once, as key and as value.
+and both programs read it by ``kernels/latent_attention.py``: a decode
+step the live rows' own pages, each once, as key and as value
+(``mla_latent_decode``); a block of the cold prefill the row's carried
+latents up to its own rows in ONE launch a layer (``mla_latent_prefill``:
+scores, probabilities and the running sum of 8 heads x 256 queries stay
+in fast memory, tiles of keys no query of the block may see are not
+fetched), the plain pass being the CPU's path and the kernel's oracle.
 
 The cold prefill takes the absorbed form as well (278.5 kFLOP a causal
 pair and layer at these widths where keys and values expanded from the
@@ -166,7 +171,7 @@ DEEPSEEK_V3_PRESETS = {
                   original_max_position_embeddings=64),
 }
 
-# keys a piece of the prefill's causal pass takes at most: the scores of
+# keys a piece of the plain causal pass takes at most: the scores of
 # 128 heads x 256 queries x 1024 keys are what 64 x 256 x 2048 are
 ATTEND_KEYS_HEADS = G.ATTEND_KEYS * 64
 

@@ -92,6 +92,8 @@ from .. import nn
 from ..distributed.fleet.moe import (moe_dropless_ffn, moe_full_stream,
                                      moe_route_held)
 from ..kernels import topk_mask
+from ..kernels.latent_attention import (latent_prefill_pallas,
+                                        prefill_kernel_serves)
 from .llama import PagedPrograms, _rms, _row_pages, _token_insert
 
 __all__ = ["GlmMoeDsaConfig", "GlmMoeDsaForCausalLM", "GLM_MOE_DSA_PRESETS"]
@@ -517,14 +519,21 @@ def _causal_latent_pass(cfg, qc, lat_c, l, start, qcol, pad, first,
     """The absorbed queries qc [blk, H, lanes] of one block of a cold
     prefill (columns ``qcol`` = ``start..`` of the window) against the row's
     latents lat_c [L, total, lanes] of layer ``l`` from the row's first
-    token to the block's own: an online softmax, a piece of at most
-    ``at_most`` keys at a time (``ATTEND_KEYS``); ``allowed`` [blk,
-    total] narrows what a query sees. Returns the probabilities' sum of
-    latents [blk, H, rank] float32."""
+    token to the block's own; ``allowed`` [blk, total] narrows what a
+    query sees. On the chip ONE launch of a kernel that keeps scores,
+    probabilities and the running sum in fast memory
+    (``kernels/latent_attention.py``, ``mla_latent_prefill``); elsewhere,
+    and as that kernel's oracle, an online softmax a piece of at most
+    ``at_most`` keys at a time (``ATTEND_KEYS``). Returns the
+    probabilities' sum of latents [blk, H, rank] float32."""
     blk, H = qc.shape[0], qc.shape[1]
     total, lanes = lat_c.shape[1:]
     rank = cfg.kv_lora_rank
     scale = cfg.logit_divisor
+    if prefill_kernel_serves(qc, lat_c, rank):
+        with jax.named_scope("mla_prefill_attn"):
+            return latent_prefill_pallas(qc, lat_c, l, start, pad, allowed,
+                                         rank=rank, scale=1.0 / scale)
     kc, lo, hi = _block_pieces(total, blk, start, first,
                                at_most or ATTEND_KEYS)
 

@@ -92,6 +92,48 @@ def run_engine(m, prompt_list, max_new=8, mesh=None, **kw):
     return drain(eng, reqs), eng
 
 
+def latent_prefill_against_plain(start, pad, rows, keys, topk=None, heads=4,
+                                 blk=16, total=96, seed=0):
+    """The cold prefill's kernel (``kernels/latent_attention.py``,
+    ``mla_latent_prefill``, here in interpret mode at ``rows`` rows a
+    program and ``keys`` keys a fold) against
+    the plain pass it stands for (``glm_moe_dsa._causal_latent_pass``'s
+    body, what the CPU takes), float32: one block of ``blk`` queries at
+    columns ``start..`` of a window of ``total`` whose column ``pad``
+    holds position 0, layer 1 of 2, lanes past the latent zero; under
+    ``topk`` each query sees its ``topk`` best of drawn scores alone.
+    Returns (kernel's, plain pass's, the mask or None)."""
+    from types import SimpleNamespace
+    from unittest import mock
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import latent_attention as LA
+    from paddle_tpu.models import glm_moe_dsa as G
+    rng = np.random.RandomState(seed)
+    lanes, rank = 128, 64
+    lat_c = rng.randn(2, total, lanes).astype(np.float32)
+    qc = rng.randn(blk, heads, lanes).astype(np.float32)
+    lat_c[..., 80:] = qc[..., 80:] = 0
+    cfg = SimpleNamespace(kv_lora_rank=rank, logit_divisor=3.0)
+    qcol = start + jnp.arange(blk)
+    allowed = None
+    if topk is not None:
+        seen = G._block_seen(qcol, pad, 0, total)
+        allowed = G._chosen_mask(
+            jnp.where(seen, jnp.asarray(rng.randn(blk, total), jnp.float32),
+                      -jnp.inf), topk)
+    want = G._causal_latent_pass(cfg, jnp.asarray(qc), jnp.asarray(lat_c), 1,
+                                 start, qcol, pad, pad // blk, allowed)
+    with mock.patch.multiple(LA, _PREFILL_ROWS=rows, _PREFILL_KEYS=keys):
+        assert LA._prefill_tiles(heads, blk, total) == (rows // blk, keys)
+        got = LA.latent_prefill_pallas(
+            jnp.asarray(qc), jnp.asarray(lat_c), 1, start, pad, allowed,
+            rank=rank, scale=1 / 3.0, interpret=True)
+    assert got.shape == want.shape == (blk, heads, rank)
+    return np.asarray(got), np.asarray(want), allowed
+
+
 @contextlib.contextmanager
 def per_test_clock(nodeid, limit_s):
     """Fail the test named ``nodeid`` when ``limit_s`` seconds pass inside

@@ -319,6 +319,25 @@ def _assert_no_square_scores(compiled, s_max):
     assert not square, "\n".join(square)
 
 
+def _assert_latent_prefill_kernel(compiled, launches):
+    """The cold program's causal pass over the latents is ``launches``
+    launches of the one kernel, under the scope its readers look for;
+    the fast memory it asks for is the file's stated limit, and what the
+    compiler laid out for it lies within."""
+    from paddle_tpu.kernels import latent_attention as la
+    sizes = lambda key, line: [int(n) for n in re.findall(
+        rf'"{key}":\[\{{[^}}]*"size":"(\d+)"', line)]
+    calls = [line for _, _, op, line in _hlo_instructions(compiled.as_text())
+             if op == "custom-call" and la.PREFILL_KERNEL_NAME in line]
+    assert len(calls) == launches, len(calls)
+    for line in calls:
+        assert "mla_prefill_attn" in line
+        asked, = sizes("scoped_memory_configs", line)
+        used, = sizes("used_scoped_memory_configs", line)
+        assert asked == la._PREFILL_VMEM_BYTES == 64 << 20
+        assert 8 << 20 < used <= 32 << 20, used
+
+
 def _flash_train_dp2_mp2(batch=6, seq=2048, heads=32):
     """What the dp2 x mp2 train step asks of attention: the model's
     ``_attention`` under a GSPMD mesh, forward and backward. GSPMD cannot
@@ -544,6 +563,9 @@ def _engine_glm(which, slots=8, s_max=50176, n_pages=25089, block=16):
                 temp_below=build.temp_below)
         if which == "prefill":
             _assert_no_square_scores(compiled, s_max)
+            # dense under ``index_topk`` tokens and masked past it, in
+            # the dense layer's loop and in the expert layers'
+            _assert_latent_prefill_kernel(compiled, 4)
             return
         # the decode step's selection follows its rows: no value holds
         # every slot's indexer keys to the table's length (a row's own,
@@ -638,6 +660,8 @@ def _engine_deepseek(which, slots=16, s_max=33792, n_pages=33793, block=16):
             temp_below=build.temp_below)
         if which == "prefill":
             _assert_no_square_scores(compiled, s_max)
+            # in the dense layer's loop and in the expert layers'
+            _assert_latent_prefill_kernel(compiled, 2)
             return
         # the decode step reads its latents by the kernel: no value holds
         # a row's pages to the table's length, let alone every slot's

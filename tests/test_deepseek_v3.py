@@ -27,7 +27,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 from benchmark.lib import deepseek_program  # noqa: E402
 from benchmark.lib import deepseek_reference as R  # noqa: E402
 from benchmark.lib import deepseek_weights as W  # noqa: E402
-from harness import drive  # noqa: E402
+from harness import drive, latent_prefill_against_plain  # noqa: E402
 from paddle_tpu.distributed.fleet.moe import moe_route_held  # noqa: E402
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.kernels import latent_attention as LA  # noqa: E402
@@ -357,6 +357,30 @@ def pinned_case():
     pinned_case], ids=lambda f: f.__name__.removesuffix("_case"))
 def test_deepseek_v3(case):
     case()
+
+
+@pytest.mark.parametrize("start, pad, rows, keys", [
+    # a right-aligned row: ``pad`` inside the second tile of 32 keys (the
+    # first is skipped), ``first`` = 2, the block in the window's middle
+    (48, 37, 64, 32),
+    # the first block of such a row: queries ahead of ``pad`` see nothing
+    (32, 37, 64, 32),
+    # the last block of the window over all of it, one tile a block
+    (80, 0, 64, 16),
+    # tiles of 48 keys divide no ``start`` of this block or the next
+    (32, 5, 64, 48), (64, 5, 64, 48),
+    # 4 heads in programs of two and of one
+    (64, 21, 32, 32), (80, 3, 16, 96)],
+    ids=["pad_inside_a_tile", "first_block", "last_block", "tile_48_at_32",
+         "tile_48_at_64", "two_heads_a_program", "one_head_a_program"])
+def test_prefill_kernel_against_the_plain_pass(start, pad, rows, keys):
+    got, want, _ = latent_prefill_against_plain(start, pad, rows, keys)
+    width = np.abs(want).max()
+    assert width > 1
+    np.testing.assert_allclose(got, want, atol=1e-4 * width)
+    # a query ahead of the row's first token sees nothing and adds nothing
+    ahead = np.arange(start, start + 16) < pad
+    assert not got[ahead].any() and got[~ahead].any(axis=(1, 2)).all()
 
 
 @pytest.mark.parametrize("option, kw", [

@@ -25,7 +25,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from benchmark.lib import glm_program, glm_reference as R  # noqa: E402
 from benchmark.lib import glm_weights as W  # noqa: E402
-from harness import drive  # noqa: E402
+from harness import drive, latent_prefill_against_plain  # noqa: E402
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.models import glm_moe_dsa as G  # noqa: E402
 
@@ -512,6 +512,28 @@ def small_pool_case():
     ids=lambda f: f.__name__)
 def test_glm_moe_dsa(case):
     case()
+
+
+@pytest.mark.parametrize("start, pad, topk", [
+    # every query of the block has seen fewer tokens than it may keep
+    # (the mask then holds columns it may not see: the kernel's own
+    # compare is what keeps them out)
+    (32, 8, 48),
+    # the block's first query has seen exactly ``topk``, the others more
+    (48, 9, 40),
+    # far more seen than kept, the row's first tile skipped
+    (80, 33, 8)],
+    ids=["fewer_seen_than_kept", "exactly_as_many", "eight_of_many"])
+def test_prefill_kernel_under_a_mask_against_the_plain_pass(start, pad, topk):
+    got, want, allowed = latent_prefill_against_plain(
+        start, pad, rows=32, keys=32, topk=topk)
+    seen = np.asarray(G._block_seen(start + jnp.arange(16), pad, 0, 96))
+    kept = (np.asarray(allowed) & seen).sum(axis=1)
+    assert kept.tolist() == np.minimum(seen.sum(axis=1), topk).tolist()
+    assert (kept[0] == topk) == (start - pad + 1 >= topk)
+    assert (np.asarray(allowed) & ~seen).any() == (start - pad + 1 < topk)
+    width = np.abs(want).max()
+    np.testing.assert_allclose(got, want, atol=1e-4 * width)
 
 
 @pytest.mark.parametrize("option, kw", [
