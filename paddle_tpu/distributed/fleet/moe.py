@@ -194,8 +194,8 @@ def moe_route_held(logits, top_k, held=None, scoring="softmax", bias=None,
     to fit: an even router brings ``N * k * count / E`` pairs; P is
     twice that, rounded up to whole tiles of 128 rows, at most ``N *
     k``. Because the held pairs lie at the HEAD of ``order``,
-    :func:`moe_dropless_ffn` may read ``order[:P]`` alone whenever
-    ``group_sizes.sum() <= P``; it relies on that place."""
+    :func:`moe_dropless_ffn` may read ``order[:r]`` alone whenever
+    ``group_sizes.sum() <= r``; it relies on that place."""
     x = logits.astype(jnp.float32)
     if scoring == "softmax":
         scores = jax.nn.softmax(x, axis=-1)
@@ -239,15 +239,33 @@ def _keep_best_groups(choice, n_group, topk_group):
     return jnp.where(kept[:, :, None], per, -jnp.inf).reshape(n, e)
 
 
-def moe_full_stream(group_sizes, n_pairs, stream_rows=None):
-    """Whether :func:`moe_dropless_ffn` runs its products over the whole
-    stream of ``n_pairs`` rows: ``True`` (a Python bool) where the short
-    stream of ``stream_rows`` is not at most half of it (every expert
-    held: never shorter), else a traced bool, true when the pairs of
-    this call outgrow it."""
+def moe_stream_rungs(n_pairs, stream_rows):
+    """The lengths of the short streams :func:`moe_dropless_ffn` offers
+    a call of ``n_pairs`` pairs whose route said ``stream_rows`` (P),
+    ascending; ``()`` where the whole stream is the only program: P is
+    not at most half of it (every expert held: never shorter). The chip's
+    grouped kernel tiles a stream's rows by 512 where 512 divides it,
+    else by 256, else by 128, and computes a whole tile for every expert
+    it visits (PERF.md, Findings PR 39 and 46), so a rung is an ODD
+    number of tiles of 128 rows: P / 2 (what an even router brings,
+    where that is whole tiles) and P, each a tile longer where its tiles
+    are even: P = 128 gives (128), 256 (128, 384), 512 (384, 640)."""
     if stream_rows is None or 2 * stream_rows > n_pairs:
-        return True
-    return group_sizes.sum() > stream_rows
+        return ()
+    tiles = (r // _STREAM_TILE for r in (stream_rows // 2, stream_rows)
+             if r % _STREAM_TILE == 0)
+    return tuple((t + 1 - t % 2) * _STREAM_TILE for t in tiles)
+
+
+def moe_stream_rows(group_sizes, n_pairs, stream_rows=None):
+    """How many rows of the stream :func:`moe_dropless_ffn` runs its
+    products over: the first of :func:`moe_stream_rungs` that holds this
+    call's pairs (traced), else the whole stream's ``n_pairs``; the
+    Python int ``n_pairs`` where no short stream is offered."""
+    rows = n_pairs
+    for rung in reversed(moe_stream_rungs(n_pairs, stream_rows)):
+        rows = jnp.where(group_sizes.sum() <= rung, rung, rows)
+    return rows
 
 
 def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
@@ -269,14 +287,15 @@ def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
     stream's ``N * k`` rows (a chip that holds a share of the experts),
     the stream, the three products, the mask and the combine are built
     over the HEAD of the expert-sorted order, where the held pairs lie:
-    over ``order[:P / 2]`` (what an even router brings, where that is
-    whole tiles) when this call's pairs fit it, else over ``order[:P]``;
-    a call whose pairs outgrow P takes the whole stream
-    (:func:`moe_full_stream`), so nothing is dropped. The grouped kernel
-    computes a whole tile of rows for every expert it visits, so a
-    stream no longer than its pairs need is what keeps it at the
-    experts' bytes. Elsewhere (``None``, every expert held) the whole
-    stream is the only program traced."""
+    over the first of :func:`moe_stream_rungs` (about P / 2, what an
+    even router brings, and P) that holds this call's pairs; a call
+    whose pairs outgrow the last takes the whole stream, so nothing is
+    dropped (:func:`moe_stream_rows` says which ran). The grouped
+    kernel computes a whole tile of rows for every expert it visits, so
+    a stream no longer than its pairs need, of a length that keeps the
+    tile at 128 rows, is what keeps it at the experts' bytes. Elsewhere
+    (``None``, every expert held) the whole stream is the only program
+    traced."""
     n, d = tokens.shape
     k = topi.shape[1]
     dt = we_gate.dtype
@@ -311,10 +330,9 @@ def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
         return jnp.dot(at, out, precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32).astype(dt)
 
-    if moe_full_stream(group_sizes, n * k, stream_rows) is True:
+    rungs = moe_stream_rungs(n * k, stream_rows)
+    if not rungs:
         return full()
-    rungs = [r for r in (stream_rows // 2, stream_rows)
-             if r % _STREAM_TILE == 0]
     rung = sum((group_sizes.sum() > r).astype(jnp.int32) for r in rungs)
     return jax.lax.switch(
         rung, [functools.partial(head, r) for r in rungs] + [full])

@@ -48,6 +48,11 @@ class _NullSpan:
 
 
 _NOPROF = _NullSpan()
+# The benchmark's readers take a launch's entry (``stats()["launches"]``)
+# by position: the device counters behind ``tokens``, the host's behind
+# them. A device counter the programs gained since rides at the entry's
+# END, so that every older field keeps its place.
+_LOG_LAST = ("moe_stream_rows",)
 
 
 def _phase(prof, name, args=None):
@@ -527,7 +532,7 @@ class DecodeEngine:
         # (kp, vp), int8 engines (kp, vp, kscale, vscale), and a family
         # with per-slot state its state arrays behind them.
         _kv_scales_of = _llama.kv_scales_of
-        self._prefill_block = self._prefill_block_rows(self.s_max)
+        self._prefill_block = _llama.prefill_block_rows(cfg, self.s_max)
         progs = m.paged_programs(
             chunk=self.chunk, prefill_block=self._prefill_block,
             mp_axis=mp, seq_axis=sq, n_seq=n_sq)
@@ -793,20 +798,6 @@ class DecodeEngine:
             self._decode_progs[n] = fn
         return fn
 
-    @staticmethod
-    def _prefill_block_rows(s_max: int) -> int:
-        """Rows the cold prefill runs at a time. 256 is where a block's
-        matmuls cost what reading their bfloat16 weights costs (two
-        operations a weight byte a row, against a v5e's 240 a byte): a
-        smaller block re-reads the weights for nothing, a larger one
-        pads a short prompt for nothing and is no cheaper a row
-        (``PERF.md``, Findings, PR 33: 128, 256 and 512 on the chip).
-        Halved until the window holds two blocks."""
-        rows = 256
-        while rows > 8 and 2 * rows > s_max:
-            rows //= 2
-        return rows
-
     def _bucket_window(self, n: int) -> int:
         """Tail-window bucket for prefix-hit prefill: powers of two from
         16, capped at s_max — mixed tail lengths share a few compiled
@@ -1023,13 +1014,18 @@ class DecodeEngine:
 
     def _launch_entries(self):
         """``[t, kind, units, rows, tokens, *device counters, *host
-        counters]`` a launch, oldest first; the counters run on from
-        launch to launch (the device's are int32 and may wrap)."""
+        counters, *the device counters of _LOG_LAST]`` a launch, oldest
+        first; the counters run on from launch to launch (the device's
+        are int32 and may wrap)."""
         import jax
         log = list(self._launches.copy())
         counts = jax.device_get([e[5] for e in log]) if self._c_device \
             else [()] * len(log)
-        return [[*e[:5], *(int(v) for v in c), *e[6]]
+        names = self._progs.device_counters
+        ahead = [i for i, n in enumerate(names) if n not in _LOG_LAST]
+        last = [i for i, n in enumerate(names) if n in _LOG_LAST]
+        return [[*e[:5], *(int(c[i]) for i in ahead), *e[6],
+                 *(int(c[i]) for i in last)]
                 for e, c in zip(log, counts)]
 
     def _drain_scale_resets(self):

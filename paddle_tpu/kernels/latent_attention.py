@@ -48,8 +48,8 @@ own, as they lie in the prefill's contiguous carry.
 
 The kernel (:func:`latent_prefill_pallas`, ``mla_latent_prefill`` in a
 trace) is ONE launch a (block, layer): grid ``(H / G,)``, one program
-the ``G x blk`` rows of G heads (``_prefill_tiles``: 8 x 256 at the
-cells' widths), their queries, the softmax's state (m, l ``[G, blk,
+the ``G x blk`` rows of G heads (``_prefill_tiles``: 4 x 512 at the
+cells' blocks of 512 queries, 8 x 256 at a block of 256), their queries, the softmax's state (m, l ``[G, blk,
 1]``) and the float32 accumulator (the output block itself, ``[G, blk,
 rank]``) in VMEM for the whole walk over the keys; ``layer``, ``start``
 and ``pad`` are prefetched scalars and the trip count is data. The keys

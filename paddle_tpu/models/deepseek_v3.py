@@ -48,7 +48,7 @@ and both programs read it by ``kernels/latent_attention.py``: a decode
 step the live rows' own pages, each once, as key and as value
 (``mla_latent_decode``); a block of the cold prefill the row's carried
 latents up to its own rows in ONE launch a layer (``mla_latent_prefill``:
-scores, probabilities and the running sum of 8 heads x 256 queries stay
+scores, probabilities and the running sum of 4 heads x 512 queries stay
 in fast memory, tiles of keys no query of the block may see are not
 fetched), the plain pass being the CPU's path and the kernel's oracle.
 
@@ -57,7 +57,8 @@ pair and layer at these widths where keys and values expanded from the
 latents take 81.9 k): expanded keys and values of a 32 k row are 2.7 GB a
 layer, which one chip cannot carry beside the weights, and expanded anew
 for every block of 256 queries they cost 33.5 MFLOP a key where the
-block's own attention costs 21.0: 0.76 of the absorbed pass's operations,
+block's own attention costs 21.0: 0.76 of the absorbed pass's operations
+(0.44 at the 512 queries a block of a share of the experts holds),
 in head-sized products of contraction 192 and 128 against the absorbed
 pass's 640 and 2048 (PERF.md, Findings PR 44, has the chip's reading).
 """
@@ -275,9 +276,10 @@ class DeepseekV3ForCausalLM(G.GlmMoeDsaForCausalLM):
             kv_layers=cfg.num_hidden_layers, kv_heads=1,
             head_dim=cfg.latent_lanes, value_pool=False,
             slot_state=lambda slots: (
-                jax.ShapeDtypeStruct((4,), jnp.int32),),
+                jax.ShapeDtypeStruct((5,), jnp.int32),),
             device_counters=("moe_pairs", "moe_expert_visits",
-                             "moe_full_stream", "moe_groups_visited"),
+                             "moe_full_stream", "moe_groups_visited",
+                             "moe_stream_rows"),
             host_counters=ctx_tokens(cfg.num_hidden_layers),
             trace_scopes=("mla_prefill_attn", "mla_dense_decode",
                           "moe_group_route", "moe_shared_ffn",

@@ -89,8 +89,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import nn
-from ..distributed.fleet.moe import (moe_dropless_ffn, moe_full_stream,
-                                     moe_route_held)
+from ..distributed.fleet.moe import (moe_dropless_ffn, moe_route_held,
+                                     moe_stream_rows)
 from ..kernels import topk_mask
 from ..kernels.latent_attention import (latent_prefill_pallas,
                                         prefill_kernel_serves)
@@ -384,10 +384,11 @@ def _sparse_attend(cfg, qc, sel, ok):
 def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     """x + ffn(rms(x)); an expert layer adds the shared expert, computed
     whole, to the held experts' part of the routed sum. ``rows`` [n]
-    marks real tokens; ``counts`` int32 [3] gains (pairs computed, held
-    experts visited, 1 if the expert products took the whole stream),
-    and under a router with groups a fourth entry the groups that hold
-    a chosen expert of some real token."""
+    marks real tokens; ``counts`` int32 gains (pairs computed, held
+    experts visited, 1 if the expert products took the whole stream,
+    under a router with groups the groups that hold a chosen expert of
+    some real token, and last the rows of the stream the expert products
+    ran over)."""
     y = _rms(x, lp["post_ln"], cfg.rms_norm_eps)
     if f_kind == "dense":
         return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
@@ -410,14 +411,15 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
                                w["we_down"],
                                precision=jax.lax.Precision.DEFAULT,
                                stream_rows=stream_rows)
-    whole = moe_full_stream(sizes, order.shape[0], stream_rows)
-    gained = [sizes.sum(), (sizes > 0).sum(), jnp.asarray(whole, jnp.int32)]
+    ran = moe_stream_rows(sizes, order.shape[0], stream_rows)
+    gained = [sizes.sum(), (sizes > 0).sum(), ran == order.shape[0]]
     if cfg.n_group > 1:
         group = topi // (cfg.n_routed_experts // cfg.n_group)
         seen = (group[:, :, None] == jnp.arange(cfg.n_group)) \
             & rows[:, None, None]
-        gained.append(seen.any(axis=(0, 1)).sum().astype(jnp.int32))
-    counts = counts + jnp.stack(gained)
+        gained.append(seen.any(axis=(0, 1)).sum())
+    counts = counts + jnp.stack(
+        [jnp.asarray(g, jnp.int32) for g in (*gained, ran)])
     return x + shared + out.astype(x.dtype), counts
 
 
@@ -960,9 +962,9 @@ class GlmMoeDsaForCausalLM(nn.Layer):
             kv_layers=cfg.num_hidden_layers, kv_heads=1,
             head_dim=cfg.latent_lanes, v_head_dim=cfg.index_head_dim,
             slot_state=lambda slots: (
-                jax.ShapeDtypeStruct((3,), jnp.int32),),
+                jax.ShapeDtypeStruct((4,), jnp.int32),),
             device_counters=("moe_pairs", "moe_expert_visits",
-                             "moe_full_stream"),
+                             "moe_full_stream", "moe_stream_rows"),
             host_counters=dsa_tokens(cfg.num_hidden_layers, cfg.index_topk,
                                      widths),
             trace_scopes=("dsa_index_scores", "dsa_topk", "mla_prefill_attn",

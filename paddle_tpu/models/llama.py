@@ -238,6 +238,30 @@ class PagedPrograms:
     trace_scopes: tuple = ()
 
 
+def prefill_block_rows(cfg, s_max):
+    """Rows the cold prefill of a configuration runs at a time (what the
+    engine hands ``paged_programs`` as ``prefill_block``). 256 is where
+    a block's DENSE matmuls cost what reading their bfloat16 weights
+    costs (two operations a weight byte a row, against a v5e's 240 a
+    byte): a smaller block re-reads the weights for nothing, a larger
+    one pads a short prompt for nothing and is no cheaper a row
+    (``PERF.md``, Findings PR 33: 128, 256 and 512 on the chip). A
+    configuration that holds a SHARE of a router's experts
+    (``held_experts`` fewer than ``n_routed_experts``) reads a held
+    expert's weights once a block for the rows the router sends it,
+    ``rows * num_experts_per_tok / n_routed_experts``: where 256 rows
+    bring it fewer than 16, the block is 512 (Findings PR 46). Halved
+    until the window holds two blocks."""
+    rows = 256
+    held = getattr(cfg, "held_experts", None)
+    if held is not None and held[1] < cfg.n_routed_experts \
+            and rows * cfg.num_experts_per_tok < 16 * cfg.n_routed_experts:
+        rows = 512
+    while rows > 8 and 2 * rows > s_max:
+        rows //= 2
+    return rows
+
+
 def kv_scales_of(pool):
     """The int8 pools' scales ``(kscale, vscale)`` of a paged program's
     ``*pool``, None for float pools."""

@@ -43,8 +43,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..distributed.fleet.moe import (moe_dropless_ffn, moe_full_stream,
-                                     moe_route_held)
+from ..distributed.fleet.moe import (moe_dropless_ffn, moe_route_held,
+                                     moe_stream_rows)
 from ..kernels.paged_attention import paged_decode_attention
 from .llama import PagedPrograms, _rms, _rope, _row_pages, _token_insert
 
@@ -207,8 +207,9 @@ def _qkv(cfg, lp, h, positions, theta):
 def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     """x + ffn(rms(x)); an expert layer routes over all the router's
     experts and computes the held ones' part. ``rows`` [n] marks real
-    tokens; ``counts`` int32 [3] gains (pairs computed, held experts
-    visited, 1 if the expert products took the whole stream)."""
+    tokens; ``counts`` int32 [4] gains (pairs computed, held experts
+    visited, 1 if the expert products took the whole stream, the rows of
+    the stream they ran over)."""
     y = _rms(x, lp["post_ln"], cfg.layernorm_epsilon)
     if f_kind == "dense":
         return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
@@ -226,9 +227,10 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
                                w["we_up"], w["we_down"],
                                precision=jax.lax.Precision.DEFAULT,
                                stream_rows=stream_rows)
-    whole = moe_full_stream(sizes, order.shape[0], stream_rows)
-    counts = counts + jnp.stack([sizes.sum(), (sizes > 0).sum(),
-                                 jnp.asarray(whole, jnp.int32)])
+    ran = moe_stream_rows(sizes, order.shape[0], stream_rows)
+    counts = counts + jnp.stack(
+        [jnp.asarray(g, jnp.int32) for g in (
+            sizes.sum(), (sizes > 0).sum(), ran == order.shape[0], ran)])
     return x + out.astype(x.dtype), counts
 
 
@@ -604,9 +606,9 @@ class MimoV2ForCausalLM(nn.Layer):
                                      dtype),
                 jax.ShapeDtypeStruct((nw, slots, kvw, win, cfg.v_head_dim),
                                      dtype),
-                jax.ShapeDtypeStruct((3,), jnp.int32)),
+                jax.ShapeDtypeStruct((4,), jnp.int32)),
             device_counters=("moe_pairs", "moe_expert_visits",
-                             "moe_full_stream"),
+                             "moe_full_stream", "moe_stream_rows"),
             unsupported={
                 "prefix_cache": "a prefix hit needs the window layers' "
                                 "last keys and values at the page "

@@ -134,6 +134,44 @@ def latent_prefill_against_plain(start, pad, rows, keys, topk=None, heads=4,
     return np.asarray(got), np.asarray(want), allowed
 
 
+def cold_prefill_at_blocks(model, run, tokens=696, s_max=1024):
+    """One prompt of ``tokens`` (whole pages of 8) through a family's
+    cold prefill at blocks of 256 and of 512 rows (its first block
+    partly padding at either), the same pages handed to both: ``run(cfg, w, embed,
+    final_norm, lm_head, ids, pad_len, table_row, pool, block)`` gives
+    (float32 logits, pool). The logits agree, every page and whatever a
+    slot holds is written alike, the device counters count the same
+    pairs and the rows the stream ran over lie above them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving import DecodeEngine
+    eng = DecodeEngine(model, capacity=1, s_max=s_max, chunk=4, block_size=8,
+                       prefix_cache=False)
+    ids = np.zeros((1, s_max), np.int32)
+    ids[0, s_max - tokens:] = np.random.RandomState(3).randint(
+        1, eng._cfg.vocab_size, (tokens,))
+    table_row = np.zeros((eng._max_blocks,), np.int32)
+    table_row[:-(-tokens // 8)] = 1 + np.arange(-(-tokens // 8))
+    got = {}
+    for block in (256, 512):
+        got[block] = jax.jit(lambda *pool: run(
+            eng._cfg, *eng._weights(), ids,
+            np.array([s_max - tokens], np.int32), table_row, pool, block)
+        )(*eng._pool())
+    (la, pa), (lb, pb) = got[256], got[512]
+    assert float(jnp.abs(la).max()) > 0.01
+    np.testing.assert_allclose(la, lb, atol=2e-5)
+    for a, b in zip(pa[:-1], pb[:-1]):
+        if a.shape[1] == eng.n_blocks:  # page 0 is what no row's table names
+            a, b = a[:, 1:], b[:, 1:]
+        assert float(jnp.abs(a).max()) > 0.01
+        np.testing.assert_allclose(a, b, atol=2e-5)
+    names = eng._progs.device_counters
+    ca, cb = (dict(zip(names, np.asarray(p[-1]).tolist())) for p in (pa, pb))
+    assert ca["moe_pairs"] == cb["moe_pairs"] > 0
+    assert all(c["moe_stream_rows"] >= c["moe_pairs"] for c in (ca, cb))
+
+
 @contextlib.contextmanager
 def per_test_clock(nodeid, limit_s):
     """Fail the test named ``nodeid`` when ``limit_s`` seconds pass inside

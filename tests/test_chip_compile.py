@@ -308,15 +308,23 @@ def _assert_pools_stay_put(compiled, pool, temp_below=None):
 def _assert_no_square_scores(compiled, s_max):
     """The cold prefill's work follows its prompt: no instruction
     produces square scores (a value with two dimensions of the window's
-    width, give or take a block)."""
-    from paddle_tpu.inference.serving import DecodeEngine
-    wide = range(s_max, s_max + DecodeEngine._prefill_block_rows(s_max) + 1)
+    width, give or take a block: 512 rows at the most)."""
+    wide = range(s_max, s_max + 512 + 1)
     square = []
     for _, shape, _, line in _hlo_instructions(compiled.as_text()):
         for dims in re.findall(r"\w+\[([\d,]+)\]", shape):
             if sum(int(d) in wide for d in dims.split(",")) >= 2:
                 square.append(line.strip()[:200])
     assert not square, "\n".join(square)
+
+
+def _expert_streams(text):
+    """The shapes the grouped expert products of a compiled program give
+    (``[rows of the stream, width]``): which lengths of the stream it
+    holds a program for."""
+    return {shape.split("{")[0] for _, shape, op, line in
+            _hlo_instructions(text)
+            if op == "custom-call" and "ragged-dot-none" in line}
 
 
 def _assert_latent_prefill_kernel(compiled, launches):
@@ -472,16 +480,16 @@ def _engine_mimo(which, slots=48, s_max=9216, n_pages=27649, block=16):
                     line[:200]
             assert not re.match(r"\w+\[(16|96),\d+,(2048|4096)\]", shape), \
                 line[:200]
-        # ... over the head of the expert-sorted stream, as long as an
-        # even router's pairs or twice that (P) in whole tiles of 128,
-        # beside the whole stream's for a call whose pairs outgrow P
-        streams = (256 * 8, 256, 128) if which == "prefill" \
+        # ... over the head of the expert-sorted stream, about as long
+        # as an even router's pairs or twice that (P) in an odd number
+        # of tiles of 128, beside the whole stream's for a call whose
+        # pairs outgrow them: a block of 512 rows (P = 512), a decode
+        # step of 48 (P = 128)
+        streams = (512 * 8, 640, 384) if which == "prefill" \
             else (slots * 8, 128)
-        products = {shape.split("{")[0] for _, shape, op, line in
-                    _hlo_instructions(text)
-                    if op == "custom-call" and "ragged-dot-none" in line}
-        assert products == {f"bf16[{r},{width}]" for r in streams
-                            for width in (2048, 4096)}, products
+        assert _expert_streams(text) == {
+            f"bf16[{r},{width}]" for r in streams
+            for width in (2048, 4096)}
         for wide in (256, 128):       # the K pool, the V pool, the rings
             _assert_pools_stay_put(
                 compiled, jax.ShapeDtypeStruct(
@@ -555,7 +563,11 @@ def _engine_glm(which, slots=8, s_max=50176, n_pages=25089, block=16):
                 line[:200]
             # the indexer's [rows, heads, keys] scores come in pieces
             assert not re.match(rf"\w+\[\d+,32,{s_max}\]", shape), line[:200]
-        assert "ragged-dot-none" in text
+        # a block of 512 rows, a decode step's whole stream of 8 slots
+        streams = (512 * 8, 640, 384) if which == "prefill" else (slots * 8,)
+        assert _expert_streams(text) == {
+            f"bf16[{r},{width}]" for r in streams
+            for width in (2048, 6144)}
         for wide in (640, 128):     # the latent pages, the indexer's
             _assert_pools_stay_put(
                 compiled, jax.ShapeDtypeStruct(
@@ -654,7 +666,11 @@ def _engine_deepseek(which, slots=16, s_max=33792, n_pages=33793, block=16):
             # ([16, 8, 7168] is the 16 slots' 8 choices, not 16 experts')
             assert not re.match(r"\w+\[(16,(?!8,)|64,)\d+,(2048|7168)\]",
                                 shape), line[:200]
-        assert "ragged-dot-none" in text
+        # a block of 512 rows, a decode step's whole stream of 16 slots
+        streams = (512 * 8, 640, 384) if which == "prefill" else (slots * 8,)
+        assert _expert_streams(text) == {
+            f"bf16[{r},{width}]" for r in streams
+            for width in (2048, 7168)}
         _assert_pools_stay_put(
             compiled, jax.ShapeDtypeStruct((5, n_pages, 1, block, 640), BF16),
             temp_below=build.temp_below)

@@ -12,6 +12,7 @@ uncut layer; what the engine refuses; and that the code this family
 shares with ``glm_moe_dsa`` and ``mimo_v2`` left their programs as they
 were."""
 
+import functools
 import hashlib
 import pathlib
 import sys
@@ -27,7 +28,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 from benchmark.lib import deepseek_program  # noqa: E402
 from benchmark.lib import deepseek_reference as R  # noqa: E402
 from benchmark.lib import deepseek_weights as W  # noqa: E402
-from harness import drive, latent_prefill_against_plain  # noqa: E402
+from harness import (cold_prefill_at_blocks, drive,  # noqa: E402
+                     latent_prefill_against_plain)
 from paddle_tpu.distributed.fleet.moe import moe_route_held  # noqa: E402
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.kernels import latent_attention as LA  # noqa: E402
@@ -95,14 +97,15 @@ def engine_case():
     # one kind of page: a latent of 16 + 8 in a lane tile, no second pool
     assert eng._kp.shape == (5, 25, 1, 8, 128) and eng._vp is None
     assert len(eng._pool()) == 2 and eng._n_pool == 2
-    assert [s.shape for s in eng._state_specs] == [(4,)]
+    assert [s.shape for s in eng._state_specs] == [(5,)]
     assert deepseek_program.kv_bytes_per_block(CFG, 8) == 5 * 8 * 128 * 2
     # the counters: a decode step reads a live row's context in every layer
     log = stats["launches"]
     assert stats["mla_ctx_tokens"] == 5 * stats["decode_ctx_tokens"] > 0
     assert log[-1][5:] == [stats[n] for n in (
         "moe_pairs", "moe_expert_visits", "moe_full_stream",
-        "moe_groups_visited", "mla_ctx_tokens")]
+        "moe_groups_visited", "mla_ctx_tokens", "moe_stream_rows")]
+    assert stats["moe_stream_rows"] >= stats["moe_pairs"]
     units = 4 * (stats["device_steps"] + stats["prefill_blocks"])
     assert 0 < stats["moe_expert_visits"] <= 4 * units
     assert units <= stats["moe_groups_visited"] <= 4 * units
@@ -130,7 +133,7 @@ def logits_case():
                                    np.arange(n - 1, seq.size)))
             for n, seq in zip(sizes, seqs)]
     pool = (jnp.zeros((5, 20, 1, bs, cfg.latent_lanes)),
-            jnp.zeros((4,), jnp.int32))
+            jnp.zeros((5,), jnp.int32))
     tables = np.zeros((3, 8), np.int32)
     tables[0], tables[2] = np.r_[1:9], np.r_[9:17]
     prefill = jax.jit(lambda ids, pad, table, pool: G._prefill(
@@ -306,7 +309,7 @@ def share_case():
              for k in ("we_gate", "we_up", "we_down")}
         out, counts = jax.jit(lambda w, held: G._ffn(
             mcfg, w, held, "moe", 1, x, jnp.ones((24,), bool),
-            jnp.zeros((4,), jnp.int32)))(w, held)
+            jnp.zeros((5,), jnp.int32)))(w, held)
         parts.append(np.asarray(out - x) - shared)
         assert 0 <= int(counts[0]) < 4 * 24 and int(counts[1]) <= 4
         groups.add(int(counts[3]))
@@ -319,9 +322,13 @@ def share_case():
 # sha256 of the StableHLO that glm_moe_dsa's two paged programs lower to
 # on its debug model, and moe_route_held without groups at the expert
 # cells' router (mimo_v2's and glm_moe_dsa's route), taken at the parent
-# of the PR that moved their shared code under this family
-PINNED = {"prefill_paged": "59b4df8933ac561c",
-          "decode_chunk_paged": "fee0de2eae3fe4f9",
+# of the PR that moved their shared code under this family; the two
+# programs taken again where the device counters gained their fourth
+# entry, the stream's rows (by operation and type the parent's text but
+# for the vector's length, that entry's constant and its place in the
+# sum: 59b4df8933ac561c and fee0de2eae3fe4f9 before)
+PINNED = {"prefill_paged": "b2db1d487bac8ee6",
+          "decode_chunk_paged": "bc36a82a64f17437",
           "moe_route_held": "2ae2a8358eb6b7e4"}
 
 
@@ -352,9 +359,16 @@ def pinned_case():
             == PINNED[name], name
 
 
+def block_case():
+    """A cold prefill at blocks of 512 rows against the same prompt at
+    256: the logits and the one pool's pages."""
+    cold_prefill_at_blocks(
+        model(), functools.partial(G._prefill, attend=D._prefill_attend))
+
+
 @pytest.mark.parametrize("case", [
     engine_case, logits_case, kernel_case, yarn_case, route_case, share_case,
-    pinned_case], ids=lambda f: f.__name__.removesuffix("_case"))
+    pinned_case, block_case], ids=lambda f: f.__name__.removesuffix("_case"))
 def test_deepseek_v3(case):
     case()
 

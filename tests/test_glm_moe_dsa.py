@@ -25,7 +25,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from benchmark.lib import glm_program, glm_reference as R  # noqa: E402
 from benchmark.lib import glm_weights as W  # noqa: E402
-from harness import drive, latent_prefill_against_plain  # noqa: E402
+from harness import (cold_prefill_at_blocks, drive,  # noqa: E402
+                     latent_prefill_against_plain)
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.models import glm_moe_dsa as G  # noqa: E402
 
@@ -141,7 +142,7 @@ def logits_case():
             SEED, CFG, seq, np.arange(n_prompt - 1, seq.size)))
         pool = (jnp.zeros((5, n_pages, 1, bs, cfg.latent_lanes)),
                 jnp.zeros((5, n_pages, 1, bs, cfg.index_head_dim)),
-                jnp.zeros((3,), jnp.int32))
+                jnp.zeros((4,), jnp.int32))
         ids = np.zeros((1, s_max), np.int32)
         ids[0, s_max - n_prompt:] = seq[:n_prompt]
         table = jnp.asarray(np.r_[1:9], jnp.int32)
@@ -171,7 +172,7 @@ def engine_case():
     stats = eng.stats()
     assert stats["admitted"] == stats["retired"] == 3
     assert "ssm_row_steps" not in stats         # a recurrent family's own
-    assert [s.shape for s in eng._state_specs] == [(3,)]
+    assert [s.shape for s in eng._state_specs] == [(4,)]
     assert eng._kp.shape == (5, 17, 1, 8, 128)      # 16 + 8 -> a lane tile
     assert eng._vp.shape == (5, 17, 1, 8, 16)
     assert eng._progs.unsupported.keys() >= {"prefix_cache", "spec_decode"}
@@ -181,7 +182,8 @@ def counters_case():
     """The counters read what the lengths say: a decode step scores a
     live row's context in every layer and selects ``min(context,
     index_topk)`` of it; the launches' entries carry both behind the
-    device's three."""
+    device's three, and the device's later fourth behind them: every
+    older field stands where it stood."""
     eng, ps, news, reqs = served()
     stats = eng.stats()
     log = stats["launches"]     # [t, kind, units, rows, tokens, *counters]
@@ -196,7 +198,9 @@ def counters_case():
                            stats["moe_full_stream"],
                            stats["dsa_scored_tokens"],
                            stats["dsa_selected_tokens"],
-                           stats["dsa_scored_columns"]]
+                           stats["dsa_scored_columns"],
+                           stats["moe_stream_rows"]]
+    assert stats["moe_stream_rows"] >= stats["moe_pairs"]
     assert all(b[8] - a[8] == (5 * b[4] if b[1] == "decode" else 0)
                for a, b in zip(log, log[1:]))
     fed = sum(p.size + n - 1 for p, n in zip(ps, news))
@@ -216,7 +220,8 @@ def counters_case():
                  "engine_dsa_selected_tokens_total",
                  "engine_dsa_scored_columns_total", "engine_moe_pairs_total",
                  "engine_moe_expert_visits_total",
-                 "engine_moe_full_stream_total"):
+                 "engine_moe_full_stream_total",
+                 "engine_moe_stream_rows_total"):
         assert name in snap
 
 
@@ -280,9 +285,9 @@ def columns_case():
     assert stats["dsa_scored_tokens"] < stats["dsa_scored_columns"]
     log = [e for e in stats["launches"] if e[1] == "decode"]
     assert len(log) == len(lens_log)
-    assert log[-1][8:] == [stats["dsa_scored_tokens"],
-                           stats["dsa_selected_tokens"],
-                           stats["dsa_scored_columns"]]
+    assert log[-1][8:11] == [stats["dsa_scored_tokens"],
+                             stats["dsa_selected_tokens"],
+                             stats["dsa_scored_columns"]]
     assert "engine_dsa_scored_columns_total" in str(eng.metrics.snapshot())
     # on the host as in the program: one rule
     assert G._width_index(np.asarray([0, 31, 32, 127, 128, 167]),
@@ -475,7 +480,7 @@ def share_case():
         w = {k: jnp.concatenate([jnp.zeros_like(held[k]), held[k]])
              for k in ("we_gate", "we_up", "we_down")}
         out, counts = G._ffn(mcfg, w, held, "moe", 1, x,
-                             jnp.ones((24,), bool), jnp.zeros((3,), jnp.int32))
+                             jnp.ones((24,), bool), jnp.zeros((4,), jnp.int32))
         parts.append(np.asarray(out - x) - shared)
         assert 0 < int(counts[0]) < 2 * 24 and 0 < int(counts[1]) <= 4
     assert np.abs(whole - shared).max() > 0.01
@@ -505,10 +510,17 @@ def small_pool_case():
     assert served_gap(r_high.wait(1), high.size) < 1e-6
 
 
+def block_case():
+    """A cold prefill at blocks of 512 rows against the same prompt at
+    256, most of it past ``index_topk`` tokens: the logits, the latent
+    pages and the indexer's."""
+    cold_prefill_at_blocks(model(), G._prefill)
+
+
 @pytest.mark.parametrize("case", [
     logits_case, engine_case, counters_case, absorbed_case, chosen_case,
     wrong_selection_case, share_case, small_pool_case, widths_case,
-    columns_case, ties_case, kernel_case],
+    columns_case, ties_case, kernel_case, block_case],
     ids=lambda f: f.__name__)
 def test_glm_moe_dsa(case):
     case()

@@ -22,7 +22,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from benchmark.lib import mimo_program, mimo_reference  # noqa: E402
 from benchmark.lib import mimo_weights as W  # noqa: E402
-from harness import drive  # noqa: E402
+from harness import cold_prefill_at_blocks, drive  # noqa: E402
 from paddle_tpu.distributed.fleet.moe import moe_route_held  # noqa: E402
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
@@ -142,7 +142,9 @@ def stats_case():
     assert sum(e[4] for e in decode) == stats["decode_ctx_tokens"]
     assert sum(e[2] * e[3] for e in decode) == stats["decode_row_steps"]
     assert log[-1][5:] == [stats["moe_pairs"], stats["moe_expert_visits"],
-                           stats["moe_full_stream"]]
+                           stats["moe_full_stream"], stats["moe_stream_rows"]]
+    # every (step or block, expert layer) ran the whole stream of its rows
+    assert stats["moe_stream_rows"] >= stats["moe_pairs"] > 0
     assert all(a[0] <= b[0] and a[5] < b[5] for a, b in zip(log, log[1:]))
 
 
@@ -191,7 +193,7 @@ def share_case():
         w = {k: jnp.concatenate([jnp.zeros_like(held[k])] * 2 + [held[k]])
              for k in ("we_gate", "we_up", "we_down")}
         out, counts = M._ffn(mcfg, w, held, "moe", 2, x,
-                             jnp.ones((24,), bool), jnp.zeros((3,), jnp.int32))
+                             jnp.ones((24,), bool), jnp.zeros((4,), jnp.int32))
         parts.append(np.asarray(out - x))
         assert 0 < int(counts[0]) < 2 * 24 and 0 < int(counts[1]) <= 4
     np.testing.assert_allclose(sum(parts), whole, atol=2e-6)
@@ -287,9 +289,18 @@ def kernel_case():
     assert not pa._kernel_serves(kp.astype(jnp.int8), vp.astype(jnp.int8))
 
 
+def block_case():
+    """A cold prefill at blocks of 512 rows against the same prompt at
+    256: the logits, the global layers' pages and the window layers'
+    rings."""
+    cold_prefill_at_blocks(
+        model(), lambda cfg, *a: M._prefill(cfg, *a[:-2], jnp.int32(0),
+                                            *a[-2:]))
+
+
 @pytest.mark.parametrize("case", [
     engine_case, stats_case, ring_case, share_case, router_case,
-    window_case, kernel_case], ids=lambda f: f.__name__)
+    window_case, kernel_case, block_case], ids=lambda f: f.__name__)
 def test_mimo_v2(case):
     case()
 
@@ -313,7 +324,7 @@ def test_what_the_family_cannot_serve_raises_at_construction(option, kw):
 
 
 def _share_layer(n=256, d=64, f=32):
-    """One expert layer at the routing shapes of the long_in cell (256
+    """One expert layer at the routing shapes of the long_in cell (``n``
     rows, 8 of 256 experts a token, 16 held) and a small width: the
     second of two expert layers, so that the layer's groups lie behind
     another layer's in the one stack. (config, stack, layer leaves, x)."""
@@ -351,32 +362,70 @@ def _dense_share(cfg, w, y, topi, gates, layer=1):
 # the whole-stream program with every expert held, as the parent of the
 # PR that brought the short stream lowered it (sha256 of the StableHLO)
 WHOLE_STREAM_PINNED = "15c774aeb92595e3"
-# case -> (selection bias on which experts, rows of padding ahead, the
-# pairs it must bring (above, at most), whether the whole stream runs);
-# P = 256 of 2048 rows, an even router brings 128 pairs
+HELD = slice(32, 48)
+# case -> (rows of the call, selection bias on which experts, rows of
+# padding ahead, the router's (n_group, topk_group), the pairs it must
+# bring (above, at most), the rows of the stream the products run over).
+# 8 of 256 experts a token, 16 held: an even router brings rows / 2 pairs
 STREAM_CASES = {
-    "pairs_fit_an_even_share": (None, 0, (64, 128), 0),
-    "pairs_fit_twice_it": ((slice(32, 48), 0.005), 0, (128, 256), 0),
-    "pairs_overflow": ((slice(32, 40), 10.0), 0, (2047, 2048), 1),
-    "padded_first_block": ((slice(32, 48), 0.005), 100, (64, 128), 0),
+    # a decode chunk of 48 slots: P = 128 of 384 pairs, one rung of 128
+    "48_rows_fit_the_rung": (48, None, 0, (1, 1), (8, 128), 128),
+    "48_rows_overflow": (48, (slice(32, 40), 10.0), 0, (1, 1),
+                         (383, 384), 384),
+    # a block of 256 rows: P = 256 of 2048 pairs, rungs of 128 and 384
+    "256_rows_fit_an_even_share": (256, None, 0, (1, 1), (64, 128), 128),
+    "256_rows_between_the_rungs": (256, (HELD, 0.005), 0, (1, 1),
+                                   (128, 256), 384),
+    "256_rows_past_P_in_the_last_rung": (256, (HELD, 0.03), 0, (1, 1),
+                                         (256, 384), 384),
+    "256_rows_past_the_last_rung": (256, (HELD, 0.05), 0, (1, 1),
+                                    (384, 640), 2048),
+    "256_rows_overflow": (256, (slice(32, 40), 10.0), 0, (1, 1),
+                          (2047, 2048), 2048),
+    "256_rows_padded_first_block": (256, (HELD, 0.005), 100, (1, 1),
+                                    (64, 128), 128),
+    # a block of 512 rows: P = 512 of 4096 pairs, rungs of 384 and 640
+    "512_rows_fit_the_first_rung": (512, None, 0, (1, 1), (128, 384), 384),
+    "512_rows_between_the_rungs": (512, (HELD, 0.02), 0, (1, 1),
+                                   (512, 640), 640),
+    "512_rows_past_the_last_rung": (512, (HELD, 0.03), 0, (1, 1),
+                                    (640, 1024), 4096),
+    # ... under a router that keeps 4 of 8 groups of 32 experts (the
+    # held 16 are half of the second group)
+    "512_rows_grouped_first_rung": (512, None, 0, (8, 4), (128, 384), 384),
+    "512_rows_grouped_between": (512, (HELD, 0.01), 0, (8, 4),
+                                 (384, 640), 640),
 }
+
+
+@pytest.mark.parametrize("n_pairs, p, rungs", [
+    (2048, None, ()), (64, 64, ()), (128, 128, ()), (384, 128, (128,)),
+    (2048, 256, (128, 384)), (4096, 512, (384, 640)), (2048, 384, (384,)),
+    (4096, 1024, (640, 1152))])
+def test_the_short_streams_are_odd_numbers_of_tiles(n_pairs, p, rungs):
+    """``moe_stream_rungs``: about P / 2 and P rows, each an odd number
+    of the chip's tiles of 128 rows; none where P is over half of the
+    stream (every expert held; a decode chunk of 8 or 16 slots)."""
+    from paddle_tpu.distributed.fleet import moe
+    assert moe.moe_stream_rungs(n_pairs, p) == rungs
 
 
 @pytest.mark.parametrize("case", [*STREAM_CASES, "every_expert_held"])
 def test_expert_products_over_the_head_of_the_stream(case):
-    """``moe_dropless_ffn`` over ``order[:P / 2]`` and ``order[:P]``
-    against the whole stream and against every held expert computed
-    densely, where the rule engages: nothing is dropped when the pairs
-    outgrow P (every token favours held experts), and the third device
-    counter says when the whole stream ran."""
+    """``moe_dropless_ffn`` over the head of the expert-sorted order, as
+    long as the first rung that holds the call's pairs, against the
+    whole stream and against every held expert computed densely, where
+    the rule engages: nothing is dropped when the pairs outgrow the last
+    rung (every token favours held experts), and the device counters say
+    when the whole stream ran and how many rows the products ran over."""
     from paddle_tpu.distributed.fleet import moe
-    cfg, w, lp, x = _share_layer()
-    n, k = x.shape[0], cfg.num_experts_per_tok
     if case == "every_expert_held":
+        cfg, w, lp, x = _share_layer()
+        n = x.shape[0]
         topi, gates, order, sizes, rows_p = moe_route_held(
             x @ lp["router"][:, :16], 2)
-        assert rows_p == n * 2 \
-            and moe.moe_full_stream(sizes, n * 2, rows_p) is True
+        assert rows_p == n * 2 and moe.moe_stream_rungs(n * 2, rows_p) == () \
+            and moe.moe_stream_rows(sizes, n * 2, rows_p) == n * 2
         args = (x, topi, gates, order, sizes,
                 *(w[name][:16] for name in ("we_gate", "we_up", "we_down")))
 
@@ -389,30 +438,41 @@ def test_expert_products_over_the_head_of_the_stream(case):
         assert hashlib.sha256(lowered.encode()).hexdigest()[:16] \
             == WHOLE_STREAM_PINNED
         return
-    bias, pad, (above, at_most), whole_stream = STREAM_CASES[case]
+    n, bias, pad, (n_group, topk_group), (above, at_most), ran = \
+        STREAM_CASES[case]
+    cfg, w, lp, x = _share_layer(n)
+    k = cfg.num_experts_per_tok
     if bias is not None:
         lp["router_bias"] = lp["router_bias"].at[bias[0]].set(bias[1])
     rows = jnp.arange(n) >= pad
-    got, counts = jax.jit(
-        lambda x, rows: M._ffn(cfg, w, lp, "moe", 1, x, rows,
-                               jnp.zeros((3,), jnp.int32)))(x, rows)
     y = M._rms(x, lp["post_ln"], cfg.layernorm_epsilon)
     topi, gates, order, sizes, rows_p = moe_route_held(
         y @ lp["router"], k, cfg.held_experts, scoring=cfg.scoring_func,
-        bias=lp["router_bias"], rows=rows)
-    assert rows_p == 256 and order.shape == (n * k,)
+        bias=lp["router_bias"], rows=rows, n_group=n_group,
+        topk_group=topk_group)
+    assert rows_p == max(128, n) and order.shape == (n * k,)
     assert above < int(sizes.sum()) <= at_most
-    assert counts.tolist() == [int(sizes.sum()), int((sizes > 0).sum()),
-                               whole_stream]
+    assert int(moe.moe_stream_rows(sizes, n * k, rows_p)) == ran
+    groups = jnp.zeros((32,), jnp.int32).at[16:].set(sizes)
+    if n_group == 1:
+        got, counts = jax.jit(
+            lambda x, rows: M._ffn(cfg, w, lp, "moe", 1, x, rows,
+                                   jnp.zeros((4,), jnp.int32)))(x, rows)
+        assert counts.tolist() == [int(sizes.sum()), int((sizes > 0).sum()),
+                                   int(ran == n * k), ran]
+        got = got - x
+    else:       # this family's router has no groups: the stream alone
+        got = jax.jit(lambda y: moe.moe_dropless_ffn(
+            y, topi, gates, order, groups, w["we_gate"], w["we_up"],
+            w["we_down"], stream_rows=rows_p))(y)
     want = _dense_share(cfg, w, y, topi, gates)
     assert np.abs(want).max() > 0.01
-    np.testing.assert_allclose(got - x, want, atol=2e-6)
-    assert float(jnp.abs(got - x)[:pad].sum()) == 0.0
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    assert float(jnp.abs(got)[:pad].sum()) == 0.0
     # the same inputs through the whole stream
-    groups = jnp.zeros((32,), jnp.int32).at[16:].set(sizes)
     whole = moe.moe_dropless_ffn(y, topi, gates, order, groups, w["we_gate"],
                                  w["we_up"], w["we_down"])
-    np.testing.assert_allclose(got - x, whole, atol=2e-6)
+    np.testing.assert_allclose(got, whole, atol=2e-6)
 
 
 # sha256 of the StableHLO granite_hybrid.py's two paged programs lower to

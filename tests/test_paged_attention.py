@@ -477,11 +477,11 @@ class TestPagedEngine:
         token and a block and a part each equal solo ``generate``, and
         the engine counts the blocks it ran beside what whole windows
         would have been."""
-        from paddle_tpu.inference.serving import DecodeEngine
+        from paddle_tpu.models.llama import prefill_block_rows
         m = shared_model()
         sizes = (31, 32, 33, 40, 9)
         prompts = make_prompts(np.random.RandomState(4), 128, sizes)
-        assert DecodeEngine._prefill_block_rows(64) == 32
+        assert prefill_block_rows(m.config, 64) == 32
         outs, eng = run_engine(m, prompts, kv_dtype=kv,
                                prefix_cache=False)
         for p, o in zip(prompts, outs):
@@ -490,14 +490,29 @@ class TestPagedEngine:
         assert st["prefill_blocks"] == 1 + 1 + 2 + 2 + 1
         assert st["prefill_window_blocks"] == 2 * len(sizes)
 
-    @pytest.mark.parametrize("s_max,rows", [
-        (2560, 256), (3328, 256), (2048, 256), (512, 256), (511, 128),
-        (144, 64), (96, 32), (64, 32), (16, 8), (10, 8)])
-    def test_prefill_block_rows_rule(self, s_max, rows):
-        """256 rows a block wherever the window holds two of them, else
-        the largest power of two that gives two blocks."""
-        from paddle_tpu.inference.serving import DecodeEngine
-        assert DecodeEngine._prefill_block_rows(s_max) == rows
+    @pytest.mark.parametrize("s_max,rows,experts", [
+        (2560, 256, None), (3328, 256, None), (2048, 256, None),
+        (512, 256, None), (511, 128, None), (144, 64, None),
+        (96, 32, None), (64, 32, None), (16, 8, None), (10, 8, None),
+        # (experts a token, the router's, held here): a block of 256
+        # rows brings a held expert 8 rows, 64 (every expert held:
+        # Mixtral's 2 of 8), 16 and 15
+        (33792, 512, (8, 256, 16)), (9216, 256, (2, 8, 8)),
+        (9216, 256, (8, 128, 16)), (9216, 512, (15, 256, 16)),
+        (9216, 256, (8, 256, 256)),
+        (1024, 512, (8, 256, 16)), (1023, 256, (8, 256, 16)),
+        (64, 32, (8, 256, 16))])
+    def test_prefill_block_rows_rule(self, s_max, rows, experts):
+        """256 rows a block, 512 where the configuration holds a share
+        of a router's experts and 256 rows bring a held expert fewer
+        than 16, wherever the window holds two of them, else the largest
+        power of two that gives two blocks."""
+        from types import SimpleNamespace
+        from paddle_tpu.models.llama import prefill_block_rows
+        cfg = SimpleNamespace() if experts is None else SimpleNamespace(
+            num_experts_per_tok=experts[0], n_routed_experts=experts[1],
+            held_experts=(0, experts[2]))
+        assert prefill_block_rows(cfg, s_max) == rows
 
     def test_sustained_admission_never_resets(self):
         """Continuous mixed arrivals far past the contiguous engine's
