@@ -37,7 +37,7 @@ from benchmark.lib import manifest as mf
 from benchmark.runners.serve import engine_kwargs
 from paddle_tpu.distributed.fleet import moe
 from paddle_tpu.inference.serving import DecodeEngine
-from paddle_tpu.models import llama
+from paddle_tpu.models import paged_stack
 
 manifest = mf.load_manifest(args.manifest)
 cfg, mix = mf.cell_files(manifest, mf.find_cell(manifest, args.cell))
@@ -80,7 +80,7 @@ def prefill(eng, tokens):
 
 
 def variant(block, rungs, lengths, counter=None):
-    patches = [mock.patch.object(llama, "prefill_block_rows",
+    patches = [mock.patch.object(paged_stack, "prefill_block_rows",
                                  lambda cfg, s_max: block)]
     if rungs == "parent":
         patches.append(mock.patch.object(moe, "moe_stream_rungs",

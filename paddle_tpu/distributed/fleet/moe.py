@@ -21,7 +21,7 @@ from ... import nn
 from .mp_layers import shard_hint
 
 __all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate",
-           "moe_dispatch_combine", "moe_route_held", "moe_full_stream"]
+           "moe_dispatch_combine", "moe_route_held", "moe_held_ffn"]
 
 
 class NaiveGate(nn.Layer):
@@ -336,6 +336,46 @@ def moe_dropless_ffn(tokens, topi, gates, order, group_sizes,
     rung = sum((group_sizes.sum() > r).astype(jnp.int32) for r in rungs)
     return jax.lax.switch(
         rung, [functools.partial(head, r) for r in rungs] + [full])
+
+
+def moe_held_ffn(tokens, router, bias, experts, layer, rows, counts, *,
+                 top_k, held, scoring, n_group=1, topk_group=1,
+                 gate_scale=None):
+    """The routed half of an expert layer on a chip that holds a SHARE
+    of the router's experts: tokens [N, d] (normed) are routed over all
+    of ``router``'s [d, E] outputs in float32 (:func:`moe_route_held`
+    takes the other arguments) and the held experts' part of the sum is
+    computed over the head of the stream (:func:`moe_dropless_ffn`, scope
+    ``moe_expert_ffn``), the weights times ``gate_scale`` where a family
+    scales them. ``experts`` (we_gate, we_up, we_down): ONE stack of all
+    expert layers' held experts ``[layers * count, ...]``, this
+    ``layer``'s (data) named by their place in it. ``counts`` int32 gains
+    (pairs computed, held experts visited, 1 if the products took the
+    whole stream, with ``n_group`` > 1 the groups that hold a chosen
+    expert of some real token, last the stream's rows the products ran
+    over). Returns ([N, d] in the experts' dtype, counts)."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    topi, gates, order, sizes, stream_rows = moe_route_held(
+        logits, top_k, held, scoring=scoring, bias=bias, rows=rows,
+        n_group=n_group, topk_group=topk_group)
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((experts[0].shape[0],), jnp.int32), sizes,
+        (layer * held[1],))
+    with jax.named_scope("moe_expert_ffn"):
+        if gate_scale is not None:
+            gates = gates * gate_scale
+        out = moe_dropless_ffn(tokens, topi, gates, order, groups, *experts,
+                               precision=jax.lax.Precision.DEFAULT,
+                               stream_rows=stream_rows)
+    ran = moe_stream_rows(sizes, order.shape[0], stream_rows)
+    gained = [sizes.sum(), (sizes > 0).sum(), ran == order.shape[0]]
+    if n_group > 1:
+        group = topi // (router.shape[-1] // n_group)
+        seen = (group[:, :, None] == jnp.arange(n_group)) & rows[:, None, None]
+        gained.append(seen.any(axis=(0, 1)).sum())
+    return out, counts + jnp.stack(
+        [jnp.asarray(g, jnp.int32) for g in (*gained, ran)])
 
 
 def moe_permute(x, slot, num_experts, capacity):

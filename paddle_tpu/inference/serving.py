@@ -458,6 +458,7 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         from ..models import llama as _llama
+        from ..models import paged_stack as _seam
         m = self.model
         cfg = m.config
         self._names = m._stacked_names()
@@ -526,13 +527,13 @@ class DecodeEngine:
 
         # The two programs every paged engine runs, the cold prefill and
         # the decode chunk, are the model family's (``paged_programs``,
-        # models.llama.PagedPrograms), with the geometry of its block
+        # models.paged_stack.PagedPrograms), with the geometry of its block
         # pool and whatever a slot holds beside its pages. They take the
         # pool arrays LAST as ``*pool`` (ISSUE 8): fp engines pass
         # (kp, vp), int8 engines (kp, vp, kscale, vscale), and a family
         # with per-slot state its state arrays behind them.
-        _kv_scales_of = _llama.kv_scales_of
-        self._prefill_block = _llama.prefill_block_rows(cfg, self.s_max)
+        _kv_scales_of = _seam.kv_scales_of
+        self._prefill_block = _seam.prefill_block_rows(cfg, self.s_max)
         progs = m.paged_programs(
             chunk=self.chunk, prefill_block=self._prefill_block,
             mp_axis=mp, seq_axis=sq, n_seq=n_sq)

@@ -1,10 +1,12 @@
 """paddle_tpu.models — flagship model families (BASELINE configs 3-5).
 
-Served through the paged ``DecodeEngine``: ``llama`` (every option),
-and four families with paged programs of their own, each of which
-refuses at construction (``PagedPrograms.unsupported``, option -> why)
-the prefix cache, chunked prefill, speculative decoding, int8 KV, a mesh
-and the contiguous engine: ``granite_hybrid`` (per-slot recurrent
+Served through the paged ``DecodeEngine``, which meets a family through
+``paged_stack.PagedPrograms`` (``models/paged_stack.py``: the seam, and
+the block walk, run scan and greedy chunk the families' programs share):
+``llama`` (every option), and four families with paged programs of their
+own, each of which refuses at construction (``unsupported``, option ->
+why) the prefix cache, chunked prefill, speculative decoding, int8 KV, a
+mesh and the contiguous engine: ``granite_hybrid`` (per-slot recurrent
 state), ``mimo_v2`` (window rings, a held share of experts),
 ``glm_moe_dsa`` (a latent page and an indexer page, learned sparse
 attention) and ``deepseek_v3`` (ONE kind of page, the latent's, read

@@ -72,7 +72,7 @@ import jax.numpy as jnp
 
 from ..kernels.latent_attention import latent_decode_attention
 from . import glm_moe_dsa as G
-from .llama import PagedPrograms, _token_insert
+from .paged_stack import PagedPrograms, _token_insert, greedy_chunk
 
 __all__ = ["DeepseekV3Config", "DeepseekV3ForCausalLM", "DEEPSEEK_V3_PRESETS"]
 
@@ -255,19 +255,8 @@ class DeepseekV3ForCausalLM(G.GlmMoeDsaForCausalLM):
             """One chunk; a slot with ``lens == 0`` holds no row: its
             token reads nothing, is routed to no expert and counted
             nowhere."""
-            live = lens > 0
-
-            def body(carry, i):
-                tok, pool = carry
-                logits, pool = _decode_step(cfg, stacked, embed, fnorm, lm,
-                                            tok, tables, lens + i, pool,
-                                            live)
-                nxt = jnp.argmax(logits, axis=-1)
-                return (nxt, pool), nxt
-
-            (tok, pool), toks = jax.lax.scan(body, (tok, pool),
-                                             jnp.arange(chunk))
-            return (toks, *pool)
+            return greedy_chunk(_decode_step, (cfg, stacked, embed, fnorm, lm),
+                                chunk, tok, tables, lens, pool)
 
         family = "a program of this family's own (latent pages)"
         return PagedPrograms(

@@ -89,16 +89,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import nn
-from ..distributed.fleet.moe import (moe_dropless_ffn, moe_route_held,
-                                     moe_stream_rows)
+from ..distributed.fleet.moe import moe_held_ffn
 from ..kernels import topk_mask
 from ..kernels.latent_attention import (latent_prefill_pallas,
                                         prefill_kernel_serves)
-from .llama import PagedPrograms, _rms, _row_pages, _token_insert
+from .llama import _rms
+from .paged_stack import (PagedPrograms, _row_pages, _token_insert,
+                          block_window, greedy_chunk, scan_runs, walk_blocks)
 
 __all__ = ["GlmMoeDsaConfig", "GlmMoeDsaForCausalLM", "GLM_MOE_DSA_PRESETS"]
 
-_HI = jax.lax.Precision.HIGHEST
 _NEG = -1e30
 _LANES = 128
 SCORE_KEYS = 4096       # keys a piece of the indexer's scores takes at most
@@ -383,12 +383,8 @@ def _sparse_attend(cfg, qc, sel, ok):
 
 def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     """x + ffn(rms(x)); an expert layer adds the shared expert, computed
-    whole, to the held experts' part of the routed sum. ``rows`` [n]
-    marks real tokens; ``counts`` int32 gains (pairs computed, held
-    experts visited, 1 if the expert products took the whole stream,
-    under a router with groups the groups that hold a chosen expert of
-    some real token, and last the rows of the stream the expert products
-    ran over)."""
+    whole, to the held experts' part of the routed sum (``moe_held_ffn``:
+    ``rows`` [n] marks real tokens, ``counts`` int32 gains)."""
     y = _rms(x, lp["post_ln"], cfg.rms_norm_eps)
     if f_kind == "dense":
         return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
@@ -396,30 +392,11 @@ def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     with jax.named_scope("moe_shared_ffn"):
         shared = (jax.nn.silu(y @ lp["ws_gate"]) * (y @ lp["ws_up"])) \
             @ lp["ws_down"]
-    logits = jnp.dot(y.astype(jnp.float32), lp["router"], precision=_HI)
-    topi, gates, order, sizes, stream_rows = moe_route_held(
-        logits, cfg.num_experts_per_tok, cfg.held_experts,
-        scoring=cfg.scoring_func, bias=lp["router_bias"], rows=rows,
-        n_group=cfg.n_group, topk_group=cfg.topk_group)
-    # this layer's experts by their place in the one stack of all layers'
-    held = cfg.held_experts[1]
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((w["we_gate"].shape[0],), jnp.int32), sizes, (f * held,))
-    with jax.named_scope("moe_expert_ffn"):
-        out = moe_dropless_ffn(y, topi, gates * cfg.routed_scaling_factor,
-                               order, groups, w["we_gate"], w["we_up"],
-                               w["we_down"],
-                               precision=jax.lax.Precision.DEFAULT,
-                               stream_rows=stream_rows)
-    ran = moe_stream_rows(sizes, order.shape[0], stream_rows)
-    gained = [sizes.sum(), (sizes > 0).sum(), ran == order.shape[0]]
-    if cfg.n_group > 1:
-        group = topi // (cfg.n_routed_experts // cfg.n_group)
-        seen = (group[:, :, None] == jnp.arange(cfg.n_group)) \
-            & rows[:, None, None]
-        gained.append(seen.any(axis=(0, 1)).sum())
-    counts = counts + jnp.stack(
-        [jnp.asarray(g, jnp.int32) for g in (*gained, ran)])
+    out, counts = moe_held_ffn(
+        y, lp["router"], lp["router_bias"], [w[n] for n in _EXPERTS], f,
+        rows, counts, top_k=cfg.num_experts_per_tok, held=cfg.held_experts,
+        scoring=cfg.scoring_func, n_group=cfg.n_group,
+        topk_group=cfg.topk_group, gate_scale=cfg.routed_scaling_factor)
     return x + shared + out.astype(x.dtype), counts
 
 
@@ -634,56 +611,37 @@ def _prefill_attend(cfg, lp, x, positions, caches, l, start, pad, first):
 def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
              pool, block, attend=_prefill_attend):
     """The cold prefill of ONE right-aligned row (ids [1, s], pad_len
-    [1]): the window is walked in blocks of ``block`` rows from the block
-    of the first token (the trip count is data). What the row caches a
+    [1]); ``paged_stack.walk_blocks`` has the walk. What the row caches a
     token and layer (this family: latents and indexer keys; one carried
     array a page pool, as wide as its pages) lies in contiguous carries,
-    every layer's, which a block reads from the row's first token to its
-    own rows: ``attend(cfg, lp, x, positions, caches, l, start, pad,
-    first)`` writes the block's own and gives the probabilities' sum of
-    latents [block, H, rank] and the carries. At the window's end they
-    are written page by page through ``table_row``, whole pages, every
-    pool under the one table. Returns (float32 logits [1, V] of the last
-    token, pool)."""
+    which a block reads from the row's first token to its own rows:
+    ``attend(cfg, lp, x, positions, caches, l, start, pad, first)`` writes
+    the block's own and gives the probabilities' sum of latents [block, H,
+    rank] and the carries. At the window's end they are written, whole
+    pages through ``table_row``, every pool under the one table."""
     *pages, counts = pool
-    s = ids.shape[1]
-    block = min(block, s)
-    n_blocks = -(-s // block)
-    total = n_blocks * block
-    shift = total - s
-    ids = jnp.pad(ids[0], (shift, 0))
-    pad = pad_len[0] + shift
-    first = pad // block
-    dtype = embed.dtype
-    L = cfg.num_hidden_layers
+    window = block_window(ids, pad_len, block)
+    pad, total = window.pad, window.total
 
-    def run_block(i, carry):
-        caches, counts, _ = carry
-        start = i * block
-        cols = start + jnp.arange(block)
-        rows = cols >= pad
-        positions = jnp.maximum(cols - pad, 0)
-        x = jnp.take(embed, jax.lax.dynamic_slice_in_dim(ids, start, block),
-                     axis=0)
-        for f_kind, l0, n in cfg.runs():
-            def layer(carry, j, f_kind=f_kind, l0=l0):
-                x, caches, counts = carry
-                l = l0 + j
-                lp = _layer_params(w, f_kind, l, j)
-                o_lat, caches = attend(cfg, lp, x, positions, caches, l,
-                                       start, pad, first)
-                x, counts = _ffn(cfg, w, lp, f_kind, j,
-                                 x + _out_proj(cfg, lp, o_lat), rows, counts)
-                return (x, caches, counts), None
+    def run_layers(x, state, blk):
+        def layer(f_kind, l0, carry, j):
+            x, caches, counts = carry
+            l = l0 + j
+            lp = _layer_params(w, f_kind, l, j)
+            o_lat, caches = attend(cfg, lp, x, blk.positions, caches, l,
+                                   blk.start, pad, blk.first)
+            x, counts = _ffn(cfg, w, lp, f_kind, j,
+                             x + _out_proj(cfg, lp, o_lat), blk.rows, counts)
+            return (x, caches, counts), None
 
-            (x, caches, counts), _ = jax.lax.scan(
-                layer, (x, caches, counts), jnp.arange(n, dtype=jnp.int32))
-        return caches, counts, x[-1:]
+        x, *state = scan_runs(cfg.runs(), layer, (x, *state))
+        return x, tuple(state)
 
-    caches, counts, last = jax.lax.fori_loop(
-        first, n_blocks, run_block,
-        (tuple(jnp.zeros((L, total, p.shape[-1]), dtype) for p in pages),
-         counts, jnp.zeros((1, embed.shape[1]), dtype)))
+    (caches, counts), last = walk_blocks(
+        window, embed,
+        lambda: (tuple(jnp.zeros((cfg.num_hidden_layers, total, p.shape[-1]),
+                                 embed.dtype) for p in pages), counts),
+        run_layers)
     logits = _logits(cfg, last, final_norm, lm_head)
     mb, bs = table_row.shape[0], pages[0].shape[-2]
     pages = [p.at[:, table_row].set(_row_pages(c[:, :, None], pad, mb, bs))
@@ -801,17 +759,14 @@ def _decode_layers(cfg, w, x, pool, live, attend):
     x, l, pages)`` -> (the layer's attention output [b, d], the page
     pools with the tokens' own written); pool = (*page pools, device
     counters). Returns (x, pool)."""
-    for f_kind, l0, n in cfg.runs():
-        def layer(carry, j, f_kind=f_kind, l0=l0):
-            x, (*pages, counts) = carry
-            lp = _layer_params(w, f_kind, l0 + j, j)
-            o, pages = attend(lp, x, l0 + j, pages)
-            x, counts = _ffn(cfg, w, lp, f_kind, j, x + o, live, counts)
-            return (x, (*pages, counts)), None
+    def layer(f_kind, l0, carry, j):
+        x, (*pages, counts) = carry
+        lp = _layer_params(w, f_kind, l0 + j, j)
+        o, pages = attend(lp, x, l0 + j, pages)
+        x, counts = _ffn(cfg, w, lp, f_kind, j, x + o, live, counts)
+        return (x, (*pages, counts)), None
 
-        (x, pool), _ = jax.lax.scan(layer, (x, tuple(pool)),
-                                    jnp.arange(n, dtype=jnp.int32))
-    return x, pool
+    return scan_runs(cfg.runs(), layer, (x, tuple(pool)))
 
 
 def leaf_shapes(cfg, indexer=True):
@@ -938,22 +893,11 @@ class GlmMoeDsaForCausalLM(nn.Layer):
             """One chunk; a slot with ``lens == 0`` holds no row: its
             tokens are scored against nothing, routed to no expert and
             counted nowhere."""
-            live = lens > 0
             bs = pool[0].shape[-2]
             widths[:] = decode_widths(tables.shape[1] * bs, cfg.index_topk,
                                       bs)
-
-            def body(carry, i):
-                tok, pool = carry
-                logits, pool = _decode_step(cfg, stacked, embed, fnorm, lm,
-                                            tok, tables, lens + i, pool,
-                                            live)
-                nxt = jnp.argmax(logits, axis=-1)
-                return (nxt, pool), nxt
-
-            (tok, pool), toks = jax.lax.scan(body, (tok, pool),
-                                             jnp.arange(chunk))
-            return (toks, *pool)
+            return greedy_chunk(_decode_step, (cfg, stacked, embed, fnorm, lm),
+                                chunk, tok, tables, lens, pool)
 
         family = "a program of this family's own (latent and indexer pages)"
         return PagedPrograms(

@@ -26,7 +26,6 @@ and zero elsewhere, which leaves every score and output as it was.
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 
 import jax
@@ -36,9 +35,10 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..kernels.paged_attention import paged_decode_attention
 from ..kernels.ssm_update import lane_pack, pack_state, ssm_decode_update
-from .llama import (PagedPrograms, _attention_keymask,
-                    _attention_prefix_span, _rms, _row_pages,
-                    _token_insert)
+from .llama import _attention_keymask, _attention_prefix_span, _rms
+from .paged_stack import (PagedPrograms, _row_pages, _token_insert,
+                          block_window, greedy_chunk, run_scans, scan_runs,
+                          walk_blocks)
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridForCausalLM",
            "GRANITE_PRESETS"]
@@ -141,13 +141,14 @@ _SHARED = ("input_ln", "post_ln", "w_gate", "w_up", "w_down")
 _MAMBA = ("in_proj", "dt_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D",
           "ssm_norm", "out_proj")
 _ATTN = ("wq", "wk", "wv", "wo")
+_OWN = {"mamba": _MAMBA, "attention": _ATTN}
 
 
-def _layer_params(w, kinds, l, i):
-    """Layer ``l``'s leaves, index ``i`` within its kind, taken from the
-    stacks where they lie (``l`` and ``i`` are data)."""
+def _layer_params(w, kind, l, i):
+    """Layer ``l``'s leaves, index ``i`` within its ``kind``, taken from
+    the stacks where they lie (``l`` and ``i`` are data)."""
     lp = {n: w[n][l] for n in _SHARED}
-    lp.update({n: w[n][i] for n in kinds})
+    lp.update({n: w[n][i] for n in _OWN[kind]})
     return lp
 
 
@@ -328,24 +329,18 @@ def _decode_step(cfg, w, embed, final_norm, tok, tables, lens, pool,
         cfg.embedding_multiplier, embed.dtype)
     rm = cfg.residual_multiplier
 
-    def mamba_layer(l0, i0, carry, j):
+    def layer(kind, l0, i0, carry, j):
         x, (kp, vp, ssm, conv) = carry
-        lp = _layer_params(w, _MAMBA, l0 + j, i0 + j)
-        out, ssm, conv = _mamba_decode(cfg, lp, x, i0 + j, ssm, conv, live)
+        lp = _layer_params(w, kind, l0 + j, i0 + j)
+        if kind == "mamba":
+            out, ssm, conv = _mamba_decode(cfg, lp, x, i0 + j, ssm, conv,
+                                           live)
+        else:
+            out, kp, vp = _attention_decode(cfg, lp, x, i0 + j, kp, vp,
+                                            tables, lens)
         return (_mlp(cfg, lp, x + rm * out), (kp, vp, ssm, conv)), None
 
-    def attention_layer(l0, i0, carry, j):
-        x, (kp, vp, ssm, conv) = carry
-        lp = _layer_params(w, _ATTN, l0 + j, i0 + j)
-        out, kp, vp = _attention_decode(cfg, lp, x, i0 + j, kp, vp, tables,
-                                        lens)
-        return (_mlp(cfg, lp, x + rm * out), (kp, vp, ssm, conv)), None
-
-    layers = {"mamba": mamba_layer, "attention": attention_layer}
-    for kind, l0, i0, n in cfg.runs():
-        (x, pool), _ = jax.lax.scan(
-            functools.partial(layers[kind], l0, i0), (x, tuple(pool)),
-            jnp.arange(n, dtype=jnp.int32))
+    x, pool = scan_runs(cfg.runs(), layer, (x, tuple(pool)))
     return _logits(cfg, x, embed, final_norm), pool
 
 
@@ -360,81 +355,70 @@ def _logits(cfg, x, embed, final_norm):
 def _prefill(cfg, w, embed, final_norm, ids, pad_len, table_row, slot, pool,
              block):
     """The cold prefill of ONE right-aligned row (ids [1, s], pad_len
-    [1]): the window is walked in blocks of ``block`` rows from the block
-    of the first token (the trip count is data), every Mamba layer runs
-    the chunked recurrence with its state and convolution window carried
-    from block to block, every attention layer reads the row's earlier
-    keys from a contiguous carry. What is left at the window's end, where
-    the last token lies, is written once: the keys and values page by
-    page through ``table_row``, the states and windows into ``slot``.
-    Returns (float32 logits [1, V] of the last token, pool)."""
+    [1]); ``paged_stack.walk_blocks`` has the walk. The family's own: a
+    block's Mamba layers run the chunked recurrence with state and
+    convolution window carried from block to block, its attention layers
+    read the row's earlier keys from a contiguous carry; at the window's
+    end keys and values are written page by page through ``table_row``,
+    states and windows into ``slot``. Returns (float32 logits, pool)."""
     kp, vp, ssm, conv = pool
-    s = ids.shape[1]
-    block = min(block, s)
-    n_blocks = -(-s // block)
-    shift = n_blocks * block - s
-    ids = jnp.pad(ids[0], (shift, 0))
-    pad = pad_len[0] + shift
-    real = (jnp.arange(n_blocks * block) >= pad)[None]
-    first = pad // block
+    window = block_window(ids, pad_len, block)
+    pad = window.pad
+    real = (jnp.arange(window.total) >= pad)[None]
     kvh, hd = cfg.num_key_value_heads, cfg.head_dim
     pack = _kv_pack(kvh, hd)
     rm = cfg.residual_multiplier
     dtype = embed.dtype
     # attention reads divide by sqrt(hd)
     q_scale = jnp.asarray(cfg.attention_multiplier * hd ** 0.5, dtype)
-    kv0 = jnp.zeros((cfg.n_attention, n_blocks * block, kvh, hd), dtype)
 
-    def run_block(i, carry):
-        kc, vc, sc, cc, _ = carry
-        start = i * block
-        valid = start + jnp.arange(block) >= pad
-        x = jnp.take(embed, jax.lax.dynamic_slice_in_dim(ids, start, block),
-                     axis=0) * jnp.asarray(cfg.embedding_multiplier, dtype)
-        states, windows = [], []
-        for kind, l0, i0, n in cfg.runs():
-            idx = jnp.arange(n, dtype=jnp.int32)
+    def init():
+        kv0 = jnp.zeros((cfg.n_attention, window.total, kvh, hd), dtype)
+        return (kv0, kv0,
+                jnp.zeros((cfg.n_mamba, cfg.mamba_n_heads, cfg.mamba_d_head,
+                           cfg.mamba_d_state), ssm.dtype),
+                jnp.zeros((conv.shape[0],) + conv.shape[2:], conv.dtype))
+
+    def run_layers(x, state, blk):
+        kc, vc, sc, cc = state
+        x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
+
+        def mamba_layer(_, l0, i0, x, j, st, win):
+            lp = _layer_params(w, "mamba", l0 + j, i0 + j)
+            h = _rms(x, lp["input_ln"], cfg.rms_norm_eps)
+            out, st, win = _mamba_seq(cfg, lp, h, st, win, blk.rows)
+            return _mlp(cfg, lp, x + rm * out), (st, win)
+
+        def attention_layer(_, l0, i0, carry, j):
+            x, kc, vc = carry
+            a = i0 + j
+            lp = _layer_params(w, "attention", l0 + j, a)
+            h = _rms(x, lp["input_ln"], cfg.rms_norm_eps)
+            q, k, v = _qkv(cfg, lp, h)
+            o = _attention_prefix_span(
+                (q * q_scale)[None], k[None], v[None], blk.rows[None],
+                kc[a][None], vc[a][None], real,
+                (blk.first, blk.i, window.block))
+            kc = jax.lax.dynamic_update_slice(
+                kc, k[None], (a, blk.start, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                vc, v[None], (a, blk.start, 0, 0))
+            out = o.reshape(window.block, -1) @ lp["wo"]
+            return (_mlp(cfg, lp, x + rm * out), kc, vc), None
+
+        kept = []
+        for (kind, _, i0, n), scan in run_scans(cfg.runs()):
             if kind == "mamba":
-                def layer(x, xs, l0=l0, i0=i0):
-                    j, st, win = xs
-                    lp = _layer_params(w, _MAMBA, l0 + j, i0 + j)
-                    h = _rms(x, lp["input_ln"], cfg.rms_norm_eps)
-                    out, st, win = _mamba_seq(cfg, lp, h, st, win, valid)
-                    return _mlp(cfg, lp, x + rm * out), (st, win)
-
-                x, (st, win) = jax.lax.scan(
-                    layer, x, (idx, sc[i0:i0 + n], cc[i0:i0 + n]))
-                states.append(st)
-                windows.append(win)
+                x, states = scan(mamba_layer, x, sc[i0:i0 + n],
+                                 cc[i0:i0 + n])
+                kept.append(states)
             else:
-                def layer(carry, j, l0=l0, i0=i0):
-                    x, kc, vc = carry
-                    a = i0 + j
-                    lp = _layer_params(w, _ATTN, l0 + j, a)
-                    h = _rms(x, lp["input_ln"], cfg.rms_norm_eps)
-                    q, k, v = _qkv(cfg, lp, h)
-                    o = _attention_prefix_span(
-                        (q * q_scale)[None], k[None], v[None], valid[None],
-                        kc[a][None], vc[a][None], real,
-                        (first, i, block))
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, k[None], (a, start, 0, 0))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, v[None], (a, start, 0, 0))
-                    out = o.reshape(block, -1) @ lp["wo"]
-                    return (_mlp(cfg, lp, x + rm * out), kc, vc), None
+                (x, kc, vc), _ = scan(attention_layer, (x, kc, vc))
+        sc, cc = (jnp.concatenate(states) for states in zip(*kept))
+        return x, (kc, vc, sc, cc)
 
-                (x, kc, vc), _ = jax.lax.scan(layer, (x, kc, vc), idx)
-        return (kc, vc, jnp.concatenate(states), jnp.concatenate(windows),
-                x[-1:])
-
-    kc, vc, sc, cc, last = jax.lax.fori_loop(
-        first, n_blocks, run_block,
-        (kv0, kv0, jnp.zeros((cfg.n_mamba, cfg.mamba_n_heads,
-                              cfg.mamba_d_head, cfg.mamba_d_state),
-                             ssm.dtype),
-         jnp.zeros((conv.shape[0],) + conv.shape[2:], conv.dtype),
-         jnp.zeros((1, embed.shape[1]), dtype)))
+    (kc, vc, sc, cc), last = walk_blocks(window, embed, init, run_layers,
+                                         positions=False)
     logits = _logits(cfg, last, embed, final_norm)
     mb, bs = table_row.shape[0], kp.shape[-2]
     kp = kp.at[:, table_row].set(_row_pages(kc, pad, mb, bs, pack))
@@ -462,25 +446,20 @@ def _forward(cfg, w, embed, final_norm, ids):
     state = jnp.zeros((cfg.mamba_n_heads, cfg.mamba_d_head,
                        cfg.mamba_d_state), jnp.float32)
     window = jnp.zeros((cfg.mamba_d_conv - 1, cfg.conv_dim), dtype)
-    def mamba_layer(l0, i0, x, j):
-        lp = _layer_params(w, _MAMBA, l0 + j, i0 + j)
+
+    def layer(kind, l0, i0, x, j):
+        lp = _layer_params(w, kind, l0 + j, i0 + j)
         h = _rms(x, lp["input_ln"], cfg.rms_norm_eps)
-        out, _, _ = _mamba_seq(cfg, lp, h, state, window, valid)
+        if kind == "mamba":
+            out, _, _ = _mamba_seq(cfg, lp, h, state, window, valid)
+        else:
+            qh, k, v = _qkv(cfg, lp, h)
+            o = _attention_keymask((qh * q_scale)[None], k[None], v[None],
+                                   valid[None])
+            out = o.reshape(x.shape[0], -1) @ lp["wo"]
         return _mlp(cfg, lp, x + rm * out), None
 
-    def attention_layer(l0, i0, x, j):
-        lp = _layer_params(w, _ATTN, l0 + j, i0 + j)
-        h = _rms(x, lp["input_ln"], cfg.rms_norm_eps)
-        qh, k, v = _qkv(cfg, lp, h)
-        o = _attention_keymask((qh * q_scale)[None], k[None], v[None],
-                               valid[None])
-        return _mlp(cfg, lp, x + rm * (o.reshape(x.shape[0], -1)
-                                       @ lp["wo"])), None
-
-    layers = {"mamba": mamba_layer, "attention": attention_layer}
-    for kind, l0, i0, n in cfg.runs():
-        x, _ = jax.lax.scan(functools.partial(layers[kind], l0, i0), x,
-                            jnp.arange(n, dtype=jnp.int32))
+    x = scan_runs(cfg.runs(), layer, x)
     return _logits(cfg, x[:s], embed, final_norm)
 
 
@@ -570,18 +549,8 @@ class GraniteHybridForCausalLM(nn.Layer):
                                tables, lens, *pool):
             """One chunk; a slot with ``lens == 0`` holds no row, and
             its state is neither read nor written."""
-            live = lens > 0
-
-            def body(carry, i):
-                tok, pool = carry
-                logits, pool = _decode_step(cfg, stacked, embed, fnorm, tok,
-                                            tables, lens + i, pool, live)
-                nxt = jnp.argmax(logits, axis=-1)
-                return (nxt, pool), nxt
-
-            (tok, pool), toks = jax.lax.scan(body, (tok, pool),
-                                             jnp.arange(chunk))
-            return (toks, *pool)
+            return greedy_chunk(_decode_step, (cfg, stacked, embed, fnorm),
+                                chunk, tok, tables, lens, pool)
 
         state_dtype = jnp.dtype(cfg.dtype)
         hpack = lane_pack(cfg.mamba_n_heads, cfg.mamba_d_head)

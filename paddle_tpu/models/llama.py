@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import functools
 import math
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +37,8 @@ from ..distributed.fleet.pipeline import safe_psum  # the ONE bf16-psum shim
 from ..kernels.paged_attention import (paged_decode_attention,
                                        merge_softmax_partials,
                                        seq_local_pages)
+from .paged_stack import (PagedPrograms, _quantized_token_insert, _row_pages,
+                          _token_insert, _write_page_ids, kv_scales_of)
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "llama_loss_fn",
            "LLAMA_PRESETS", "quantize_weights_int8"]
@@ -182,90 +184,6 @@ LLAMA_PRESETS = {
                         num_experts_per_tok=2, moe_intermediate_size=86,
                         moe_num_shared_experts=1),
 }
-
-
-@dataclass(frozen=True)
-class PagedPrograms:
-    """What a model family gives ``DecodeEngine``'s paged mode: its two
-    programs, the geometry of its block pool, what a slot holds beside
-    its pages, and what it cannot serve.
-
-    ``prefill_paged(stacked, embed, final_norm, lm_head, scales, ids,
-    pad_len, table_row, [slot,] *pool)`` -> (first token [1], *pool) and
-    ``decode_chunk_paged(stacked, embed, final_norm, lm_head, scales, tok,
-    tables, lens, *pool)`` -> (tokens [chunk, b], *pool); the engine jits
-    them under these names. ``pool`` is (k pool, v pool[, k scales, v
-    scales]) followed by the arrays of ``slot_state``: per-slot state no
-    page table describes, one ``ShapeDtypeStruct`` an array for the
-    engine's ``slots``. A family with such state is handed the ``slot``
-    of the row it prefills, its prefill takes the pool donated, and the
-    engine refuses at construction every option in ``unsupported``
-    (option -> why). ``v_head_dim``: the width of a value head in the
-    pool where it is not a key head's (0: ``head_dim``).
-    ``value_pool=False``: the family keeps ONE kind of page (a latent
-    that is key and value at once); the engine then builds no second
-    pool, ``pool`` is (page pool, *``slot_state``) and a block costs one
-    page a layer.
-    ``device_counters`` names the entries of the LAST array of
-    ``slot_state``, an int32 vector the two programs add to on the
-    device (what only the device knows: which experts a step's rows
-    chose); the engine hands it over like the rest but does not donate
-    it, fetches it in ``stats()`` alone and keeps
-    ``engine_<name>_total``. ``host_counters`` (name -> function) is
-    what the engine counts itself at every decode launch for a family
-    whose decode work is no plain function of the context: each function
-    is handed the contexts of the live rows at each of the launch's
-    steps (int64 [steps, rows]) and gives what ``engine_<name>_total``
-    grows by; the totals ride in each launch's entry behind the device
-    counters. ``trace_scopes`` names the ``jax.named_scope``s of the two
-    programs that a reader of a device trace should be able to find: a
-    trace names an event by its compiled instruction and carries no
-    scope, so with ``profile`` on the engine reads each program's
-    compiled text once and gives ``stats()["scopes"]``: program ->
-    {instruction name: scope}."""
-    prefill_paged: object
-    decode_chunk_paged: object
-    kv_layers: int
-    kv_heads: int
-    head_dim: int
-    slot_state: object = None
-    chunks_per_block: int = 0
-    unsupported: dict = field(default_factory=dict)
-    v_head_dim: int = 0
-    value_pool: bool = True
-    device_counters: tuple = ()
-    host_counters: dict = field(default_factory=dict)
-    trace_scopes: tuple = ()
-
-
-def prefill_block_rows(cfg, s_max):
-    """Rows the cold prefill of a configuration runs at a time (what the
-    engine hands ``paged_programs`` as ``prefill_block``). 256 is where
-    a block's DENSE matmuls cost what reading their bfloat16 weights
-    costs (two operations a weight byte a row, against a v5e's 240 a
-    byte): a smaller block re-reads the weights for nothing, a larger
-    one pads a short prompt for nothing and is no cheaper a row
-    (``PERF.md``, Findings PR 33: 128, 256 and 512 on the chip). A
-    configuration that holds a SHARE of a router's experts
-    (``held_experts`` fewer than ``n_routed_experts``) reads a held
-    expert's weights once a block for the rows the router sends it,
-    ``rows * num_experts_per_tok / n_routed_experts``: where 256 rows
-    bring it fewer than 16, the block is 512 (Findings PR 46). Halved
-    until the window holds two blocks."""
-    rows = 256
-    held = getattr(cfg, "held_experts", None)
-    if held is not None and held[1] < cfg.n_routed_experts \
-            and rows * cfg.num_experts_per_tok < 16 * cfg.n_routed_experts:
-        rows = 512
-    while rows > 8 and 2 * rows > s_max:
-        rows //= 2
-    return rows
-
-
-def kv_scales_of(pool):
-    """The int8 pools' scales ``(kscale, vscale)`` of a paged program's
-    ``*pool``, None for float pools."""
-    return (pool[2], pool[3]) if len(pool) == 4 else None
 
 
 def _rope(x, positions, theta, head_dim):
@@ -1115,79 +1033,6 @@ def _decode_step(cfg, stacked, embed, final_norm, lm_head, token, cache_k,
     return logits, cks, cvs
 
 
-def _write_page_ids(page, n_pages, seq_axis):
-    """Where a decode step reads and writes its rows' write pages
-    ``page`` [b]: (read ids, write ids, scatter mode). On page-sharded
-    pools (``seq_axis``, 2-D mesh) ``page`` is a GLOBAL id: reads clamp
-    into the local stripe of ``n_pages`` (garbage on non-owners, whose
-    writes are dropped) and writes rebase + drop non-owned rows, so the
-    update lands exactly once, on the owning shard."""
-    if seq_axis is None:
-        return page, page, None
-    wp, owned = seq_local_pages(page, n_pages, seq_axis)
-    return jnp.where(owned, wp, 0), wp, "drop"
-
-
-def _set_page_row(pages, off, tok):
-    """pages [b, kvh, bs, hd] with row ``off[b]`` of row b's page
-    replaced by tok [b, kvh, hd]. A select, which fuses into the ops
-    around it in the pages' own layout (a scatter along the in-page
-    axis asks for another)."""
-    slot = jnp.arange(pages.shape[2])[:, None] == off[:, None, None, None]
-    return jnp.where(slot, tok[:, :, None, :].astype(pages.dtype), pages)
-
-
-def _token_insert(pool, layer, page, off, tok, seq_axis=None):
-    """Append ONE token per row into layer ``layer`` of a stacked pool
-    [L, N, kvh, bs, hd]: page/off [b] int32 write cursors, tok
-    [b, kvh, hd]. Written as a read-modify-write of the rows' PAGES
-    (b x [kvh, bs, hd], gathered and scattered whole at
-    ``[layer, page]``), not as a scatter of b rows of ``hd``: a page is
-    what the paged kernel's DMA reads, so the chip's compiler keeps the
-    pool in the kernel's layout for both, where a scatter along the
-    in-page axis wants a layout of its own and pays for it with a copy
-    of the whole pool in every layer. A row's write page is private
-    (shared prefix pages are full; copy-on-write clones a partial one
-    at admission), so no two live rows collide; inactive rows all land
-    on the NULL page, whose content nobody reads. ``seq_axis``:
-    :func:`_write_page_ids`."""
-    rp, wp, mode = _write_page_ids(page, pool.shape[1], seq_axis)
-    pages = _set_page_row(pool[layer, rp], off, tok)  # [b, kvh, bs, hd]
-    return pool.at[layer, wp].set(pages, mode=mode)
-
-
-def _quantized_token_insert(pool, scales, layer, page, off, tok,
-                            seq_axis=None):
-    """Append ONE token per row into layer ``layer`` of a stacked int8
-    pool with a RUNNING-MAX per-(page, kv head) scale (ISSUE 8 int8
-    paged KV).
-
-    pool [L, N, kvh, bs, hd] int8 codes; scales [L, N, kvh] f32; layer
-    an int32 scalar; page/off [b] int32 write cursors; tok [b, kvh, hd]
-    f32. Only the rows' pages are read and written, at ``[layer, page]``
-    where the pool lies. The page's scale only
-    ever grows (``new = max(old, amax(tok)/127)``), and the resident
-    codes are re-expressed in the new scale by ``round(q * old/new)`` —
-    when the token doesn't raise the max the ratio is exactly 1.0 and
-    ``round(q * 1.0) == q``, so untouched tokens keep their codes
-    bit-identical (the no-op case every step but the occasional
-    outlier). Inactive rows write the NULL page, same as the fp path.
-    ``seq_axis``: :func:`_write_page_ids`."""
-    rp, wp, mode = _write_page_ids(page, pool.shape[1], seq_axis)
-    amax = jnp.abs(tok).max(axis=-1)                     # [b, kvh]
-    old = scales[layer, rp]                              # [b, kvh]
-    new = jnp.maximum(old, amax / 127.0)
-    codes = pool[layer, rp]                              # [b, kvh, bs, hd]
-    ratio = (old / new)[:, :, None, None]
-    req = jnp.clip(jnp.round(codes.astype(jnp.float32) * ratio),
-                   -127, 127)
-    qt = jnp.clip(jnp.round(tok / new[:, :, None]), -127, 127)
-    req = _set_page_row(req, off, qt)
-    pool = pool.at[layer, wp].set(req.astype(pool.dtype), mode=mode)
-    scales = scales.at[layer, wp].set(new, mode=mode)
-    return pool, scales
-
-
 def _paged_decode_layer_step(cfg, lp, x, pool, layer, tables, lens,
                              mp_axis=None, seq_axis=None, n_seq=1):
     """One decoder layer for ONE token per row against the PAGED KV
@@ -1305,18 +1150,6 @@ def _paged_decode_step(cfg, stacked, embed, final_norm, lm_head, token,
     return logits, pool
 
 
-def _row_pages(kc, pad, mb, bs, pack=1):
-    """One row's contiguous keys (or values) [L, s, kvh, hd], window
-    column ``pad`` holding its first token, as pool pages
-    [L, mb, kvh/pack, bs, pack*hd] from context position 0."""
-    la, s, kvh, hd = kc.shape
-    kc = jnp.roll(kc, -pad, axis=1)
-    if s < mb * bs:
-        kc = jnp.pad(kc, ((0, 0), (0, mb * bs - s), (0, 0), (0, 0)))
-    kc = kc[:, :mb * bs].reshape(la, mb, bs, kvh // pack, pack * hd)
-    return jnp.swapaxes(kc, 2, 3)
-
-
 def _write_row_pages(pool, toks, win, new, scales=None, seq_axis=None):
     """Write ONE row's pages ``win`` [nw] into the stacked pool
     [L, N, kvh, bs, hd], whole, at ``[layer, page]`` where the pool
@@ -1324,7 +1157,7 @@ def _write_row_pages(pool, toks, win, new, scales=None, seq_axis=None):
     a row). toks [L, nw, kvh, bs, hd] holds the launch's tokens where
     they belong in those pages; ``new`` [nw, bs] marks them. Positions
     it does not mark keep what the pages hold (a read-modify-write by
-    a select, as :func:`_set_page_row`); ``new=None``: the pages are a
+    a select, as ``paged_stack._set_page_row``); ``new=None``: the pages are a
     cold row's from position 0 and nothing they held is kept, so they
     are not read. Every page of ``win`` is the row's own or NULL, so
     no other row sees the write.

@@ -43,14 +43,15 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..distributed.fleet.moe import (moe_dropless_ffn, moe_route_held,
-                                     moe_stream_rows)
+from ..distributed.fleet.moe import moe_held_ffn
 from ..kernels.paged_attention import paged_decode_attention
-from .llama import PagedPrograms, _rms, _rope, _row_pages, _token_insert
+from .llama import _rms, _rope
+from .paged_stack import (PagedPrograms, _row_pages, _token_insert,
+                          block_window, greedy_chunk, run_scans, scan_runs,
+                          walk_blocks)
 
 __all__ = ["MimoV2Config", "MimoV2ForCausalLM", "MIMO_V2_PRESETS"]
 
-_HI = jax.lax.Precision.HIGHEST
 _NEG = -1e30
 
 
@@ -206,31 +207,16 @@ def _qkv(cfg, lp, h, positions, theta):
 
 def _ffn(cfg, w, lp, f_kind, f, x, rows, counts):
     """x + ffn(rms(x)); an expert layer routes over all the router's
-    experts and computes the held ones' part. ``rows`` [n] marks real
-    tokens; ``counts`` int32 [4] gains (pairs computed, held experts
-    visited, 1 if the expert products took the whole stream, the rows of
-    the stream they ran over)."""
+    experts and computes the held ones' part (``fleet.moe.moe_held_ffn``:
+    ``rows`` [n] marks real tokens, ``counts`` int32 [4] gains)."""
     y = _rms(x, lp["post_ln"], cfg.layernorm_epsilon)
     if f_kind == "dense":
         return x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
             @ lp["w_down"], counts
-    logits = jnp.dot(y.astype(jnp.float32), lp["router"], precision=_HI)
-    topi, gates, order, sizes, stream_rows = moe_route_held(
-        logits, cfg.num_experts_per_tok, cfg.held_experts,
-        scoring=cfg.scoring_func, bias=lp["router_bias"], rows=rows)
-    # this layer's experts by their place in the one stack of all layers'
-    held = cfg.held_experts[1]
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((w["we_gate"].shape[0],), jnp.int32), sizes, (f * held,))
-    with jax.named_scope("moe_expert_ffn"):
-        out = moe_dropless_ffn(y, topi, gates, order, groups, w["we_gate"],
-                               w["we_up"], w["we_down"],
-                               precision=jax.lax.Precision.DEFAULT,
-                               stream_rows=stream_rows)
-    ran = moe_stream_rows(sizes, order.shape[0], stream_rows)
-    counts = counts + jnp.stack(
-        [jnp.asarray(g, jnp.int32) for g in (
-            sizes.sum(), (sizes > 0).sum(), ran == order.shape[0], ran)])
+    out, counts = moe_held_ffn(
+        y, lp["router"], lp["router_bias"], [w[n] for n in _EXPERTS], f,
+        rows, counts, top_k=cfg.num_experts_per_tok, held=cfg.held_experts,
+        scoring=cfg.scoring_func)
     return x + out.astype(x.dtype), counts
 
 
@@ -310,92 +296,69 @@ def _global_block(cfg, lp, q, k, v, kc, vc, start, pad, first, i):
 def _prefill(cfg, w, embed, final_norm, lm_head, ids, pad_len, table_row,
              slot, pool, block):
     """The cold prefill of ONE right-aligned row (ids [1, s], pad_len
-    [1]): the window is walked in blocks of ``block`` rows from the block
-    of the first token (the trip count is data). A global layer reads the
-    row's earlier keys from a contiguous carry; a window layer the block
-    and the ``sliding_window`` tokens before it, carried from block to
-    block and never read back from anywhere. At the window's end, where
-    the last token lies, the global layers' keys and values are written
-    page by page through ``table_row`` and the window layers' last
-    ``sliding_window`` tokens into ``slot`` of the rings, each at its
-    position modulo the ring. Returns (float32 logits [1, V] of the last
-    token, pool)."""
+    [1]); ``paged_stack.walk_blocks`` has the walk. The family's own: a
+    global layer reads the row's earlier keys from a contiguous carry, a
+    window layer the block and the ``sliding_window`` tokens before it,
+    carried from block to block; at the window's end the global layers'
+    keys and values go page by page through ``table_row``, the window
+    layers' last ``sliding_window`` tokens into ``slot`` of the rings at
+    their positions modulo the ring. Returns (float32 logits, pool)."""
     kp, vp, rk, rv, counts = pool
-    s = ids.shape[1]
-    block = min(block, s)
-    win = cfg.sliding_window
-    n_blocks = -(-s // block)
-    total = n_blocks * block
-    shift = total - s
-    ids = jnp.pad(ids[0], (shift, 0))
-    pad = pad_len[0] + shift
-    first = pad // block
+    window = block_window(ids, pad_len, block)
+    total, pad, win = window.total, window.pad, cfg.sliding_window
     dtype = embed.dtype
     ng, nw = cfg.count("global"), cfg.count("window")
     kvg, kvw = cfg.num_key_value_heads, cfg.swa_num_key_value_heads
     hd, hdv = cfg.head_dim, cfg.v_head_dim
 
-    def run_block(i, carry):
-        kc, vc, pk, pv, counts, _ = carry
-        start = i * block
-        cols = start + jnp.arange(block)
-        rows = cols >= pad
-        positions = jnp.maximum(cols - pad, 0)
-        x = jnp.take(embed, jax.lax.dynamic_slice_in_dim(ids, start, block),
-                     axis=0)
-        prev_k, prev_v = [], []
-        for a_kind, f_kind, l0, a0, f0, n in cfg.runs():
-            idx = jnp.arange(n, dtype=jnp.int32)
+    def run_layers(x, state, blk):
+        kc, vc, pk, pv, counts = state
+
+        def global_layer(_, f_kind, l0, a0, f0, carry, j):
+            x, kc, vc, counts = carry
+            a = a0 + j
+            lp = _layer_params(w, "global", f_kind, l0 + j, a, f0 + j)
+            h = _rms(x, lp["input_ln"], cfg.layernorm_epsilon)
+            q, k, v = _qkv(cfg, lp, h, blk.positions, cfg.rope_theta)
+            x = x + _global_block(cfg, lp, q, k, v, kc[a], vc[a], blk.start,
+                                  pad, blk.first, blk.i)
+            kc = jax.lax.dynamic_update_slice(
+                kc, k[None], (a, blk.start, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                vc, v[None], (a, blk.start, 0, 0))
+            x, counts = _ffn(cfg, w, lp, f_kind, f0 + j, x, blk.rows, counts)
+            return (x, kc, vc, counts), None
+
+        def window_layer(_, f_kind, l0, a0, f0, carry, j, pkl, pvl):
+            x, counts = carry
+            lp = _layer_params(w, "window", f_kind, l0 + j, a0 + j, f0 + j)
+            h = _rms(x, lp["input_ln"], cfg.layernorm_epsilon)
+            q, k, v = _qkv(cfg, lp, h, blk.positions, cfg.swa_rope_theta)
+            o, pkl, pvl = _window_block(cfg, lp, q, k, v, pkl, pvl,
+                                        blk.start, pad)
+            x, counts = _ffn(cfg, w, lp, f_kind, f0 + j, x + o, blk.rows,
+                             counts)
+            return (x, counts), (pkl, pvl)
+
+        prev = []
+        for (a_kind, _, _, a0, _, n), scan in run_scans(cfg.runs()):
             if a_kind == "global":
-                def layer(carry, j, f_kind=f_kind, l0=l0, a0=a0, f0=f0):
-                    x, kc, vc, counts = carry
-                    a = a0 + j
-                    lp = _layer_params(w, "global", f_kind, l0 + j, a,
-                                       f0 + j)
-                    h = _rms(x, lp["input_ln"], cfg.layernorm_epsilon)
-                    q, k, v = _qkv(cfg, lp, h, positions, cfg.rope_theta)
-                    x = x + _global_block(cfg, lp, q, k, v, kc[a], vc[a],
-                                          start, pad, first, i)
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, k[None], (a, start, 0, 0))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, v[None], (a, start, 0, 0))
-                    x, counts = _ffn(cfg, w, lp, f_kind, f0 + j, x, rows,
-                                     counts)
-                    return (x, kc, vc, counts), None
-
-                (x, kc, vc, counts), _ = jax.lax.scan(
-                    layer, (x, kc, vc, counts), idx)
+                (x, kc, vc, counts), _ = scan(global_layer,
+                                              (x, kc, vc, counts))
             else:
-                def layer(carry, xs, f_kind=f_kind, l0=l0, a0=a0, f0=f0):
-                    x, counts = carry
-                    j, pkl, pvl = xs
-                    lp = _layer_params(w, "window", f_kind, l0 + j, a0 + j,
-                                       f0 + j)
-                    h = _rms(x, lp["input_ln"], cfg.layernorm_epsilon)
-                    q, k, v = _qkv(cfg, lp, h, positions,
-                                   cfg.swa_rope_theta)
-                    o, pkl, pvl = _window_block(cfg, lp, q, k, v, pkl, pvl,
-                                                start, pad)
-                    x, counts = _ffn(cfg, w, lp, f_kind, f0 + j, x + o,
-                                     rows, counts)
-                    return (x, counts), (pkl, pvl)
+                (x, counts), kept = scan(window_layer, (x, counts),
+                                         pk[a0:a0 + n], pv[a0:a0 + n])
+                prev.append(kept)
+        pk, pv = (jnp.concatenate(kept) for kept in zip(*prev))
+        return x, (kc, vc, pk, pv, counts)
 
-                (x, counts), (pkl, pvl) = jax.lax.scan(
-                    layer, (x, counts),
-                    (idx, pk[a0:a0 + n], pv[a0:a0 + n]))
-                prev_k.append(pkl)
-                prev_v.append(pvl)
-        return (kc, vc, jnp.concatenate(prev_k), jnp.concatenate(prev_v),
-                counts, x[-1:])
-
-    kc, vc, pk, pv, counts, last = jax.lax.fori_loop(
-        first, n_blocks, run_block,
-        (jnp.zeros((ng, total, kvg, hd), dtype),
-         jnp.zeros((ng, total, kvg, hdv), dtype),
-         jnp.zeros((nw, win, kvw, hd), dtype),
-         jnp.zeros((nw, win, kvw, hdv), dtype),
-         counts, jnp.zeros((1, embed.shape[1]), dtype)))
+    (kc, vc, pk, pv, counts), last = walk_blocks(
+        window, embed,
+        lambda: (jnp.zeros((ng, total, kvg, hd), dtype),
+                 jnp.zeros((ng, total, kvg, hdv), dtype),
+                 jnp.zeros((nw, win, kvw, hd), dtype),
+                 jnp.zeros((nw, win, kvw, hdv), dtype), counts),
+        run_layers)
     logits = _logits(cfg, last, final_norm, lm_head)
     mb, bs = table_row.shape[0], kp.shape[-2]
     kc = jnp.pad(kc, ((0, 0),) * 3 + ((0, kp.shape[-1] - hd),))
@@ -482,10 +445,7 @@ def _decode_step(cfg, w, embed, final_norm, lm_head, tok, tables, lens,
         x, counts = _ffn(cfg, w, lp, f_kind, f0 + j, x + o, live, counts)
         return (x, (kp, vp, rk, rv, counts)), None
 
-    for a_kind, f_kind, l0, a0, f0, n in cfg.runs():
-        (x, pool), _ = jax.lax.scan(
-            lambda c, j, r=(a_kind, f_kind, l0, a0, f0): layer(*r, c, j),
-            (x, tuple(pool)), jnp.arange(n, dtype=jnp.int32))
+    x, pool = scan_runs(cfg.runs(), layer, (x, tuple(pool)))
     return _logits(cfg, x, final_norm, lm_head), pool
 
 
@@ -579,19 +539,8 @@ class MimoV2ForCausalLM(nn.Layer):
                                tables, lens, *pool):
             """One chunk; a slot with ``lens == 0`` holds no row: its
             tokens are routed to no expert and counted nowhere."""
-            live = lens > 0
-
-            def body(carry, i):
-                tok, pool = carry
-                logits, pool = _decode_step(cfg, stacked, embed, fnorm, lm,
-                                            tok, tables, lens + i, pool,
-                                            live)
-                nxt = jnp.argmax(logits, axis=-1)
-                return (nxt, pool), nxt
-
-            (tok, pool), toks = jax.lax.scan(body, (tok, pool),
-                                             jnp.arange(chunk))
-            return (toks, *pool)
+            return greedy_chunk(_decode_step, (cfg, stacked, embed, fnorm, lm),
+                                chunk, tok, tables, lens, pool)
 
         dtype = jnp.dtype(cfg.dtype)
         nw, kvw = cfg.count("window"), cfg.swa_num_key_value_heads

@@ -8,7 +8,8 @@ Groups:
 
 - :func:`scan_paths` — the full shared scan set every SC03+ checker
   sees: ``paddle_tpu/inference/``, ``paddle_tpu/observability/``,
-  ``paddle_tpu/distributed/watchdog.py``, ``paddle_tpu/models/llama.py``,
+  ``paddle_tpu/distributed/watchdog.py``, ``paddle_tpu/models/llama.py``
+  and the families' seam ``paddle_tpu/models/paged_stack.py``,
   ``paddle_tpu/kernels/`` and ``bench.py``;
 - :func:`timer_inference_paths` / :func:`timer_shared_clock_paths` —
   SC01's two historic tiers (inference/ bans ``time.perf_counter``;
@@ -94,6 +95,7 @@ def timer_model_paths() -> list[pathlib.Path]:
     they compute rides inside the engine's spans, so a wall clock of
     their own would fork the one the traces share."""
     return [PKG / "models" / "llama.py",
+            PKG / "models" / "paged_stack.py",
             PKG / "models" / "granite_hybrid.py",
             PKG / "models" / "mimo_v2.py"] + _glob(PKG / "kernels")
 
@@ -116,6 +118,7 @@ def scan_paths() -> list[pathlib.Path]:
         + _glob(PKG / "observability")
         + [WATCHDOG]
         + [PKG / "models" / "llama.py",
+           PKG / "models" / "paged_stack.py",
            PKG / "models" / "granite_hybrid.py",
            PKG / "models" / "mimo_v2.py"]
         + _glob(PKG / "kernels")

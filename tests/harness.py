@@ -172,6 +172,31 @@ def cold_prefill_at_blocks(model, run, tokens=696, s_max=1024):
     assert all(c["moe_stream_rows"] >= c["moe_pairs"] for c in (ca, cb))
 
 
+def paged_program_hashes(model):
+    """The first 16 digits of the sha256 of the StableHLO that a family's
+    two paged programs lower to on ``model`` (a family with per-slot
+    state, so its prefill is handed a ``slot``), in the engine shape the
+    pinned programs of every family were taken at: program -> digits.
+    ``as_text()`` carries no source locations, so code that moves
+    between functions leaves them alone; what the program computes, and
+    the order it is traced in, does not."""
+    import hashlib
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving import DecodeEngine
+    eng = DecodeEngine(model, **ENGINE_KW, prefix_cache=False)
+    st, embed, fnorm, lm = eng._weights()
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    texts = {
+        "prefill_paged": eng._prefill.lower(
+            st, embed, fnorm, lm, eng._scales, i32(1, 64), i32(1),
+            i32(eng._max_blocks), i32(), *eng._pool()).as_text(),
+        "decode_chunk_paged": eng._decode.lower(
+            st, embed, fnorm, lm, eng._scales, i32(2),
+            i32(2, eng._max_blocks), i32(2), *eng._pool()).as_text()}
+    return {name: hashlib.sha256(text.encode()).hexdigest()[:16]
+            for name, text in texts.items()}
+
+
 @contextlib.contextmanager
 def per_test_clock(nodeid, limit_s):
     """Fail the test named ``nodeid`` when ``limit_s`` seconds pass inside

@@ -29,7 +29,7 @@ from benchmark.lib import deepseek_program  # noqa: E402
 from benchmark.lib import deepseek_reference as R  # noqa: E402
 from benchmark.lib import deepseek_weights as W  # noqa: E402
 from harness import (cold_prefill_at_blocks, drive,  # noqa: E402
-                     latent_prefill_against_plain)
+                     latent_prefill_against_plain, paged_program_hashes)
 from paddle_tpu.distributed.fleet.moe import moe_route_held  # noqa: E402
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.kernels import latent_attention as LA  # noqa: E402
@@ -359,6 +359,19 @@ def pinned_case():
             == PINNED[name], name
 
 
+# ... and this family's own two on its ``debug`` preset, taken at the
+# parent of the PR that moved the block walk, the run scan, the greedy
+# chunk and the held experts' FFN out of the family modules
+OWN_PINNED = {"prefill_paged": "f0eee3ff52d02da8",
+              "decode_chunk_paged": "59490ba4ef010970"}
+
+
+def own_pinned_case():
+    m = D.DeepseekV3ForCausalLM("debug")
+    m.eval()
+    assert paged_program_hashes(m) == OWN_PINNED
+
+
 def block_case():
     """A cold prefill at blocks of 512 rows against the same prompt at
     256: the logits and the one pool's pages."""
@@ -368,7 +381,8 @@ def block_case():
 
 @pytest.mark.parametrize("case", [
     engine_case, logits_case, kernel_case, yarn_case, route_case, share_case,
-    pinned_case, block_case], ids=lambda f: f.__name__.removesuffix("_case"))
+    pinned_case, own_pinned_case, block_case],
+    ids=lambda f: f.__name__.removesuffix("_case"))
 def test_deepseek_v3(case):
     case()
 

@@ -29,6 +29,7 @@ from harness import (cold_prefill_at_blocks, drive,  # noqa: E402
                      latent_prefill_against_plain)
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.models import glm_moe_dsa as G  # noqa: E402
+from paddle_tpu.models.paged_stack import _token_insert  # noqa: E402
 
 # the reference pads a sequence to shapes it compiles once; the cell's
 # are 4096 tokens, these tests' sequences are under 64
@@ -303,8 +304,8 @@ def full_width_attention(cfg, lp, x, l, kp, vp, tables, lens):
     s = tables.shape[1] * bs
     qc, lat, qi, ki, wi = G._project(cfg, lp, x, lens)
     page = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]
-    kp = G._token_insert(kp, l, page, lens % bs, lat[:, None])
-    vp = G._token_insert(vp, l, page, lens % bs, ki[:, None])
+    kp = _token_insert(kp, l, page, lens % bs, lat[:, None])
+    vp = _token_insert(vp, l, page, lens % bs, ki[:, None])
     keys = jnp.take(vp.reshape(n_layers * n_pages, bs, vp.shape[-1]),
                     l * n_pages + tables, axis=0)
     sc = jnp.where(jnp.arange(s)[None, :] <= lens[:, None],

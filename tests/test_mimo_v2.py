@@ -22,7 +22,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from benchmark.lib import mimo_program, mimo_reference  # noqa: E402
 from benchmark.lib import mimo_weights as W  # noqa: E402
-from harness import cold_prefill_at_blocks, drive  # noqa: E402
+from harness import (cold_prefill_at_blocks, drive,  # noqa: E402
+                     paged_program_hashes)
 from paddle_tpu.distributed.fleet.moe import moe_route_held  # noqa: E402
 from paddle_tpu.inference.serving import DecodeEngine  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
@@ -298,9 +299,24 @@ def block_case():
                                             *a[-2:]))
 
 
+# sha256 of the StableHLO this family's two paged programs lower to on
+# its ``debug`` preset, taken at the parent of the PR that moved the
+# block walk, the run scan, the greedy chunk and the held experts' FFN
+# out of the family modules (models/paged_stack.py, fleet/moe.py)
+PINNED = {"prefill_paged": "cfe714433bd8298b",
+          "decode_chunk_paged": "9568685f6302c9a8"}
+
+
+def pinned_case():
+    m = M.MimoV2ForCausalLM("debug")
+    m.eval()
+    assert paged_program_hashes(m) == PINNED
+
+
 @pytest.mark.parametrize("case", [
     engine_case, stats_case, ring_case, share_case, router_case,
-    window_case, kernel_case, block_case], ids=lambda f: f.__name__)
+    window_case, kernel_case, block_case, pinned_case],
+    ids=lambda f: f.__name__)
 def test_mimo_v2(case):
     case()
 

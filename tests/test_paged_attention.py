@@ -151,8 +151,8 @@ class TestPagedKernel:
         the stacked pools lands at [l, page, :, off] and leaves every
         other layer's pages (and scales), and every other page of layer
         l, bit-identical."""
-        from paddle_tpu.models.llama import (_quantized_token_insert,
-                                             _token_insert)
+        from paddle_tpu.models.paged_stack import (_quantized_token_insert,
+                                                   _token_insert)
         _, kp, _, table, lens = _random_paged(seed=5)
         bs = kp.shape[3]
         lens = np.asarray(lens) - 1          # write cursors inside pages
@@ -477,7 +477,7 @@ class TestPagedEngine:
         token and a block and a part each equal solo ``generate``, and
         the engine counts the blocks it ran beside what whole windows
         would have been."""
-        from paddle_tpu.models.llama import prefill_block_rows
+        from paddle_tpu.models.paged_stack import prefill_block_rows
         m = shared_model()
         sizes = (31, 32, 33, 40, 9)
         prompts = make_prompts(np.random.RandomState(4), 128, sizes)
@@ -508,7 +508,7 @@ class TestPagedEngine:
         than 16, wherever the window holds two of them, else the largest
         power of two that gives two blocks."""
         from types import SimpleNamespace
-        from paddle_tpu.models.llama import prefill_block_rows
+        from paddle_tpu.models.paged_stack import prefill_block_rows
         cfg = SimpleNamespace() if experts is None else SimpleNamespace(
             num_experts_per_tok=experts[0], n_routed_experts=experts[1],
             held_experts=(0, experts[2]))

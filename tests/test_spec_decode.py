@@ -206,7 +206,7 @@ class TestInt8PagedKV:
         half the per-(page, head) scale step."""
         import jax.numpy as jnp
         from paddle_tpu.kernels.paged_attention import KV_SCALE_EPS
-        from paddle_tpu.models.llama import _quantized_token_insert
+        from paddle_tpu.models.paged_stack import _quantized_token_insert
         rng = np.random.RandomState(20)
         tok = rng.randn(2, 3, 8).astype(np.float32)
         pool = jnp.zeros((2, 4, 3, 16, 8), jnp.int8)
@@ -229,7 +229,7 @@ class TestInt8PagedKV:
         resident codes: ratio old/new == 1.0 exactly, round(q*1.0)==q."""
         import jax.numpy as jnp
         from paddle_tpu.kernels.paged_attention import KV_SCALE_EPS
-        from paddle_tpu.models.llama import _quantized_token_insert
+        from paddle_tpu.models.paged_stack import _quantized_token_insert
         rng = np.random.RandomState(21)
         big = (rng.randn(1, 2, 8) * 4).astype(np.float32)
         small = (rng.randn(1, 2, 8) * 0.01).astype(np.float32)

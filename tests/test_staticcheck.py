@@ -827,6 +827,27 @@ def test_scan_set_covers_the_stack():
         assert required in names, f"{required} fell out of the scan set"
 
 
+def test_family_modules_keep_to_the_seam():
+    """A model family writes its equations, its carry and its page
+    write; the block walk of the cold prefill is
+    ``models/paged_stack.py``'s, and what a family takes from a sibling
+    are llama's kernel wrappers and plain attention reads, nothing of the
+    seam (``PagedPrograms``, ``_row_pages``, ``_token_insert``)."""
+    import ast
+    import re
+    allowed = {"_rms", "_rope", "_attention_keymask",
+               "_attention_prefix_span"}
+    for name in ("granite_hybrid", "mimo_v2", "glm_moe_dsa", "deepseek_v3"):
+        text = (config.PKG / "models" / f"{name}.py").read_text()
+        assert not re.search(r"fori_loop\(\s*first,\s*n_blocks", text) \
+            and "def run_block" not in text, f"{name} walks blocks itself"
+        taken = {a.name for node in ast.walk(ast.parse(text))
+                 if isinstance(node, ast.ImportFrom) and node.module == "llama"
+                 for a in node.names}
+        assert taken <= allowed, f"{name} imports {taken - allowed} of llama"
+        assert ".llama." not in text and "import llama" not in text
+
+
 # -- byte-equivalence with the pre-port lints -------------------------------
 
 def _legacy_timer_offenders(paths, banned, allow_alias_def):
